@@ -471,9 +471,7 @@ class Transformer(nn.Module):
                 side=s,
             )
 
-        from ..ops.jax_compat import shard_map
-
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             body, mesh=mesh,
             in_specs=(p_specs, x_spec, side_specs, key_spec),
             out_specs=(x_spec, P()),

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 import flax.linen as nn
 
+from . import kv_policy
 from . import masks as masks_lib
 from .flash_attention import (
     StaticMask,
@@ -153,6 +154,52 @@ Dtype = Any
 NEG_INF = -0.7 * float(np.finfo(np.float32).max)
 
 
+def _per_device(kernel, args):
+    """Run ``kernel(*args)`` — a Pallas attention call — once per device of
+    the ambient mesh. XLA cannot partition a Mosaic kernel: left to
+    GSPMD/Shardy inside a multi-device jit, lowering stops with
+    "Mosaic kernels cannot be automatically partitioned. Please wrap the
+    call in a shard_map." (jax 0.9.0, first met on a four-chip v5e — the
+    CPU tier interprets the kernels, which partition like any jnp code, so
+    it never saw this). Attention is independent over batch and heads, so
+    the call is wrapped in a shard_map that splits exactly those: dim 0 of
+    every argument and of the result over the data axes (dp, fsdp) and —
+    for the (b, h, n, d) layouts — dim 1 over tp. The first argument sets
+    the layout: packed (b, n, 3*h*d) qkv, or (b, h, n, d) q.
+    The shard_map is manual over every mesh axis not already manual (inside
+    the pipeline's pp region only the remaining ones), which is what Mosaic
+    requires. A batch or head count the axes do not divide stays
+    replicated on that dim. No mesh, or one device: a plain call."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.context import active_mesh, batch_axes
+
+    mesh = active_mesh()
+    if mesh is None or mesh.size == 1:
+        return kernel(*args)
+    context = jax.sharding.get_abstract_mesh()
+    manual = frozenset(context.manual_axes)
+    free = frozenset(mesh.axis_names) - manual
+    per_head = args[0].ndim == 4
+    data = tuple(a for a in (batch_axes(mesh) or ()) if a in free)
+    if args[0].shape[0] % int(np.prod([mesh.shape[a] for a in data] or [1])):
+        data = ()
+    head = None
+    if per_head and "tp" in free and args[0].shape[1] % mesh.shape["tp"] == 0:
+        head = "tp"
+
+    def spec(x):
+        dims = [data or None] + ([head] if x.ndim == 4 else [])
+        return P(*dims, *([None] * (x.ndim - len(dims))))
+
+    # the result has the first argument's layout
+    return jax.shard_map(
+        kernel, mesh=context if manual else mesh,
+        in_specs=tuple(spec(a) for a in args), out_specs=spec(args[0]),
+        axis_names=free, check_vma=False,
+    )(*args)
+
+
 def lane_pack_enabled() -> bool:
     """Whether single-token decode sweeps may use the lane-packed
     formulation (``PatternAttention._cache_attend``). "auto" (default):
@@ -164,9 +211,7 @@ def lane_pack_enabled() -> bool:
     the pack off-TPU keeps every CPU decode path on the one shared gemm.
     ``DALLE_TPU_LANE_PACK=0|1`` forces either way (tests use 1 to
     exercise the packed math on CPU)."""
-    from .kv_policy import tpu_auto_env
-
-    return tpu_auto_env("DALLE_TPU_LANE_PACK")
+    return kv_policy.tpu_auto_env("DALLE_TPU_LANE_PACK")
 
 
 def _softmax(scores: jnp.ndarray, stable: bool, axis: int = -1) -> jnp.ndarray:
@@ -382,7 +427,7 @@ class PatternAttention(nn.Module):
                 )
                 out = out.reshape(b, n, inner)
         else:
-            from ..parallel.context import sp_extent
+            from ..parallel.context import axis_extent, sp_extent
 
             use_sp = (
                 not force_dense
@@ -428,6 +473,9 @@ class PatternAttention(nn.Module):
             # kernel's full-square compute beats any grouped formulation
             # that materializes scores in HBM (see the measurement note at
             # _pattern_attend below)
+            # (under tensor parallelism the packed (b, n, 3*h*d) layout is
+            # split over tp in contiguous thirds, not by head: those meshes
+            # take the per-head flash path below, heads over tp)
             if (
                 not use_sp
                 and not use_block_sparse
@@ -436,6 +484,7 @@ class PatternAttention(nn.Module):
                 and _flash_block(n) == n
                 and fused_qkv_supported(n, h, d)
                 and (rotary_pos_emb is None or rot_static is not None)
+                and axis_extent("tp") == 1
             ):
                 pattern = (
                     _cached_flash_mask(self, n)
@@ -445,11 +494,17 @@ class PatternAttention(nn.Module):
                     _cached_rot_slice(rot_static, n)
                     if rot_static is not None else None
                 )
-                out = fused_qkv_attention(
-                    qkv,
-                    None if mask is None else mask[:, :n],
-                    h, d, rot, self.causal, pattern, d**-0.5,
-                    jax.devices()[0].platform != "tpu",
+                interpret = kv_policy.pallas_interpret()
+                kv_policy.record_route(
+                    f"forward/{self.attn_type}", "fused_qkv_flash", interpret
+                )
+                causal, scale = self.causal, d**-0.5
+                args = (qkv,) if mask is None else (qkv, mask[:, :n])
+                out = _per_device(
+                    lambda x, km=None: fused_qkv_attention(
+                        x, km, h, d, rot, causal, pattern, scale, interpret
+                    ),
+                    args,
                 )
                 out = dense(self.dim, True, "to_out")(out)
                 return nn.Dropout(self.dropout)(out, deterministic=deterministic)
@@ -463,6 +518,9 @@ class PatternAttention(nn.Module):
                 q, k, v = (apply_rotary_emb(table, t) for t in (q, k, v))
 
             if use_sp:
+                kv_policy.record_route(
+                    f"forward/{self.attn_type}", "sequence_parallel"
+                )
                 out = self._sp_attend(q, k, v, mask, n)
             elif use_block_sparse:
                 out = self._block_sparse_attend(q, k, v, n, mask)
@@ -473,6 +531,9 @@ class PatternAttention(nn.Module):
             ):
                 out = self._flash_attend(q, k, v, n, mask)
             else:
+                kv_policy.record_route(
+                    f"forward/{self.attn_type}", "jnp_pattern_attend"
+                )
                 out = self._pattern_attend(
                     q * (d**-0.5), k, v, mask, force_dense=force_dense
                 )
@@ -489,21 +550,23 @@ class PatternAttention(nn.Module):
         regions. A runtime (b, n) key-padding mask streams through the kernel
         as a fourth operand — no dense (n, n) fallback. The non-causal full
         pattern is analytic (all blocks dense), so it carries no (n, n)
-        pattern operand either. Falls back to interpret mode off-TPU so
-        tests run anywhere."""
+        pattern operand either. Interpret mode off-TPU
+        (kv_policy.pallas_interpret) so tests run anywhere."""
         block = _flash_block(n)
         pattern = None
         if self.attn_type != "full":
             pattern = _cached_flash_mask(self, n)
-        return flash_attention(
-            q, k, v,
-            key_mask=None if mask is None else mask[:, :n],
-            causal=self.causal,
-            pattern_mask=pattern,
-            sm_scale=self.dim_head**-0.5,
-            block_q=block,
-            block_k=block,
-            interpret=jax.devices()[0].platform != "tpu",
+        interpret = kv_policy.pallas_interpret()
+        kv_policy.record_route(
+            f"forward/{self.attn_type}", "blocked_flash", interpret
+        )
+        causal, scale = self.causal, self.dim_head**-0.5
+        args = (q, k, v) if mask is None else (q, k, v, mask[:, :n])
+        return _per_device(
+            lambda q, k, v, km=None: flash_attention(
+                q, k, v, km, causal, pattern, scale, block, block, interpret
+            ),
+            args,
         )
 
     # ----------------------------------------------------- block-sparse path
@@ -518,11 +581,18 @@ class PatternAttention(nn.Module):
         from .block_sparse_attention import block_sparse_attention
 
         layout = _cached_block_layout(self, n, _sparse_block(n))
-        return block_sparse_attention(
-            q, k, v, layout,
-            key_mask=None if mask is None else mask[:, :n],
-            sm_scale=self.dim_head**-0.5,
-            interpret=jax.devices()[0].platform != "tpu",
+        interpret = kv_policy.pallas_interpret()
+        kv_policy.record_route(
+            f"forward/{self.attn_type}", "block_sparse_pair_grid", interpret
+        )
+        scale = self.dim_head**-0.5
+        args = (q, k, v) if mask is None else (q, k, v, mask[:, :n])
+        return _per_device(
+            lambda q, k, v, km=None: block_sparse_attention(
+                q, k, v, layout, key_mask=km, sm_scale=scale,
+                interpret=interpret,
+            ),
+            args,
         )
 
     # -------------------------------------------------- sequence parallelism
@@ -580,7 +650,7 @@ class PatternAttention(nn.Module):
                 and plan.layout.visited_block_frac <= ENGAGE_FRAC
                 and sparse_kernel_enabled()
             )
-            interp = jax.devices()[0].platform != "tpu"
+            interp = kv_policy.pallas_interpret()
             stable = self.stable
             sp_axis = self.sp_axis
 
@@ -603,9 +673,7 @@ class PatternAttention(nn.Module):
 
         args = (q, k, v) if mask is None else (q, k, v, mask[:, :n])
         in_specs = (qspec,) * 3 + ((mspec,) if mask is not None else ())
-        from .jax_compat import shard_map
-
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=qspec,
             check_vma=False,
         )(*args)
@@ -839,8 +907,6 @@ class PatternAttention(nn.Module):
         replacement for the inline ``b == 8`` magic branch that used to
         live here, with the full measured flat-vs-4-D history in its
         docstring)."""
-        from . import kv_policy
-
         if self.has_variable("cache", "cached_key_pages"):
             return "paged"
         if self.has_variable("cache", "cached_key"):
@@ -917,7 +983,7 @@ class PatternAttention(nn.Module):
             cached_value.value.reshape(b, L, h * d),
             idx, cos, sin, rot_p, key_mask,
             heads=h, dim_head=d, use_rotary=use_rotary,
-            interpret=jax.devices()[0].platform != "tpu",
+            interpret=kv_policy.pallas_interpret(),
         )
         upd = jax.lax.dynamic_update_slice_in_dim
         row_shape = (b, 1, h * d) if flat_kv else (b, 1, h, d)
@@ -1020,8 +1086,6 @@ class PatternAttention(nn.Module):
         format only: the flat/4d caches never consult this (their
         single-stream int8 experiment measured SLOWER — the note at the
         bottom of this file)."""
-        from . import kv_policy
-
         if self.has_variable("cache", "cached_key_scale_pages"):
             return "int8"
         if self.has_variable("cache", "cached_key_pages"):
@@ -1042,7 +1106,7 @@ class PatternAttention(nn.Module):
         copy_pages/copy_pages_across/reset_rows and the prefix-cache
         arena indirection) covers scales by construction. Returned
         scale variables are None when unquantized."""
-        from . import kv_policy, paged_kv
+        from . import paged_kv
 
         h, d, L = self.heads, self.dim_head, self.seq_len
         page = kv_policy.page_size()
@@ -1176,11 +1240,20 @@ class PatternAttention(nn.Module):
             block_len is not None
             and ragged_attention.use_kernel(causal_full, mask is not None)
         ):
+            interpret = kv_policy.pallas_interpret()
+            kv_policy.record_route(
+                f"ragged_block/{self.attn_type}", "ragged_paged_kernel",
+                interpret,
+            )
             return ragged_attention.kernel_attend(
                 q, k_pool.value, v_pool.value, table.value, idx, block_len,
-                interpret=jax.devices()[0].platform != "tpu",
+                interpret=interpret,
                 k_scales=None if k_scale is None else k_scale.value,
                 v_scales=None if v_scale is None else v_scale.value,
+            )
+        if block_len is not None:
+            kv_policy.record_route(
+                f"ragged_block/{self.attn_type}", "paged_gather_jnp"
             )
 
         k_cache = paged_kv.gather(k_pool.value, table.value)  # (b, W, h*d)
@@ -1236,6 +1309,7 @@ class PatternAttention(nn.Module):
             # off the plain gemm at some head counts, and off-TPU the
             # fused-vs-split bit-parity gates need every decode on the
             # one shared gemm below.
+            kv_policy.record_route("decode_token", "lane_packed_einsum")
             P_ = 128 // d
             G = h // P_
             eye = jnp.eye(P_, dtype=q.dtype)
@@ -1257,6 +1331,9 @@ class PatternAttention(nn.Module):
             )
             return out.reshape(b, 1, h, d)
 
+        kv_policy.record_route(
+            "decode_token" if n == 1 else "decode_block", "cache_block_attend"
+        )
         return cache_block_attend(q, k_cache, v_cache, allowed, self.stable)
 
     # Decode cost accounting (int8 serving, v5e-1, measured by trace —

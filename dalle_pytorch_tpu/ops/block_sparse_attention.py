@@ -58,8 +58,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .jax_compat import tpu_compiler_params
-
 NEG_INF = -1e30
 LANES = 128
 
@@ -376,7 +374,7 @@ def _pair_call(kernel, grid, in_specs, out_specs, out_shape, scratch, table,
         out_shape=out_shape,
         # batch*heads steps are independent; the pair dimension accumulates
         # (q-block groups are contiguous runs) so it must stay ordered
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         cost_estimate=cost,

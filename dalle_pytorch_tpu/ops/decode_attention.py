@@ -53,8 +53,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .jax_compat import tpu_compiler_params
-
 NEG_INF = -1e30
 
 # opt-in dispatch (see module docstring): flip via env or monkeypatch
@@ -245,7 +243,7 @@ def fused_decode_attention(
             jax.ShapeDtypeStruct((b, 1, h * d), k_cache.dtype),
             jax.ShapeDtypeStruct((b, 1, h * d), v_cache.dtype),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

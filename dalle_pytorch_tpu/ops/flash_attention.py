@@ -47,8 +47,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .jax_compat import tpu_compiler_params
-
 NEG_INF = -1e30
 LANES = 128
 
@@ -374,7 +372,7 @@ def _call(kernel, grid, in_specs, out_specs, out_shape, scratch, scalar, operand
         out_shape=out_shape,
         # batch*heads and outer blocks are independent; only the innermost
         # (accumulating) dimension is order-dependent — lets Mosaic pipeline
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         cost_estimate=cost,
@@ -875,7 +873,7 @@ def _call_plain(kernel, grid, in_specs, out_specs, out_shape, operands, interpre
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * len(grid),
             # the head-group backward holds several (n, n) f32 temporaries
             # at once (s, p, dp, ds); the default 16 MiB scoped-vmem budget

@@ -136,23 +136,65 @@ _QUANT_OVERRIDE: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
 )
 
 
+def on_tpu() -> bool:
+    """THE platform decision for kernel routing and Pallas lowering: jax's
+    default backend is a TPU. Every "compiled kernel or not" choice in ops/
+    resolves here — ``pallas_interpret`` for the interpret flag of every
+    ``pallas_call`` and ``tpu_auto_env`` for the opt-in kernels — so a
+    process that lost the chip (libtpu failing to initialise leaves jax on
+    the CPU with a warning) answers the same way everywhere, and one
+    assertion on this backend covers every kernel (chip_smoke.py makes it
+    in each child and then checks the lowered programs for Mosaic
+    custom calls). jax is imported lazily: this module stays import-light
+    for pure policy callers."""
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
+def pallas_interpret() -> bool:
+    """The ``interpret=`` argument of every ``pallas_call`` in ops/:
+    compiled by Mosaic on a TPU, interpreted everywhere else (the CPU
+    test tier). Read at trace time."""
+    return not on_tpu()
+
+
 def tpu_auto_env(name: str) -> bool:
     """Tri-state env gate for TPU-only optimizations: "auto" (the
-    default when the variable is unset) resolves to backend == "tpu";
+    default when the variable is unset) resolves to ``on_tpu()``;
     "1"/"0" force either way. ONE parser for every such knob —
-    ``DALLE_TPU_LANE_PACK`` (ops/attention.py:lane_pack_enabled) and
-    ``DALLE_TPU_RAGGED_KERNEL`` (ops/ragged_attention.py:use_kernel) —
-    so platform resolution and error wording cannot drift between them.
-    jax is imported lazily: only the "auto" branch needs a backend, and
-    this module stays import-light for pure policy callers."""
+    ``DALLE_TPU_LANE_PACK`` (ops/attention.py:lane_pack_enabled),
+    ``DALLE_TPU_RAGGED_KERNEL`` (ops/ragged_attention.py:use_kernel) and
+    ``DALLE_TPU_SPARSE_KERNEL``
+    (ops/block_sparse_attention.py:sparse_kernel_enabled) — so platform
+    resolution and error wording cannot drift between them."""
     v = os.environ.get(name, "auto")
     if v not in ("auto", "0", "1"):
         raise ValueError(f"{name} must be 'auto', '0' or '1', got {v!r}")
     if v == "auto":
-        import jax
-
-        return jax.devices()[0].platform == "tpu"
+        return on_tpu()
     return v == "1"
+
+
+# every distinct attention-implementation choice made at trace time this
+# process, in order: which kernel or jnp path each attention call site
+# took, and whether its Pallas kernel was compiled or interpreted. The
+# selections themselves are legitimate (pattern, shape, platform); the
+# record makes them observable, like CHOICE_LOG for the cache layout —
+# chip_smoke.py asserts the flagship's expected routes from it.
+ROUTE_LOG: list = []
+
+
+def record_route(site: str, impl: str, interpret: Optional[bool] = None) -> None:
+    """Note that attention call site ``site`` resolved to implementation
+    ``impl`` (``interpret``: the Pallas mode, None for jnp paths). Once
+    per distinct choice, also emitted on the ``dalle_tpu.kv_policy``
+    logger."""
+    entry = {"site": site, "impl": impl, "interpret": interpret}
+    if entry in ROUTE_LOG:
+        return
+    ROUTE_LOG.append(entry)
+    logger.info("attention route: %s -> %s (interpret=%s)", site, impl, interpret)
 
 
 def page_size() -> int:

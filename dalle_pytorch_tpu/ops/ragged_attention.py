@@ -58,8 +58,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .jax_compat import tpu_compiler_params
-
 NEG_INF = -1e30
 LANES = 128
 
@@ -286,7 +284,7 @@ def kernel_attend(q, k_pool, v_pool, table, start, length, interpret=False,
         ),
         out_shape=jax.ShapeDtypeStruct((b, n, hd), q.dtype),
         # rows are independent; the page dimension accumulates in order
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         cost_estimate=pl.CostEstimate(

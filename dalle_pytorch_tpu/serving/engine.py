@@ -236,7 +236,8 @@ class EngineConfig:
     # (paged_kv.dequant) — roughly HALVING the engine's largest HBM
     # tenant: ~2x concurrent slots per chip at fixed budget, ~2x
     # prefix-cache arena working set, and a faster streamed-page decode
-    # under the kv_sweep_weight_stream_hbm_roofline bound (BENCH_r01).
+    # under the kv_sweep_weight_stream_hbm_roofline bound
+    # (bench.py:decode_roofline_tokens_per_sec; not measured on a chip).
     # Parity tiers: quantized-vs-quantized holds the standing BITWISE
     # contract (cold/warm hit, split/fused, preempt replay, spec
     # decode); quantized-vs-f32 is the pinned token-agreement threshold
@@ -1071,12 +1072,9 @@ class Engine:
         if config.vitals or config.controller:
             peaks = None
             if config.cost_ledger:
-                try:
-                    peaks = vitals_mod.peaks_for(
-                        jax.devices()[0].device_kind
-                    )
-                except Exception:
-                    peaks = None
+                # None for a kind with no table entry (the gauge then
+                # reads 0.0); anything else going wrong here is a bug
+                peaks = vitals_mod.peaks_for(jax.devices()[0].device_kind)
             self.vitals = vitals_mod.Vitals(
                 window=config.vitals_window, peaks=peaks
             )

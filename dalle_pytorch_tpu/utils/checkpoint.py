@@ -3,8 +3,10 @@
 
 Plain format (reference train_dalle.py:514-519 ``torch.save`` of
 ``{hparams, vae_params, epoch, weights, opt_state, scheduler_state}``):
-one msgpack file holding json-encoded hparams plus the numpy-ified state
-pytree — readable on any host, no framework pickle.
+one msgpack payload holding json-encoded hparams plus the numpy-ified state
+pytree — readable on any host, no framework pickle. A payload above
+``PART_BYTES`` is spread over sibling part files no larger than that, and
+the file at the checkpoint's path becomes a small index naming them.
 
 Sharded format (reference DeepSpeed ``save_checkpoint`` into a ``-ds-cp/``
 dir, train_dalle.py:520-544): an orbax directory checkpoint that writes each
@@ -23,6 +25,7 @@ the previous verified save, never a poisoned restore.
 
 from __future__ import annotations
 
+import hashlib
 import json
 import shutil
 import sys
@@ -56,18 +59,82 @@ def _to_host(tree: Any) -> Any:
     return jax.tree_util.tree_map(lambda x: np.asarray(x), tree)
 
 
+# Largest file a plain save writes. Hosts cap file sizes (RLIMIT_FSIZE, a
+# scratch filesystem's own limit): the flagship's 3.6 GiB single file died
+# with EFBIG on a TPU machine whose limit lies somewhere above the 64 MiB
+# VAE checkpoint it had just accepted.
+PART_BYTES = 32 << 20
+
+_PARTS_MAGIC = b"dalle-tpu-checkpoint-parts\n"
+
+
+def _part_files(p: Path) -> set:
+    return set(p.parent.glob(p.name + ".*.part[0-9]*"))
+
+
+def _write_parts(p: Path, data: bytes) -> bytes:
+    """Write ``data`` as ``<name>.<tag>.partNNNN`` files of at most
+    ``PART_BYTES`` beside ``p`` and return the index that takes its place
+    at ``p``: the magic line, then json ``[{name, bytes, sha256}, ...]``.
+    The tag is derived from the content, so the parts of the save being
+    replaced are never overwritten — they stay valid until the index swap."""
+    view = memoryview(data)
+    chunks = [view[i:i + PART_BYTES] for i in range(0, len(view), PART_BYTES)]
+    shas = [hashlib.sha256(c).hexdigest() for c in chunks]
+    tag = hashlib.sha256("".join(shas).encode()).hexdigest()[:12]
+    index = []
+    for i, (chunk, sha) in enumerate(zip(chunks, shas)):
+        name = f"{p.name}.{tag}.part{i:04d}"
+        p.with_name(name).write_bytes(chunk)
+        index.append({"name": name, "bytes": len(chunk), "sha256": sha})
+    return _PARTS_MAGIC + json.dumps(index).encode()
+
+
+def _parts_index(p: Path) -> list:
+    """The part entries when the file at ``p`` is an index, else []."""
+    with open(p, "rb") as f:
+        if f.read(len(_PARTS_MAGIC)) != _PARTS_MAGIC:
+            return []
+        return json.loads(f.read())
+
+
+def _part(p: Path, entry: dict, checksum: bool) -> Path:
+    """The part file ``entry`` names beside ``p``; a typed refusal when it
+    is missing, torn or (with ``checksum``) corrupt."""
+    part = p.with_name(entry["name"])
+    if not part.exists():
+        reason = "missing"
+    elif part.stat().st_size != entry["bytes"]:
+        reason = "size mismatch (torn write)"
+    elif checksum and _file_sha256(part) != entry["sha256"]:
+        reason = "checksum mismatch (bit corruption)"
+    else:
+        return part
+    raise CheckpointError(f"checkpoint {p}: part {entry['name']}: {reason}")
+
+
+def _file_sha256(path: Path) -> str:
+    with open(path, "rb") as f:
+        return hashlib.file_digest(f, "sha256").hexdigest()
+
+
 def save_checkpoint(path: str, state: Any, meta: Optional[dict] = None) -> None:
-    """Plain single-file save: msgpack of {meta-json, state} with every leaf
-    a host numpy array (gathers sharded arrays — use the sharded format for
-    models that don't fit one host)."""
+    """Plain save: msgpack of {meta-json, state} with every leaf a host
+    numpy array (gathers sharded arrays — use the sharded format for models
+    that don't fit one host), in one file or, above ``PART_BYTES``, in part
+    files behind an index at ``path``."""
     payload = {
         _HEADER_KEY: json.dumps(meta or {}),
         "state": serialization.to_state_dict(_to_host(state)),
     }
     p = Path(path)
     p.parent.mkdir(parents=True, exist_ok=True)
+    data = serialization.msgpack_serialize(payload)
+    stale = _part_files(p)
+    if len(data) > PART_BYTES:
+        data = _write_parts(p, data)
     tmp = p.with_suffix(p.suffix + ".tmp")
-    tmp.write_bytes(serialization.msgpack_serialize(payload))
+    tmp.write_bytes(data)
     # invalidate any PREVIOUS save's sidecar before the content swap: a
     # crash between replace and the new sidecar must leave "no manifest"
     # (unverified but loadable), never a stale manifest describing the old
@@ -75,14 +142,31 @@ def save_checkpoint(path: str, state: Any, meta: Optional[dict] = None) -> None:
     Path(str(p) + FILE_MANIFEST_SUFFIX).unlink(missing_ok=True)
     tmp.replace(p)  # atomic: never leave a torn checkpoint
     # sha256+size sidecar, written last (single-file two-phase commit):
-    # serving loads verify against it instead of trusting the file
+    # serving loads verify against it instead of trusting the file; an
+    # index carries its parts' checksums, so the sidecar vouches for them
     write_file_manifest(p)
+    for old in stale - {p.with_name(e["name"]) for e in _parts_index(p)}:
+        old.unlink(missing_ok=True)
+
+
+def _read_payload(p: Path):
+    """The msgpack payload saved at ``p``, reassembled from its parts."""
+    index = _parts_index(p)
+    if not index:
+        return p.read_bytes()
+    buf = bytearray(sum(e["bytes"] for e in index))
+    view, at = memoryview(buf), 0
+    for e in index:
+        with open(_part(p, e, checksum=False), "rb") as f:
+            f.readinto(view[at:at + e["bytes"]])
+        at += e["bytes"]
+    return buf
 
 
 def load_checkpoint(path: str, target: Any = None) -> tuple[Any, dict]:
     """-> (state, meta). With ``target`` (a template pytree) the state is
     restored into that structure; otherwise a raw nested dict is returned."""
-    raw = serialization.msgpack_restore(Path(path).read_bytes())
+    raw = serialization.msgpack_restore(_read_payload(Path(path)))
     meta = json.loads(raw.pop(_HEADER_KEY, "{}"))
     state = raw["state"]
     if target is not None:
@@ -101,15 +185,17 @@ def check_checkpoint_file(path: str, require_manifest: bool = False) -> None:
     unless ``require_manifest``; msgpack parse errors downstream still
     surface, they are just no longer the FIRST line of defense."""
     ok, reason = verify_file_manifest(path)
-    if ok:
-        return
-    if reason == "no manifest" and not require_manifest:
+    if not ok and reason == "no manifest" and not require_manifest:
         print(
             f"WARNING: {path} has no manifest sidecar (pre-manifest save); "
             "loading unverified", file=sys.stderr,
         )
         return
-    raise CheckpointError(f"checkpoint {path}: {reason}")
+    if not ok:
+        raise CheckpointError(f"checkpoint {path}: {reason}")
+    # the sidecar vouches for the index, the index for each part
+    for e in _parts_index(Path(path)):
+        _part(Path(path), e, checksum=True)
 
 
 # ----------------------------------------------------------- sharded format

@@ -15,6 +15,7 @@ import math
 import sys
 import threading
 import time
+from pathlib import Path
 from typing import Any, Dict, List, Optional, Tuple
 
 # ------------------------------------------------------------------ labels
@@ -576,12 +577,16 @@ class MetricsLogger:
         metadata: Optional[dict] = None,
     ):
         """Upload a file as a wandb artifact (the reference's per-epoch
-        checkpoint upload, train_dalle.py:637-649 / train_vae.py:298-313);
-        no-op without an active wandb run."""
+        checkpoint upload, train_dalle.py:637-649 / train_vae.py:298-313),
+        with the part files a large plain checkpoint keeps beside its
+        index (utils/checkpoint.py); no-op without an active wandb run."""
         if not self.enabled or self._wandb is None:
             return
         artifact = self._wandb.Artifact(name, type=type, metadata=metadata or {})
         artifact.add_file(path)
+        p = Path(path)
+        for part in sorted(p.parent.glob(p.name + ".*.part[0-9]*")):
+            artifact.add_file(str(part))
         self._wandb.run.log_artifact(artifact)
 
     def finish(self):

@@ -94,7 +94,18 @@ def _engine_images(engine, dalle, prompt_row, num_images, tag, seed):
     }
     if bad:
         raise RuntimeError(f"engine failed requests: {bad}")
+    # a COMPLETED outcome says the state machine finished, not that the
+    # device computed sense: a token outside the image vocabulary or a
+    # non-finite pixel would otherwise be written out as a valid PNG
+    tokens = np.stack([results[rid].tokens for rid in ids])
+    if tokens.min() < 0 or tokens.max() >= dalle.num_image_tokens:
+        raise RuntimeError(
+            f"engine sampled tokens outside [0, {dalle.num_image_tokens}): "
+            f"min {tokens.min()}, max {tokens.max()}"
+        )
     images = np.stack([results[rid].image for rid in ids])
+    if not np.isfinite(images).all():
+        raise RuntimeError("VAE decode stage produced non-finite pixels")
     scores = None
     if engine.postdecode is not None and engine.postdecode.rerank:
         scores = np.asarray(
@@ -110,6 +121,10 @@ def main():
     import jax.numpy as jnp
     import numpy as np
     from PIL import Image
+
+    from dalle_pytorch_tpu.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from dalle_pytorch_tpu.data import ChineseTokenizer, HugTokenizer, SimpleTokenizer
     from dalle_pytorch_tpu.models import generate_image_tokens, generate_texts
@@ -152,6 +167,7 @@ def main():
         tokenizer = HugTokenizer(args.bpe_path)
     else:
         tokenizer = SimpleTokenizer(args.bpe_path)
+    print(f"tokenizer: {type(tokenizer).__name__}")
 
     clip = clip_params = None
     if args.clip_path:

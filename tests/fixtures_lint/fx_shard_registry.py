@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from lint.shard.audit import hlo_sharding_str
 from lint.shard.types import ShardEntry
 
 _PATH = "tests/fixtures_lint/fx_shard_registry.py"
@@ -47,10 +48,8 @@ def _mesh():
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    from dalle_pytorch_tpu.ops.jax_compat import shard_map
-
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs)
 
 
 def _noisy(x):
@@ -109,7 +108,7 @@ def _matmul(a, b):
 
 
 def _hlo(spec, ndim):
-    return str(NamedSharding(_mesh(), spec)._to_xla_hlo_sharding(ndim))
+    return hlo_sharding_str(NamedSharding(_mesh(), spec), ndim)
 
 
 def _jit_lower(fn, args, in_specs=None, out_specs=None):

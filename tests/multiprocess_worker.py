@@ -132,7 +132,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     # platform setup must precede the first backend-initializing jax call.
-    # Preserve inherited XLA_FLAGS (site configs may carry memory/threading
+    # Preserve inherited XLA_FLAGS (the environment may carry memory/threading
     # flags the in-pytest baseline also sees) but override the device count —
     # the pytest parent pins 8, this worker needs its own local_devices.
     kept = [
@@ -146,13 +146,13 @@ def main(argv=None):
 
     jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_threefry_partitionable", True)
-    jax.config.update(
-        "jax_compilation_cache_dir", str(REPO / "tests" / ".jax_cache")
-    )
+    sys.path.insert(0, str(REPO))
+    from dalle_pytorch_tpu.compile_cache import enable_compile_cache
+
+    enable_compile_cache(default=REPO / "tests" / ".jax_cache")
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
-    sys.path.insert(0, str(REPO))
     from dalle_pytorch_tpu.parallel import init_distributed, make_runtime
     from dalle_pytorch_tpu.utils.checkpoint import load_checkpoint, save_checkpoint
 

@@ -1,9 +1,12 @@
-"""Bench trend gate (tools/bench_trend.py, ISSUE 19): the committed
-``BENCH_r*.json`` history parses into per-metric series, the gate exits
-0 on that history, and the SEEDED regression fixture
+"""Bench trend gate (tools/bench_trend.py, ISSUE 19): a ``BENCH_r*``-style
+history parses into per-metric series, the gate exits 0 on a flat
+history, and the SEEDED regression fixture
 (tests/fixtures_bench/regression_new.jsonl) proves the red path — a
-regressed latency folded in as the newest point exits nonzero. Pure
-stdlib + subprocess; no jax."""
+regressed latency folded in as the newest point exits nonzero. The
+history is SYNTHETIC (tests/fixtures_bench/history_r0*.jsonl, passed via
+the tool's --root/--history-glob): the repo's own BENCH_r*.json records
+are frozen, pre-ledger claims and no longer a gate's input. Pure stdlib +
+subprocess; no jax."""
 
 import json
 import subprocess
@@ -14,7 +17,9 @@ import pytest
 
 REPO = Path(__file__).resolve().parent.parent
 TOOL = REPO / "tools" / "bench_trend.py"
-FIXTURE = REPO / "tests" / "fixtures_bench" / "regression_new.jsonl"
+FIXTURES = REPO / "tests" / "fixtures_bench"
+FIXTURE = FIXTURES / "regression_new.jsonl"
+HISTORY = ("--root", str(FIXTURES), "--history-glob", "history_r*.jsonl")
 
 sys.path.insert(0, str(REPO / "tools"))
 import bench_trend  # noqa: E402
@@ -123,15 +128,15 @@ def run_tool(*args):
 
 
 class TestGate:
-    def test_check_exits_zero_on_committed_history(self):
-        proc = run_tool("--check")
+    def test_check_exits_zero_on_flat_history(self):
+        proc = run_tool(*HISTORY, "--check")
         assert proc.returncode == 0, proc.stdout + proc.stderr
         summary = json.loads(proc.stdout.strip().splitlines()[-1])
         assert summary["regressed"] == 0
         assert summary["gated"] >= 1  # the gate is not vacuous
 
     def test_seeded_regression_fixture_fails_red(self):
-        proc = run_tool("--new", str(FIXTURE), "--check")
+        proc = run_tool(*HISTORY, "--new", str(FIXTURE), "--check")
         assert proc.returncode == 1, proc.stdout + proc.stderr
         assert "REGRESSION gen_latency_p50" in proc.stderr
         rows = [
@@ -146,5 +151,5 @@ class TestGate:
     def test_without_check_regression_still_exits_zero(self):
         # report-only mode never gates: the pre-flight opts in with
         # --check
-        proc = run_tool("--new", str(FIXTURE))
+        proc = run_tool(*HISTORY, "--new", str(FIXTURE))
         assert proc.returncode == 0

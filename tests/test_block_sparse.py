@@ -33,7 +33,6 @@ from dalle_pytorch_tpu.ops.block_sparse_attention import (
     reference_attend,
     sp_block_sparse_attend,
 )
-from dalle_pytorch_tpu.ops.jax_compat import shard_map
 from dalle_pytorch_tpu.parallel import make_runtime
 
 
@@ -265,7 +264,7 @@ def _sp_setup(sp, use_kernel):
             use_kernel=use_kernel, interpret=True,
         )
 
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh, in_specs=(qspec,) * 3 + (P(None, "sp"),),
         out_specs=qspec, check_vma=False,
     )

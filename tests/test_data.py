@@ -252,9 +252,11 @@ class TestMetricsLogger:
 
         f = tmp_path / "m.ckpt"
         f.write_bytes(b"x")
+        part = tmp_path / "m.ckpt.0123456789ab.part0000"
+        part.write_bytes(b"y")
         logger.log_artifact("trained-vae", str(f), metadata={"dim": 8})
         (a,) = logger._wandb.artifacts
-        assert a.name == "trained-vae" and a.files == [str(f)]
+        assert a.name == "trained-vae" and a.files == [str(f), str(part)]
         assert a.metadata == {"dim": 8}
 
     def test_noop_without_wandb(self, capsys):

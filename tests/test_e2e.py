@@ -411,12 +411,12 @@ def test_train_cli_preemption_resume(shapes_dataset, trained_vae, tmp_path):
         "--telemetry",
         "--telemetry_dir", str(tmp_path / "flight"),
     ]
+    # os.environ already carries the suite's compile-cache directory
+    # (tests/conftest.py exports it), so both phases share and warm it
     env = {
         **os.environ,
         "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-        # share the suite's persistent compile cache so both phases warm it
-        "JAX_COMPILATION_CACHE_DIR": str(REPO / "tests" / ".jax_cache"),
     }
     proc = subprocess.Popen(
         [sys.executable, str(REPO / "train_dalle.py"), *argv],
@@ -459,12 +459,7 @@ def test_train_cli_preemption_resume(shapes_dataset, trained_vae, tmp_path):
     # relaunch: the startup probe must resume from the emergency step and
     # finish; the injected NaN one step after the resume point exercises
     # the on-device skip + batch retry
-    # no persistent compile cache for the resumed process: checkpoint
-    # restore + cache deserialization in one process intermittently
-    # corrupts the allocator in this jaxlib (observed SIGABRT, 'corrupted
-    # double-linked list'); the resume pays one cold compile instead
     renv = {**env, "DALLE_TPU_FAULTS": f"nan_at_step={step + 1}"}
-    renv.pop("JAX_COMPILATION_CACHE_DIR")
     relaunch = subprocess.run(
         [sys.executable, str(REPO / "train_dalle.py"), *argv],
         cwd=tmp_path, text=True, timeout=300,

@@ -13,7 +13,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from dalle_pytorch_tpu.models import DALLE
 from dalle_pytorch_tpu.parallel import gpipe, make_runtime, stack_layer_params
-from dalle_pytorch_tpu.ops.jax_compat import shard_map
 
 
 def pp_mesh(n=4):
@@ -48,7 +47,7 @@ def test_gpipe_matches_sequential(n_micro):
     mesh = pp_mesh(stages)
     p_specs = jax.tree_util.tree_map(lambda _: P("pp"), stacked)
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             functools.partial(
                 gpipe, toy_layer, axis_name="pp", n_stages=stages,
                 n_micro=n_micro,
@@ -93,7 +92,7 @@ def test_gpipe_gradients_match_sequential():
             lambda l: l.reshape(stages, depth // stages, *l.shape[1:]), stacked
         )
         p_specs = jax.tree_util.tree_map(lambda _: P("pp"), stacked)
-        out, _ = shard_map(
+        out, _ = jax.shard_map(
             functools.partial(
                 gpipe, toy_layer, axis_name="pp", n_stages=stages, n_micro=2
             ),

@@ -169,6 +169,82 @@ class TestFileManifest:
             check_checkpoint_file(str(path), require_manifest=True)
 
 
+class TestCheckpointParts:
+    """A plain save above ``PART_BYTES`` writes no file larger than that:
+    part files behind an index at the checkpoint's path (the flagship's
+    3.6 GiB single file met EFBIG on a TPU machine with a file-size cap)."""
+
+    @pytest.fixture
+    def small_parts(self, monkeypatch):
+        from dalle_pytorch_tpu.utils import checkpoint
+
+        monkeypatch.setattr(checkpoint, "PART_BYTES", 4096)
+        return checkpoint
+
+    STATE = {"w": np.arange(5000, dtype=np.float32), "b": np.ones(7)}
+
+    def test_roundtrip_in_bounded_files(self, tmp_path, small_parts):
+        path = tmp_path / "m.ckpt"
+        small_parts.save_checkpoint(str(path), self.STATE, {"k": 1})
+        parts = sorted(tmp_path.glob("m.ckpt.*.part*"))
+        assert len(parts) == 5
+        assert all(f.stat().st_size <= 4096 for f in tmp_path.iterdir())
+        small_parts.check_checkpoint_file(str(path), require_manifest=True)
+        state, meta = small_parts.load_checkpoint(str(path))
+        assert meta == {"k": 1}
+        np.testing.assert_array_equal(state["w"], self.STATE["w"])
+        np.testing.assert_array_equal(state["b"], self.STATE["b"])
+
+    def test_survives_a_file_size_limit(self, tmp_path, small_parts):
+        """The chip machine's failure, reproduced: under RLIMIT_FSIZE one
+        file of the whole payload is EFBIG, the parts are not."""
+        import errno
+        import resource
+
+        soft, hard = resource.getrlimit(resource.RLIMIT_FSIZE)
+        resource.setrlimit(resource.RLIMIT_FSIZE, (8192, hard))
+        try:
+            small_parts.save_checkpoint(str(tmp_path / "m.ckpt"), self.STATE)
+            small_parts.PART_BYTES = 1 << 20
+            with pytest.raises(OSError) as e:
+                small_parts.save_checkpoint(str(tmp_path / "n.ckpt"), self.STATE)
+        finally:
+            resource.setrlimit(resource.RLIMIT_FSIZE, (soft, hard))
+        assert e.value.errno == errno.EFBIG
+        state, _ = small_parts.load_checkpoint(str(tmp_path / "m.ckpt"))
+        np.testing.assert_array_equal(state["w"], self.STATE["w"])
+
+    def test_corrupt_and_missing_parts_are_typed_errors(self, tmp_path, small_parts):
+        path = tmp_path / "m.ckpt"
+        small_parts.save_checkpoint(str(path), self.STATE)
+        victim = sorted(tmp_path.glob("m.ckpt.*.part*"))[2]
+        data = bytearray(victim.read_bytes())
+        data[100] ^= 0xFF
+        victim.write_bytes(bytes(data))
+        with pytest.raises(small_parts.CheckpointError, match="part0002: checksum"):
+            small_parts.check_checkpoint_file(str(path))
+        victim.write_bytes(bytes(data[:-1]))
+        with pytest.raises(small_parts.CheckpointError, match="part0002: size"):
+            small_parts.load_checkpoint(str(path))
+        victim.unlink()
+        with pytest.raises(small_parts.CheckpointError, match="part0002: missing"):
+            small_parts.check_checkpoint_file(str(path))
+
+    def test_resave_leaves_only_the_new_parts(self, tmp_path, small_parts):
+        path = tmp_path / "m.ckpt"
+        small_parts.save_checkpoint(str(path), self.STATE)
+        old = set(tmp_path.glob("m.ckpt.*.part*"))
+        small_parts.save_checkpoint(str(path), {"w": self.STATE["w"] + 1})
+        new = set(tmp_path.glob("m.ckpt.*.part*"))
+        assert new and not (new & old)
+        small_parts.check_checkpoint_file(str(path), require_manifest=True)
+        # a later save that fits one file takes its parts with it
+        small_parts.save_checkpoint(str(path), {"w": np.ones(3)})
+        assert not list(tmp_path.glob("m.ckpt.*.part*"))
+        state, _ = small_parts.load_checkpoint(str(path))
+        np.testing.assert_array_equal(state["w"], np.ones(3))
+
+
 # -------------------------------------------------------------------- retry
 
 

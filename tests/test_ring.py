@@ -19,7 +19,6 @@ from dalle_pytorch_tpu.models import DALLE
 from dalle_pytorch_tpu.ops.attention import PatternAttention, dense_attend
 from dalle_pytorch_tpu.ops.ring_attention import ring_attention, ulysses_attend
 from dalle_pytorch_tpu.parallel import activate_mesh, make_runtime
-from dalle_pytorch_tpu.ops.jax_compat import shard_map
 
 
 def sp_mesh(n=8):
@@ -55,7 +54,7 @@ def test_ring_attention_forward_parity(use_mask):
     spec = P(None, None, "sp", None)
     if use_mask:
         fn = jax.jit(
-            shard_map(
+            jax.shard_map(
                 lambda q, k, v, m: body(q, k, v, key_mask=m),
                 mesh=mesh,
                 in_specs=(spec, spec, spec, P(None, "sp")),
@@ -66,7 +65,7 @@ def test_ring_attention_forward_parity(use_mask):
         out = fn(q, k, v, km)
     else:
         fn = jax.jit(
-            shard_map(
+            jax.shard_map(
                 body, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
                 check_vma=False,
             )
@@ -88,7 +87,7 @@ def test_ring_attention_noncausal_and_masked_rows():
 
     spec = P(None, None, "sp", None)
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda q, k, v, m: ring_attention(
                 q, k, v, "sp", 8, causal=False, sm_scale=scale, key_mask=m
             ),
@@ -116,7 +115,7 @@ def test_ring_attention_gradient_parity():
     scale = d**-0.5
     spec = P(None, None, "sp", None)
 
-    ring = shard_map(
+    ring = jax.shard_map(
         functools.partial(
             ring_attention, axis_name="sp", axis_size=8, causal=True, sm_scale=scale
         ),
@@ -145,7 +144,7 @@ def test_ulysses_parity_dense():
         return dense_attend(q * scale, k, v, mask)
 
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda q, k, v, m: ulysses_attend(q, k, v, "sp", 8, attend, key_mask=m),
             mesh=mesh,
             in_specs=(spec, spec, spec, P(None, "sp")),

@@ -699,7 +699,7 @@ class TestShard:
     def test_findings_anchor_on_def_lines(self):
         res, _ = shard_fixture_result()
         f = next(x for x in res.findings if x.anchor == "fx.resharder")
-        assert f.line == 87 and f.path == f"{FX}/fx_shard_registry.py"
+        assert f.line == 86 and f.path == f"{FX}/fx_shard_registry.py"
         ghost = next(x for x in res.findings if x.anchor == "fx.ghost")
         assert ghost.path == f"{FX}/fx_shard_contract.json"
 

@@ -13,8 +13,10 @@ instead of a vibe:
 - ``--new FILE``: fold a fresh run's records (raw JSONL, or a
   BENCH_r-style JSON with a ``tail``) in as the latest point;
 - ``--check``: exit nonzero iff any gated metric REGRESSED past its
-  tolerance — the serve_smoke/chaos_soak lint pre-flight wires this in
-  so a perf regression fails red before a correctness smoke even runs.
+  tolerance. (No longer a stage of the serve_smoke/chaos_soak lint
+  pre-flight: the committed history is frozen — BENCH_r05.json is its
+  last point — and a gate over records that cannot grow guards nothing;
+  ROADMAP S0 points this tool at the ledger.)
 
 Direction is inferred per metric (latency/time/bytes/gap fall, MFU/
 throughput/accept/hit rates rise); metrics whose direction is unknown

@@ -128,8 +128,9 @@ def main(argv=None) -> int:
         # pull in jax and the audited package; the AST-only invocation
         # stays stdlib-pure and millisecond-fast. CPU-pinned: the audits
         # are abstract/host-only (eval_shape/make_jaxpr/lower + host-CPU
-        # compiles for the mesh stage) and must not grab an accelerator.
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        # compiles for the mesh stage) and must not grab an accelerator —
+        # assigned, not defaulted: a chip host may export JAX_PLATFORMS=tpu.
+        os.environ["JAX_PLATFORMS"] = "cpu"
         if args.shard:
             # the mesh audit needs a multi-device host platform (the
             # test suite's own 8-virtual-device setup); must be set

@@ -23,21 +23,24 @@ DTL151     per-entry collective budget by op kind (all-gather / all-reduce
            list at all — the silent-resharding bug class caught at lint
            time. Serving entries commit the "no collectives in serving"
            baseline ROADMAP item 1 will consciously renegotiate
-DTL152     in/out sharding-spec contract: the lowered program's actual
-           ``mhlo.sharding`` arg/result attributes vs the specs
-           ``parallel/sharding.py:params_shardings`` derives (the
+DTL152     in/out sharding-spec contract: the program's actual arg/result
+           shardings (read from jax's ``Compiled.input_shardings`` /
+           ``output_shardings``, never from MLIR attribute text) vs the
+           specs ``parallel/sharding.py:params_shardings`` derives (the
            ``:lowered`` anchor — drift between the rule engine and what
-           GSPMD is handed lives in CODE and survives --emit-contract),
+           the partitioner is handed lives in CODE and survives
+           --emit-contract),
            and the derived specs/digests vs the committed contract (the
            ``:contract`` anchor — cleared by an intentional re-emit)
 DTL153     accidental replication: a parameter the rules declare sharded
            but whose lowered sharding is fully replicated — the fsdp/tp
            memory story is fiction for that parameter. Lives in code;
            --emit-contract cannot clear it
-DTL154     in-program sharding-constraint sites (``custom_call @Sharding``
-           net of shard_map boundary markers) over the entry's budget —
-           each one a potential device-to-device reshard copy not
-           attributable to a declared spec boundary
+DTL154     in-program sharding-constraint sites
+           (``sdy.sharding_constraint``, net of jax's own all-open
+           annotations) over the entry's budget — each one a potential
+           device-to-device reshard copy not attributable to a declared
+           spec boundary
 DTL155     registry <-> contract 1:1 with stale-entry failure (the
            DTL101/102 mirror): an unregistered contract entry or an
            uncommitted registry entry both fail ``--check``
@@ -61,8 +64,8 @@ from .audit import (
     compiled_collectives,
     emit_contract,
     load_contract,
+    hlo_sharding_str,
     lowered_collectives,
-    parse_main_shardings,
     reshard_constraints,
     run_shard,
     shard_reports_only,
@@ -76,8 +79,8 @@ __all__ = [
     "compiled_collectives",
     "emit_contract",
     "load_contract",
+    "hlo_sharding_str",
     "lowered_collectives",
-    "parse_main_shardings",
     "reshard_constraints",
     "run_shard",
     "shard_reports_only",

@@ -3,17 +3,22 @@
 For every registered :class:`~.types.ShardEntry` this module
 
 * lowers the program (the entry's thunk — ``fn.lower(...)`` under the
-  entry's mesh; abstract avals, no device execution) and reads the
-  ``@main`` signature: per-argument/per-result ``mhlo.sharding``
-  attributes (what GSPMD is actually handed), explicit ``stablehlo.*``
-  collective ops, and ``custom_call @Sharding`` constraint sites net of
-  shard_map boundary markers (``@SPMDFullToShardShape`` /
-  ``@SPMDShardToFullShape``);
+  entry's mesh; abstract avals, no device execution) and counts, in the
+  lowered text, the explicit ``stablehlo.*`` collective ops and the
+  ``sdy.sharding_constraint`` sites a program declares mid-flight;
 * for ``partitioned`` entries (multi-device meshes) ALSO compiles the
   lowered program on the host-platform device mesh and counts the
   collectives in the post-SPMD-partitioning HLO — the ground truth that
-  includes every all-gather/all-reduce GSPMD *inserted*, which is
-  exactly what the lowered text cannot show.
+  includes every all-gather/all-reduce the partitioner *inserted*, which
+  is exactly what the lowered text cannot show;
+* reads per-argument/per-result shardings from jax's OWN objects —
+  ``Compiled.input_shardings`` / ``Compiled.output_shardings`` (an
+  argument jit dropped as unused comes back ``None``) — never from
+  attribute text in the MLIR: that text is a property of the lowering
+  dialect (``mhlo.sharding`` under GSPMD, ``sdy.sharding`` under Shardy)
+  and changed under this audit once already. Entries that declare
+  nothing to judge (no expected shardings, no parameter intents, not
+  partitioned) are not compiled and report no shardings.
 
 The per-entry facts are checked against the committed contract file
 (``tools/shard_contracts.json``), yielding DTL15x findings (code table
@@ -53,8 +58,6 @@ _COLLECTIVE_OPS: Tuple[Tuple[str, str], ...] = (
     ("all-to-all", "all_to_all"),
 )
 
-_ARG_RE = re.compile(r"%arg(\d+): (tensor<[^>]*>)")
-_SHARD_RE = re.compile(r'mhlo\.sharding = "([^"]*)"')
 
 
 @contextlib.contextmanager
@@ -71,100 +74,50 @@ def _pinned_compile_flags():
         jax.config.update("jax_disable_most_optimizations", prev)
 
 
-# --------------------------------------------------------------- parsing
+# ------------------------------------------------------------- shardings
 
 
-def _main_region(text: str) -> Tuple[str, str]:
-    """(argument region, result region) of the lowered module's ``@main``
-    signature. Bracket matching is quote-aware: HLO sharding strings
-    contain unbalanced ``<=`` tokens that would wreck naive depth
-    counting."""
-    start = text.find("@main(")
-    if start < 0:
-        return "", ""
-    i = start + len("@main(")
-    args, j = _balanced(text, i)
-    arrow = text.find("->", j)
-    if arrow < 0:
-        return args, ""
-    k = text.find("(", arrow)
-    newline = text.find("\n", arrow)
-    if k < 0 or (newline >= 0 and k > newline):
-        # single unparenthesized result type
-        end = newline if newline >= 0 else len(text)
-        region = text[arrow + 2:end].strip().rstrip("{").strip()
-        return args, region
-    res, _ = _balanced(text, k + 1)
-    return args, res
+def hlo_sharding_str(sharding, ndim: int) -> str:
+    """Canonical text of a jax ``Sharding`` on a rank-``ndim`` array
+    (``{replicated}``, ``{devices=[2,1]<=[2]}``, ...). THE one call into
+    jax's sharding -> HLO conversion: the registry derives its EXPECTED
+    strings through it and the audit its ACTUAL ones, so a jax that
+    renames it breaks both in this one place, loudly."""
+    return str(sharding._to_xla_hlo_sharding(ndim))
 
 
-def _balanced(text: str, i: int) -> Tuple[str, int]:
-    """Text up to the paren that closes the one just before ``i``,
-    skipping quoted strings."""
-    depth, j, in_str = 1, i, False
-    while j < len(text) and depth:
-        c = text[j]
-        if in_str:
-            if c == '"':
-                in_str = False
-        elif c == '"':
-            in_str = True
-        elif c == "(":
-            depth += 1
-        elif c == ")":
-            depth -= 1
-        j += 1
-    return text[i:j - 1], j
-
-
-def _split_top(region: str) -> List[str]:
-    """Split a type-list region on top-level commas (quote- and
-    bracket-aware; ``tensor<...>`` angle brackets carry no commas, and
-    sharding strings are inside quotes)."""
-    out, buf, depth, in_str = [], "", 0, False
-    for c in region:
-        if in_str:
-            buf += c
-            if c == '"':
-                in_str = False
-            continue
-        if c == '"':
-            in_str = True
-            buf += c
-            continue
-        if c in "([{":
-            depth += 1
-        elif c in ")]}":
-            depth -= 1
-        if c == "," and depth == 0:
-            out.append(buf)
-            buf = ""
-        else:
-            buf += c
-    if buf.strip():
-        out.append(buf)
-    return out
-
-
-def parse_main_shardings(
-    text: str,
+def compiled_shardings(
+    lowered, compiled,
 ) -> Tuple[List[Optional[str]], List[Optional[str]]]:
-    """Per-argument and per-result ``mhlo.sharding`` strings (None when
-    the attribute is absent) from the lowered ``@main`` signature."""
-    arg_region, res_region = _main_region(text)
-    matches = list(_ARG_RE.finditer(arg_region))
-    args: List[Optional[str]] = []
-    for k, m in enumerate(matches):
-        seg_end = (matches[k + 1].start() if k + 1 < len(matches)
-                   else len(arg_region))
-        seg = arg_region[m.start():seg_end]
-        sh = _SHARD_RE.search(seg)
-        args.append(sh.group(1) if sh else None)
-    outs: List[Optional[str]] = []
-    for seg in _split_top(res_region):
-        sh = _SHARD_RE.search(seg)
-        outs.append(sh.group(1) if sh else None)
-    return args, outs
+    """Per-argument and per-result sharding strings of a compiled
+    program, flattened in argument order. ``None`` marks an argument jit
+    dropped as unused (``keep_unused=False`` is the production default —
+    the canonical loss ignores its rng): it never reaches the program, so
+    it is neither sharded nor replicated."""
+    import jax
+
+    def flat(tree):
+        return jax.tree_util.tree_leaves(tree, is_leaf=lambda x: x is None)
+
+    args, kwargs = compiled.input_shardings
+    infos = jax.tree_util.tree_leaves(lowered.args_info)
+    in_sh = flat(args) + flat(kwargs)
+    assert len(in_sh) == len(infos), (len(in_sh), len(infos))
+    ins = [
+        None if sh is None else hlo_sharding_str(sh, len(info.shape))
+        for sh, info in zip(in_sh, infos)
+    ]
+    out_sh = flat(compiled.output_shardings)
+    out_infos = jax.tree_util.tree_leaves(lowered.out_info)
+    assert len(out_sh) == len(out_infos), (len(out_sh), len(out_infos))
+    outs = [
+        None if sh is None else hlo_sharding_str(sh, len(info.shape))
+        for sh, info in zip(out_sh, out_infos)
+    ]
+    return ins, outs
+
+
+# --------------------------------------------------------------- counting
 
 
 def lowered_collectives(text: str) -> Dict[str, int]:
@@ -193,36 +146,32 @@ def compiled_collectives(text: str) -> Dict[str, int]:
     return out
 
 
-_SHARDING_SITE_RE = re.compile(
-    r"(%[\w.#]+)\s*=\s*stablehlo\.custom_call @Sharding\("
-    r'[^)]*\)\s*\{backend_config = "([^"]*)"'
-)
-_SPMD_MARKER_RE = re.compile(
-    r"@SPMD(?:FullToShardShape|ShardToFullShape)\((%[\w.#]+)"
+_CONSTRAINT_RE = re.compile(
+    r"sdy\.sharding_constraint\s+%[\w.#]+\s+<@(\w+),\s*\[([^\]]*)\]>"
 )
 
 
 def reshard_constraints(text: str) -> int:
-    """In-program ``@Sharding`` constraint sites NOT attributable to a
-    shard_map boundary. A boundary ``@Sharding``'s SSA result is consumed
-    directly by a ``@SPMDFullToShardShape``/``@SPMDShardToFullShape``
-    marker (jax's shard_map lowering emits the pair on every operand and
-    result, in full-manual and partial-manual mode alike) — those are
-    declared spec boundaries. Markers with a non-empty
-    ``unspecified_dims`` backend config are jax's internal partial-
-    sharding annotations (key arrays, partial-manual operands), not
-    programmer constraints, and are excluded too. What remains is the
-    ``with_sharding_constraint``-shaped reshard point a program declares
-    mid-flight — each one a potential device-to-device copy, so the
-    count is contract-budgeted (DTL154)."""
-    boundary_values = set(_SPMD_MARKER_RE.findall(text))
+    """In-program ``sdy.sharding_constraint`` sites a PROGRAMMER declared:
+    the ``with_sharding_constraint``-shaped reshard point mid-flight —
+    each one a potential device-to-device copy, so the count is
+    contract-budgeted (DTL154). shard_map boundaries are not constraint
+    ops under Shardy (``sdy.manual_computation`` carries its specs as
+    attributes), so nothing needs netting out for them. What IS excluded
+    are jax's own annotations, which can move nothing: those on the
+    ``@empty_mesh`` (PRNG key data), and those inside partial-manual
+    regions that name no mesh axis and leave a dimension open (``{?}``)
+    or have no dimension at all. A constraint counts when it sits on a
+    real mesh and names an axis, or pins every dimension closed (an
+    explicit ``P()`` replication)."""
     n = 0
-    for value, backend_config in _SHARDING_SITE_RE.findall(text):
-        if backend_config:
+    for mesh, dims in _CONSTRAINT_RE.findall(text):
+        if mesh == "empty_mesh":
             continue
-        if value in boundary_values:
-            continue
-        n += 1
+        names_axis = '"' in dims
+        all_closed = bool(dims.strip()) and "?" not in dims
+        if names_axis or all_closed:
+            n += 1
     return n
 
 
@@ -241,41 +190,29 @@ def _spec_repr(spec) -> str:
 def audit_shard_entry(ep: ShardEntry) -> Dict[str, Any]:
     """Lower (and for multi-device meshes compile) one entry; return the
     per-entry report the checkers and ``--emit-contract`` consume."""
+    judged = bool(ep.in_shardings or ep.out_shardings or ep.param_intents)
+    actual_in: List[Optional[str]] = []
+    actual_out: List[Optional[str]] = []
     with _pinned_compile_flags():
         lowered = ep.lower()
         text = lowered.as_text()
         explicit = lowered_collectives(text)
+        compiled = lowered.compile() if ep.partitioned or judged else None
         if ep.partitioned:
             level = "partitioned"
-            collectives = compiled_collectives(
-                lowered.compile().as_text()
-            )
+            collectives = compiled_collectives(compiled.as_text())
         else:
             level = "lowered"
             collectives = dict(explicit)
+        if compiled is not None:
+            actual_in, actual_out = compiled_shardings(lowered, compiled)
 
-    actual_in, actual_out = parse_main_shardings(text)
-    # jit drops unused args from the lowered module (keep_unused=False is
-    # the production default — the canonical loss ignores its rng, so
-    # that key never reaches @main); align the EXPECTED per-arg list
-    # through the lowering's kept-variable indices
     arg_paths = list(ep.arg_paths)
     in_expected = list(ep.in_shardings)
-    pos_of = {i: i for i in range(len(actual_in))}
-    if in_expected and len(in_expected) != len(actual_in):
-        try:
-            kept = sorted(lowered._lowering.compile_args["kept_var_idx"])
-        except (AttributeError, KeyError, TypeError):
-            kept = None
-        if kept is not None and len(kept) == len(actual_in) \
-                and (not kept or kept[-1] < len(in_expected)):
-            arg_paths = [ep.arg_paths[i] for i in kept]
-            in_expected = [ep.in_shardings[i] for i in kept]
-            pos_of = {orig: p for p, orig in enumerate(kept)}
-    # the intent->arg join is only sound when expected and lowered args
-    # line up 1:1; when they don't (kept_var_idx unavailable on a future
-    # jax), the <arity> DTL152 mismatch below fails the gate LOUDLY and
-    # DTL153 must stay silent rather than misjoin to the wrong args
+    # the intent->arg join is only sound when expected and actual args
+    # line up 1:1; when they don't, the <arity> DTL152 mismatch below
+    # fails the gate LOUDLY and DTL153 must stay silent rather than
+    # misjoin to the wrong args
     intents_judgeable = (not ep.in_shardings
                          or len(in_expected) == len(actual_in))
 
@@ -288,8 +225,9 @@ def audit_shard_entry(ep: ShardEntry) -> Dict[str, Any]:
                 f"{len(actual_in)} args",
             ))
         for path, exp, act in zip(arg_paths, in_expected, actual_in):
-            if exp is not None and act != exp:
-                in_mismatches.append((path, exp, act or "<none>"))
+            # act None: jit dropped the argument — nothing to compare
+            if exp is not None and act is not None and act != exp:
+                in_mismatches.append((path, exp, act))
     if ep.out_shardings:
         if len(ep.out_shardings) != len(actual_out):
             out_mismatches.append((
@@ -300,19 +238,19 @@ def audit_shard_entry(ep: ShardEntry) -> Dict[str, Any]:
             if exp is not None and act != exp:
                 out_mismatches.append((path, exp, act or "<none>"))
 
-    # DTL153: rule-engine intent said "sharded", the lowered program says
+    # DTL153: rule-engine intent said "sharded", the compiled program says
     # "fully replicated" — join on the flattened argument index. An arg
-    # jit DROPPED (absent from pos_of) never reaches @main at all: that
-    # is unused, not replicated — skip it rather than misreport.
+    # jit DROPPED (None) never reaches the program at all: that is
+    # unused, not replicated — skip it rather than misreport.
     replicated_intents: List[Dict[str, Any]] = []
     for intent in ep.param_intents:
         if not intents_judgeable or not intent.get("intent_sharded"):
             continue
-        pos = pos_of.get(intent.get("arg"))
+        pos = intent.get("arg")
         if pos is None or pos >= len(actual_in):
             continue
         act = actual_in[pos]
-        if act is None or "replicated" in act or "maximal" in act:
+        if act is not None and ("replicated" in act or "maximal" in act):
             replicated_intents.append(intent)
 
     param_specs = {
@@ -330,13 +268,16 @@ def audit_shard_entry(ep: ShardEntry) -> Dict[str, Any]:
         "collectives": collectives,
         "explicit_collectives": explicit,
         "reshard_constraints": reshard_constraints(text),
-        "in_args": len(actual_in),
+        "in_args": sum(1 for s in actual_in if s is not None),
         "out_vals": len(actual_out),
         "sharded_in_args": sum(
             1 for s in actual_in
             if s is not None and "replicated" not in s and "maximal" not in s
         ),
-        "in_sharding_digest": _digest(actual_in),
+        # over the args that reach the program (dropped ones carry none)
+        "in_sharding_digest": _digest(
+            [s for s in actual_in if s is not None]
+        ),
         "out_sharding_digest": _digest(actual_out),
         "in_mismatches": in_mismatches,
         "out_mismatches": out_mismatches,
@@ -382,7 +323,13 @@ def emit_contract(reports: List[Dict[str, Any]]) -> Dict[str, Any]:
                 k: r["param_specs"][k] for k in sorted(r["param_specs"])
             },
         }
-    return {"version": 1, "entries": entries}
+    import jax
+
+    # the counts are a property of (program, partitioner): a jax upgrade
+    # may legitimately move them — the stamp says which jax the committed
+    # numbers describe, so a red gate after an upgrade reads as "re-emit
+    # and review", not as a regression in the program
+    return {"version": 1, "jax_version": jax.__version__, "entries": entries}
 
 
 def check_reports(

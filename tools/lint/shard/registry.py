@@ -66,11 +66,13 @@ def _flat_paths_and_specs(tree, shardings):
     abstract arg/out pytree and its matching sharding pytree."""
     import jax
 
+    from lint.shard.audit import hlo_sharding_str
+
     path_leaves = jax.tree_util.tree_flatten_with_path(tree)[0]
     sh_leaves = jax.tree_util.tree_flatten(shardings)[0]
     paths = [jax.tree_util.keystr(kp) for kp, _ in path_leaves]
     expected = [
-        str(s._to_xla_hlo_sharding(len(leaf.shape)))
+        hlo_sharding_str(s, len(leaf.shape))
         for (kp, leaf), s in zip(path_leaves, sh_leaves)
     ]
     return paths, expected

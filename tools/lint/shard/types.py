@@ -21,9 +21,9 @@ runs under and everything the sharding audit needs to judge it:
 * ``arg_paths``/``in_shardings`` (and the ``out_*`` twins) are the
   flattened per-argument tree paths and EXPECTED HLO sharding strings
   the registry derives from ``parallel/sharding.py`` — the audit
-  compares them 1:1 against the ``mhlo.sharding`` attributes of the
-  lowered ``@main`` signature (DTL152). Empty sequences skip the check
-  (the 1-device serving entries);
+  compares them 1:1 against the compiled program's own
+  ``input_shardings``/``output_shardings`` (DTL152). Empty sequences
+  skip the check (the 1-device serving entries);
 * ``param_intents`` is the :func:`parallel.sharding.spec_report` list
   for the parameter leaves (with ``"arg"`` indices into the flattened
   argument list), feeding the DTL153 accidental-replication check.

@@ -71,17 +71,16 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# a CPU gate (tiny dim_head=8 models no kernel compiles for): assigned, not
+# defaulted — on a chip host that exports JAX_PLATFORMS=tpu this process
+# and the lint children it spawns would otherwise open the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 
 def lint_preflight(label: str = "serve smoke") -> int:
-    """Static-analysis + trend pre-flight (docs/DESIGN.md §11), all four
-    stages in escalation order: first the AST stage alone (``lint.py
-    --check`` — stdlib-only, so a corrupt tree still fails in
-    milliseconds), then the bench TREND gate (``bench_trend.py --check``
-    — also stdlib-only: the committed BENCH_r*.json history must hold
-    its per-metric tolerances, so a perf regression fails red before a
-    correctness smoke even runs; ISSUE 19), then the TRACE + SHARD
+    """Static-analysis pre-flight (docs/DESIGN.md §11), in escalation
+    order: first the AST stage alone (``lint.py --check`` — stdlib-only,
+    so a corrupt tree still fails in milliseconds), then the TRACE + SHARD
     composition (``lint.py --trace --shard --check``, one subprocess —
     the CLI composes both contract stages in one exit code, so the
     preflight pays one jax+package import, not two): every serving jit
@@ -93,12 +92,13 @@ def lint_preflight(label: str = "serve smoke") -> int:
     request is admitted. Subprocesses on purpose: the AST stage must
     not inherit this process's jax initialization, and the contract
     stages re-import the package fresh so a broken import fails the
-    gate, not the drill."""
+    gate, not the drill. (tools/bench_trend.py is no longer a stage: the
+    BENCH_r*.json history it read will never grow again, and a gate over
+    frozen records guards nothing — ROADMAP S0 points it at the ledger.)"""
     import subprocess
 
     for stage, script, args in (
         ("lint", "lint.py", ["--check"]),
-        ("bench-trend", "bench_trend.py", ["--check"]),
         ("contract-lint", "lint.py", ["--trace", "--shard", "--check"]),
     ):
         proc = subprocess.run(

@@ -59,7 +59,8 @@ REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 sys.path.insert(0, str(REPO / "tools"))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# a CPU gate: assigned, not defaulted (tools/serve_smoke.py)
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 
 def _non_postmortem_unclosed(path, summary) -> list:

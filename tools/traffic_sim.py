@@ -14,9 +14,10 @@ cross-validate each other:
   built from the SAME scheduler primitives the real engine uses
   (``Scheduler``/``PagePool``/``TokenBudget``/``pages_for``), replacing
   only the device work with a per-iteration cost distribution
-  calibrated from committed BENCH records (~1.0 ms/token bf16 decode on
-  v5e, BENCH_r04 / ROADMAP). This is what reaches 100k+ requests in
-  seconds.
+  set to the order of the last chip record (a round 1.0 ms/token; the
+  pre-PR-1 docs/BENCH_LATEST.jsonl has 0.901 ms/token bf16 batch-1
+  decode on v5e) — a modelled constant, not a measurement of the
+  engine. This is what reaches 100k+ requests in seconds.
 * **fidelity lane** — the real tiny-model engine fleet on a
   ``FakeClock``, thousands of requests, asserting the modeled lane's
   predicted shed fraction / p99 TTFT / occupancy trajectory within the
@@ -74,7 +75,8 @@ REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 sys.path.insert(0, str(REPO / "tools"))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# a CPU tool: assigned, not defaulted (tools/serve_smoke.py)
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import random
 
@@ -100,9 +102,10 @@ _RETRIABLE = (RejectReason.QUEUE_FULL, RejectReason.NO_REPLICA)
 @dataclass(frozen=True)
 class IterationCostModel:
     """Virtual cost of one engine scheduling iteration in the modeled
-    lane. Defaults are calibrated from the committed accelerator
-    records: decode ~1.0 ms/token bf16 on v5e (BENCH_r04; ROADMAP
-    "decode at ~1.0 ms/token"), prefill amortized well under decode
+    lane. Defaults are round numbers of the order of the last chip
+    record: decode 1.0 ms/token bf16 (docs/BENCH_LATEST.jsonl, which
+    predates the engine, has 0.901 at batch 1 on v5e), prefill
+    amortized well under decode
     (compute-bound batch processing of the whole chunk — the 0.9
     ms/token batch-1 decode figure in DESIGN §6 bounds it above), plus
     a fixed per-iteration dispatch overhead. ``jitter_frac`` draws
@@ -1556,7 +1559,8 @@ def _mode_record(mode: str, seed: int) -> dict:
             "decode_ms_per_token": IterationCostModel.decode_ms_per_token,
             "prefill_ms_per_token": IterationCostModel.prefill_ms_per_token,
             "fixed_overhead_ms": IterationCostModel.fixed_overhead_ms,
-            "source": "BENCH_r04 / ROADMAP: ~1.0 ms/token bf16 decode v5e",
+            "source": "modelled constant, order of docs/BENCH_LATEST.jsonl "
+                      "(0.901 ms/token bf16 batch-1 decode, v5e, pre-engine)",
         },
     }
 

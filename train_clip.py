@@ -64,6 +64,10 @@ def parse_args():
 def main():
     args = parse_args()
 
+    from dalle_pytorch_tpu.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from dalle_pytorch_tpu.data import (
         ChineseTokenizer,
         DataLoader,

@@ -202,6 +202,10 @@ def pick_tokenizer(args):
 def main():
     args = parse_args()
 
+    from dalle_pytorch_tpu.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from dalle_pytorch_tpu.data import DataLoader, TarImageTextDataset, TarLoader, TextImageDataset
     from dalle_pytorch_tpu.models import DALLE, DiscreteVAE, generate_images
     from dalle_pytorch_tpu.models.factory import (
@@ -387,7 +391,8 @@ def main():
     n_params = sum(int(np.prod(p.shape)) for p in jax.tree_util.tree_leaves(params))
     logger.log_text(
         f"DALLE {n_params:,} params | seq {dalle.total_seq_len} | "
-        f"mesh {dict(runtime.mesh.shape)}"
+        f"mesh {dict(runtime.mesh.shape)} | "
+        f"tokenizer {type(tokenizer).__name__}"
     )
 
     optimizer = optax.chain(

@@ -56,6 +56,10 @@ def parse_args():
 def main():
     args = parse_args()
 
+    from dalle_pytorch_tpu.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from dalle_pytorch_tpu.data import DataLoader, ImageFolderDataset
     from dalle_pytorch_tpu.models import DiscreteVAE
     from dalle_pytorch_tpu.models.factory import save_vae_checkpoint
