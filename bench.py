@@ -2230,9 +2230,7 @@ def bench_serve_control(on_cpu: bool, seed: int = 0):
         (grant geometry never re-traces), and every request still
         completes (the head-of-line floor).
 
-    The record's value is the spec channel's width drop; the cost-ledger
-    entries the vitals layer charged during warmup (lowered-module FLOPs
-    and bytes, no extra backend compile) ride along as fields."""
+    The record's value is the spec channel's width drop."""
     from dalle_pytorch_tpu.models import DALLE
     from dalle_pytorch_tpu.serving import (
         ControlConfig, Engine, EngineConfig, FakeClock, Outcome, Request,
@@ -2271,11 +2269,11 @@ def bench_serve_control(on_cpu: bool, seed: int = 0):
         eng = Engine(dalle, params, EngineConfig(
             max_batch=2, prefill_chunk=2, fused_iteration=True,
             spec_decode=True, spec_k=spec_k, spec_draft_depth=1,
-            controller=controller, cost_ledger=controller,
+            controller=controller,
             control=ControlConfig(interval=4) if controller else None,
         ), clock=FakeClock(step_dt=1.0))
-        # warm both signature classes + slot indices (and, controller-on,
-        # charge the cost ledger) outside the measured trace
+        # warm both signature classes + slot indices outside the measured
+        # trace
         for i in range(2):
             eng.submit(Request(
                 request_id=f"__warm{i}__",
@@ -2322,7 +2320,6 @@ def bench_serve_control(on_cpu: bool, seed: int = 0):
         np.array_equal(toks_on[rid], toks_off[rid]) for rid in toks_off
     ), "controller-on tokens diverged from controller-off (f32 parity)"
     spec_vitals = eng_on.vitals.snapshot()
-    ledger = eng_on.vitals.ledger.snapshot() if eng_on.vitals.ledger else {}
 
     # ---- budget channel: virtual-time interference ----
     cc = ControlConfig(interval=2, gap_high_s=0.5)
@@ -2379,7 +2376,6 @@ def bench_serve_control(on_cpu: bool, seed: int = 0):
         "controller_on_tokens_bit_identical_to_off": True,  # asserted
         "compiles_in_trace": compiles_trace,
         "jit_recompiles_in_trace": sig_trace,
-        "cost_ledger": ledger,
         "budget_default": budget_default,
         "budget_min_under_interference": budget_min,
         "budget_floor": floor,

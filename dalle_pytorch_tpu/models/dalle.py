@@ -213,11 +213,13 @@ class DALLE(nn.Module):
         img = jnp.ones((b, self.image_seq_len), dtype=bool)
         return jnp.concatenate((bos, mask, img), axis=1)[:, :n]
 
+    @jax.named_scope("head_loss")
     def _head(self, out: jnp.ndarray) -> jnp.ndarray:
         if self.stable:
             out = divide_max(out)
         return self.to_logits(self.final_norm(out)).astype(jnp.float32)
 
+    @jax.named_scope("head_loss")
     def _head_image(self, out: jnp.ndarray) -> jnp.ndarray:
         """Image-vocab-only head: the ``[ext:]`` column slice of the
         ``to_logits`` matvec, for decode steps that can only emit image
@@ -258,25 +260,26 @@ class DALLE(nn.Module):
         assert text.shape[-1] == self.text_seq_len, (
             f"text length {text.shape[-1]} != text_seq_len {self.text_seq_len}"
         )
-        text = self.remap_text(text)
-        tokens = self.text_emb(text)
-        if not self.rotary_emb:
-            tokens = tokens + self.text_pos_emb(jnp.arange(self.text_len_internal))[None]
-
-        if image is not None and image.shape[1] > 0:
-            image_tokens = self.image_emb(image)
+        with jax.named_scope("embed"):
+            text = self.remap_text(text)
+            tokens = self.text_emb(text)
             if not self.rotary_emb:
-                image_tokens = image_tokens + self.image_pos_emb(
-                    image_tokens.shape[1]
-                ).astype(image_tokens.dtype)
-            tokens = jnp.concatenate((tokens, image_tokens), axis=1)
+                tokens = tokens + self.text_pos_emb(jnp.arange(self.text_len_internal))[None]
 
-        # drop the trailing token: it never predicts anything
-        if tokens.shape[1] > self.total_seq_len:
-            tokens = tokens[:, : self.total_seq_len]
-        n = tokens.shape[1]
+            if image is not None and image.shape[1] > 0:
+                image_tokens = self.image_emb(image)
+                if not self.rotary_emb:
+                    image_tokens = image_tokens + self.image_pos_emb(
+                        image_tokens.shape[1]
+                    ).astype(image_tokens.dtype)
+                tokens = jnp.concatenate((tokens, image_tokens), axis=1)
 
-        x = tokens.astype(self.dtype)
+            # drop the trailing token: it never predicts anything
+            if tokens.shape[1] > self.total_seq_len:
+                tokens = tokens[:, : self.total_seq_len]
+            n = tokens.shape[1]
+
+            x = tokens.astype(self.dtype)
         if self.sp_axis is not None and not self.is_initializing():
             from ..parallel.context import constrain_seq_sharded
 
@@ -286,27 +289,27 @@ class DALLE(nn.Module):
             mask=self._full_key_mask(mask, n),
             deterministic=deterministic,
         )
-        if self.stable:
-            out = divide_max(out)
-        normed = self.final_norm(out)
-
-        if not return_loss:
+        if return_loss:
+            if self.serve_quant:
+                raise ValueError(
+                    "serve_quant is an inference-only mode (int8 kernels receive "
+                    "no meaningful gradients); train with serve_quant=False and "
+                    "quantize the checkpoint via utils/quantize.py"
+                )
+            assert image is not None, "when training, image tokens must be supplied"
+            assert image.shape[1] == self.image_seq_len, (
+                f"the loss needs the full image sequence, got {image.shape[1]} of "
+                f"{self.image_seq_len} tokens"
+            )
+        with jax.named_scope("head_loss"):
+            if self.stable:
+                out = divide_max(out)
+            normed = self.final_norm(out)
+            if return_loss:
+                return self._split_head_loss(normed, text, image)
             logits = self.to_logits(normed)  # compute dtype
             lmask = jnp.asarray(self.logits_mask_np()[:n])[None]
             return jnp.where(lmask, NEG_INF, logits.astype(jnp.float32))
-
-        if self.serve_quant:
-            raise ValueError(
-                "serve_quant is an inference-only mode (int8 kernels receive "
-                "no meaningful gradients); train with serve_quant=False and "
-                "quantize the checkpoint via utils/quantize.py"
-            )
-        assert image is not None, "when training, image tokens must be supplied"
-        assert image.shape[1] == self.image_seq_len, (
-            f"the loss needs the full image sequence, got {image.shape[1]} of "
-            f"{self.image_seq_len} tokens"
-        )
-        return self._split_head_loss(normed, text, image)
 
     def _split_head_loss(self, normed, text, image):
         """Weighted split CE with a block-diagonal head.
@@ -373,9 +376,10 @@ class DALLE(nn.Module):
         assert T <= self.text_len_internal, (
             f"prefill covers text positions only, got {T} > {self.text_len_internal}"
         )
-        emb = self.text_emb(tokens)
-        if not self.rotary_emb:
-            emb = emb + self.text_pos_emb(jnp.arange(T))[None]
+        with jax.named_scope("embed"):
+            emb = self.text_emb(tokens)
+            if not self.rotary_emb:
+                emb = emb + self.text_pos_emb(jnp.arange(T))[None]
 
         out = self.transformer(
             emb.astype(self.dtype),
@@ -437,9 +441,10 @@ class DALLE(nn.Module):
             f"{self.text_len_internal}"
         )
         start = jnp.asarray(start, jnp.int32)
-        emb = self.text_emb(tokens)
-        if not self.rotary_emb:
-            emb = emb + self.text_pos_emb(start + jnp.arange(c))[None]
+        with jax.named_scope("embed"):
+            emb = self.text_emb(tokens)
+            if not self.rotary_emb:
+                emb = emb + self.text_pos_emb(start + jnp.arange(c))[None]
 
         out = self.transformer(
             emb.astype(self.dtype),
@@ -538,23 +543,24 @@ class DALLE(nn.Module):
         pos = start[:, None] + jnp.arange(n, dtype=jnp.int32)[None]  # (b, n)
         is_text = pos < self.text_len_internal
 
-        text_tok = jnp.clip(tokens, 0, self.num_text_tokens_ext - 1)
-        img_tok = jnp.clip(tokens, 0, self.num_image_tokens - 1)
-        emb = jnp.where(
-            is_text[..., None], self.text_emb(text_tok), self.image_emb(img_tok)
-        )
-        if not self.rotary_emb:
-            tpos = jnp.clip(pos, 0, self.text_len_internal - 1)
-            ipos = jnp.clip(
-                pos - self.text_len_internal, 0, self.image_seq_len - 1
+        with jax.named_scope("embed"):
+            text_tok = jnp.clip(tokens, 0, self.num_text_tokens_ext - 1)
+            img_tok = jnp.clip(tokens, 0, self.num_image_tokens - 1)
+            emb = jnp.where(
+                is_text[..., None], self.text_emb(text_tok), self.image_emb(img_tok)
             )
-            img_grid = self.image_pos_emb(self.image_seq_len)
-            pe = jnp.where(
-                is_text[..., None],
-                self.text_pos_emb(tpos),
-                jnp.take(img_grid[0], ipos, axis=0),
-            )
-            emb = emb + pe.astype(emb.dtype)
+            if not self.rotary_emb:
+                tpos = jnp.clip(pos, 0, self.text_len_internal - 1)
+                ipos = jnp.clip(
+                    pos - self.text_len_internal, 0, self.image_seq_len - 1
+                )
+                img_grid = self.image_pos_emb(self.image_seq_len)
+                pe = jnp.where(
+                    is_text[..., None],
+                    self.text_pos_emb(tpos),
+                    jnp.take(img_grid[0], ipos, axis=0),
+                )
+                emb = emb + pe.astype(emb.dtype)
 
         out = self.transformer(
             emb.astype(self.dtype),
@@ -632,31 +638,32 @@ class DALLE(nn.Module):
         ragged = jnp.ndim(pos) == 1
         is_text = pos < self.text_len_internal
 
-        text_tok = jnp.clip(token, 0, self.num_text_tokens_ext - 1)
-        img_tok = jnp.clip(token, 0, self.num_image_tokens - 1)
-        emb = jnp.where(
-            is_text[:, None] if ragged else is_text,
-            self.text_emb(text_tok), self.image_emb(img_tok),
-        )
-        if not self.rotary_emb:
-            tpos = jnp.clip(pos, 0, self.text_len_internal - 1)
-            ipos = jnp.clip(pos - self.text_len_internal, 0, self.image_seq_len - 1)
-            img_grid = self.image_pos_emb(self.image_seq_len)
-            if ragged:
-                # per-sequence positions (continuous batching): the learned
-                # tables become row gathers — (b,) indices -> (b, dim)
-                pe = jnp.where(
-                    is_text[:, None],
-                    self.text_pos_emb(tpos),
-                    jnp.take(img_grid[0], ipos, axis=0),
-                )
-            else:
-                pe = jnp.where(
-                    is_text,
-                    self.text_pos_emb(tpos)[None],
-                    jax.lax.dynamic_slice_in_dim(img_grid[0], ipos, 1, axis=0),
-                )
-            emb = emb + pe.astype(emb.dtype)
+        with jax.named_scope("embed"):
+            text_tok = jnp.clip(token, 0, self.num_text_tokens_ext - 1)
+            img_tok = jnp.clip(token, 0, self.num_image_tokens - 1)
+            emb = jnp.where(
+                is_text[:, None] if ragged else is_text,
+                self.text_emb(text_tok), self.image_emb(img_tok),
+            )
+            if not self.rotary_emb:
+                tpos = jnp.clip(pos, 0, self.text_len_internal - 1)
+                ipos = jnp.clip(pos - self.text_len_internal, 0, self.image_seq_len - 1)
+                img_grid = self.image_pos_emb(self.image_seq_len)
+                if ragged:
+                    # per-sequence positions (continuous batching): the learned
+                    # tables become row gathers — (b,) indices -> (b, dim)
+                    pe = jnp.where(
+                        is_text[:, None],
+                        self.text_pos_emb(tpos),
+                        jnp.take(img_grid[0], ipos, axis=0),
+                    )
+                else:
+                    pe = jnp.where(
+                        is_text,
+                        self.text_pos_emb(tpos)[None],
+                        jax.lax.dynamic_slice_in_dim(img_grid[0], ipos, 1, axis=0),
+                    )
+                emb = emb + pe.astype(emb.dtype)
 
         x = emb[:, None, :].astype(self.dtype)
         out = self.transformer(

@@ -284,6 +284,7 @@ def _decode_tokens_body(
     image_only = prefill_len == text_len_internal
     k_full = max(int((1 - filter_thres) * dalle.total_tokens), 1)
 
+    @jax.named_scope("sample")
     def apply_sample(tokens, key, logits, i, sliced=False):
         """Sample the token at position i+1 from consumed-position-i logits
         (teacher-forced while i+1 < known_len). ``sliced`` marks logits that

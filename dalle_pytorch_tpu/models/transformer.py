@@ -298,14 +298,20 @@ class Transformer(nn.Module):
             else min(max(int(depth_limit), 1), self.depth)
         )
 
+        # device-trace scopes (utils/telemetry_names.py DEVICE_SCOPES):
+        # ``attn.<attn_type>`` names the layer KIND, not the kernel that runs
+        # it, so a reader of a profiler trace keeps finding the same work
+        # after a reroute; the Flax module path (``attn_<ind>``) stays beneath
         if sequential and not self.reversible:
             for ind in range(depth_eff):
                 akw, fkw = self._block_kwargs(
                     ind, mask, rot, deterministic, decode, block_len,
                     block_start,
                 )
-                x = x + self.attn_blocks[ind](x, **akw)
-                x = x + self.ff_blocks[ind](x, **fkw)
+                with jax.named_scope(f"attn.{self.layer_kinds[ind]}"):
+                    x = x + self.attn_blocks[ind](x, **akw)
+                with jax.named_scope("ff"):
+                    x = x + self.ff_blocks[ind](x, **fkw)
             return x
 
         if self.reversible and (self.is_initializing() or decode):
@@ -316,8 +322,10 @@ class Transformer(nn.Module):
                     ind, mask, rot, deterministic, decode, block_len,
                     block_start,
                 )
-                x1 = x1 + self.attn_blocks[ind](x2, **akw)
-                x2 = x2 + self.ff_blocks[ind](x1, **fkw)
+                with jax.named_scope(f"attn.{self.layer_kinds[ind]}"):
+                    x1 = x1 + self.attn_blocks[ind](x2, **akw)
+                with jax.named_scope("ff"):
+                    x2 = x2 + self.ff_blocks[ind](x1, **fkw)
             return (x1 + x2) / 2
 
         # pure-function paths: remat or reversible training. Block closures
@@ -505,6 +513,7 @@ class Transformer(nn.Module):
 
             def make_fn(mod, is_attn, kind=kind):
                 static_kwargs = dict(deterministic=deterministic)
+                scope = f"attn.{kind}" if is_attn else "ff"
 
                 def fn(p, t, kw):
                     call_kwargs = dict(static_kwargs)
@@ -512,10 +521,11 @@ class Transformer(nn.Module):
                         call_kwargs["mask"] = kw.get("mask")
                         call_kwargs["rotary_pos_emb"] = kw.get("rot")
                     rngs = {"dropout": kw["rng"]} if "rng" in kw else None
-                    y, mut = mod.apply(
-                        {"params": p}, t, rngs=rngs, mutable=["moe_aux"],
-                        **call_kwargs,
-                    )
+                    with jax.named_scope(scope):
+                        y, mut = mod.apply(
+                            {"params": p}, t, rngs=rngs, mutable=["moe_aux"],
+                            **call_kwargs,
+                        )
                     aux = sum(
                         jax.tree_util.tree_leaves(mut.get("moe_aux", {})),
                         jnp.zeros((), jnp.float32),

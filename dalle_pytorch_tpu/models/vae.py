@@ -174,6 +174,7 @@ class DiscreteVAE(nn.Module):
             x = block(x)
         return self.enc_out(x)
 
+    @jax.named_scope("vae.encode")
     def get_codebook_indices(self, img: jnp.ndarray) -> jnp.ndarray:
         """Hard-argmax token ids (b, f*f) — the no-grad encode used for DALL-E
         training (reference dalle_pytorch.py:164-169)."""

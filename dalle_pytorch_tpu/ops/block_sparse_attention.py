@@ -361,9 +361,10 @@ def _pair_cost(n_pairs, n_qblocks, bh, bq, bk, d, dots, dtype_bytes):
 
 
 def _pair_call(kernel, grid, in_specs, out_specs, out_shape, scratch, table,
-               operands, interpret, cost):
+               operands, interpret, cost, *, name):
     return pl.pallas_call(
         kernel,
+        name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -454,6 +455,7 @@ def _bs_fwd(q, k, v, key_mask, mask_i8, fwd_table, kv_table, sm_scale,
     )
     o, lse = _pair_call(
         kernel,
+        name="block_sparse_fwd",
         grid=(bh, n_pairs),
         in_specs=in_specs,
         out_specs=[
@@ -534,6 +536,7 @@ def _pair_bwd_rule(sm_scale, block_q, block_k, interpret, res, do):
     )
     (dq,) = _pair_call(
         dq_kernel,
+        name="block_sparse_dq",
         grid=(bh, n_pairs_q),
         in_specs=dq_specs,
         out_specs=[pl.BlockSpec((1, bq, d), q_im)],
@@ -557,6 +560,7 @@ def _pair_bwd_rule(sm_scale, block_q, block_k, interpret, res, do):
     )
     dk, dv = _pair_call(
         dkv_kernel,
+        name="block_sparse_dkv",
         grid=(bh, n_pairs_k),
         in_specs=dkv_specs,
         out_specs=[

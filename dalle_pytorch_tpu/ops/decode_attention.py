@@ -232,6 +232,7 @@ def fused_decode_attention(
     row_out = pl.BlockSpec((1, 1, hpb * d), lambda b_, g, s: (b_, 0, g))
     out, k_row, v_row = pl.pallas_call(
         kernel,
+        name="decode_attend",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, groups),

@@ -359,9 +359,10 @@ def _kernel_cost(
     )
 
 
-def _call(kernel, grid, in_specs, out_specs, out_shape, scratch, scalar, operands, interpret, cost=None):
+def _call(kernel, grid, in_specs, out_specs, out_shape, scratch, scalar, operands, interpret, cost=None, *, name):
     return pl.pallas_call(
         kernel,
+        name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -451,6 +452,7 @@ def _flash_fwd(q, k, v, key_mask, causal, pattern_mask, sm_scale, block_q, block
     )
     o, lse = _call(
         kernel,
+        name="flash_fwd",
         grid=(bh, nq, nk),
         in_specs=in_specs,
         out_specs=[
@@ -547,6 +549,7 @@ def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, 
         )
         dq, dk, dv = _call(
             fused_kernel,
+            name="flash_bwd",
             grid=(bh, 1, 1),
             in_specs=fused_specs,
             out_specs=[
@@ -608,6 +611,7 @@ def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, 
     )
     dq, deltaf = _call(
         dq_kernel,
+        name="flash_dq",
         grid=(bh, nq, nk),
         in_specs=dq_specs,
         out_specs=[
@@ -665,6 +669,7 @@ def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, 
     )
     dk, dv = _call(
         dkv_kernel,
+        name="flash_dkv",
         grid=(bh, nk, nq),
         in_specs=dkv_specs,
         out_specs=[
@@ -866,9 +871,10 @@ def _fused_qkv_bwd_kernel(
 FUSED_VMEM_LIMIT_BYTES = 100 * 1024 * 1024
 
 
-def _call_plain(kernel, grid, in_specs, out_specs, out_shape, operands, interpret, cost):
+def _call_plain(kernel, grid, in_specs, out_specs, out_shape, operands, interpret, cost, *, name):
     return pl.pallas_call(
         kernel,
+        name=name,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -1002,6 +1008,7 @@ def _fused_qkv_fwd(qkv, key_mask, heads, dim_head, rot, causal, pattern_mask, sm
 
     o, lse = _call_plain(
         wrapped,
+        name="flash_qkv_fwd",
         grid=(b, g),
         in_specs=in_specs,
         out_specs=[
@@ -1070,6 +1077,7 @@ def _fused_bwd_rule(heads, dim_head, rot, causal, pattern_mask, sm_scale, interp
 
     dq, dk, dv = _call_plain(
         wrapped,
+        name="flash_qkv_bwd",
         grid=(b, g),
         in_specs=in_specs,
         out_specs=[

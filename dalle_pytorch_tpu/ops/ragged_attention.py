@@ -271,6 +271,7 @@ def kernel_attend(q, k_pool, v_pool, table, start, length, interpret=False,
         )
     out = pl.pallas_call(
         kernel,
+        name="ragged_paged_attend",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, l_pages),

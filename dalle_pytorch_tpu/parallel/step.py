@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..utils import profiling as _profiling  # noqa: F401  (TELEMETRY spans -> profiler)
 from .mesh import MeshRuntime
 from .sharding import opt_state_shardings, params_shardings, shard_pytree
 
@@ -142,25 +143,34 @@ def make_train_step(
                 state.step == nan_inject_step,
                 jnp.asarray(jnp.nan, loss.dtype), loss,
             )
-        updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
-        if dynamic_lr:
-            updates = jax.tree_util.tree_map(lambda u: -lr * u, updates)
-        params = optax.apply_updates(state.params, updates)
-        skipped, consec = state.skipped, state.consec_skipped
-        if nan_guard:
-            finite = jnp.isfinite(loss) & jnp.isfinite(optax.global_norm(grads))
-            keep = lambda new, old: jax.tree_util.tree_map(
-                lambda n, o: jnp.where(finite, n, o), new, old
-            )
-            params = keep(params, state.params)
-            opt_state = keep(opt_state, state.opt_state)
-            skipped = skipped + jnp.where(finite, 0, 1).astype(jnp.int32)
-            consec = jnp.where(finite, 0, consec + 1).astype(jnp.int32)
-            # the returned loss IS the rejection signal: NaN for ANY
-            # rejected step — including finite-loss/non-finite-grad — so
-            # the host's retry/abort verdict always agrees with the
-            # device's select
-            loss = jnp.where(finite, loss, jnp.asarray(jnp.nan, loss.dtype))
+        with jax.named_scope("update"):
+            with jax.named_scope("update.optimizer"):
+                updates, opt_state = optimizer.update(
+                    grads, state.opt_state, state.params
+                )
+                if dynamic_lr:
+                    updates = jax.tree_util.tree_map(lambda u: -lr * u, updates)
+                params = optax.apply_updates(state.params, updates)
+            skipped, consec = state.skipped, state.consec_skipped
+            if nan_guard:
+                with jax.named_scope("update.nan_guard"):
+                    finite = jnp.isfinite(loss) & jnp.isfinite(
+                        optax.global_norm(grads)
+                    )
+                    keep = lambda new, old: jax.tree_util.tree_map(
+                        lambda n, o: jnp.where(finite, n, o), new, old
+                    )
+                    params = keep(params, state.params)
+                    opt_state = keep(opt_state, state.opt_state)
+                    skipped = skipped + jnp.where(finite, 0, 1).astype(jnp.int32)
+                    consec = jnp.where(finite, 0, consec + 1).astype(jnp.int32)
+                    # the returned loss IS the rejection signal: NaN for ANY
+                    # rejected step — including finite-loss/non-finite-grad —
+                    # so the host's retry/abort verdict always agrees with
+                    # the device's select
+                    loss = jnp.where(
+                        finite, loss, jnp.asarray(jnp.nan, loss.dtype)
+                    )
         new_state = TrainState(
             step=state.step + 1, params=params, opt_state=opt_state,
             skipped=skipped, consec_skipped=consec,

@@ -3,6 +3,7 @@ control, page-pool pressure handling, and the replicated front door.
 See engine.py for the single-replica architecture, router.py for the
 fleet coordinator, and docs/DESIGN.md for the failure models."""
 
+from ..utils import profiling as _profiling  # noqa: F401  (TELEMETRY spans -> profiler)
 from .control import ControlConfig, Controller, Decision
 from .engine import Engine, EngineConfig, check_accounting
 from .journal import (

@@ -251,10 +251,6 @@ class EngineConfig:
     vitals: bool = False
     # window length, in worked iterations
     vitals_window: int = 32
-    # charge each serving jit's cost_analysis() FLOPs/bytes into the
-    # vitals cost ledger ONCE per signature (an extra lowering per jit
-    # name, off the timed path) so roofline fraction is a live gauge
-    cost_ledger: bool = False
     # deterministic adaptive control loop (serving/control.py): maps
     # vitals windows to effective knobs between iterations, through
     # data-only channels that cannot recompile. Implies vitals.
@@ -365,9 +361,10 @@ def _prefill_jit(dalle: DALLE, params, cache, internal_text, key, k: int,
         method=DALLE.prefill_step,
         mutable=["cache"],
     )
-    tok = jax.random.categorical(
-        key, top_k_filter(img, k=k) / temperature, axis=-1
-    )
+    with jax.named_scope("sample"):
+        tok = jax.random.categorical(
+            key, top_k_filter(img, k=k) / temperature, axis=-1
+        )
     # the raw last-position logits ride along for the prefix cache's
     # terminal payload (a full-prefix hit re-samples from EXACTLY these
     # values with its own key); unread when prefix caching is off
@@ -405,9 +402,10 @@ def _prefill_last_jit(dalle: DALLE, params, cache, chunk, start, k: int,
         method=DALLE.prefill_chunk,
         mutable=["cache"],
     )
-    tok = jax.random.categorical(
-        key, top_k_filter(img, k=k) / temperature, axis=-1
-    )
+    with jax.named_scope("sample"):
+        tok = jax.random.categorical(
+            key, top_k_filter(img, k=k) / temperature, axis=-1
+        )
     # raw logits for the prefix cache's terminal payload (see _prefill_jit)
     return mutated["cache"], tok, img
 
@@ -429,8 +427,9 @@ def _decode_jit(dalle: DALLE, params, cache, tok, pos, keys, k: int,
         method=DALLE.decode_step,
         mutable=["cache"],
     )
-    filtered = top_k_filter(logits, k=k) / temperature
-    samples = jax.vmap(jax.random.categorical)(keys, filtered)
+    with jax.named_scope("sample"):
+        filtered = top_k_filter(logits, k=k) / temperature
+        samples = jax.vmap(jax.random.categorical)(keys, filtered)
     return mutated["cache"], samples.astype(jnp.int32)
 
 
@@ -475,8 +474,9 @@ def _iteration_jit(dalle: DALLE, params, cache, prompts, tok, start, length,
         method=DALLE.fused_step,
         mutable=["cache"],
     )
-    filtered = top_k_filter(logits, k=k) / temperature
-    samples = jax.vmap(jax.random.categorical)(keys, filtered)
+    with jax.named_scope("sample"):
+        filtered = top_k_filter(logits, k=k) / temperature
+        samples = jax.vmap(jax.random.categorical)(keys, filtered)
     if any_final:
         # final-chunk iterations (already their own warm signature class)
         # also surface the raw per-row logits: the prefix cache's terminal
@@ -591,10 +591,11 @@ def _spec_iteration_jit(dalle: DALLE, params, cache, prompts, tok, start,
             method=DALLE.fused_step, mutable=["cache"],
         )
         draft_cache = dmut["cache"]
-        dfilt = top_k_filter(dlog, k=k) / temperature
-        cur = jax.vmap(jax.random.categorical)(
-            keys[:, i], dfilt
-        ).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            dfilt = top_k_filter(dlog, k=k) / temperature
+            cur = jax.vmap(jax.random.categorical)(
+                keys[:, i], dfilt
+            ).astype(jnp.int32)
         drafts.append(cur)
     del draft_cache  # the chain is scratch; verify starts from `cache`
 
@@ -609,10 +610,11 @@ def _spec_iteration_jit(dalle: DALLE, params, cache, prompts, tok, start,
         rowwise_head=any_final, all_logits=True,
         method=DALLE.fused_step, mutable=["cache"],
     )  # (B, width, V_img)
-    filtered = top_k_filter(logits, k=k) / temperature
-    samples = jax.vmap(jax.random.categorical)(
-        keys.reshape(B * width), filtered.reshape(B * width, -1)
-    ).reshape(B, width).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        filtered = top_k_filter(logits, k=k) / temperature
+        samples = jax.vmap(jax.random.categorical)(
+            keys.reshape(B * width), filtered.reshape(B * width, -1)
+        ).reshape(B, width).astype(jnp.int32)
     if spec_k:
         dmat = jnp.concatenate([d[:, None] for d in drafts], axis=1)
         valid = (
@@ -641,9 +643,10 @@ def _sample_cached_jit(logits, key, k: int, temperature):
     own ``fold_in(key(seed), T)`` key. Elementwise + sort ops on
     identical inputs, so the sampled token is bit-identical to the cold
     run's on every platform (no matmul reassociation in this program)."""
-    return jax.random.categorical(
-        key, top_k_filter(logits, k=k) / temperature, axis=-1
-    )
+    with jax.named_scope("sample"):
+        return jax.random.categorical(
+            key, top_k_filter(logits, k=k) / temperature, axis=-1
+        )
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -1021,6 +1024,7 @@ class Engine:
         # chunk plus one decode step
         self.dispatches = 0
         self.iterations = 0
+        self.tokens_committed = 0
         # KV footprint accounting (the quantized-KV capacity lever,
         # docs/DESIGN.md §6.1): bytes of K/V storage — content AND
         # scale pools — per slot row, computed from the REAL cache
@@ -1065,19 +1069,11 @@ class Engine:
         # built without this block.
         self._eff_spec_k = config.spec_k
         self._eff_watermark = config.high_watermark
-        self._last_jit_name: Optional[str] = None
         self.vitals: Optional[vitals_mod.Vitals] = None
         self.controller: Optional[Controller] = None
         self._control_interval = 0
         if config.vitals or config.controller:
-            peaks = None
-            if config.cost_ledger:
-                # None for a kind with no table entry (the gauge then
-                # reads 0.0); anything else going wrong here is a bug
-                peaks = vitals_mod.peaks_for(jax.devices()[0].device_kind)
-            self.vitals = vitals_mod.Vitals(
-                window=config.vitals_window, peaks=peaks
-            )
+            self.vitals = vitals_mod.Vitals(window=config.vitals_window)
         if config.controller:
             cc = config.control if config.control is not None else (
                 ControlConfig()
@@ -1205,32 +1201,44 @@ class Engine:
         each its own jit dispatch. Fused mode: the whole iteration —
         decode rows AND granted prefill chunks — as ONE ragged dispatch.
         Returns False when the engine is fully idle."""
-        self._sweep_terminations()
-        self._admit()
-        if self.fused:
-            worked = (
-                self._spec_iteration() if self.spec
-                else self._fused_iteration()
-            )
-        else:
-            worked = self._decode_once()
-            worked = self._advance_prefills() or worked
-        if self.postdecode is not None:
-            # post-decode stage work runs AFTER the token work of the
-            # iteration, metered by its own budget — subordinate to
-            # decode by construction (DESIGN.md §8.5)
-            worked = self.postdecode.step() or worked
-        if worked:
-            self.iterations += 1
-        self.clock.tick()
-        if self.vitals is not None and worked:
-            self._observe_vitals()
-            if (
-                self.controller is not None
-                and self.iterations % self._control_interval == 0
-            ):
-                self._run_controller()
-        self._publish_gauges()
+        # every phase is a lexical child span of ``serve.step`` (a phase's
+        # self time = its span minus its children); under any profiler
+        # capture they land on the device events' clock
+        # (utils/profiling.py), which is what attributes a device gap to
+        # the host phase that covers it (DESIGN.md §9)
+        with TELEMETRY.span("serve.step"):
+            with TELEMETRY.span("serve.step.sweep"):
+                self._sweep_terminations()
+            with TELEMETRY.span("serve.step.admit"):
+                self._admit()
+            if self.fused:
+                worked = (
+                    self._spec_iteration() if self.spec
+                    else self._fused_iteration()
+                )
+            else:
+                worked = self._decode_once()
+                # split mode's budgeted chunks: each is a
+                # ``serve.prefill_chunk`` child of ``serve.step``
+                worked = self._advance_prefills() or worked
+            if self.postdecode is not None:
+                # post-decode stage work runs AFTER the token work of the
+                # iteration, metered by its own budget — subordinate to
+                # decode by construction (DESIGN.md §8.5)
+                with TELEMETRY.span("serve.step.stages"):
+                    worked = self.postdecode.step() or worked
+            if worked:
+                self.iterations += 1
+            self.clock.tick()
+            with TELEMETRY.span("serve.step.publish"):
+                if self.vitals is not None and worked:
+                    self._observe_vitals()
+                    if (
+                        self.controller is not None
+                        and self.iterations % self._control_interval == 0
+                    ):
+                        self._run_controller()
+                self._publish_gauges()
         return (worked or bool(self.sched) or any(self.slots)
                 or bool(self.postdecode))
 
@@ -1262,6 +1270,7 @@ class Engine:
             ),
             "queued": len(self.sched),
             "staged": 0 if self.postdecode is None else len(self.postdecode),
+            "tokens_committed": self.tokens_committed,
             "pool_total": self.pool.total,
             "pool_used": self.pool.used,
             "pool_occupancy": self.pool.occupancy,
@@ -1413,6 +1422,7 @@ class Engine:
             now = self.clock.now()
             entry.admit_time = now
             entry.generated = [int(tok0)]
+            self._commit_tokens(1)
             # queue wait = submit (or preemption requeue's ORIGINAL
             # submit) to this admission — what the client experienced
             self.histograms.observe("serve.queue_wait_s", now - entry.submit_time)
@@ -1582,6 +1592,7 @@ class Engine:
         )
         tok0 = int(tok[0])
         entry.generated = [tok0]
+        self._commit_tokens(1)
         slot.tok = tok0
         self.slots[idx] = slot
         self.counters.inc("serve.admitted")
@@ -2432,6 +2443,7 @@ class Engine:
         slot.cache1 = None
         slot.internal = None
         entry.generated = [tok0]
+        self._commit_tokens(1)
         slot.tok = tok0
         slot.pos = self.T
         slot.phase = _DECODE
@@ -2474,32 +2486,33 @@ class Engine:
             )
             self.clock.advance(cfg.stall_penalty_s)
         pending = self._pending
-        # a pending FINAL-chunk sample counts like a decode sample: it
-        # becomes generated[0] at readback (completion is count-based)
-        in_flight = (
-            set() if pending is None else {id(s) for s, _ in pending[1]}
-        )
-        dispatchable = [
-            s for s in self.slots
-            if s and s.phase == _DECODE
-            and len(s.entry.generated) + (1 if id(s) in in_flight else 0)
-            < s.entry.effective_max_new
-        ]
-        for slot in sorted(
-            dispatchable,
-            key=lambda s: -self.sched.effective_priority(s.entry),
-        ):
-            if self.slots[slot.index] is not slot:
-                continue
-            # pages covering [0, pos], minus the prefix pages the slot
-            # maps SHARED (charged to the index, not to this request)
-            needed = slot.pos // self.page + 1 - len(slot.shared_nodes)
-            deficit = needed - self.pool.held(slot.entry.request_id)
-            if deficit > 0 and not self._alloc_or_preempt(slot, deficit):
-                continue
-        dispatchable = [s for s in dispatchable if self.slots[s.index] is s]
+        with TELEMETRY.span("serve.step.plan"):
+            # a pending FINAL-chunk sample counts like a decode sample: it
+            # becomes generated[0] at readback (completion is count-based)
+            in_flight = (
+                set() if pending is None else {id(s) for s, _ in pending[1]}
+            )
+            dispatchable = [
+                s for s in self.slots
+                if s and s.phase == _DECODE
+                and len(s.entry.generated) + (1 if id(s) in in_flight else 0)
+                < s.entry.effective_max_new
+            ]
+            for slot in sorted(
+                dispatchable,
+                key=lambda s: -self.sched.effective_priority(s.entry),
+            ):
+                if self.slots[slot.index] is not slot:
+                    continue
+                # pages covering [0, pos], minus the prefix pages the slot
+                # maps SHARED (charged to the index, not to this request)
+                needed = slot.pos // self.page + 1 - len(slot.shared_nodes)
+                deficit = needed - self.pool.held(slot.entry.request_id)
+                if deficit > 0 and not self._alloc_or_preempt(slot, deficit):
+                    continue
+            dispatchable = [s for s in dispatchable if self.slots[s.index] is s]
 
-        chunks = self._plan_fused_prefills(len(dispatchable))
+            chunks = self._plan_fused_prefills(len(dispatchable))
 
         worked = False
         with TELEMETRY.span(
@@ -2518,7 +2531,8 @@ class Engine:
                 prev, self._pending = new_pending, None
             if prev is not None:
                 worked = True
-                self._fused_readback(prev)
+                with TELEMETRY.span("serve.step.readback"):
+                    self._fused_readback(prev)
         return worked
 
     def _dispatch_fused(self, dispatchable: List[_Slot],
@@ -2528,56 +2542,57 @@ class Engine:
         samples will be consumed (decode rows and final chunks), host
         token scatter only for decode inputs not already on device."""
         B = self.config.max_batch
-        start = np.zeros((B,), np.int32)
-        length = np.zeros((B,), np.int32)
-        final = np.zeros((B,), bool)
-        host_idx: List[int] = []
-        host_tok: List[int] = []
-        key_idx: List[int] = []
-        key_list = []
-        entries: List[Tuple[_Slot, str]] = []
-        for s in dispatchable:
-            start[s.index] = s.pos
-            length[s.index] = 1
-            key_idx.append(s.index)
-            key_list.append(jax.random.fold_in(
-                jax.random.key(s.entry.request.seed), s.pos + 1
-            ))
-            if pending is None or not s.tok_on_device:
-                host_idx.append(s.index)
-                host_tok.append(s.tok)
-            entries.append((s, _DECODE))
-        for s, c in chunks:
-            self.counters.inc("serve.prefill_chunks")
-            start[s.index] = s.filled
-            length[s.index] = c
-            if s.filled + c >= self.T:
-                final[s.index] = True
+        with TELEMETRY.span("serve.step.fold_keys"):
+            start = np.zeros((B,), np.int32)
+            length = np.zeros((B,), np.int32)
+            final = np.zeros((B,), bool)
+            host_idx: List[int] = []
+            host_tok: List[int] = []
+            key_idx: List[int] = []
+            key_list = []
+            entries: List[Tuple[_Slot, str]] = []
+            for s in dispatchable:
+                start[s.index] = s.pos
+                length[s.index] = 1
                 key_idx.append(s.index)
                 key_list.append(jax.random.fold_in(
-                    jax.random.key(s.entry.request.seed), self.T
+                    jax.random.key(s.entry.request.seed), s.pos + 1
                 ))
-                entries.append((s, _PREFILL))
-        if dispatchable:
-            self.counters.inc("serve.decode_steps")
-        tok = pending[0] if pending is not None else self._zero_tok
-        if host_idx:
-            tok = tok.at[jnp.asarray(host_idx)].set(
-                jnp.asarray(host_tok, jnp.int32)
+                if pending is None or not s.tok_on_device:
+                    host_idx.append(s.index)
+                    host_tok.append(s.tok)
+                entries.append((s, _DECODE))
+            for s, c in chunks:
+                self.counters.inc("serve.prefill_chunks")
+                start[s.index] = s.filled
+                length[s.index] = c
+                if s.filled + c >= self.T:
+                    final[s.index] = True
+                    key_idx.append(s.index)
+                    key_list.append(jax.random.fold_in(
+                        jax.random.key(s.entry.request.seed), self.T
+                    ))
+                    entries.append((s, _PREFILL))
+            if dispatchable:
+                self.counters.inc("serve.decode_steps")
+            tok = pending[0] if pending is not None else self._zero_tok
+            if host_idx:
+                tok = tok.at[jnp.asarray(host_idx)].set(
+                    jnp.asarray(host_tok, jnp.int32)
+                )
+            keys = self._filler_keys
+            if key_idx:
+                keys = keys.at[jnp.asarray(key_idx)].set(jnp.stack(key_list))
+            jit_args = (
+                self.dalle, self.params, self.cache, self._prompts,
+                tok, jnp.asarray(start), jnp.asarray(length), jnp.asarray(final),
+                keys, self._W, self.k_img, self.config.temperature,
+                bool(final.any()),
             )
-        keys = self._filler_keys
-        if key_idx:
-            keys = keys.at[jnp.asarray(key_idx)].set(jnp.stack(key_list))
         self.dispatches += 1
         self.counters.inc("serve.dispatches")
-        jit_args = (
-            self.dalle, self.params, self.cache, self._prompts,
-            tok, jnp.asarray(start), jnp.asarray(length), jnp.asarray(final),
-            keys, self._W, self.k_img, self.config.temperature,
-            bool(final.any()),
-        )
-        self._maybe_charge_cost("iteration", _iteration_jit, jit_args)
-        self.cache, samples, flogits = _iteration_jit(*jit_args)
+        with TELEMETRY.span("serve.step.dispatch"):
+            self.cache, samples, flogits = _iteration_jit(*jit_args)
         for s in self.slots:
             if s is not None and s.phase == _DECODE:
                 s.tok_on_device = False
@@ -2596,9 +2611,11 @@ class Engine:
         transitioning those slots to the decode phase."""
         samples, entries = prev
         samples = np.asarray(samples)
+        committed = 0
         for s, kind in entries:
             if self.slots[s.index] is not s:
                 continue  # terminated/evicted while the step was in flight
+            committed += 1
             if kind == _DECODE:
                 s.tok = int(samples[s.index])
                 s.entry.generated.append(s.tok)
@@ -2606,6 +2623,7 @@ class Engine:
                     self._complete(s)
             else:
                 self._finish_prefill_fused(s, int(samples[s.index]))
+        self._commit_tokens(committed)
 
     def _finish_prefill_fused(self, slot: _Slot, tok0: int) -> None:
         """Readback half of a fused prefill completion: the phase
@@ -2656,52 +2674,53 @@ class Engine:
                 "serve.decode_stall", penalty_s=cfg.stall_penalty_s
             )
             self.clock.advance(cfg.stall_penalty_s)
-        dispatchable = [
-            s for s in self.slots
-            if s and s.phase == _DECODE
-            and len(s.entry.generated) < s.entry.effective_max_new
-        ]
-        spec_on = True
-        if dispatchable and FAULTS.take("spec_verify_abort"):
-            spec_on = False
-            self.counters.inc("serve.fault_spec_verify_abort")
-            self.counters.inc("serve.spec.fallbacks")
-        widths: Dict[int, int] = {}
-        for s in dispatchable:
-            remaining = s.entry.effective_max_new - len(s.entry.generated)
-            # capping the verify width at the remaining budget keeps the
-            # worst-case page demand identical to plain decode (the last
-            # written position never passes T + max_new - 2). The
-            # EFFECTIVE spec_k (controller-adjustable, <= the static
-            # cfg.spec_k the jit was traced with) is pure row data — the
-            # adaptation channel that cannot recompile (DESIGN §8.6)
-            widths[id(s)] = 1 if not spec_on else min(
-                self._eff_spec_k + 1, remaining
-            )
-        for slot in sorted(
-            dispatchable,
-            key=lambda s: -self.sched.effective_priority(s.entry),
-        ):
-            if self.slots[slot.index] is not slot:
-                continue
-            # pages covering the whole verify block [0, pos + k - 1],
-            # minus the prefix pages the slot maps shared
-            k_b = widths[id(slot)]
-            needed = (
-                (slot.pos + k_b - 1) // self.page + 1
-                - len(slot.shared_nodes)
-            )
-            deficit = needed - self.pool.held(slot.entry.request_id)
-            if deficit > 0 and not self._alloc_or_preempt(slot, deficit):
-                continue
-        dispatchable = [s for s in dispatchable if self.slots[s.index] is s]
+        with TELEMETRY.span("serve.step.plan"):
+            dispatchable = [
+                s for s in self.slots
+                if s and s.phase == _DECODE
+                and len(s.entry.generated) < s.entry.effective_max_new
+            ]
+            spec_on = True
+            if dispatchable and FAULTS.take("spec_verify_abort"):
+                spec_on = False
+                self.counters.inc("serve.fault_spec_verify_abort")
+                self.counters.inc("serve.spec.fallbacks")
+            widths: Dict[int, int] = {}
+            for s in dispatchable:
+                remaining = s.entry.effective_max_new - len(s.entry.generated)
+                # capping the verify width at the remaining budget keeps the
+                # worst-case page demand identical to plain decode (the last
+                # written position never passes T + max_new - 2). The
+                # EFFECTIVE spec_k (controller-adjustable, <= the static
+                # cfg.spec_k the jit was traced with) is pure row data — the
+                # adaptation channel that cannot recompile (DESIGN §8.6)
+                widths[id(s)] = 1 if not spec_on else min(
+                    self._eff_spec_k + 1, remaining
+                )
+            for slot in sorted(
+                dispatchable,
+                key=lambda s: -self.sched.effective_priority(s.entry),
+            ):
+                if self.slots[slot.index] is not slot:
+                    continue
+                # pages covering the whole verify block [0, pos + k - 1],
+                # minus the prefix pages the slot maps shared
+                k_b = widths[id(slot)]
+                needed = (
+                    (slot.pos + k_b - 1) // self.page + 1
+                    - len(slot.shared_nodes)
+                )
+                deficit = needed - self.pool.held(slot.entry.request_id)
+                if deficit > 0 and not self._alloc_or_preempt(slot, deficit):
+                    continue
+            dispatchable = [s for s in dispatchable if self.slots[s.index] is s]
 
-        # decode charged at VERIFY width: a speculative row occupies its
-        # whole block of the iteration's token budget, so prefill grants
-        # shrink exactly as if that many plain decode rows ran
-        chunks = self._plan_fused_prefills(
-            sum(widths[id(s)] for s in dispatchable)
-        )
+            # decode charged at VERIFY width: a speculative row occupies its
+            # whole block of the iteration's token budget, so prefill grants
+            # shrink exactly as if that many plain decode rows ran
+            chunks = self._plan_fused_prefills(
+                sum(widths[id(s)] for s in dispatchable)
+            )
 
         if not dispatchable and not chunks:
             return False
@@ -2716,7 +2735,8 @@ class Engine:
                 n_verify=len(dispatchable), drafted=drafted,
             ):
                 prev = self._dispatch_spec(dispatchable, widths, chunks)
-                self._spec_readback(prev)
+                with TELEMETRY.span("serve.step.readback"):
+                    self._spec_readback(prev)
         return True
 
     def _dispatch_spec(self, verifies: List[_Slot], widths: Dict[int, int],
@@ -2731,57 +2751,56 @@ class Engine:
         lives at a data-dependent column of the previous sample
         matrix)."""
         B, W = self.config.max_batch, self._W
-        start = np.zeros((B,), np.int32)
-        length = np.zeros((B,), np.int32)
-        final = np.zeros((B,), bool)
-        host_idx: List[int] = []
-        host_tok: List[int] = []
-        entries: List[Tuple[_Slot, str, int]] = []
-        for s in verifies:
-            k_b = widths[id(s)]
-            start[s.index] = s.pos
-            length[s.index] = k_b
-            host_idx.append(s.index)
-            host_tok.append(s.tok)
-            entries.append((s, _DECODE, k_b))
-        for s, c in chunks:
-            self.counters.inc("serve.prefill_chunks")
-            start[s.index] = s.filled
-            length[s.index] = c
-            if s.filled + c >= self.T:
-                final[s.index] = True
-                entries.append((s, _PREFILL, c))
-        if verifies:
-            self.counters.inc("serve.decode_steps")
-        # the token scatter rides a FIXED padded shape (index vector
-        # padded to B with an out-of-range drop sentinel): a speculative
-        # trace mixes every (verify-width, final-chunk) combination, and
-        # an un-padded scatter would compile one tiny module per distinct
-        # row count — in-trace compiles the zero-compile contract
-        # forbids. Sampling keys are derived entirely IN-TRACE from
-        # self._base_keys (written at admission), no per-iteration key
-        # assembly at all.
-        tok = self._zero_tok
-        if host_idx:
-            pad = B - len(host_idx)
-            tok = tok.at[jnp.asarray(host_idx + [B] * pad)].set(
-                jnp.asarray(host_tok + [0] * pad, jnp.int32), mode="drop"
+        with TELEMETRY.span("serve.step.fold_keys"):
+            start = np.zeros((B,), np.int32)
+            length = np.zeros((B,), np.int32)
+            final = np.zeros((B,), bool)
+            host_idx: List[int] = []
+            host_tok: List[int] = []
+            entries: List[Tuple[_Slot, str, int]] = []
+            for s in verifies:
+                k_b = widths[id(s)]
+                start[s.index] = s.pos
+                length[s.index] = k_b
+                host_idx.append(s.index)
+                host_tok.append(s.tok)
+                entries.append((s, _DECODE, k_b))
+            for s, c in chunks:
+                self.counters.inc("serve.prefill_chunks")
+                start[s.index] = s.filled
+                length[s.index] = c
+                if s.filled + c >= self.T:
+                    final[s.index] = True
+                    entries.append((s, _PREFILL, c))
+            if verifies:
+                self.counters.inc("serve.decode_steps")
+            # the token scatter rides a FIXED padded shape (index vector
+            # padded to B with an out-of-range drop sentinel): a speculative
+            # trace mixes every (verify-width, final-chunk) combination, and
+            # an un-padded scatter would compile one tiny module per distinct
+            # row count — in-trace compiles the zero-compile contract
+            # forbids. Sampling keys are derived entirely IN-TRACE from
+            # self._base_keys (written at admission), no per-iteration key
+            # assembly at all.
+            tok = self._zero_tok
+            if host_idx:
+                pad = B - len(host_idx)
+                tok = tok.at[jnp.asarray(host_idx + [B] * pad)].set(
+                    jnp.asarray(host_tok + [0] * pad, jnp.int32), mode="drop"
+                )
+            jit_args = (
+                self.dalle, self.params, self.cache, self._prompts,
+                tok, jnp.asarray(start), jnp.asarray(length), jnp.asarray(final),
+                self._base_keys, W, self.k_img, self.config.temperature,
+                bool(final.any()), self.config.spec_k,
+                self.config.spec_draft_depth,
             )
         self.dispatches += 1
         self.counters.inc("serve.dispatches")
-        jit_args = (
-            self.dalle, self.params, self.cache, self._prompts,
-            tok, jnp.asarray(start), jnp.asarray(length), jnp.asarray(final),
-            self._base_keys, W, self.k_img, self.config.temperature,
-            bool(final.any()), self.config.spec_k,
-            self.config.spec_draft_depth,
-        )
-        self._maybe_charge_cost(
-            "iteration_spec", _spec_iteration_jit, jit_args
-        )
-        self.cache, samples, accepted, flogits = _spec_iteration_jit(
-            *jit_args
-        )
+        with TELEMETRY.span("serve.step.dispatch"):
+            self.cache, samples, accepted, flogits = _spec_iteration_jit(
+                *jit_args
+            )
         self._advance_dispatched_chunks(chunks, final, flogits)
         return samples, accepted, entries
 
@@ -2795,9 +2814,11 @@ class Engine:
         samples, accepted, entries = prev
         samples = np.asarray(samples)
         accepted = np.asarray(accepted)
+        committed = 0
         for s, kind, k_b in entries:
             if self.slots[s.index] is not s:
                 continue  # terminated/evicted by the termination sweep
+            committed += 1 if kind != _DECODE else int(accepted[s.index])
             if kind == _DECODE:
                 acc = int(accepted[s.index])
                 assert 1 <= acc <= k_b, (
@@ -2823,6 +2844,7 @@ class Engine:
                     self._complete(s)
             else:
                 self._finish_prefill_fused(s, int(samples[s.index, k_b - 1]))
+        self._commit_tokens(committed)
 
     def _record_first_token(self, entry: Entry, now: float) -> None:
         """TTFT bookkeeping: set once per request (a preempted request's
@@ -2860,33 +2882,34 @@ class Engine:
             )
             self.clock.advance(cfg.stall_penalty_s)
         pending = self._pending
-        in_flight = (
-            set() if pending is None else {id(s) for s in pending[1]}
-        )
-        # a slot whose in-flight sample will hit its budget at readback is
-        # NOT dispatched again (completion is count-based: the host knows
-        # the tally without reading token values — the lookahead seam)
-        dispatchable = [
-            s for s in self.slots
-            if s and s.phase == _DECODE
-            and len(s.entry.generated) + (1 if id(s) in in_flight else 0)
-            < s.entry.effective_max_new
-        ]
-        # page growth: writing position ``pos`` needs pages [0, pos//page];
-        # allocate on boundary crossings, preempting on failure
-        for slot in sorted(
-            dispatchable,
-            key=lambda s: -self.sched.effective_priority(s.entry),
-        ):
-            if self.slots[slot.index] is not slot:
-                continue  # evicted by a previous iteration of this loop
-            # pages covering [0, pos], minus the prefix pages the slot
-            # maps SHARED (charged to the index, not to this request)
-            needed = slot.pos // self.page + 1 - len(slot.shared_nodes)
-            deficit = needed - self.pool.held(slot.entry.request_id)
-            if deficit > 0 and not self._alloc_or_preempt(slot, deficit):
-                continue  # the requester itself was evicted
-        dispatchable = [s for s in dispatchable if self.slots[s.index] is s]
+        with TELEMETRY.span("serve.step.plan"):
+            in_flight = (
+                set() if pending is None else {id(s) for s in pending[1]}
+            )
+            # a slot whose in-flight sample will hit its budget at readback is
+            # NOT dispatched again (completion is count-based: the host knows
+            # the tally without reading token values — the lookahead seam)
+            dispatchable = [
+                s for s in self.slots
+                if s and s.phase == _DECODE
+                and len(s.entry.generated) + (1 if id(s) in in_flight else 0)
+                < s.entry.effective_max_new
+            ]
+            # page growth: writing position ``pos`` needs pages [0, pos//page];
+            # allocate on boundary crossings, preempting on failure
+            for slot in sorted(
+                dispatchable,
+                key=lambda s: -self.sched.effective_priority(s.entry),
+            ):
+                if self.slots[slot.index] is not slot:
+                    continue  # evicted by a previous iteration of this loop
+                # pages covering [0, pos], minus the prefix pages the slot
+                # maps SHARED (charged to the index, not to this request)
+                needed = slot.pos // self.page + 1 - len(slot.shared_nodes)
+                deficit = needed - self.pool.held(slot.entry.request_id)
+                if deficit > 0 and not self._alloc_or_preempt(slot, deficit):
+                    continue  # the requester itself was evicted
+            dispatchable = [s for s in dispatchable if self.slots[s.index] is s]
         worked = False
         # ONE span per dispatched decode step; with lookahead it brackets
         # the dispatch of step N AND the (synchronizing) readback of step
@@ -2908,7 +2931,8 @@ class Engine:
                 prev, self._pending = new_pending, None
             if prev is not None:
                 worked = True
-                self._readback(prev)
+                with TELEMETRY.span("serve.step.readback"):
+                    self._readback(prev)
         return worked
 
     def _dispatch_decode(self, dispatchable: List[_Slot], pending):
@@ -2919,39 +2943,40 @@ class Engine:
         are computed for ACTIVE slots only and scattered over the cached
         filler-key array."""
         B = self.config.max_batch
-        pos = np.zeros((B,), np.int32)
-        host_idx: List[int] = []
-        host_tok: List[int] = []
-        key_idx: List[int] = []
-        key_list = []
-        for s in dispatchable:
-            pos[s.index] = s.pos
-            key_idx.append(s.index)
-            # the token at position pos+1 is drawn from this key — pure
-            # (seed, position) addressing, independent of batch history
-            key_list.append(jax.random.fold_in(
-                jax.random.key(s.entry.request.seed), s.pos + 1
-            ))
-            if pending is None or not s.tok_on_device:
-                host_idx.append(s.index)
-                host_tok.append(s.tok)
-        tok = pending[0] if pending is not None else self._zero_tok
-        if host_idx:
-            tok = tok.at[jnp.asarray(host_idx)].set(
-                jnp.asarray(host_tok, jnp.int32)
+        with TELEMETRY.span("serve.step.fold_keys"):
+            pos = np.zeros((B,), np.int32)
+            host_idx: List[int] = []
+            host_tok: List[int] = []
+            key_idx: List[int] = []
+            key_list = []
+            for s in dispatchable:
+                pos[s.index] = s.pos
+                key_idx.append(s.index)
+                # the token at position pos+1 is drawn from this key — pure
+                # (seed, position) addressing, independent of batch history
+                key_list.append(jax.random.fold_in(
+                    jax.random.key(s.entry.request.seed), s.pos + 1
+                ))
+                if pending is None or not s.tok_on_device:
+                    host_idx.append(s.index)
+                    host_tok.append(s.tok)
+            tok = pending[0] if pending is not None else self._zero_tok
+            if host_idx:
+                tok = tok.at[jnp.asarray(host_idx)].set(
+                    jnp.asarray(host_tok, jnp.int32)
+                )
+            keys = self._filler_keys.at[jnp.asarray(key_idx)].set(
+                jnp.stack(key_list)
             )
-        keys = self._filler_keys.at[jnp.asarray(key_idx)].set(
-            jnp.stack(key_list)
-        )
+            jit_args = (
+                self.dalle, self.params, self.cache,
+                tok, jnp.asarray(pos), keys,
+                self.k_img, self.config.temperature,
+            )
         self.dispatches += 1
         self.counters.inc("serve.dispatches")
-        jit_args = (
-            self.dalle, self.params, self.cache,
-            tok, jnp.asarray(pos), keys,
-            self.k_img, self.config.temperature,
-        )
-        self._maybe_charge_cost("decode", _decode_jit, jit_args)
-        self.cache, samples = _decode_jit(*jit_args)
+        with TELEMETRY.span("serve.step.dispatch"):
+            self.cache, samples = _decode_jit(*jit_args)
         for s in self.slots:
             if s is not None and s.phase == _DECODE:
                 s.tok_on_device = False
@@ -2959,6 +2984,13 @@ class Engine:
             s.pos += 1
             s.tok_on_device = True
         return samples, list(dispatchable)
+
+    def _commit_tokens(self, n: int) -> None:
+        """Count ``n`` tokens appended to some ``entry.generated`` — the
+        one place tokens committed are tallied (a preempted request's
+        replay commits, and counts, its tokens again)."""
+        self.tokens_committed += n
+        self.counters.inc("serve.tokens_committed", n)
 
     def _readback(self, prev) -> None:
         """Read back one dispatched step's samples (the only host<-device
@@ -2968,13 +3000,16 @@ class Engine:
         cancel semantics are defined at readback time."""
         samples, slots = prev
         samples = np.asarray(samples)
+        committed = 0
         for s in slots:
             if self.slots[s.index] is not s:
                 continue  # terminated/evicted while the step was in flight
             s.tok = int(samples[s.index])
             s.entry.generated.append(s.tok)
+            committed += 1
             if len(s.entry.generated) >= s.entry.effective_max_new:
                 self._complete(s)
+        self._commit_tokens(committed)
 
     def _alloc_or_preempt(self, slot: _Slot, n: int) -> bool:
         """Allocate ``n`` pages for ``slot``, evicting victims until it
@@ -3051,43 +3086,48 @@ class Engine:
         Prefix-cache discipline: shared mappings are RELEASED (refcount
         only — the pages live in arena rows the reset below cannot name;
         ``paged_kv.reset_rows``), and the row bound is asserted so an
-        arena row can never be zeroed through this path."""
-        if slot.shared_nodes:
-            self.prefix.release(slot.shared_nodes)
-            slot.shared_nodes = []
-        self.pool.free_all(slot.entry.request_id)
-        idx = slot.index
-        assert 0 <= idx < self.config.max_batch, (
-            f"slot reset named row {idx} outside the slot rows "
-            f"[0, {self.config.max_batch}) — arena rows are owned by the "
-            "prefix index and are never reset here"
-        )
-        if slot.phase == _PREFILL:
-            TELEMETRY.end(
-                slot.prefill_span, outcome="aborted", filled=slot.filled
+        arena row can never be zeroed through this path.
+
+        One ``serve.step.release`` span per call: a release happens once
+        per finished (or evicted) request, nested in whichever phase of
+        the iteration decided it."""
+        with TELEMETRY.span("serve.step.release"):
+            if slot.shared_nodes:
+                self.prefix.release(slot.shared_nodes)
+                slot.shared_nodes = []
+            self.pool.free_all(slot.entry.request_id)
+            idx = slot.index
+            assert 0 <= idx < self.config.max_batch, (
+                f"slot reset named row {idx} outside the slot rows "
+                f"[0, {self.config.max_batch}) — arena rows are owned by the "
+                "prefix index and are never reset here"
             )
-            slot.prefill_span = None
-            slot.cache1 = None
-            slot.internal = None
-            if not self.fused:
-                # split mode: the chunks lived in a private batch-1 cache
-                # (dropped above); the batched row was never written
-                self.slots[idx] = None
-                return
-            # fused mode: the row's chunks were written straight into the
-            # batched cache — fall through to the same device reset a
-            # decoding slot gets
+            if slot.phase == _PREFILL:
+                TELEMETRY.end(
+                    slot.prefill_span, outcome="aborted", filled=slot.filled
+                )
+                slot.prefill_span = None
+                slot.cache1 = None
+                slot.internal = None
+                if not self.fused:
+                    # split mode: the chunks lived in a private batch-1 cache
+                    # (dropped above); the batched row was never written
+                    self.slots[idx] = None
+                    return
+                # fused mode: the row's chunks were written straight into the
+                # batched cache — fall through to the same device reset a
+                # decoding slot gets
 
-        def fn(path, x):
-            key = getattr(path[-1], "key", None)
-            if key in paged_kv.POOL_LEAF_KEYS:
-                return paged_kv.reset_rows(x, idx)
-            if key == "page_table":
-                return paged_kv.reset_table_rows(x, idx)
-            return x.at[idx].set(jnp.zeros_like(x[idx]))
+            def fn(path, x):
+                key = getattr(path[-1], "key", None)
+                if key in paged_kv.POOL_LEAF_KEYS:
+                    return paged_kv.reset_rows(x, idx)
+                if key == "page_table":
+                    return paged_kv.reset_table_rows(x, idx)
+                return x.at[idx].set(jnp.zeros_like(x[idx]))
 
-        self.cache = jax.tree_util.tree_map_with_path(fn, self.cache)
-        self.slots[slot.index] = None
+            self.cache = jax.tree_util.tree_map_with_path(fn, self.cache)
+            self.slots[slot.index] = None
 
     def _complete(self, slot: _Slot) -> None:
         if self.prefix is not None:
@@ -3305,32 +3345,7 @@ class Engine:
             prefix_misses=self._prefix_misses,
             deadline_misses=self._outcome_counts[Outcome.DEADLINE_EXCEEDED],
             terminations=sum(self._outcome_counts.values()),
-            jit_name=self._last_jit_name,
         )
-
-    def _maybe_charge_cost(self, name: str, fn, args: tuple) -> None:
-        """Charge the vitals cost ledger ONCE per jit name with the
-        executable's own cost_analysis() FLOPs/bytes. Uses AOT lowering
-        (``fn.lower`` never executes, so donated buffers are safe) and
-        fails open: the ledger is observability, never load-bearing."""
-        self._last_jit_name = name
-        if (
-            self.vitals is None
-            or not self.config.cost_ledger
-            or self.vitals.ledger.has(name)
-        ):
-            return
-        try:
-            ca = fn.lower(*args).cost_analysis()
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
-            self.vitals.ledger.charge(
-                name,
-                float(ca.get("flops", 0.0) or 0.0),
-                float(ca.get("bytes accessed", 0.0) or 0.0),
-            )
-        except Exception:
-            self.vitals.ledger.charge(name, 0.0, 0.0)
 
     def _run_controller(self) -> None:
         """One controller evaluation between iterations: vitals window

@@ -637,7 +637,8 @@ class Router:
                 if r.skip_steps > 0:
                     r.skip_steps -= 1   # injected stall: the engine hangs
                 else:
-                    r.engine.step()
+                    with TELEMETRY.span("router.step", replica=r.id):
+                        r.engine.step()
                     stepped += 1
                 self._harvest_locked(r)
             for r in self._replicas:

@@ -93,6 +93,9 @@ class Telemetry:
         # signal handler interrupting a thread that already holds the lock
         self.clock = clock or _MonotonicClock()
         self.enabled = False
+        # name -> context manager entered around every lexical span();
+        # wiring, not state: reset() leaves it (see span())
+        self.annotate = None
         self.ring_size = int(ring_size)
         self._buf: deque = deque()
         self._open: Dict[int, Tuple[str, float]] = {}  # sid -> (name, t0)
@@ -203,19 +206,33 @@ class Telemetry:
     @contextmanager
     def span(self, name: str, **attrs: Any) -> Iterator[Optional[int]]:
         """Lexical span: times the with-block, nests via a per-thread
-        stack (children record this span as ``parent``)."""
-        if not self.enabled:
-            yield None
-            return
-        sid = self.begin(name, **attrs)
-        st = self._stack()
-        st.append(sid)
+        stack (children record this span as ``parent``).
+
+        The with-block also runs inside ``self.annotate(name)`` when that
+        hook is set, WHETHER OR NOT the ring is enabled: the jax side
+        (utils/profiling.py) sets it to ``jax.profiler.TraceAnnotation``,
+        so any profiler capture holds the program's lexical spans on the
+        device events' clock. ``begin``/``end`` spans are not bridged: a
+        profiler annotation must close in the order it opened on its
+        thread, and those straddle iterations. With the ring disabled and
+        no hook, a span makes no record and no call."""
+        annotate = self.annotate
+        note = None if annotate is None else annotate(name)
+        if note is not None:
+            note.__enter__()
+        sid = self.begin(name, **attrs) if self.enabled else None
+        if sid is not None:
+            st = self._stack()
+            st.append(sid)
         try:
             yield sid
         finally:
-            if st and st[-1] == sid:
-                st.pop()
-            self.end(sid)
+            if sid is not None:
+                if st and st[-1] == sid:
+                    st.pop()
+                self.end(sid)
+            if note is not None:
+                note.__exit__(None, None, None)
 
     def event(self, name: str, **attrs: Any) -> None:
         """Point-in-time record (``ph: "I"``)."""

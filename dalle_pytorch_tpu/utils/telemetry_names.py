@@ -7,9 +7,12 @@ is declared here, per kind — one dot-separated namespace per subsystem
 ``telemetry.*`` the layer itself). The static checker
 (``tools/lint.py``, finding DTL041) flags any literal passed to
 ``counters.inc`` / ``gauges.set`` / ``histograms.observe`` /
-``TELEMETRY.span|begin|event`` that is not registered under the matching
-kind, and DTL042 flags registered names missing from the DESIGN.md §9
-tables — so the registry, the code, and the operator docs cannot drift.
+``TELEMETRY.span|begin|event`` — and to ``jax.named_scope`` or
+``pl.pallas_call(name=...)`` (``DEVICE_SCOPES``, ``KERNEL_NAMES``: the
+names the device trace carries) — that is not registered under the
+matching kind, and DTL042 flags registered names missing from the
+DESIGN.md §9 tables — so the registry, the code, and the operator docs
+cannot drift.
 
 This module is parsed by AST (never imported) by the linter, so keep the
 sets as flat literals. It is also importable at runtime (host-side only,
@@ -41,6 +44,20 @@ SPANS = frozenset({
     "serve.iteration",      # one per fused ragged iteration (one dispatch)
     "serve.spec_verify",    # one per speculative iteration: draft+verify+
                             # accept dispatch and its synchronous readback
+    # Engine.step cut into its phases: each a lexical child of serve.step,
+    # so a phase's self time is its span minus its children, and under a
+    # profiler capture each lands on the device events' clock
+    # (utils/profiling.py; DESIGN.md §9 "Spans on the profiler's clock")
+    "serve.step",           # one whole Engine.step() call
+    "serve.step.sweep",     # deadlines, cancels
+    "serve.step.admit",     # admission; serve.prefill* nest inside
+    "serve.step.plan",      # dispatchable-slot selection, page growth
+    "serve.step.fold_keys", # per-slot PRNG keys + token/key scatters
+    "serve.step.dispatch",  # the one model-jit call of the iteration
+    "serve.step.readback",  # the one host<-device sync
+    "serve.step.release",   # one per released slot (pages + row reset)
+    "serve.step.stages",    # post-decode pipeline; serve.stage.* nest inside
+    "serve.step.publish",   # vitals, controller, gauges
     # post-decode pipeline (serving/postdecode.py): one span per batched
     # stage dispatch — the auto "<span>_s" histograms ARE the per-stage
     # latency distributions
@@ -48,6 +65,7 @@ SPANS = frozenset({
     "serve.stage.clip_rerank",
     # replicated front door (serving/router.py)
     "router.request",       # router submit -> typed outcome
+    "router.step",          # one replica's Engine.step() (attr replica)
     # trainer (train_dalle.py)
     "train.step",           # dispatch -> verdict (device-inclusive)
     "train.data_wait",
@@ -114,6 +132,7 @@ COUNTERS = frozenset({
     "serve.preempted",
     "serve.decode_steps",
     "serve.dispatches",     # model-jit dispatches (fused: 1/iteration)
+    "serve.tokens_committed",  # tokens appended to a request's output
     "serve.prefill_chunks",
     "serve.prefill_retries",
     "serve.fault_request_cancel",
@@ -228,7 +247,6 @@ GAUGES = frozenset({
     "serve.vitals.stage_lag",           # windowed mean post-decode depth
     "serve.vitals.deadline_miss_rate",  # windowed misses/terminations
     "serve.vitals.occupancy",           # windowed mean pool occupancy
-    "serve.vitals.roofline_frac",       # iteration FLOPs/s vs device peak
     # effective knob levels the control loop last applied
     "serve.control.spec_k",
     "serve.control.budget",
@@ -268,11 +286,56 @@ HISTOGRAMS = frozenset({
     "router.retry_after_s",
 })
 
+# ------------------------------------------- device scopes and kernels
+
+# jax.named_scope names inside the jitted steps: what a reader of a
+# profiler trace must know (the layer KIND, the stage), nothing about the
+# implementation under it. They reach every device event through the HLO
+# op_name metadata; the backward pass inherits them through JAX's
+# transpose(jvp(...)) wrapping. The Flax module path stays beneath.
+DEVICE_SCOPES = frozenset({
+    # one per attention sublayer, by its attn_type (models/transformer.py)
+    "attn.full",
+    "attn.axial_row",
+    "attn.axial_col",
+    "attn.conv_like",
+    "attn.sparse",
+    "attn.mlp",
+    "ff",                   # one per feed-forward sublayer
+    "embed",                # token + positional embeddings (models/dalle.py)
+    "head_loss",            # final norm, logits, the weighted cross-entropy
+    "sample",               # top-k, gumbel, the draw (serving jits)
+    "vae.encode",           # DiscreteVAE.get_codebook_indices
+    # parallel/step.py: the clip lives in the optimizer chain it is handed
+    "update",
+    "update.optimizer",
+    "update.nan_guard",
+})
+
+# pl.pallas_call(name=...): kernel and pass, ending in a letter (trace
+# reducers strip trailing digits and dots)
+KERNEL_NAMES = frozenset({
+    "flash_fwd",            # ops/flash_attention.py, blocked
+    "flash_bwd",            #   single-block fused backward
+    "flash_dq",
+    "flash_dkv",
+    "flash_qkv_fwd",        #   packed whole-row (fused qkv) route
+    "flash_qkv_bwd",
+    "block_sparse_fwd",     # ops/block_sparse_attention.py pair grid
+    "block_sparse_dq",
+    "block_sparse_dkv",
+    "ragged_paged_attend",  # ops/ragged_attention.py
+    "decode_attend",        # ops/decode_attention.py
+})
+
 # span durations are auto-observed as "<span>_s" (utils/telemetry.py);
 # derived here so readers (bench latency splits) can validate against it
 SPAN_DURATION_HISTOGRAMS = frozenset(s + "_s" for s in SPANS)
 
-ALL_NAMES = SPANS | EVENTS | COUNTERS | GAUGES | HISTOGRAMS
+ALL_NAMES = (
+    SPANS | EVENTS | COUNTERS | GAUGES | HISTOGRAMS
+    | DEVICE_SCOPES | KERNEL_NAMES
+)
 
 _KINDS = {
     "span": SPANS,
@@ -280,12 +343,14 @@ _KINDS = {
     "counter": COUNTERS,
     "gauge": GAUGES,
     "histogram": HISTOGRAMS | SPAN_DURATION_HISTOGRAMS,
+    "scope": DEVICE_SCOPES,
+    "kernel": KERNEL_NAMES,
 }
 
 
 def is_registered(name: str, kind: str = None) -> bool:
     """True iff ``name`` is registered (optionally under ``kind`` in
-    span/event/counter/gauge/histogram)."""
+    span/event/counter/gauge/histogram/scope/kernel)."""
     if kind is None:
         return name in ALL_NAMES or name in SPAN_DURATION_HISTOGRAMS
     return name in _KINDS[kind]

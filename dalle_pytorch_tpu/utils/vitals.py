@@ -13,99 +13,16 @@ the cumulative series — windowing is subtraction over ring samples
 never a producer-side reset.
 
 Host-only by lint contract (DTL021, tools/lint/config.py): no jax
-anywhere in this module. Device facts enter as plain floats — the COST
-LEDGER is charged by the ENGINE (the layer where jax is allowed) with
-each serving jit's ``compiled.cost_analysis()`` FLOPs/bytes, once per
-signature; this module only divides those numbers by wall time to keep
-the per-iteration roofline fraction a live gauge instead of a bench
-artifact (docs/DESIGN.md §8.6).
+anywhere in this module. It holds no device number: how close a kernel
+or a step runs to its roofline is read from a profiler trace by the
+benchmark (``benchmarks/``, PERF.md §3), against its one table of peaks.
 """
 
 from __future__ import annotations
 
-import threading
 from typing import Dict, Optional
 
 from .metrics import GaugeRing
-
-# device peaks for the live roofline gauge, keyed by jax device_kind —
-# mirrors bench.py's PEAK_FLOPS/PEAK_HBM_BPS tables (bf16 matmul peak,
-# HBM stream peak). Unknown kinds (CPU tiers) get None: the gauge reads
-# 0.0 rather than inventing a CPU roofline.
-DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
-    "TPU v4": {"flops": 275e12, "bytes_ps": 1.2e12},
-    "TPU v5 lite": {"flops": 197e12, "bytes_ps": 0.82e12},
-    "TPU v5e": {"flops": 197e12, "bytes_ps": 0.82e12},
-    "TPU v5p": {"flops": 459e12, "bytes_ps": 2.77e12},
-}
-
-
-def peaks_for(device_kind: Optional[str]) -> Optional[Dict[str, float]]:
-    """Peak FLOPs/s and HBM bytes/s for a device kind, or None when the
-    kind has no table entry (roofline gauge stays 0)."""
-    if device_kind is None:
-        return None
-    return DEVICE_PEAKS.get(device_kind)
-
-
-class CostLedger:
-    """Once-per-signature cost entries for the serving jits.
-
-    The engine charges each jit name exactly once with the FLOPs and
-    bytes its compiled executable reports (``cost_analysis()``); repeat
-    charges are ignored so a steady-state iteration pays one dict probe.
-    Entries are plain floats — the ledger is importable (and testable)
-    anywhere the host-only observability layer is.
-    """
-
-    _GUARDED_BY = {"_lock": ("_entries",)}
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self._entries: Dict[str, Dict[str, float]] = {}
-
-    def charge(self, name: str, flops: float, bytes_accessed: float) -> bool:
-        """Record ``name``'s per-dispatch cost; False if already charged
-        (the once-per-signature contract — first capture wins)."""
-        with self._lock:
-            if name in self._entries:
-                return False
-            self._entries[name] = {
-                "flops": float(flops),
-                "bytes_accessed": float(bytes_accessed),
-            }
-            return True
-
-    def has(self, name: str) -> bool:
-        with self._lock:
-            return name in self._entries
-
-    def entry(self, name: str) -> Optional[Dict[str, float]]:
-        with self._lock:
-            e = self._entries.get(name)
-            return dict(e) if e is not None else None
-
-    def snapshot(self) -> Dict[str, Dict[str, float]]:
-        with self._lock:
-            return {k: dict(v) for k, v in self._entries.items()}
-
-    def roofline_frac(self, name: str, dt_s: float,
-                      peaks: Optional[Dict[str, float]]) -> float:
-        """Fraction of the binding roof one dispatch of ``name`` achieved
-        over ``dt_s`` wall seconds: max(FLOPs/s / peak_flops, bytes/s /
-        peak_bytes). 0.0 when the name is uncharged, the peaks are
-        unknown, or the window is degenerate (FakeClock dt=0)."""
-        if peaks is None or dt_s <= 0.0:
-            return 0.0
-        e = self.entry(name)
-        if e is None:
-            return 0.0
-        fracs = []
-        if peaks.get("flops"):
-            fracs.append(e["flops"] / dt_s / peaks["flops"])
-        if peaks.get("bytes_ps"):
-            fracs.append(e["bytes_accessed"] / dt_s / peaks["bytes_ps"])
-        return max(fracs) if fracs else 0.0
 
 
 def _window_delta(ring: GaugeRing) -> float:
@@ -127,12 +44,9 @@ class Vitals:
     themselves are thread-safe for concurrent scrape-side readers.
     """
 
-    def __init__(self, window: int = 32,
-                 peaks: Optional[Dict[str, float]] = None):
+    def __init__(self, window: int = 32):
         assert window >= 2, window
         self.window = window
-        self.peaks = peaks
-        self.ledger = CostLedger()
         # level series: windowed directly
         self._occupancy = GaugeRing(window)
         self._stage_lag = GaugeRing(window)
@@ -145,8 +59,6 @@ class Vitals:
         self._deadline_misses = GaugeRing(window)
         self._terminations = GaugeRing(window)
         self._last_now: Optional[float] = None
-        self._last_jit: Optional[str] = None
-        self._last_dt = 0.0
         self.iterations = 0
 
     def observe_iteration(
@@ -154,15 +66,12 @@ class Vitals:
         spec_drafted: float, spec_accepted: float,
         prefix_hits: float, prefix_misses: float,
         deadline_misses: float, terminations: float,
-        jit_name: Optional[str] = None,
     ) -> None:
         """Push one iteration's sample set. All counter-style arguments
         are CUMULATIVE (lifetime) values; the vitals layer windows them."""
         if self._last_now is not None:
-            self._last_dt = max(0.0, now - self._last_now)
-            self._gap.push(self._last_dt)
+            self._gap.push(max(0.0, now - self._last_now))
         self._last_now = now
-        self._last_jit = jit_name
         self._occupancy.push(occupancy)
         self._stage_lag.push(stage_queued)
         self._spec_drafted.push(spec_drafted)
@@ -183,11 +92,6 @@ class Vitals:
         misses = _window_delta(self._prefix_misses)
         dl = _window_delta(self._deadline_misses)
         terms = _window_delta(self._terminations)
-        roofline = 0.0
-        if self._last_jit is not None:
-            roofline = self.ledger.roofline_frac(
-                self._last_jit, self._last_dt, self.peaks
-            )
         return {
             "iterations": float(self.iterations),
             "spec_accept_rate": accepted / drafted if drafted > 0 else 0.0,
@@ -199,7 +103,6 @@ class Vitals:
             "stage_lag": self._stage_lag.window()["mean"],
             "deadline_miss_rate": dl / terms if terms > 0 else 0.0,
             "occupancy": self._occupancy.window()["mean"],
-            "roofline_frac": roofline,
         }
 
     def publish(self, gauges) -> Dict[str, float]:
@@ -215,5 +118,4 @@ class Vitals:
             "serve.vitals.deadline_miss_rate", snap["deadline_miss_rate"]
         )
         gauges.set("serve.vitals.occupancy", snap["occupancy"])
-        gauges.set("serve.vitals.roofline_frac", snap["roofline_frac"])
         return snap

@@ -25,3 +25,12 @@ GAUGES = frozenset({
 HISTOGRAMS = frozenset({
     "fx.wait_s",
 })
+
+DEVICE_SCOPES = frozenset({
+    "fx_scope",
+    "fx_attn.alpha",
+})
+
+KERNEL_NAMES = frozenset({
+    "fx_kernel_fwd",
+})

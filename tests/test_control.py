@@ -66,7 +66,7 @@ def vit(**kw):
     base = {
         "iterations": 0.0, "spec_accept_rate": 0.0, "spec_drafted": 0.0,
         "prefix_hit_frac": 0.0, "decode_gap_s": 0.0, "stage_lag": 0.0,
-        "deadline_miss_rate": 0.0, "occupancy": 0.0, "roofline_frac": 0.0,
+        "deadline_miss_rate": 0.0, "occupancy": 0.0,
     }
     base.update(kw)
     return base
@@ -333,7 +333,6 @@ class TestEngineControl:
             "serve.vitals.deadline_miss_rate",
             "serve.vitals.stage_lag",
             "serve.vitals.prefix_hit_frac",
-            "serve.vitals.roofline_frac",
         ):
             assert name in published, name
         assert gauges.get("serve.vitals.decode_gap_s") == pytest.approx(1.0)

@@ -1,0 +1,129 @@
+"""The Pallas kernels of the training cell, compiled for a described (not
+attached) TPU v5e at the cell's own shapes: what Mosaic and the TPU lowering
+accept, and that every kernel arrives in the compiled program under the name
+``utils/telemetry_names.py:KERNEL_NAMES`` registers — the instruction name a
+profiler trace shows (docs/DESIGN.md §9, PERF.md §3).
+
+Nothing runs here, so this says nothing about results or times. The topology
+is described inside a fixture and nowhere at import: only one process may
+load the TPU's library, and under several workers only the worker that is
+given THIS file may try (``on-chip-measurement`` guide §2). Keep every such
+compile in this one file.
+"""
+
+import os
+import re
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from dalle_pytorch_tpu.ops import block_sparse_attention as bs
+from dalle_pytorch_tpu.ops import masks as masks_lib
+from dalle_pytorch_tpu.ops.flash_attention import (
+    StaticMask,
+    flash_attention,
+    fused_qkv_attention,
+)
+from dalle_pytorch_tpu.utils.telemetry_names import KERNEL_NAMES
+
+# train-d12sparse-posemb-b8: batch 8, 16 heads of 64, 256 + 1024 positions
+B, H, D, TEXT, FMAP = 8, 16, 64, 257, 32
+N = TEXT + FMAP * FMAP - 1
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without the chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _axial_row_mask():
+    return masks_lib.axial_mask(TEXT, FMAP, axis=0)[:N, :N]
+
+
+def _packed(qkv):
+    # the route of the cell's ``full`` and ``axial_col`` layers
+    return fused_qkv_attention(qkv, None, H, D, None, True, None, D**-0.5, False)
+
+
+def _blocked(q, k, v):
+    pattern = StaticMask(_axial_row_mask())
+    return flash_attention(q, k, v, None, True, pattern, D**-0.5, 256, 256, False)
+
+
+def _pair_grid(q, k, v):
+    # the route of the cell's ``axial_row`` and ``conv_like`` layers
+    layout = bs.compile_block_layout(_axial_row_mask(), 128, 128)
+    return bs.block_sparse_attention(q, k, v, layout, sm_scale=D**-0.5, interpret=False)
+
+
+ROUTES = {
+    "packed_flash": (_packed, [(B, N, 3 * H * D)], {"flash_qkv_fwd", "flash_qkv_bwd"}),
+    "blocked_flash": (_blocked, [(B, H, N, D)] * 3, {"flash_fwd", "flash_dq", "flash_dkv"}),
+    "pair_grid": (
+        _pair_grid, [(B, H, N, D)] * 3,
+        {"block_sparse_fwd", "block_sparse_dq", "block_sparse_dkv"},
+    ),
+}
+
+
+@pytest.mark.parametrize("route", sorted(ROUTES))
+def test_kernels_compile_for_v5e_under_their_registered_names(route, one_chip):
+    fn, shapes, names = ROUTES[route]
+    assert names <= KERNEL_NAMES
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip) for s in shapes]
+
+    def loss(*xs):
+        return jnp.sum(fn(*xs).astype(jnp.float32))
+
+    grad = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(len(args)))))
+    text = grad.lower(*args).compile().as_text()
+    # a Pallas call's instruction is named after the innermost component of
+    # its name stack, the kernel's ``name=`` (here wrapped as
+    # ``jvp_<name>_``, in the whole step plain ``<name>.<n>``): what a
+    # profiler trace names the event by
+    kernels = re.findall(r"^\s*%([\w.\-]+) = .*tpu_custom_call", text, re.M)
+    assert len(kernels) == len(names), kernels
+    for name in names:
+        assert any(name in k for k in kernels), f"{name} not among {kernels}"
+
+
+def test_every_registered_kernel_is_named_at_its_call():
+    """The registry against the source: each name is the ``name=`` of a
+    ``pallas_call`` (or of a wrapper that hands it on) under ops/."""
+    import pathlib
+
+    ops = pathlib.Path(bs.__file__).parent
+    named = set()
+    for path in ops.glob("*.py"):
+        named |= set(re.findall(r'\bname="([a-z_]+)"', path.read_text()))
+    assert KERNEL_NAMES <= named, KERNEL_NAMES - named
+    assert np.all([n[-1].isalpha() for n in KERNEL_NAMES])

@@ -217,6 +217,25 @@ class TestTelemetryNames:
         res = self.run(full=False)
         assert 12 not in {f.line for f in res.findings}  # fx.request_s ok
 
+    def test_device_scopes_and_kernel_names(self):
+        """``jax.named_scope`` literals and ``pallas_call(name=)`` (direct
+        or through an ops/ wrapper) are names like every other; a kernel
+        with no name at all is a finding too."""
+        cfg = fixture_config(names=NamesConfig(
+            registry_path=f"{FX}/fx_names_registry.py",
+            doc_path=f"{FX}/fx_names_doc.md",
+        ))
+        res = run_lint(cfg, paths=[f"{FX}/fx_device_names.py"],
+                       checkers=["telemetry-names"], full=False)
+        assert codes_lines(res.findings) == [
+            ("DTL041", 13),   # fx_scoop: unregistered scope
+            ("DTL041", 17),   # f"fx_ffn.{...}": head matches no scope
+            ("DTL041", 20),   # fx_kernel_bwd: unregistered kernel
+            ("DTL041", 21),   # pallas_call without name=
+            ("DTL041", 22),   # unregistered, through a wrapper
+            ("DTL041", 23),   # a kernel's name used as a scope
+        ], [f.render() for f in res.findings]
+
     def test_doc_crosscheck(self):
         res = self.run(full=True)
         dtl042 = [f for f in res.findings if f.code == "DTL042"]

@@ -1,8 +1,8 @@
 """Engine vitals layer (utils/vitals.py) and its metrics substrate
 (ISSUE 19): windowed histogram deltas that never reset the cumulative
-Prometheus series, the gauge ring's sliding reductions, the
-once-per-signature cost ledger, and the Vitals windows the controller
-consumes — all pure host arithmetic, no engine required."""
+Prometheus series, the gauge ring's sliding reductions, and the Vitals
+windows the controller consumes — all pure host arithmetic, no engine
+required."""
 
 import math
 
@@ -14,11 +14,7 @@ from dalle_pytorch_tpu.utils.metrics import (
     HistogramCheckpoint,
     gauges,
 )
-from dalle_pytorch_tpu.utils.vitals import (
-    CostLedger,
-    Vitals,
-    peaks_for,
-)
+from dalle_pytorch_tpu.utils.vitals import Vitals
 
 
 # ------------------------------------------------------------ GaugeRing
@@ -133,48 +129,11 @@ class TestSnapshotDelta:
         assert h.snapshot() == before
 
 
-# ----------------------------------------------------------- CostLedger
-
-
-class TestCostLedger:
-    def test_charge_once_per_signature(self):
-        led = CostLedger()
-        assert led.charge("iteration", 100.0, 200.0)
-        assert not led.charge("iteration", 999.0, 999.0)  # first wins
-        assert led.entry("iteration") == {
-            "flops": 100.0, "bytes_accessed": 200.0,
-        }
-        assert led.has("iteration") and not led.has("decode")
-        assert led.entry("decode") is None
-
-    def test_roofline_frac_binding_roof(self):
-        led = CostLedger()
-        led.charge("it", 1e12, 1e12)
-        peaks = {"flops": 2e12, "bytes_ps": 1e12}
-        # over 1s: flops frac 0.5, bytes frac 1.0 -> the binding roof
-        assert led.roofline_frac("it", 1.0, peaks) == pytest.approx(1.0)
-        # over 2s both halve
-        assert led.roofline_frac("it", 2.0, peaks) == pytest.approx(0.5)
-
-    def test_roofline_degenerate_inputs(self):
-        led = CostLedger()
-        led.charge("it", 1e12, 1e12)
-        peaks = {"flops": 1e12, "bytes_ps": 1e12}
-        assert led.roofline_frac("it", 0.0, peaks) == 0.0  # FakeClock dt=0
-        assert led.roofline_frac("it", 1.0, None) == 0.0   # unknown device
-        assert led.roofline_frac("other", 1.0, peaks) == 0.0  # uncharged
-
-    def test_peaks_table(self):
-        assert peaks_for("TPU v5 lite")["flops"] > 0
-        assert peaks_for("cpu") is None
-        assert peaks_for(None) is None
-
-
 # --------------------------------------------------------------- Vitals
 
 
 def feed(v, n, *, dt=1.0, drafted=0, accepted=0, hits=0, misses=0,
-         dl=0, terms=0, occ=0.5, stage=0.0, jit=None, t0=0.0):
+         dl=0, terms=0, occ=0.5, stage=0.0, t0=0.0):
     """Push n iterations of CUMULATIVE samples growing linearly."""
     for i in range(1, n + 1):
         v.observe_iteration(
@@ -182,7 +141,6 @@ def feed(v, n, *, dt=1.0, drafted=0, accepted=0, hits=0, misses=0,
             spec_drafted=drafted * i, spec_accepted=accepted * i,
             prefix_hits=hits * i, prefix_misses=misses * i,
             deadline_misses=dl * i, terminations=terms * i,
-            jit_name=jit,
         )
 
 
@@ -230,13 +188,6 @@ class TestVitals:
         assert snap["spec_accept_rate"] == 0.0
         assert snap["prefix_hit_frac"] == 0.0
         assert snap["deadline_miss_rate"] == 0.0
-        assert snap["roofline_frac"] == 0.0
-
-    def test_roofline_live_gauge(self):
-        v = Vitals(window=4, peaks={"flops": 1e9, "bytes_ps": 1e9})
-        v.ledger.charge("iteration", 5e8, 1e8)
-        feed(v, 4, dt=1.0, jit="iteration")
-        assert v.snapshot()["roofline_frac"] == pytest.approx(0.5)
 
     def test_publish_sets_registered_gauges(self):
         v = Vitals(window=4)
@@ -250,7 +201,6 @@ class TestVitals:
         assert gauges.get("serve.vitals.occupancy") == pytest.approx(0.5)
         assert gauges.get("serve.vitals.deadline_miss_rate") == 0.0
         assert gauges.get("serve.vitals.stage_lag") == 0.0
-        assert gauges.get("serve.vitals.roofline_frac") == 0.0
 
     def test_snapshot_keys_are_stable(self):
         # a deterministic controller must never branch on key existence

@@ -12,7 +12,12 @@ Checked call shapes (first positional argument):
   ``histograms.observe/get(...)`` — receiver's last attribute component
   must literally be ``counters``/``gauges``/``histograms`` (the module
   registries or an engine's ``self.counters`` child view);
-* ``TELEMETRY.begin/span(...)`` (spans) and ``TELEMETRY.event(...)``.
+* ``TELEMETRY.begin/span(...)`` (spans) and ``TELEMETRY.event(...)``;
+* ``jax.named_scope(...)`` (``DEVICE_SCOPES``: the names the jitted
+  steps put on every device event of a profiler trace) and the ``name=``
+  keyword of ``pl.pallas_call(...)`` or of one of the ops/ wrappers that
+  forward it (``KERNEL_NAMES``). A ``pallas_call`` with no ``name=`` is
+  a finding too: an unnamed kernel is ``fn`` in every trace.
 
 Literal names must be registered exactly. f-strings with a literal head
 (``f"serve.rejected.{reason.value}"``) must have a head that prefixes at
@@ -43,7 +48,10 @@ from .core import (
     str_const,
 )
 
-_REGISTRY_SETS = ("SPANS", "EVENTS", "COUNTERS", "GAUGES", "HISTOGRAMS")
+_REGISTRY_SETS = ("SPANS", "EVENTS", "COUNTERS", "GAUGES", "HISTOGRAMS",
+                  "DEVICE_SCOPES", "KERNEL_NAMES")
+# ops/ helpers that hand their ``name=`` keyword on to ``pl.pallas_call``
+_KERNEL_CALL_WRAPPERS = {"_call", "_call_plain", "_pair_call"}
 
 # receiver last-component -> (checked methods, registry kind)
 _RECEIVERS = {
@@ -56,6 +64,11 @@ _TELEMETRY_METHODS = {
     "span": "SPANS",
     "event": "EVENTS",
 }
+
+
+def _kind_word(kind: str) -> str:
+    """'COUNTERS' -> 'counter', 'DEVICE_SCOPES' -> 'device scope'."""
+    return kind.lower().replace("_", " ")[:-1]
 
 
 def _receiver_tail(node: ast.AST) -> Optional[str]:
@@ -98,19 +111,39 @@ def check(files: Sequence[SourceFile], config,
             if not isinstance(node, ast.Call):
                 continue
             fn = node.func
-            if not isinstance(fn, ast.Attribute) or not node.args:
-                continue
+            callee = _receiver_tail(fn)
+            arg = None
             kind = None
-            tail = _receiver_tail(fn.value)
-            if tail in _RECEIVERS:
-                methods, kind_key = _RECEIVERS[tail]
-                if fn.attr in methods:
-                    kind = kind_key
-            elif tail == "TELEMETRY" and fn.attr in _TELEMETRY_METHODS:
-                kind = _TELEMETRY_METHODS[fn.attr]
+            if callee == "pallas_call" or callee in _KERNEL_CALL_WRAPPERS:
+                kind = "KERNEL_NAMES"
+                arg = next(
+                    (kw.value for kw in node.keywords if kw.arg == "name"),
+                    None,
+                )
+                if arg is None:
+                    if callee == "pallas_call":
+                        findings.append(Finding(
+                            "DTL041", sf.path, node.lineno,
+                            "pallas_call without name=: the kernel is "
+                            "anonymous in every profiler trace — name it "
+                            f"and register the name in {nc.registry_path}",
+                            anchor="KERNEL_NAMES:<unnamed>",
+                        ))
+                    continue
+            elif callee == "named_scope" and node.args:
+                kind = "DEVICE_SCOPES"
+                arg = node.args[0]
+            elif isinstance(fn, ast.Attribute) and node.args:
+                tail = _receiver_tail(fn.value)
+                if tail in _RECEIVERS:
+                    methods, kind_key = _RECEIVERS[tail]
+                    if fn.attr in methods:
+                        kind = kind_key
+                elif tail == "TELEMETRY" and fn.attr in _TELEMETRY_METHODS:
+                    kind = _TELEMETRY_METHODS[fn.attr]
+                arg = node.args[0]
             if kind is None:
                 continue
-            arg = node.args[0]
             name = str_const(arg)
             valid = kind_names[kind]
             if name is not None:
@@ -121,7 +154,7 @@ def check(files: Sequence[SourceFile], config,
                              else "not in the registry")
                     findings.append(Finding(
                         "DTL041", sf.path, node.lineno,
-                        f"telemetry name {name!r} used as {kind.lower()[:-1]} "
+                        f"telemetry name {name!r} used as {_kind_word(kind)} "
                         f"is {where} — add it to "
                         f"{nc.registry_path} (and docs §9) or fix the typo",
                         anchor=f"{kind}:{name}",
@@ -136,7 +169,7 @@ def check(files: Sequence[SourceFile], config,
                 findings.append(Finding(
                     "DTL041", sf.path, node.lineno,
                     f"dynamic telemetry name with head {prefix!r} matches "
-                    f"no registered {kind.lower()} — register the expanded "
+                    f"no registered {_kind_word(kind)} — register the expanded "
                     f"names or fix the namespace",
                     anchor=f"{kind}:{prefix}*",
                 ))
@@ -164,7 +197,7 @@ def check(files: Sequence[SourceFile], config,
             if not documented(name):
                 findings.append(Finding(
                     "DTL042", nc.registry_path, reg_line,
-                    f"registered {kind.lower()[:-1]} {name!r} is not "
+                    f"registered {_kind_word(kind)} {name!r} is not "
                     f"documented in {nc.doc_path} {nc.doc_section}* — "
                     f"add it to the name tables (backtick-quoted)",
                     anchor=name,

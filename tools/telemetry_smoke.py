@@ -299,7 +299,7 @@ def main(argv=None) -> int:
 
     eng = Engine(dalle, params, EngineConfig(
         max_batch=2, prefill_chunk=2, fused_iteration=True,
-        controller=True, cost_ledger=True,
+        controller=True,
         control=ControlConfig(interval=2),
     ), clock=FakeClock(step_dt=1.0))
     rng = np.random.RandomState(5)
@@ -336,7 +336,7 @@ def main(argv=None) -> int:
               f"flight file — the audit trail is incomplete")
     dump = TELEMETRY.dump()
     for series in ("serve_vitals_occupancy", "serve_vitals_decode_gap_s",
-                   "serve_vitals_roofline_frac", "serve_control_decisions",
+                   "serve_control_decisions",
                    "serve_control_budget"):
         check(series in dump,
               f"vitals/control series {series!r} missing from /metrics")

@@ -219,6 +219,7 @@ def main():
         make_runtime,
         make_train_step,
     )
+    from dalle_pytorch_tpu.utils.profiling import StepCapture
     from dalle_pytorch_tpu.utils import (
         FAULTS,
         MetricsLogger,
@@ -557,7 +558,10 @@ def main():
     throughput = Throughput(window=10)
     prev_loss = None
     step_span = None  # open train.step telemetry span (dispatch -> verdict)
-    tracing = False
+    capture = StepCapture(
+        args.profile_trace_dir if runtime.is_root_worker() else None,
+        args.profile_step,
+    )
     # applied_steps keys the step rng by BATCH, not by dispatch attempt: a
     # batch retried after a NaN skip reuses its key, so a recovered run's
     # update sequence matches an unfaulted run's exactly
@@ -689,22 +693,14 @@ def main():
                     "text": jnp.asarray(batch["text"]),
                     "image": image_tokens,
                 }
-                if args.profile_trace_dir is not None and runtime.is_root_worker():
-                    # trace a steady-state window: block so compilation and
-                    # the profiled steps don't overlap in the capture
-                    if global_step == args.profile_step:
-                        jax.block_until_ready(state.params)
-                        jax.profiler.start_trace(args.profile_trace_dir)
-                        tracing = True
-                    elif global_step == args.profile_step + 3:
-                        jax.block_until_ready(state.params)
-                        jax.profiler.stop_trace()
-                        tracing = False
-                        logger.log_text(
-                            f"profiler trace for steps "
-                            f"{args.profile_step}..{args.profile_step + 2} "
-                            f"written to {args.profile_trace_dir}"
-                        )
+                # a steady-state window of three steps; the loop's lexical
+                # TELEMETRY spans land in the same capture (utils/profiling.py)
+                if capture.at_step(global_step, state.params):
+                    logger.log_text(
+                        f"profiler trace for steps "
+                        f"{args.profile_step}..{args.profile_step + 2} "
+                        f"written to {args.profile_trace_dir}"
+                    )
 
                 state, loss = step_fn(
                     state, train_batch, jax.random.key(applied_steps),
@@ -766,9 +762,7 @@ def main():
                     # finished above — write the emergency step-granular
                     # checkpoint and exit cleanly; the next launch resumes
                     # from it via the startup probe
-                    if tracing:
-                        jax.profiler.stop_trace()
-                        tracing = False
+                    capture.close()
                     # as with periodic saves: resolve the in-flight step's
                     # verdict so scheduler state is complete and a
                     # just-rejected batch is recorded as unconsumed (the
@@ -793,9 +787,7 @@ def main():
             logger.log_artifact("trained-dalle", ckpt_path, metadata=vars(args))
             logger.log_text(f"epoch {epoch} complete")
 
-    if tracing:  # training ended inside the trace window
-        jax.block_until_ready(state.params)
-        jax.profiler.stop_trace()
+    capture.close(state.params)  # training ended inside the trace window
 
     logger.finish()
 
