@@ -434,15 +434,37 @@ class PatternAttention(nn.Module):
                 and not self.is_initializing()
                 and sp_extent(self.sp_axis) > 1
             )
-            # pair-grid block-sparse kernel (ops/block_sparse_attention.py):
-            # the grid visits only live block pairs, so — unlike the packed
-            # flash path below, whose affine index maps still DMA every
-            # block — sparse patterns stop paying dense memory traffic.
-            # Policy-gated (auto = TPU): the dense-mask paths stay the
-            # fallback and the parity oracle.
+            # ONE rule picks the training kernel of a static pattern, from
+            # what the call itself shows (n, heads, mesh, rotary form):
+            #   1. the packed single-block flash kernel wherever it is
+            #      eligible: q/k/v head slices stream straight out of the
+            #      projection layout, rotary applied in-kernel, the non-full
+            #      patterns streaming their static int8 mask as an operand —
+            #      no split/reshape/transpose/rotary sweeps through HBM;
+            #   2. the pair grid (ops/block_sparse_attention.py) only where
+            #      (1) cannot run and the COMPILED layout really skips block
+            #      pairs (ENGAGE_FRAC; policy-gated, auto = TPU);
+            #   3. the blocked flash kernel, then the jnp forms.
+            # The chip reading behind the order (v5e, seq 1280; the head of
+            # ops/block_sparse_attention.py has it whole): a pair-grid
+            # layer 10.5 ms, the packed kernel's whole square 2.2 ms —
+            # the pair grid pays per grid step, not per FLOP.
+            # (under tensor parallelism the packed (b, n, 3*h*d) layout is
+            # split over tp in contiguous thirds, not by head: those meshes
+            # take the per-head routes, heads over tp)
+            use_packed = (
+                not use_sp
+                and not force_dense
+                and self.use_flash
+                and _flash_block(n) == n
+                and fused_qkv_supported(n, h, d)
+                and (rotary_pos_emb is None or rot_static is not None)
+                and axis_extent("tp") == 1
+            )
             use_block_sparse = False
             if (
-                not use_sp
+                not use_packed
+                and not use_sp
                 and not force_dense
                 and self.attn_type != "full"
                 and _sparse_block(n) > 0
@@ -453,39 +475,17 @@ class PatternAttention(nn.Module):
                 )
 
                 if sparse_kernel_enabled():
-                    # engage only when the COMPILED layout actually skips
-                    # block pairs: a pattern whose live stride is finer
-                    # than the 128-block edge (axial_col at fmap <= 128,
-                    # the 16-block DeepSpeed-style random layout) visits
-                    # every causal pair — the pair grid would pay kernel
-                    # overhead for zero skipped FLOPs, so it declines and
-                    # the dense/flash paths keep those patterns
+                    # a pattern whose live stride is finer than the
+                    # 128-block edge (axial_col at fmap <= 128, the 16-block
+                    # DeepSpeed-style random layout) visits every causal
+                    # pair — the pair grid would pay kernel overhead for
+                    # zero skipped FLOPs, so it declines and the
+                    # dense/flash paths keep those patterns
                     layout = _cached_block_layout(self, n, _sparse_block(n))
                     use_block_sparse = (
                         layout.visited_block_frac <= ENGAGE_FRAC
                     )
-            # packed single-block path: q/k/v head slices stream straight
-            # out of the projection layout, rotary applied in-kernel — no
-            # split/reshape/transpose/rotary sweeps through HBM. EVERY
-            # pattern rides this kernel at flash-eligible shapes, with the
-            # non-full patterns streaming their static mask as an in-kernel
-            # operand — measured at the flagship shape (seq 1280, v5e), the
-            # kernel's full-square compute beats any grouped formulation
-            # that materializes scores in HBM (see the measurement note at
-            # _pattern_attend below)
-            # (under tensor parallelism the packed (b, n, 3*h*d) layout is
-            # split over tp in contiguous thirds, not by head: those meshes
-            # take the per-head flash path below, heads over tp)
-            if (
-                not use_sp
-                and not use_block_sparse
-                and self.use_flash
-                and not force_dense
-                and _flash_block(n) == n
-                and fused_qkv_supported(n, h, d)
-                and (rotary_pos_emb is None or rot_static is not None)
-                and axis_extent("tp") == 1
-            ):
+            if use_packed:
                 pattern = (
                     _cached_flash_mask(self, n)
                     if self.attn_type != "full" else None
@@ -574,10 +574,11 @@ class PatternAttention(nn.Module):
     def _block_sparse_attend(self, q, k, v, n: int, mask=None):
         """Pair-grid block-sparse kernel (ops/block_sparse_attention.py):
         the compiled BlockLayout's live pairs ARE the grid, so masked
-        blocks cost neither DMA nor FLOPs — the path that makes the
-        sparse patterns pay at seq >= 2048. Interpret mode off-TPU, where
-        the CPU parity tier pins it allclose against the dense-mask
-        reference per layout (tests/test_block_sparse.py)."""
+        blocks cost neither DMA nor FLOPs. Chosen only at shapes the packed
+        single-block flash kernel cannot run (``__call__``'s training gate;
+        the chip reading is at the head of that module). Interpret mode
+        off-TPU, where the CPU parity tier pins it allclose against the
+        dense-mask reference per layout (tests/test_block_sparse.py)."""
         from .block_sparse_attention import block_sparse_attention
 
         layout = _cached_block_layout(self, n, _sparse_block(n))

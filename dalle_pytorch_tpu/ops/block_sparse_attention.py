@@ -41,9 +41,25 @@ the shard_map body: all-gather K/V/Q over sp, each chip computes its
 assigned (permuted) q-rows, and a static inverse permutation restores
 natural order before each chip returns its contiguous shard.
 
+What a grid step costs, and where the kernel is chosen (v5e, PERF.md
+section 6, PR 31): at the 128 x 128 block a step is two 128 x 128 x 64 dots,
+~0.02 us of MXU work, under ~0.7 us of Mosaic's per-step overhead. At seq
+1280 (batch 8, 16 heads: 8 x 16 x 40 live pairs = 5,120 steps in each of the
+three kernels) an axial_row or conv_like layer took 10.5 ms forward +
+backward against 2.2 ms for the packed single-block flash kernel
+(ops/flash_attention.py:fused_qkv_attention, 64 steps) computing the WHOLE
+square with the pattern as a streamed mask. So training
+(ops/attention.py:PatternAttention.__call__) takes the packed kernel
+wherever it is eligible and this kernel only at shapes it cannot run —
+seq > 1536 at d 64 (the whole-row block no longer fits VMEM), a ``tp``
+mesh, ``use_flash=False`` — and there only for layouts that really skip
+block pairs (``ENGAGE_FRAC``). The sequence-parallel path
+(``sp_block_sparse_attend``) is gated separately.
+
 Policy: ``DALLE_TPU_SPARSE_KERNEL`` (unset/"auto" = TPU only, "0"/"1"
-force — kv_policy.tpu_auto_env semantics); the dense-mask paths remain the
-fallback and the off-TPU default.
+force — kv_policy.tpu_auto_env semantics) switches the kernel for the
+shapes where it is a candidate; the dense-mask paths remain the fallback
+and the off-TPU default.
 """
 
 from __future__ import annotations
@@ -66,12 +82,14 @@ LANES = 128
 # floor); layouts for tests/CPU may use any block sizes in interpret mode
 DEFAULT_BLOCK = 128
 
-# routing threshold: the pair grid engages only when the compiled layout
-# skips at least this much of the dense-causal pair set. A layout whose
-# live stride is finer than the block edge (axial_col at fmap <= 128, the
-# 16-block DeepSpeed-style random layout) visits every pair — frac 1.0 —
-# and would pay pair-grid overhead for zero skipped FLOPs; those patterns
-# stay on the dense/flash paths until their geometry actually block-skips
+# routing threshold, for the shapes where the pair grid is a candidate at
+# all (the packed flash kernel ineligible — see the module docstring): it
+# engages only when the compiled layout skips at least this much of the
+# dense-causal pair set. A layout whose live stride is finer than the block
+# edge (axial_col at fmap <= 128, the 16-block DeepSpeed-style random
+# layout) visits every pair — frac 1.0 — and would pay pair-grid overhead
+# for zero skipped FLOPs; those patterns stay on the dense/flash paths
+# until their geometry actually block-skips
 ENGAGE_FRAC = 0.9
 
 

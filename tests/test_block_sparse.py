@@ -332,3 +332,87 @@ def test_dalle_sp_sparse_loss_matches_single_device():
             )
         )(params)
     np.testing.assert_allclose(float(l0), float(l1), rtol=2e-5)
+
+
+# ------------------------------------------------------------ training gate
+
+
+def _pattern_layer(attn_type, n, heads=16, **kw):
+    """A PatternAttention over n leading positions of a text + image-grid
+    sequence: the flagship 32 x 32 grid from n 1280 up, 8 x 8 below."""
+    from dalle_pytorch_tpu.ops.attention import PatternAttention
+
+    return PatternAttention(
+        dim=heads * 64, seq_len=n + 1, heads=heads, dim_head=64,
+        image_fmap_size=32 if n >= 1280 else 8, attn_type=attn_type, **kw,
+    )
+
+
+GATE_CASES = [
+    # the flagship shape: the packed kernel is eligible, every kind rides it
+    ("axial_row", 1280, {}, "fused_qkv_flash"),
+    ("conv_like", 1280, {}, "fused_qkv_flash"),
+    ("axial_col", 1280, {}, "fused_qkv_flash"),
+    ("full", 1280, {}, "fused_qkv_flash"),
+    # n 2048: the packed kernel's whole-row block no longer fits VMEM, so
+    # the pair grid takes the layouts that skip block pairs (ENGAGE_FRAC)
+    ("axial_row", 2048, {}, "block_sparse_pair_grid"),
+    ("conv_like", 2048, {}, "block_sparse_pair_grid"),
+    # use_flash=False rules the packed kernel out: the pair grid as before
+    ("axial_row", 1280, {"use_flash": False}, "block_sparse_pair_grid"),
+    ("conv_like", 1280, {"use_flash": False}, "block_sparse_pair_grid"),
+]
+
+
+@pytest.mark.parametrize(
+    "attn_type,n,kw,impl", GATE_CASES,
+    ids=[f"{a}-{n}-{'noflash' if kw else 'flash'}" for a, n, kw, _ in GATE_CASES],
+)
+def test_training_gate_prefers_packed_kernel(monkeypatch, attn_type, n, kw, impl):
+    """The training-route gate of PatternAttention, with the pair grid
+    switched ON (as on a TPU): the packed single-block flash kernel takes
+    every call it is eligible for, the pair grid only what it cannot run.
+    Abstract trace only, so the flagship shape costs nothing."""
+    from dalle_pytorch_tpu.ops import kv_policy
+
+    monkeypatch.setenv("DALLE_TPU_SPARSE_KERNEL", "1")
+    monkeypatch.setattr(kv_policy, "ROUTE_LOG", [])
+    attn = _pattern_layer(attn_type, n, **kw)
+    x = jax.ShapeDtypeStruct((1, n, attn.dim), jnp.float32)
+    params = jax.eval_shape(attn.init, jax.random.key(0), x)
+    jax.eval_shape(attn.apply, params, x)
+    assert [r["impl"] for r in kv_policy.ROUTE_LOG] == [impl]
+    assert kv_policy.ROUTE_LOG[0]["site"] == f"forward/{attn_type}"
+
+
+@pytest.mark.parametrize("attn_type", ["axial_row", "conv_like"])
+def test_packed_kernel_patterns_match_dense_through_module(monkeypatch, attn_type):
+    """The route the gate now picks for the block-skipping patterns, at the
+    smallest packed shape (n 256, 2 heads of 64): output and parameter
+    gradients of the module against its own force_dense path, with the
+    pair grid switched on so that the gate really has the choice."""
+    from dalle_pytorch_tpu.ops import kv_policy
+
+    monkeypatch.setenv("DALLE_TPU_SPARSE_KERNEL", "1")
+    monkeypatch.setattr(kv_policy, "ROUTE_LOG", [])
+    n = 256
+    attn = _pattern_layer(attn_type, n, heads=2)
+    x = jax.random.normal(jax.random.key(0), (2, n, attn.dim))
+    mask = (jax.random.uniform(jax.random.key(1), (2, n)) > 0.3).at[:, 0].set(True)
+    params = attn.init(jax.random.key(2), x)
+
+    for m in (None, mask):
+        np.testing.assert_allclose(
+            np.asarray(attn.apply(params, x, mask=m)),
+            np.asarray(attn.apply(params, x, mask=m, force_dense=True)),
+            atol=3e-4, rtol=3e-4,
+        )
+    got = jax.grad(lambda p: (attn.apply(p, x, mask=mask) ** 2).sum())(params)
+    want = jax.grad(
+        lambda p: (attn.apply(p, x, mask=mask, force_dense=True) ** 2).sum()
+    )(params)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-3, rtol=5e-3)
+    assert {r["impl"] for r in kv_policy.ROUTE_LOG} == {
+        "fused_qkv_flash", "jnp_pattern_attend"
+    }
