@@ -1,6 +1,7 @@
 from .loader import (
     DataLoader,
     ImageFolderDataset,
+    PackedTextDataset,
     TextImageDataset,
     image_to_array,
     random_resized_crop,
@@ -20,6 +21,7 @@ __all__ = [
     "DataLoader",
     "HugTokenizer",
     "ImageFolderDataset",
+    "PackedTextDataset",
     "SimpleTokenizer",
     "TarImageTextDataset",
     "TarLoader",

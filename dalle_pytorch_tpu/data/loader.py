@@ -246,3 +246,29 @@ class ImageFolderDataset:
     @staticmethod
     def collate(batch):
         return {"image": np.stack([b[0] for b in batch])}
+
+
+class PackedTextDataset:
+    """Language-model rows from a folder of ``.txt`` documents: every document
+    tokenized, followed by one end-of-text id, all packed end to end into one
+    stream and cut into rows of ``seq_len`` ids (the tail that fills no row is
+    dropped). No mask and no state reset at document boundaries: a row is one
+    causal sequence. Pairs with ``DataLoader(..., collate_fn=PackedTextDataset.collate)``."""
+
+    def __init__(self, folder: str, seq_len: int, tokenizer, eot_id: int = 0):
+        stream: List[int] = []
+        for path in sorted(Path(folder).rglob("*.txt")):
+            stream.extend(tokenizer.encode(path.read_text(errors="replace")))
+            stream.append(eot_id)
+        rows = len(stream) // seq_len
+        self.rows = np.asarray(stream[: rows * seq_len], np.int32).reshape(rows, seq_len)
+
+    def __len__(self) -> int:
+        return len(self.rows)
+
+    def __getitem__(self, ind: int) -> np.ndarray:
+        return self.rows[ind]
+
+    @staticmethod
+    def collate(batch):
+        return {"ids": np.stack(batch)}

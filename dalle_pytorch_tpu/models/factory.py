@@ -91,8 +91,9 @@ def _restore_dtypes(cfg: dict) -> dict:
     for k in ("dtype", "param_dtype"):
         if isinstance(cfg.get(k), str):
             cfg[k] = _DTYPES[cfg[k]]
-    if "attn_types" in cfg and isinstance(cfg["attn_types"], list):
-        cfg["attn_types"] = tuple(cfg["attn_types"])
+    for k in ("attn_types", "layer_types"):
+        if isinstance(cfg.get(k), list):
+            cfg[k] = tuple(cfg[k])
     if "normalization" in cfg and isinstance(cfg["normalization"], list):
         cfg["normalization"] = tuple(tuple(x) for x in cfg["normalization"])
     if "shape" in cfg and isinstance(cfg["shape"], list):
@@ -281,3 +282,36 @@ def clip_from_checkpoint(path: str) -> Tuple[Any, Any, dict]:
     )
     params = _restore_params(clip, (text, image), state["params"])
     return clip, params, meta
+
+
+# --------------------------------------------------------------- CausalLM
+
+
+def save_lm_checkpoint(
+    path: str,
+    lm,
+    params: Any,
+    extra: Optional[dict] = None,
+    opt_state: Any = None,
+):
+    """Hparams-carrying CausalLM checkpoint, the CLIP format's shape:
+    {config, params[, opt_state]}."""
+    meta = {"model_class": "CausalLM", "config": _config_dict(lm), **(extra or {})}
+    state = {"params": params}
+    if opt_state is not None:
+        state["opt_state"] = opt_state
+        meta["has_opt_state"] = True
+    save_checkpoint(path, state, meta)
+
+
+def lm_from_checkpoint(path: str) -> Tuple[Any, Any, dict]:
+    """(CausalLM module, params, meta) from a save_lm_checkpoint file."""
+    from .lm import CausalLM
+
+    state, meta = load_checkpoint(path)
+    assert meta.get("model_class") == "CausalLM", (
+        f"not a CausalLM checkpoint: {meta.get('model_class')}"
+    )
+    lm = CausalLM(**_restore_dtypes(meta["config"]))
+    ids = jnp.zeros((1, lm.seq_len), jnp.int32)
+    return lm, _restore_params(lm, (ids,), state["params"]), meta

@@ -21,22 +21,28 @@ import flax.linen as nn
 
 import functools
 
-from ..ops.attention import PatternAttention
+from ..ops.attention import GroupedKVAttention, PatternAttention
 from ..ops.flash_attention import StaticTable
 from ..ops.layers import (
     FeedForward,
     GMLPBlock,
     LayerScale,
     PreNorm,
+    PreRMSNorm,
     PreShiftToken,
+    SwiGLU,
 )
 from ..ops.moe import MoEFeedForward
 from ..ops.reversible import reversible_forward_only, reversible_sequence
 from ..ops.rotary import angles, dalle_rotary_table, lang_freqs
+from ..ops.ssm import MambaMixer
 
 Dtype = Any
 
 ATTENTION_TYPES = ("full", "axial_row", "axial_col", "conv_like", "sparse", "mlp")
+# ``layer_types``: mixers that take no pattern, mask or positional table.
+# layer type -> (layer kind, device scope)
+MIXER_TYPES = {"mamba": ("mamba", "ssm"), "attention": ("gqa", "attn.gqa")}
 
 
 def cast_tuple(val, depth: int = 1) -> tuple:
@@ -65,6 +71,17 @@ class Transformer(nn.Module):
     activation memory via ops/reversible.py), or ``remat=True``
     (jax.checkpoint per block — recompute in backward, standard pytree
     activations).
+
+    Block variants (models/lm.py's causal language models; every DALL-E and
+    CLIP configuration leaves them at their defaults, which are the block
+    above): ``layer_types`` gives each layer its mixer in place of
+    ``attn_types`` — ``mamba`` (ops/ssm.py:MambaMixer, sized by ``ssm_*``) or
+    ``attention`` (grouped-KV causal attention over ``kv_heads`` with the
+    softmax scale ``attn_scale``, no positional term); ``norm='rmsnorm'``
+    with a fixed ``residual_multiplier`` replaces LayerNorm + learned
+    LayerScale; ``ff_act='swiglu'`` with ``ff_hidden`` replaces the GEGLU
+    feed-forward. Such a stack trains and evaluates whole sequences; it has
+    no decode mode and no pipeline or sequence-parallel path yet.
     """
 
     dim: int
@@ -95,6 +112,19 @@ class Transformer(nn.Module):
     moe_every: int = 2
     moe_capacity_factor: float = 1.25
     quant: bool = False
+    layer_types: Optional[Tuple[str, ...]] = None
+    kv_heads: Optional[int] = None
+    attn_scale: Optional[float] = None
+    norm: str = "layernorm"
+    norm_eps: float = 1e-5
+    residual_multiplier: Optional[float] = None
+    ff_act: str = "geglu"
+    ff_hidden: Optional[int] = None
+    ssm_heads: int = 64
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
 
@@ -140,10 +170,15 @@ class Transformer(nn.Module):
                 f"moe_every must be >= 1 (every n-th FF becomes an expert "
                 f"layer); got {self.moe_every}"
             )
-        attn_blocks, ff_blocks, kinds = [], [], []
+        self._check_variants()
+        attn_blocks, ff_blocks, kinds, scopes = [], [], [], []
         for ind in range(self.depth):
             attn_type = attn_types[ind % len(attn_types)]
-            if attn_type == "mlp":
+            scope = f"attn.{attn_type}"
+            if self.layer_types is not None:
+                attn_type, scope = MIXER_TYPES[self.layer_types[ind]]
+                attn = self._mixer(attn_type)
+            elif attn_type == "mlp":
                 attn = GMLPBlock(
                     dim=self.dim,
                     dim_ff=self.dim * 4,
@@ -170,7 +205,14 @@ class Transformer(nn.Module):
                     dtype=self.dtype,
                     param_dtype=self.param_dtype,
                 )
-            if self.ff_experts > 0 and ind % self.moe_every == self.moe_every - 1:
+            if self.ff_act == "swiglu":
+                ff = SwiGLU(
+                    dim=self.dim,
+                    hidden=self.ff_hidden or int(self.dim * self.ff_mult),
+                    dtype=self.dtype,
+                    param_dtype=self.param_dtype,
+                )
+            elif self.ff_experts > 0 and ind % self.moe_every == self.moe_every - 1:
                 # GShard-style: every moe_every-th FF becomes a Switch-routed
                 # expert layer (ops/moe.py); experts shard over the ep axis
                 ff = MoEFeedForward(
@@ -206,29 +248,71 @@ class Transformer(nn.Module):
                     seq_len=self.seq_len, pad=self.shift_pad,
                 )
 
-            attn_blocks.append(
-                LayerScale(
-                    dim=self.dim,
-                    depth=ind + 1,
-                    fn=PreNorm(dim=self.dim, fn=attn, param_dtype=self.param_dtype),
-                    param_dtype=self.param_dtype,
-                    name=f"attn_{ind}",
-                )
-            )
-            ff_blocks.append(
-                LayerScale(
-                    dim=self.dim,
-                    depth=ind + 1,
-                    fn=PreNorm(dim=self.dim, fn=ff, param_dtype=self.param_dtype),
-                    param_dtype=self.param_dtype,
-                    name=f"ff_{ind}",
-                )
-            )
+            attn_blocks.append(self._half_block(attn, ind, self._mixer_name(ind)))
+            ff_blocks.append(self._half_block(ff, ind, f"ff_{ind}"))
             kinds.append(attn_type)
+            scopes.append(scope)
 
         self.attn_blocks = attn_blocks
         self.ff_blocks = ff_blocks
         self.layer_kinds = tuple(kinds)
+        self.layer_scopes = tuple(scopes)
+
+    def _mixer_name(self, ind: int) -> str:
+        return f"attn_{ind}" if self.layer_types is None else f"mixer_{ind}"
+
+    def _check_variants(self):
+        if self.norm not in ("layernorm", "rmsnorm") or self.ff_act not in ("geglu", "swiglu"):
+            raise ValueError(f"norm {self.norm!r} / ff_act {self.ff_act!r} is not valid")
+        if (self.norm == "rmsnorm") != (self.residual_multiplier is not None):
+            raise ValueError(
+                "norm='rmsnorm' comes with a fixed residual_multiplier, "
+                "'layernorm' with the learned LayerScale (residual_multiplier=None)"
+            )
+        if self.ff_act == "swiglu" and self.ff_experts > 0:
+            raise ValueError("the expert feed-forward is GEGLU only")
+        if self.layer_types is None:
+            return
+        bad = [t for t in self.layer_types if t not in MIXER_TYPES]
+        if bad or len(self.layer_types) != self.depth:
+            raise ValueError(
+                f"layer_types needs {self.depth} entries of {sorted(MIXER_TYPES)}; "
+                f"got {self.layer_types}"
+            )
+        if self.shift_tokens or self.reversible or self.sp_axis or self.pp_axis:
+            raise ValueError(
+                "layer_types stacks run sequentially or under remat only: no "
+                "token shift, reversible, sequence- or pipeline-parallel path"
+            )
+
+    def _mixer(self, kind: str) -> nn.Module:
+        if kind == "mamba":
+            return MambaMixer(
+                dim=self.dim, n_heads=self.ssm_heads, d_head=self.ssm_head_dim,
+                d_state=self.ssm_state, d_conv=self.ssm_conv, chunk=self.ssm_chunk,
+                eps=self.norm_eps, dtype=self.dtype, param_dtype=self.param_dtype,
+            )
+        return GroupedKVAttention(
+            dim=self.dim, heads=self.heads, kv_heads=self.kv_heads or self.heads,
+            dim_head=self.dim_head,
+            sm_scale=self.dim_head**-0.5 if self.attn_scale is None else self.attn_scale,
+            use_flash=self.use_flash, dtype=self.dtype, param_dtype=self.param_dtype,
+        )
+
+    def _half_block(self, fn: nn.Module, ind: int, name: str) -> nn.Module:
+        """The residual branch around a mixer or a feed-forward."""
+        if self.norm == "rmsnorm":
+            return PreRMSNorm(
+                fn=fn, eps=self.norm_eps, multiplier=self.residual_multiplier,
+                param_dtype=self.param_dtype, name=name,
+            )
+        return LayerScale(
+            dim=self.dim,
+            depth=ind + 1,
+            fn=PreNorm(dim=self.dim, fn=fn, param_dtype=self.param_dtype),
+            param_dtype=self.param_dtype,
+            name=name,
+        )
 
     # ------------------------------------------------------------------ call
 
@@ -236,6 +320,12 @@ class Transformer(nn.Module):
                       block_len=None, block_start=None):
         """(attn kwargs, ff kwargs) for layer ``ind`` in module-call form."""
         kind = self.layer_kinds[ind]
+        if self.layer_types is not None:
+            if decode:
+                raise NotImplementedError(
+                    "a layer_types stack has no decode mode (ROADMAP R9, R13)"
+                )
+            return dict(deterministic=deterministic), dict(deterministic=deterministic)
         akw: dict = dict(deterministic=deterministic, decode=decode)
         if kind != "mlp":
             akw.update(mask=mask, rotary_pos_emb=rot)
@@ -308,7 +398,7 @@ class Transformer(nn.Module):
                     ind, mask, rot, deterministic, decode, block_len,
                     block_start,
                 )
-                with jax.named_scope(f"attn.{self.layer_kinds[ind]}"):
+                with jax.named_scope(self.layer_scopes[ind]):
                     x = x + self.attn_blocks[ind](x, **akw)
                 with jax.named_scope("ff"):
                     x = x + self.ff_blocks[ind](x, **fkw)
@@ -322,7 +412,7 @@ class Transformer(nn.Module):
                     ind, mask, rot, deterministic, decode, block_len,
                     block_start,
                 )
-                with jax.named_scope(f"attn.{self.layer_kinds[ind]}"):
+                with jax.named_scope(self.layer_scopes[ind]):
                     x1 = x1 + self.attn_blocks[ind](x2, **akw)
                 with jax.named_scope("ff"):
                     x2 = x2 + self.ff_blocks[ind](x1, **fkw)
@@ -508,16 +598,17 @@ class Transformer(nn.Module):
         fns, params, kwargs = [], [], []
         for ind in range(self.depth):
             kind = self.layer_kinds[ind]
+            patterned = self.layer_types is None and kind != "mlp"
             attn_mod = self.attn_blocks[ind].clone(parent=None)
             ff_mod = self.ff_blocks[ind].clone(parent=None)
 
-            def make_fn(mod, is_attn, kind=kind):
+            def make_fn(mod, is_attn, patterned=patterned, scope=self.layer_scopes[ind]):
                 static_kwargs = dict(deterministic=deterministic)
-                scope = f"attn.{kind}" if is_attn else "ff"
+                scope = scope if is_attn else "ff"
 
                 def fn(p, t, kw):
                     call_kwargs = dict(static_kwargs)
-                    if is_attn and kind != "mlp":
+                    if is_attn and patterned:
                         call_kwargs["mask"] = kw.get("mask")
                         call_kwargs["rotary_pos_emb"] = kw.get("rot")
                     rngs = {"dropout": kw["rng"]} if "rng" in kw else None
@@ -535,7 +626,7 @@ class Transformer(nn.Module):
                 return fn
 
             akw: dict = {}
-            if kind != "mlp":
+            if patterned:
                 if mask is not None:
                     akw["mask"] = mask
                 if rot is not None:
@@ -546,6 +637,6 @@ class Transformer(nn.Module):
                 fkw["rng"] = self.make_rng("dropout")
 
             fns.append((make_fn(attn_mod, True), make_fn(ff_mod, False)))
-            params.append((variables[f"attn_{ind}"], variables[f"ff_{ind}"]))
+            params.append((variables[self._mixer_name(ind)], variables[f"ff_{ind}"]))
             kwargs.append((akw, fkw))
         return fns, params, kwargs

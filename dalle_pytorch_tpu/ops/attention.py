@@ -1397,3 +1397,59 @@ class PatternAttention(nn.Module):
     # 4-D shape compiled to a positions-minor layout whose one-row update
     # rewrites the whole buffer — see the measured batch-conditional
     # flat-vs-4-D policy in _decode_caches (batch 8: +38% tokens/sec).
+
+
+class GroupedKVAttention(nn.Module):
+    """Causal softmax attention with fewer key/value heads than query heads
+    and no positional term: query head ``i`` attends key/value head
+    ``i // (heads // kv_heads)``. No bias. ``sm_scale`` is the model's own
+    softmax scale (not necessarily ``dim_head ** -0.5``).
+
+    Training route: the key/value heads are broadcast to the query heads in
+    front of the blocked flash kernel (ops/flash_attention.py; the sum of the
+    gradient over a group is autodiff's); where the length has no usable
+    block (``_flash_block``) it is one dense masked softmax. No kernel here
+    groups K/V heads yet, and there is no decode mode: serving a stack with
+    such layers is ROADMAP R9's other half."""
+
+    dim: int
+    heads: int
+    kv_heads: int
+    dim_head: int
+    sm_scale: float
+    use_flash: bool = True
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, deterministic: bool = True) -> jnp.ndarray:
+        # ``deterministic``: the trunk's uniform half-block argument; no dropout here
+        b, n, _ = x.shape
+        h, g, d = self.heads, self.kv_heads, self.dim_head
+        assert h % g == 0, f"{h} query heads over {g} key/value heads"
+        dense = lambda features, name: nn.Dense(
+            features, use_bias=False, name=name, dtype=self.dtype,
+            param_dtype=self.param_dtype,
+        )
+        q = dense(h * d, "to_q")(x).reshape(b, n, h, d).transpose(0, 2, 1, 3)
+        kv = dense(2 * g * d, "to_kv")(x).reshape(b, n, 2, g, d)
+        k, v = (
+            jnp.repeat(kv[:, :, i].transpose(0, 2, 1, 3), h // g, axis=1) for i in (0, 1)
+        )
+        block = _flash_block(n) if self.use_flash else 0
+        if block:
+            interpret = kv_policy.pallas_interpret()
+            kv_policy.record_route("forward/gqa", "blocked_flash", interpret)
+            scale = float(self.sm_scale)
+            out = _per_device(
+                lambda q, k, v: flash_attention(
+                    q, k, v, None, True, None, scale, block, block, interpret
+                ),
+                (q, k, v),
+            )
+        else:
+            kv_policy.record_route("forward/gqa", "dense_masked")
+            causal = jnp.tril(jnp.ones((n, n), bool))
+            out = dense_attend(q * self.sm_scale, k, v, causal)
+        out = out.transpose(0, 2, 1, 3).reshape(b, n, h * d)
+        return dense(self.dim, "to_out")(out)

@@ -75,6 +75,43 @@ class PreNorm(nn.Module):
         return self.fn(y.astype(x.dtype), **kwargs)
 
 
+def rms_norm(v: jnp.ndarray, gain: jnp.ndarray, eps: float) -> jnp.ndarray:
+    """``gain * v / sqrt(mean(v^2) + eps)`` over the last axis, in float32."""
+    v = v.astype(jnp.float32)
+    v = v * jax.lax.rsqrt(jnp.mean(jnp.square(v), axis=-1, keepdims=True) + eps)
+    return v * gain.astype(jnp.float32)
+
+
+class RMSNorm(nn.Module):
+    """RMSNorm with a learned gain (``scale``), float32 whatever the compute
+    dtype; returns float32."""
+
+    eps: float = 1e-5
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        gain = self.param("scale", nn.initializers.ones, (x.shape[-1],), self.param_dtype)
+        return rms_norm(x, gain, self.eps)
+
+
+class PreRMSNorm(nn.Module):
+    """``multiplier * fn(RMSNorm(x))``: the pre-norm half-block of the
+    models whose residual branches carry a fixed multiplier in place of a
+    learned LayerScale."""
+
+    fn: nn.Module
+    eps: float = 1e-5
+    multiplier: float = 1.0
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, **kwargs):
+        y = RMSNorm(self.eps, self.param_dtype, name="norm")(x)
+        out = self.fn(y.astype(x.dtype), **kwargs)
+        return out if self.multiplier == 1.0 else out * self.multiplier
+
+
 class QuantDense(nn.Module):
     """Weight-only int8 Dense for serving: ``y = (x @ q) * scale [+ bias]``
     with a per-output-channel symmetric scale.
@@ -236,6 +273,24 @@ class FeedForward(nn.Module):
         x = nn.Dropout(self.dropout)(x, deterministic=deterministic)
         x = dense(self.dim)(x)
         return x
+
+
+class SwiGLU(nn.Module):
+    """``W_out (silu(a) * b)`` with ``[a, b] = W_in x``, no bias: one fused
+    projection to ``2 * hidden``, as ``FeedForward`` has it."""
+
+    dim: int
+    hidden: int
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, deterministic: bool = True):
+        dense = lambda features: nn.Dense(
+            features, use_bias=False, dtype=self.dtype, param_dtype=self.param_dtype
+        )
+        a, b = jnp.split(dense(self.hidden * 2)(x), 2, axis=-1)
+        return dense(self.dim)(nn.silu(a) * b)
 
 
 def shift_tokens(x: jnp.ndarray, text_len: int, image_size: int) -> jnp.ndarray:

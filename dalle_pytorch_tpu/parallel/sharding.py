@@ -32,13 +32,21 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 # wraps them in anonymous `fn` attributes, so the FeedForward class name
 # never appears in the path). int8 serving renames Dense_i -> QuantDense_i
 # and kernel -> kernel_q (ops/layers.py:QuantDense); the patterns cover
-# both so tensor-parallel serving keeps the Megatron layout. The 1-D
+# both so tensor-parallel serving keeps the Megatron layout. A SwiGLU
+# feed-forward (models/lm.py) has the same two Dense names. The 1-D
 # bias/scale leaves fall through to the fallback and replicate, which GSPMD
 # reshards for free.
 DEFAULT_RULES: Tuple[Tuple[str, P], ...] = (
     # attention: qkv splits heads (output dim) over tp, out-proj splits input
     (r"to_qkv/kernel(_q)?$", P("fsdp", "tp")),
     (r"to_out/kernel(_q)?$", P("tp", "fsdp")),
+    # grouped-KV attention (ops/attention.py:GroupedKVAttention): q and the
+    # fused k|v split their output features like to_qkv; to_out is above
+    (r"to_(q|kv)/kernel$", P("fsdp", "tp")),
+    # state-space mixer (ops/ssm.py): the same pair, in splits its output
+    # channels (z | x B C | dt), out its input channels
+    (r"in_proj/kernel$", P("fsdp", "tp")),
+    (r"out_proj/kernel$", P("tp", "fsdp")),
     # MoE experts: expert dim over ep, hidden over tp (ops/moe.py)
     (r"experts_in$", P("ep", "fsdp", "tp")),
     (r"experts_out$", P("ep", "tp", "fsdp")),
@@ -54,7 +62,7 @@ DEFAULT_RULES: Tuple[Tuple[str, P], ...] = (
     # vocab-sized tensors: shard the vocab dim over fsdp, features over tp;
     # int8 serving renames embedding -> embedding_q with a per-row scale
     # that shards along the same vocab dim
-    (r"(text_emb|image_emb)/embedding(_q)?$", P("fsdp", "tp")),
+    (r"(text_emb|image_emb|tok_emb)/embedding(_q)?$", P("fsdp", "tp")),
     (r"(text_emb|image_emb)/scale$", P("fsdp")),
     (r"to_logits/kernel(_q)?$", P("fsdp", "tp")),
     # CLIP latent projections

@@ -301,8 +301,14 @@ DEVICE_SCOPES = frozenset({
     "attn.conv_like",
     "attn.sparse",
     "attn.mlp",
+    # layer_types stacks (models/lm.py): the grouped-KV attention mixer, and
+    # the whole state-space mixer with its convolution and its scan inside
+    "attn.gqa",
+    "ssm",
+    "ssm.conv",
+    "ssm.scan",
     "ff",                   # one per feed-forward sublayer
-    "embed",                # token + positional embeddings (models/dalle.py)
+    "embed",                # token + positional embeddings (models/dalle.py, lm.py)
     "head_loss",            # final norm, logits, the weighted cross-entropy
     "sample",               # top-k, gumbel, the draw (serving jits)
     "vae.encode",           # DiscreteVAE.get_codebook_indices

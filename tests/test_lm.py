@@ -1,0 +1,312 @@
+"""models/lm.py:CausalLM through the program's normal train path, held to the
+plain reference (benchmarks/reference_lm.py: float32, quadratic state-space
+form, imports nothing of the program): grouped-KV attention, the loss, every
+leaf's gradient, three steps of ``make_train_step``; planted faults that must
+FAIL; the new leaves' sharding; the CLI with save and resume; and the DALL-E
+configurations' parameter trees, which this model's block variants must not
+have moved."""
+
+import hashlib
+import json
+import pathlib
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from flax import traverse_util
+
+from benchmarks import costs, reference_lm, weights_lm
+from benchmarks.drivers import train_lm as driver
+from benchmarks.drivers.train import worst_leaf_gap
+from dalle_pytorch_tpu.models.lm import CausalLM
+from dalle_pytorch_tpu.ops.attention import GroupedKVAttention
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+CFG = dict(
+    hidden_size=64, num_attention_heads=8, num_key_value_heads=2, vocab_size=50,
+    shared_intermediate_size=96, num_hidden_layers=3,
+    layer_types=["mamba", "attention", "mamba", "mamba"],   # the first three run
+    attention_multiplier=0.2, embedding_multiplier=12, residual_multiplier=0.22,
+    logits_scaling=8, rms_norm_eps=1e-5, mamba_n_heads=8, mamba_d_head=16,
+    mamba_d_state=8, mamba_d_conv=4, mamba_chunk_size=8, mamba_expand=2,
+)
+N = 21    # two carried chunk boundaries and a ragged tail
+
+
+def model_and_params(remat=False, seed=5, **over):
+    lm = CausalLM.from_config({**CFG, **over}, seq_len=N, remat=remat)
+    ids = jax.random.randint(jax.random.key(1), (2, N), 0, CFG["vocab_size"])
+    shapes = jax.eval_shape(lm.init, jax.random.key(0), ids)["params"]
+    return lm, weights_lm.make_params(shapes, seed, jnp.float32), ids
+
+
+def reference_grad(params, ids, cfg=CFG):
+    with jax.default_matmul_precision("highest"):
+        return jax.value_and_grad(lambda p: reference_lm.loss(p, cfg, ids))(params)
+
+
+def leaf_gaps(got, want) -> dict:
+    flat_g, flat_w = traverse_util.flatten_dict(got), traverse_util.flatten_dict(want)
+    return {
+        "/".join(k): float(jnp.max(jnp.abs(flat_g[k] - w)) / (jnp.max(jnp.abs(w)) + 1e-12))
+        for k, w in flat_w.items()
+    }
+
+
+# ------------------------------------------------------- grouped-KV attention
+
+
+def plain_gqa(x, p, heads, kv_heads, d, scale, group_of):
+    b, n, _ = x.shape
+    q = (x @ p["to_q"]["kernel"]).reshape(b, n, heads, d)
+    kv = (x @ p["to_kv"]["kernel"]).reshape(b, n, 2, kv_heads, d)
+    pick = jnp.asarray([group_of(i) for i in range(heads)])
+    k, v = kv[:, :, 0][:, :, pick], kv[:, :, 1][:, :, pick]      # KV heads repeated
+    s = jnp.einsum("bihd,bjhd->bhij", q, k) * scale
+    s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, -1e30)
+    out = jnp.einsum("bhij,bjhd->bihd", jax.nn.softmax(s, -1), v).reshape(b, n, heads * d)
+    return out @ p["to_out"]["kernel"]
+
+
+@pytest.mark.parametrize("n,use_flash", [(128, True), (24, True), (128, False)])
+def test_grouped_kv_attention_matches_a_plain_softmax_with_kv_heads_repeated(n, use_flash):
+    """n = 128 takes the blocked flash kernel (interpreted here), 24 has no
+    usable block and takes the dense route; both are the same function."""
+    attn = GroupedKVAttention(dim=32, heads=8, kv_heads=2, dim_head=8, sm_scale=0.2,
+                              use_flash=use_flash)
+    x = jax.random.normal(jax.random.key(0), (2, n, 32))
+    p = attn.init(jax.random.key(1), x)["params"]
+    assert set(p) == {"to_q", "to_kv", "to_out"} and p["to_kv"]["kernel"].shape == (32, 32)
+    want = plain_gqa(x, p, 8, 2, 8, 0.2, lambda i: i // 4)
+    np.testing.assert_allclose(attn.apply({"params": p}, x), want, rtol=2e-4, atol=2e-5)
+    # planted: query head i on KV head i % kv_heads is another function
+    wrong = plain_gqa(x, p, 8, 2, 8, 0.2, lambda i: i % 2)
+    assert float(jnp.max(jnp.abs(wrong - want))) > 1e-2
+
+
+# ------------------------------------------------ loss and every leaf's gradient
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_loss_and_every_leafs_gradient_match_the_reference(remat):
+    lm, params, ids = model_and_params(remat)
+    loss, grads = jax.value_and_grad(
+        lambda p: lm.apply({"params": p}, ids, return_loss=True)
+    )(params)
+    want, want_grads = reference_grad(params, ids)
+    assert abs(float(loss) - float(want)) < 1e-5 * float(want)
+    gaps = leaf_gaps(grads, want_grads)
+    assert len(gaps) == 2 + 3 * 3 + 2 * 9 + 4        # every leaf of the tree
+    assert max(gaps.values()) < 1e-4, max(gaps.items(), key=lambda kv: kv[1])
+    logits = lm.apply({"params": params}, ids)
+    want_logits = jnp.stack([reference_lm.logits(params, CFG, row) for row in ids])
+    np.testing.assert_allclose(logits, want_logits, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("left_out", ["logits_scaling", "residual_multiplier",
+                                      "embedding_multiplier", "attention_multiplier"])
+def test_a_multiplier_left_out_fails_the_comparison(left_out):
+    neutral = {"attention_multiplier": (CFG["hidden_size"] // CFG["num_attention_heads"]) ** -0.5}
+    lm, params, ids = model_and_params(**{left_out: neutral.get(left_out, 1.0)})
+    loss, grads = jax.value_and_grad(
+        lambda p: lm.apply({"params": p}, ids, return_loss=True)
+    )(params)
+    want, want_grads = reference_grad(params, ids)
+    loss_gap = abs(float(loss) - float(want)) / float(want)
+    assert loss_gap > 1e-4 or max(leaf_gaps(grads, want_grads).values()) > 1e-2, left_out
+
+
+def test_config_keys_this_model_cannot_run_are_refused():
+    for key, value in [("num_local_experts", 8), ("mamba_n_groups", 2),
+                       ("position_embedding_type", "rope"), ("attention_bias", True)]:
+        with pytest.raises(ValueError, match=key):
+            CausalLM.from_config({**CFG, key: value}, seq_len=N)
+    lm = CausalLM.from_config(CFG, seq_len=N)
+    assert lm.layer_types == ("mamba", "attention", "mamba") and lm.depth == 3
+    with pytest.raises(NotImplementedError):
+        _, params, ids = model_and_params()
+        lm.apply({"params": params}, jnp.zeros((2, N, 64)), decode=True,
+                 method=lambda m, x, decode: m.transformer(x, decode=decode))
+
+
+# --------------------------------------------------------- three train steps
+
+
+def step_ctx(seed=7, **mix):
+    base = dict(rows=2, tokens=N, document_tokens={"min": 3, "max": N}, mesh={"dp": 1},
+                learning_rate=3e-4, clip_grad_norm=0.5, remat=True, check_steps=3)
+    return types.SimpleNamespace(
+        cfg={**CFG, "compute_dtype": "float32"}, mix={**base, **mix}, seed=seed, chips=1,
+    )
+
+
+# the sound program stays under every one of these (float32 on the CPU); a
+# fault has to pass at least one
+LIMITS = {"loss": 1e-5, "grad": 1e-4, "change": 1e-3}
+
+
+def over_a_limit(gaps: dict) -> bool:
+    return (max(gaps[k] for k in ("loss1", "loss2", "loss3")) > LIMITS["loss"]
+            or gaps["grad"] > LIMITS["grad"] or gaps["change"] > LIMITS["change"])
+
+
+def three_step_gaps(program, ref) -> dict:
+    gaps = {f"loss{i}": abs(p - r) / abs(r) for i, (p, r) in
+            enumerate(zip(program["loss"], ref["loss"]), 1)}
+    gaps["grad"] = worst_leaf_gap(program["grad"], ref["grad"])[0]
+    gaps["change"] = worst_leaf_gap(program["change"], ref["change"])[0]
+    return gaps
+
+
+@pytest.fixture(scope="module")
+def reference_three_steps():
+    ctx = step_ctx()
+    _, shapes = driver._build(ctx)
+    fed = [driver.packed_batch(ctx.mix, ctx.cfg, ctx.seed, s) for s in range(3)]
+    with jax.default_matmul_precision("highest"):
+        return driver.reference_steps(ctx, shapes, fed, "f32")
+
+
+def test_three_steps_of_make_train_step_match_the_references_three(reference_three_steps):
+    """train_lm.build_step -> create_train_state -> make_train_step, through
+    the benchmark driver's own Job, on the packed batches of the seed."""
+    job = driver.Job(step_ctx())
+    program = job.first_steps()
+    assert job.steps == 3 and int(job.state.step) == 3 and int(job.state.skipped) == 0
+    gaps = three_step_gaps(program, reference_three_steps)
+    assert not over_a_limit(gaps), gaps
+    # and the packed batch is what the traffic file says
+    ids = job.fed[0]
+    assert ids.shape == (2, N) and ids.dtype == np.int32
+    assert ids.min() >= 0 and ids.max() < CFG["vocab_size"] and (ids == 0).any()
+
+
+@pytest.mark.parametrize("fault", ["no_carry", "bf16_decay"])
+def test_a_fault_planted_in_the_programs_scan_fails_the_three_steps(fault, reference_three_steps):
+    with driver._planted(fault):
+        program = driver.Job(step_ctx()).first_steps()
+    gaps = three_step_gaps(program, reference_three_steps)
+    assert over_a_limit(gaps), gaps
+    # the fault is taken out again
+    from dalle_pytorch_tpu.ops import ssm
+    assert ssm.carried_states.__module__ == ssm.log_decay.__module__ == ssm.__name__
+
+
+def test_half_of_the_tokens_left_out_of_the_loss_fails(reference_three_steps):
+    ctx = step_ctx()
+    _, shapes = driver._build(ctx)
+    fed = [driver.packed_batch(ctx.mix, ctx.cfg, ctx.seed, s) for s in range(3)]
+    with jax.default_matmul_precision("highest"):
+        half = driver.reference_steps(ctx, shapes, fed, "f32", positions=N // 2)
+    gaps = three_step_gaps(half, reference_three_steps)
+    assert over_a_limit(gaps) and gaps["grad"] > 0.05, gaps
+
+
+# ------------------------------------------------------------------ sharding
+
+
+def test_the_new_leaves_have_sharding_rules():
+    from jax.sharding import PartitionSpec as P
+    from dalle_pytorch_tpu.parallel import make_runtime
+    from dalle_pytorch_tpu.parallel.sharding import params_spec_reports
+
+    lm, params, _ = model_and_params()
+    mesh = make_runtime(dp=1, fsdp=2, tp=2, devices=jax.devices()[:4]).mesh
+    specs = {r["path"]: (r["rule"], r["spec"]) for r in params_spec_reports(params, mesh, min_size=0)}
+    want = {
+        "transformer/mixer_0/fn/in_proj/kernel": P("fsdp", "tp"),
+        "transformer/mixer_0/fn/out_proj/kernel": P("tp", "fsdp"),
+        "transformer/mixer_1/fn/to_q/kernel": P("fsdp", "tp"),
+        "transformer/mixer_1/fn/to_kv/kernel": P("fsdp", "tp"),
+        "transformer/mixer_1/fn/to_out/kernel": P("tp", "fsdp"),
+        "transformer/ff_2/fn/Dense_0/kernel": P("fsdp", "tp"),
+        "transformer/ff_2/fn/Dense_1/kernel": P("tp", "fsdp"),
+        "tok_emb/embedding": P("fsdp", "tp"),
+    }
+    for path, spec in want.items():
+        rule, got = specs[path]
+        assert rule is not None and got == spec, (path, rule, got)
+
+
+def test_a_step_on_an_fsdp_tp_mesh_matches_one_chip():
+    import optax
+    import train_lm
+    from dalle_pytorch_tpu.parallel import make_runtime
+
+    losses = []
+    for kw in (dict(dp=1, devices=jax.devices()[:1]), dict(dp=1, fsdp=2, tp=2, devices=jax.devices()[:4])):
+        lm, params, ids = model_and_params()    # the step donates its state
+        state, _, step = train_lm.build_step(lm, params, make_runtime(**kw), 0.5)
+        state, loss = step(state, {"ids": ids}, jax.random.key(0), jnp.asarray(3e-4))
+        losses.append((float(loss), float(optax.global_norm(state.params))))
+    np.testing.assert_allclose(losses[0], losses[1], rtol=1e-5)
+
+
+# ----------------------------------------------------------------------- CLI
+
+
+def test_train_lm_cli_saves_and_resumes(tmp_path, monkeypatch):
+    import sys
+    import train_lm
+    from dalle_pytorch_tpu.data import SimpleTokenizer
+    from dalle_pytorch_tpu.utils import MetricsLogger
+    from dalle_pytorch_tpu.utils.checkpoint import load_checkpoint
+
+    docs = tmp_path / "docs"
+    docs.mkdir()
+    for i in range(16):
+        (docs / f"{i}.txt").write_text(" ".join(f"word{(i * 7 + j) % 13}" for j in range(40)))
+    vocab = SimpleTokenizer().vocab_size
+    cfg = {**CFG, "hidden_size": 32, "num_attention_heads": 4, "shared_intermediate_size": 48,
+           "mamba_n_heads": 4, "vocab_size": vocab}
+    (tmp_path / "config.json").write_text(json.dumps(cfg))
+    losses = []
+    real_log = MetricsLogger.log
+    monkeypatch.setattr(MetricsLogger, "log", lambda self, logs, step=None: (
+        losses.append(logs["loss"]) if "loss" in logs else None, real_log(self, logs, step=step))[1])
+    out = tmp_path / "lm"
+    argv = ["--config", str(tmp_path / "config.json"), "--image_text_folder", str(docs),
+            "--text_seq_len", "32", "--batch_size", "8", "--epochs", "2", "--remat",
+            "--learning_rate", "3e-3", "--lm_output_file_name", str(out)]
+    monkeypatch.setattr(sys, "argv", ["train_lm.py"] + argv)
+    train_lm.main()
+    assert losses and np.all(np.isfinite(losses)) and losses[0] > np.log(vocab) - 1
+    state, meta = load_checkpoint(f"{out}.ckpt")
+    assert meta["model_class"] == "CausalLM" and meta["epoch"] == 1 and meta["has_opt_state"]
+    assert meta["config"]["layer_types"] == ["mamba", "attention", "mamba"]
+    first = len(losses)
+    monkeypatch.setattr(sys, "argv", ["train_lm.py", "--lm_path", f"{out}.ckpt"] + argv[:-2]
+                        + ["--lm_output_file_name", str(out), "--epochs", "3"])
+    train_lm.main()
+    assert len(losses) > first and np.all(np.isfinite(losses[first:]))
+    # resumed from the trained weights, not from a fresh init
+    assert losses[first] < losses[0]
+    _, meta = load_checkpoint(f"{out}.ckpt")
+    assert meta["epoch"] == 2
+
+
+# ------------------------------------- the DALL-E configurations did not move
+
+DALLE_TREES = {   # leaves, sha256 of the sorted "path shape dtype" lines, at PR 31
+    "dalle-d12-full": (162, "019495564e81d9bd186c48a016dbfb1b935ee02d5fccab3f0d385b43225dbd82"),
+    "dalle-d12-sparse": (162, "019495564e81d9bd186c48a016dbfb1b935ee02d5fccab3f0d385b43225dbd82"),
+    "dalle-d12-sparse-posemb": (165, "75f11ac5ec1bdf15d2c1933b5a5f8636f84646d1c5b8bace356652157a5905b7"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(DALLE_TREES))
+def test_every_dalle_configurations_parameter_tree_is_what_it_was(name):
+    from benchmarks.drivers.serve import build_dalle, param_shapes
+
+    on_disk = {f.stem for f in (ROOT / "benchmarks" / "configs").glob("dalle-*.json")}
+    assert on_disk == set(DALLE_TREES)
+    cfg = costs.load_config(name)
+    shapes = param_shapes(build_dalle(cfg), cfg)
+    lines = sorted(
+        f"{jax.tree_util.keystr(k)} {tuple(v.shape)} {v.dtype}"
+        for k, v in jax.tree_util.tree_leaves_with_path(shapes)
+    )
+    digest = hashlib.sha256("\n".join(lines).encode()).hexdigest()
+    assert (len(lines), digest) == DALLE_TREES[name]

@@ -1,0 +1,261 @@
+#!/usr/bin/env python
+"""Causal language-model training CLI, TPU-native.
+
+Trains ``models/lm.py:CausalLM`` — a stack of Mamba-2 state-space and
+grouped-KV attention layers described by a ``config.json`` in its source's own
+keys (``--config``) — on a folder of ``.txt`` documents packed end to end,
+with the app surface of train_clip.py and the loop of train_dalle.py: compiled
+sharded train step over a dp x fsdp x tp mesh (``make_runtime`` →
+``create_train_state`` → ``make_train_step``), one dispatch in flight with the
+step's verdict read before the next (a device-rejected non-finite step is
+retried, ``--nan_abort_after`` consecutive ones abort), ``train.*`` telemetry
+spans, checkpoint/resume carrying all hparams and the Adam moments, pre-flight
+save.
+
+``build_model`` and ``build_step`` are the step's whole construction; the
+benchmark's driver (benchmarks/drivers/train_lm.py) calls the same two.
+"""
+
+import argparse
+import json
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+
+
+def parse_args():
+    parser = argparse.ArgumentParser(description="Train a causal language model on TPU")
+    parser.add_argument("--config", type=str, required=True,
+                        help="the model's config.json (the source's own keys: "
+                             "hidden_size, layer_types, mamba_*, *_multiplier, ...)")
+    parser.add_argument("--image_text_folder", type=str, required=True,
+                        help="folder whose .txt files are the documents (images, if any, are ignored)")
+    parser.add_argument("--lm_path", type=str, default=None,
+                        help="path to a partially trained model to resume")
+    parser.add_argument("--lm_output_file_name", type=str, default="lm")
+    parser.add_argument("--chinese", action="store_true")
+    parser.add_argument("--hug", action="store_true")
+    parser.add_argument("--bpe_path", type=str, default=None)
+    parser.add_argument("--fp16", "--bf16", dest="bf16", action="store_true")
+    parser.add_argument("--wandb", action="store_true")
+    parser.add_argument("--wandb_name", default="lm_train")
+    parser.add_argument("--seed", type=int, default=42)
+
+    mesh_group = parser.add_argument_group("Mesh settings")
+    mesh_group.add_argument("--fsdp", type=int, default=1)
+    mesh_group.add_argument("--tp", type=int, default=1)
+
+    model_group = parser.add_argument_group("Model settings")
+    model_group.add_argument("--text_seq_len", type=int, default=8192)
+    model_group.add_argument("--remat", action="store_true",
+                             help="recompute each block's activations in backward")
+
+    train_group = parser.add_argument_group("Training settings")
+    train_group.add_argument("--epochs", default=20, type=int)
+    train_group.add_argument("--save_every_n_steps", default=1000, type=int)
+    train_group.add_argument("--batch_size", default=1, type=int)
+    train_group.add_argument("--learning_rate", default=3e-4, type=float)
+    train_group.add_argument("--clip_grad_norm", default=0.5, type=float)
+    train_group.add_argument("--nan_abort_after", default=5, type=int)
+    train_group.add_argument("--telemetry", action="store_true")
+    train_group.add_argument("--telemetry_dir", default=None, type=str)
+    train_group.add_argument("--metrics_port", default=None, type=int)
+    return parser.parse_args()
+
+
+def build_model(config: dict, seq_len: int, bf16: bool, remat: bool):
+    from dalle_pytorch_tpu.models.lm import CausalLM
+
+    return CausalLM.from_config(
+        config, seq_len=seq_len, remat=remat,
+        dtype=jnp.bfloat16 if bf16 else jnp.float32,
+    )
+
+
+def build_step(lm, params, runtime, clip_grad_norm: float):
+    """-> (state, shardings, step_fn): ``step_fn(state, {"ids"}, rng, lr)``.
+    Adam with the learning rate as a step argument, as train_dalle.py's."""
+    from dalle_pytorch_tpu.parallel import create_train_state, make_train_step
+
+    optimizer = optax.chain(
+        optax.clip_by_global_norm(clip_grad_norm), optax.scale_by_adam(),
+    )
+    state, shardings = create_train_state(params, optimizer, runtime)
+
+    def loss_fn(p, batch, rng):
+        return lm.apply({"params": p}, batch["ids"], return_loss=True)
+
+    step_fn = make_train_step(loss_fn, optimizer, runtime, shardings, dynamic_lr=True)
+    return state, shardings, step_fn
+
+
+def main():
+    args = parse_args()
+
+    from dalle_pytorch_tpu.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
+    from dalle_pytorch_tpu.data import (
+        ChineseTokenizer,
+        DataLoader,
+        HugTokenizer,
+        PackedTextDataset,
+        SimpleTokenizer,
+    )
+    from dalle_pytorch_tpu.models.factory import (
+        lm_from_checkpoint,
+        restore_opt_state,
+        save_lm_checkpoint,
+    )
+    from dalle_pytorch_tpu.parallel import init_distributed, make_runtime, shard_pytree
+    from dalle_pytorch_tpu.utils import TELEMETRY, MetricsLogger, Throughput, counters
+
+    init_distributed()
+    runtime = make_runtime(fsdp=args.fsdp, tp=args.tp)
+    runtime.check_batch_size(args.batch_size)
+
+    if args.chinese:
+        tokenizer = ChineseTokenizer()
+    elif args.hug:
+        tokenizer = HugTokenizer(args.bpe_path)
+    else:
+        tokenizer = SimpleTokenizer(args.bpe_path)
+
+    if args.lm_path:
+        lm, params, meta = lm_from_checkpoint(args.lm_path)
+        start_epoch = int(meta.get("epoch", -1)) + 1
+    else:
+        with open(args.config) as fh:
+            lm = build_model(json.load(fh), args.text_seq_len, args.bf16, args.remat)
+        params = jax.jit(lm.init)(
+            jax.random.key(args.seed), jnp.zeros((1, lm.seq_len), jnp.int32)
+        )["params"]
+        start_epoch = 0
+    assert tokenizer.vocab_size <= lm.vocab_size, (
+        f"the tokenizer's {tokenizer.vocab_size} ids do not fit the model's "
+        f"vocabulary of {lm.vocab_size}"
+    )
+
+    dataset = PackedTextDataset(args.image_text_folder, lm.seq_len, tokenizer)
+    assert len(dataset) > 0, (
+        f"{args.image_text_folder} packs into no row of {lm.seq_len} tokens"
+    )
+    loader = DataLoader(
+        dataset, args.batch_size, shuffle=True, seed=args.seed,
+        process_index=runtime.process_index, process_count=runtime.process_count,
+        collate_fn=PackedTextDataset.collate,
+    )
+
+    logger = MetricsLogger(
+        project="lm_train", run_name=args.wandb_name, config=vars(args),
+        enabled=runtime.is_root_worker(), use_wandb=args.wandb,
+    )
+    if args.telemetry:
+        TELEMETRY.configure(
+            enabled=runtime.is_root_worker(),
+            flight_dir=args.telemetry_dir or f"{args.lm_output_file_name}-telemetry",
+            metrics_port=args.metrics_port,
+        )
+    n_params = sum(int(np.prod(p.shape)) for p in jax.tree_util.tree_leaves(params))
+    logger.log_text(f"CausalLM {n_params:,} params | mesh {dict(runtime.mesh.shape)}")
+
+    state, shardings, step_fn = build_step(lm, params, runtime, args.clip_grad_norm)
+    del params
+    if args.lm_path:
+        # keep Adam moments across resume (same contract as train_dalle.py)
+        host_opt = restore_opt_state(
+            args.lm_path, jax.tree_util.tree_map(np.asarray, state.opt_state)
+        )
+        if host_opt is not None:
+            state = state._replace(opt_state=shard_pytree(host_opt, shardings.opt_state))
+
+    ckpt_path = f"{args.lm_output_file_name}.ckpt"
+
+    def save(epoch):
+        with TELEMETRY.span("train.ckpt_save", kind="full", epoch=epoch):
+            host_params = runtime.to_host(state.params)
+            host_opt = runtime.to_host(state.opt_state)
+            if runtime.is_root_worker():
+                save_lm_checkpoint(
+                    ckpt_path, lm, host_params, extra={"epoch": epoch}, opt_state=host_opt,
+                )
+
+    save(start_epoch - 1)  # pre-flight: fail fast on misconfiguration
+
+    throughput = Throughput(window=10)
+    lr = jnp.asarray(args.learning_rate)
+    global_step = 0
+    # keys the step rng by BATCH, not by dispatch attempt (train_dalle.py)
+    applied_steps = 0
+    prev_loss = step_span = last_fed = retry_batch = None
+
+    def process_verdict():
+        """Read the in-flight step's loss (a sync point: the next batch
+        depends on it). NaN means the device rejected the update
+        (parallel/step.py nan_guard): the batch is retried."""
+        nonlocal prev_loss, step_span, applied_steps, retry_batch
+        if prev_loss is None:
+            return
+        loss_val = float(prev_loss)
+        TELEMETRY.end(step_span, loss=loss_val, finite=math.isfinite(loss_val))
+        prev_loss = step_span = None
+        if math.isfinite(loss_val):
+            applied_steps += 1
+            return
+        nan_run = int(state.consec_skipped)
+        counters.inc("train.nan_skips")
+        TELEMETRY.event("train.nan_skip", step=global_step - 1, consec=nan_run)
+        logger.log_text(
+            f"step {global_step - 1}: non-finite loss — update skipped on "
+            f"device, retrying batch ({nan_run}/{args.nan_abort_after})"
+        )
+        if nan_run >= args.nan_abort_after:
+            TELEMETRY.event("train.nan_abort", step=global_step - 1, consec=nan_run)
+            TELEMETRY.drain("nan_abort")
+            logger.finish()
+            raise SystemExit(f"{nan_run} consecutive non-finite steps — aborting")
+        retry_batch = last_fed
+
+    for epoch in range(start_epoch, args.epochs):
+        batches, nxt, exhausted = iter(loader), None, False
+        while True:
+            # the next batch is fetched BEFORE blocking on the verdict; a
+            # retried batch goes first and the fetched one stays stashed
+            if nxt is None and not exhausted:
+                with TELEMETRY.span("train.data_wait", epoch=epoch):
+                    nxt = next(batches, None)
+                exhausted = nxt is None
+            process_verdict()
+            if retry_batch is not None:
+                batch, retry_batch = retry_batch, None
+            elif nxt is not None:
+                batch, nxt = nxt, None
+            else:
+                break
+            last_fed = batch
+            step_span = TELEMETRY.begin("train.step", step=global_step, epoch=epoch)
+            state, prev_loss = step_fn(
+                state, {"ids": jnp.asarray(batch["ids"])}, jax.random.key(applied_steps), lr,
+            )
+            if global_step % 10 == 0:
+                logger.log({"loss": float(prev_loss), "epoch": epoch}, step=global_step)
+                logger.log_text(f"step {global_step}: loss={float(prev_loss):.4f} epoch={epoch}")
+            rate = throughput.update(args.batch_size * lm.seq_len)
+            if rate is not None:
+                logger.log({"tokens_per_sec": rate}, step=global_step)
+            if global_step % args.save_every_n_steps == args.save_every_n_steps - 1:
+                process_verdict()
+                save(epoch)
+            global_step += 1
+        save(epoch)
+        logger.log_text(f"epoch {epoch} complete")
+
+    logger.finish()
+
+
+if __name__ == "__main__":
+    main()
