@@ -62,11 +62,16 @@ def create_train_state(
     o_shard = opt_state_shardings(opt_state, p_shard, runtime.mesh)
     replicated = NamedSharding(runtime.mesh, P())
     # distinct zero buffers: the step is donated, and donating one buffer
-    # through several leaves is an XLA error
+    # through several leaves is an XLA error. Placed on the mesh like every
+    # other leaf: a counter left on the default device has another type than
+    # the one the step hands back, so the SECOND call traced, lowered and
+    # loaded the whole step again (~10 s of every run's set-up), and where
+    # that second trace numbers a helper function otherwise, compiled it again
+    # under another cache key (PERF.md section 6, PR 33)
+    zero = lambda: jax.device_put(jnp.zeros((), jnp.int32), replicated)
     state = TrainState(
-        step=jnp.zeros((), jnp.int32), params=params, opt_state=opt_state,
-        skipped=jnp.zeros((), jnp.int32),
-        consec_skipped=jnp.zeros((), jnp.int32),
+        step=zero(), params=params, opt_state=opt_state,
+        skipped=zero(), consec_skipped=zero(),
     )
     shardings = TrainState(
         step=replicated, params=p_shard, opt_state=o_shard,

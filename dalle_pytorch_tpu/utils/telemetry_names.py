@@ -332,6 +332,10 @@ KERNEL_NAMES = frozenset({
     "block_sparse_dkv",
     "ragged_paged_attend",  # ops/ragged_attention.py
     "decode_attend",        # ops/decode_attention.py
+    "ssd_state_fwd",        # ops/ssm.py: what each chunk adds to the state
+    "ssd_state_bwd",
+    "ssd_chunk_fwd",        #   each chunk's output, decay matrices in VMEM
+    "ssd_chunk_bwd",
 })
 
 # span durations are auto-observed as "<span>_s" (utils/telemetry.py);

@@ -1,5 +1,5 @@
-"""The Pallas kernels of the training cell, compiled for a described (not
-attached) TPU v5e at the cell's own shapes: what Mosaic and the TPU lowering
+"""The Pallas kernels of the training cells, compiled for a described (not
+attached) TPU v5e at the cells' own shapes: what Mosaic and the TPU lowering
 accept, and that every kernel arrives in the compiled program under the name
 ``utils/telemetry_names.py:KERNEL_NAMES`` registers — the instruction name a
 profiler trace shows (docs/DESIGN.md §9, PERF.md §3).
@@ -24,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 from dalle_pytorch_tpu.ops import block_sparse_attention as bs
 from dalle_pytorch_tpu.ops import masks as masks_lib
+from dalle_pytorch_tpu.ops import ssm
 from dalle_pytorch_tpu.ops.flash_attention import (
     StaticMask,
     flash_attention,
@@ -85,7 +86,30 @@ def _pair_grid(q, k, v):
     return bs.block_sparse_attention(q, k, v, layout, sm_scale=D**-0.5, interpret=False)
 
 
+# train-granite4hmicro-d10-s8k: 1 row of 8192, 64 state-space heads of 64,
+# state 128, chunks of 256
+SSM_N, SSM_H, SSM_P, SSM_STATE, SSM_CHUNK = 8192, 64, 64, 128, 256
+F32 = jnp.float32
+
+
+def _ssd(x, dt, cum, Bm, Cm, starts):
+    # the two kernel calls of ``ssm._ssd_scan_kernels``, compiled not interpreted
+    states = ssm.ssd_chunk_states(x, dt, cum, Bm, SSM_CHUNK, SSM_P, False)
+    row = (cum - jnp.log(dt)).transpose(0, 2, 1)
+    every = jnp.ones((1, 1, SSM_H * SSM_P), F32)
+    return ssm.ssd_chunk_outputs(
+        x, cum, row, Bm, Cm, starts + states, every, SSM_CHUNK, SSM_P, False
+    )
+
+
 ROUTES = {
+    "ssd_scan": (
+        _ssd,
+        [(1, SSM_N, SSM_H * SSM_P), ((1, SSM_N, SSM_H), F32), ((1, SSM_N, SSM_H), F32),
+         (1, SSM_N, SSM_STATE), (1, SSM_N, SSM_STATE),
+         ((1, SSM_N // SSM_CHUNK, SSM_STATE, SSM_H * SSM_P), F32)],
+        {"ssd_state_fwd", "ssd_state_bwd", "ssd_chunk_fwd", "ssd_chunk_bwd"},
+    ),
     "packed_flash": (_packed, [(B, N, 3 * H * D)], {"flash_qkv_fwd", "flash_qkv_bwd"}),
     "blocked_flash": (_blocked, [(B, H, N, D)] * 3, {"flash_fwd", "flash_dq", "flash_dkv"}),
     "pair_grid": (
@@ -99,7 +123,11 @@ ROUTES = {
 def test_kernels_compile_for_v5e_under_their_registered_names(route, one_chip):
     fn, shapes, names = ROUTES[route]
     assert names <= KERNEL_NAMES
-    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip) for s in shapes]
+    # a shape alone is bfloat16; (shape, dtype) where an operand is not
+    args = [
+        jax.ShapeDtypeStruct(*(s if isinstance(s[0], tuple) else (s, jnp.bfloat16)), sharding=one_chip)
+        for s in shapes
+    ]
 
     def loss(*xs):
         return jnp.sum(fn(*xs).astype(jnp.float32))

@@ -1,8 +1,10 @@
 """ops/ssm.py: the chunked state-space scan against two other algorithms for
 the same recurrence (step by step; the quadratic form), forward and
-gradients, at lengths that are and are not multiples of the chunk; the causal
-depthwise convolution and the gated norm against plain transcriptions; and
-planted faults that must FAIL those comparisons."""
+gradients, at lengths that are and are not multiples of the chunk; the Pallas
+kernels of the scan, interpreted on the CPU at small eligible shapes, against
+the einsum form and the recurrence; the causal depthwise convolution and the
+gated norm against plain transcriptions; and planted faults that must FAIL
+those comparisons, through the einsum form and through the kernels."""
 
 import jax
 import jax.numpy as jnp
@@ -10,21 +12,22 @@ import numpy as np
 import pytest
 from flax import traverse_util
 
-from dalle_pytorch_tpu.ops import ssm
+from dalle_pytorch_tpu.ops import kv_policy, ssm
 from dalle_pytorch_tpu.ops.layers import RMSNorm
 
 B, H, P, N, CHUNK = 2, 4, 8, 6, 16
 
 
-def inputs(n, seed=0):
+def inputs(n, seed=0, dims=(B, H, P, N)):
+    b, h, p, state = dims
     ks = jax.random.split(jax.random.key(seed), 7)
-    x = jax.random.normal(ks[0], (B, n, H, P))
+    x = jax.random.normal(ks[0], (b, n, h, p))
     # steps and decays of Mamba-2's own range, so that state survives a chunk
-    dt = jnp.exp(jax.random.uniform(ks[1], (B, n, H), minval=np.log(1e-3), maxval=np.log(1e-1)))
-    A = -jax.random.uniform(ks[2], (H,), minval=1.0, maxval=16.0)
-    Bm = jax.random.normal(ks[3], (B, n, N))
-    Cm = jax.random.normal(ks[4], (B, n, N))
-    D = 1.0 + 0.1 * jax.random.normal(ks[5], (H,))
+    dt = jnp.exp(jax.random.uniform(ks[1], (b, n, h), minval=np.log(1e-3), maxval=np.log(1e-1)))
+    A = -jax.random.uniform(ks[2], (h,), minval=1.0, maxval=16.0)
+    Bm = jax.random.normal(ks[3], (b, n, state))
+    Cm = jax.random.normal(ks[4], (b, n, state))
+    D = 1.0 + 0.1 * jax.random.normal(ks[5], (h,))
     return x, dt, A, Bm, Cm, D
 
 
@@ -34,7 +37,7 @@ def recurrence(x, dt, A, Bm, Cm, D):
         xt, dtt, Bt, Ct = inp
         S = S * jnp.exp(dtt * A)[..., None, None] + (dtt[..., None] * xt)[..., None] * Bt[:, None, None, :]
         return S, jnp.einsum("bhpn,bn->bhp", S, Ct) + D[:, None] * xt
-    S0 = jnp.zeros((x.shape[0], H, P, N))
+    S0 = jnp.zeros((x.shape[0], *x.shape[2:], Bm.shape[-1]))
     _, ys = jax.lax.scan(step, S0, tuple(jnp.moveaxis(t, 1, 0) for t in (x, dt, Bm, Cm)))
     return jnp.moveaxis(ys, 0, 1)
 
@@ -108,6 +111,128 @@ def test_leaving_d_out_fails():
     exact = recurrence(x, dt, A, Bm, Cm, D)
     without = scan(x, dt, A, Bm, Cm, jnp.zeros_like(D))
     assert float(jnp.max(jnp.abs(without - exact))) > 0.5
+
+
+# ---- the Pallas kernels, interpreted, at small shapes they are eligible for
+
+# (chunk, heads): one block of 8 heads and one row block; two blocks of 16
+# heads (what all heads share is summed over them) and two row blocks
+SMALL = {"c128h8": (128, 8), "c256h32": (256, 32)}
+KP, KN = 64, 128
+
+
+def kernel_inputs(shape, n, seed=0):
+    chunk, h = SMALL[shape]
+    assert ssm.ssd_kernels_eligible(chunk, h, KP, KN)
+    return chunk, inputs(n, seed, dims=(1, h, KP, KN))
+
+
+def einsum_form(monkeypatch):
+    """``ssd_scan`` held to its einsum form at any shape."""
+    def scan(*args, **kwargs):
+        with monkeypatch.context() as m:
+            m.setattr(ssm, "ssd_kernels_eligible", lambda *a: False)
+            return ssm.ssd_scan(*args, **kwargs)
+    return scan
+
+
+@pytest.mark.parametrize("shape,n", [
+    ("c128h8", 2 * 128), ("c128h8", 3 * 128), ("c128h8", 3 * 128 - 84), ("c256h32", 2 * 256 - 7),
+])
+@pytest.mark.parametrize("other", ["einsum", "recurrence"])
+def test_the_kernels_match_the_einsum_form_and_the_recurrence_in_float32(
+    shape, n, other, monkeypatch
+):
+    chunk, args = kernel_inputs(shape, n)
+    kernels = lambda *a: ssm.ssd_scan(*a, chunk=chunk)
+    oracle = recurrence if other == "recurrence" else (
+        lambda *a: einsum_form(monkeypatch)(*a, chunk=chunk)
+    )
+    kv_policy.ROUTE_LOG.clear()
+    with jax.default_matmul_precision("highest"):
+        got = kernels(*args)
+        assert {"site": "forward/ssd", "impl": "ssd_chunk", "interpret": True} in kv_policy.ROUTE_LOG
+        np.testing.assert_allclose(got, oracle(*args), rtol=5e-5, atol=5e-5)
+        w = jax.random.normal(jax.random.key(9), got.shape)
+        loss = lambda f: (lambda *a: jnp.sum(f(*a) * w))
+        g = jax.grad(loss(kernels), argnums=range(6))(*args)
+        g_other = jax.grad(loss(oracle), argnums=range(6))(*args)
+    for name, a, b in zip("x dt A B C D".split(), g, g_other):
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4 * scale, err_msg=name)
+
+
+def test_the_kernels_in_bfloat16_stay_in_their_band(monkeypatch):
+    """As the einsum form's band test: bf16 operands on the MXU, widened just
+    before each dot, float32 everything else. And the gradients: each within
+    twice the einsum form's own distance from the float32 gradient (or 0.5 %),
+    ``A``'s above all, which is a sum of differences that cancel term by
+    term only while both cotangents of the exponents see the same rounded
+    ``dy`` (one taken from the unrounded ``dy`` read 2.4 % here)."""
+    real, seen = ssm._mxu, []
+
+    def widened(a, b, contract):
+        seen.append((a.dtype, b.dtype))
+        return real(a.astype(jnp.float32), b.astype(jnp.float32), contract)
+
+    monkeypatch.setattr(ssm, "_mxu", widened)
+    chunk, args = kernel_inputs("c256h32", 3 * 256 - 40, seed=1)
+    exact = recurrence(*args)
+    scale = float(jnp.max(jnp.abs(exact)))
+    got = ssm.ssd_scan(*args, chunk=chunk, dtype=jnp.bfloat16)
+    assert got.dtype == jnp.float32
+    assert seen and all(d == (jnp.bfloat16, jnp.bfloat16) for d in seen), set(seen)
+    err = float(jnp.max(jnp.abs(got - exact))) / scale
+    assert 1e-5 < err < 0.03, err
+
+    w = jax.random.normal(jax.random.key(9), got.shape)
+    grads = lambda f, **kw: jax.grad(
+        lambda *a: jnp.sum(f(*a, chunk=chunk, **kw) * w), argnums=range(6)
+    )(*args)
+    with jax.default_matmul_precision("highest"):
+        g_exact = grads(einsum_form(monkeypatch))
+    g_kernels = grads(ssm.ssd_scan, dtype=jnp.bfloat16)
+    g_einsum = grads(einsum_form(monkeypatch), dtype=jnp.bfloat16)
+    for name, k, e, f in zip("x dt A B C D".split(), g_kernels, g_einsum, g_exact):
+        off = lambda g: float(jnp.linalg.norm(g - f) / jnp.linalg.norm(f))
+        assert off(k) < max(2 * off(e), 0.005), (name, off(k), off(e))
+
+
+@pytest.mark.parametrize("fault", ["no_carry", "bf16_decay"])
+def test_the_planted_faults_fail_through_the_kernels(fault, monkeypatch):
+    """The two faults the benchmark plants from outside, by replacing the
+    module's ``carried_states`` and ``log_decay`` (benchmarks/drivers/
+    train_lm.py), reach the kernel route too."""
+    chunk, args = kernel_inputs("c128h8", 3 * 128, seed=2)
+    exact = recurrence(*args)
+    scale = float(jnp.max(jnp.abs(exact)))
+    healthy = float(jnp.max(jnp.abs(ssm.ssd_scan(*args, chunk=chunk) - exact)))
+    assert healthy < 1e-4 * scale
+    if fault == "no_carry":
+        monkeypatch.setattr(ssm, "carried_states", lambda states, total: jnp.zeros_like(states))
+    else:
+        real = ssm.log_decay
+        rounded = lambda t: t.astype(jnp.bfloat16).astype(jnp.float32)
+        monkeypatch.setattr(ssm, "log_decay", lambda dt, A: rounded(real(rounded(dt), rounded(A))))
+    broken = ssm.ssd_scan(*args, chunk=chunk)
+    if fault == "no_carry":
+        # the first chunk starts from zero either way; every later chunk differs
+        np.testing.assert_allclose(broken[:, :chunk], exact[:, :chunk], rtol=5e-5, atol=5e-5)
+        assert float(jnp.max(jnp.abs(broken[:, chunk:] - exact[:, chunk:]))) > 0.05 * scale
+    else:
+        assert float(jnp.max(jnp.abs(broken - exact))) > 100 * max(healthy, 1e-6 * scale)
+
+
+def test_eligibility_is_read_from_the_shape():
+    assert ssm.ssd_kernels_eligible(256, 64, 64, 128)        # the cell's mixer
+    assert ssm._head_block(64, 64) == 16 and ssm._head_block(8, 64) == 8
+    assert not ssm.ssd_kernels_eligible(CHUNK, H, P, N)      # this file's tiny shapes
+    assert not ssm.ssd_kernels_eligible(256, 64, 64, 64)     # a state of half a lane tile
+    assert not ssm.ssd_kernels_eligible(192, 64, 64, 128)    # a chunk of one and a half
+    assert not ssm.ssd_kernels_eligible(256, 12, 64, 128)    # heads in no whole block
+    kv_policy.ROUTE_LOG.clear()
+    scan(*inputs(CHUNK))
+    assert kv_policy.ROUTE_LOG == [{"site": "forward/ssd", "impl": "einsum", "interpret": None}]
 
 
 def test_causal_depthwise_convolution():
