@@ -63,7 +63,7 @@ REPO = Path(__file__).resolve().parent
 # fails here, before any phase
 NEEDED = ("train_vae.py", "train_dalle.py", "generate.py", "dalle_pytorch_tpu")
 
-# the flagship (BASELINE.json row 2; bench.py's DEPTH/DIM/HEADS/DIM_HEAD)
+# the flagship (BASELINE.json row 2)
 DIM, DEPTH, HEADS, DIM_HEAD = 1024, 12, 16, 64
 TEXT_SEQ, IMAGE_SIZE, VAE_LAYERS, NUM_TOKENS = 256, 256, 3, 8192
 SEQ = TEXT_SEQ + (IMAGE_SIZE // 2**VAE_LAYERS) ** 2  # 1280
@@ -78,8 +78,8 @@ N_IMAGES = BATCH * TRAIN_STEPS
 SERVE_REQUESTS = 4
 
 # serving checks: page geometry of the flagship slot (257 + 1024 positions
-# in 128-row pages), the fused block widths in use (1 + spec_k, and
-# bench.py's prefill chunk T // 16)
+# in 128-row pages), the fused block widths in use (1 + spec_k, and a
+# prefill chunk of T // 16)
 PAGE, SLOT_PAGES = 128, 11
 SPEC_WIDTH, CHUNK_WIDTH = 4, 16
 REPLAY_NEW_TOKENS, FUSED_NEW_TOKENS = 256, 64
@@ -202,7 +202,7 @@ def require_mosaic(phase: str, rep: dict, module: str) -> None:
 def read_losses(flight_dir: Path) -> list[float]:
     """Per-step losses from train_dalle.py --telemetry's flight recorder:
     every ``train.step`` span closes with the step's loss
-    (train_dalle.py:process_verdict)."""
+    (parallel/loop.py)."""
     flights = sorted(flight_dir.glob("flight-*.jsonl"))
     if not flights:
         raise SystemExit(f"chip_smoke: no flight-recorder file under {flight_dir}")

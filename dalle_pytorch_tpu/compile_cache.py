@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives — one rule, one place.
 
 Every entry point (train_vae.py, train_dalle.py, train_clip.py,
-generate.py, bench.py, the children of chip_smoke.py) calls
+train_lm.py, generate.py, the children of chip_smoke.py) calls
 ``enable_compile_cache()`` first thing, and the test harness calls it with
 its own default. The rule:
 

@@ -685,8 +685,8 @@ class PatternAttention(nn.Module):
         These grouped forms serve the non-flash shapes (CPU tests, decode
         mask rows, seqs not divisible by 128). At flash-eligible shapes the
         patterns ride the packed flash kernel instead — a measured decision
-        (flagship shape: depth 12, seq 1280, batch 8, v5e, 2026-07, via
-        bench.py --patterns):
+        (flagship shape: depth 12, seq 1280, batch 8, v5e, 2026-07: a
+        pre-ledger note, not in PERF_LEDGER.jsonl):
 
           full / packed flash kernel     134 ms/step   (59% MFU baseline)
           sparse via flash pattern op    138 ms/step   (0.97x)

@@ -3,8 +3,9 @@
 The flash kernel (ops/flash_attention.py) streams EVERY (q-block, k-block)
 pair and uses its scalar-prefetch visit table to skip compute on dead
 blocks — index maps stay affine, so dead blocks still pay their K/V DMA.
-That is the right trade for near-dense patterns, and it is why BENCH_r05
-measured every sparse/axial/conv variant at 0.97-0.99x of full attention:
+That is the right trade for near-dense patterns, and it is why every
+sparse/axial/conv variant ran at 0.97-0.99x of full attention there
+(pre-ledger note, not in PERF_LEDGER.jsonl):
 the sparse patterns pay full memory traffic plus a streamed mask.
 
 Here the grid itself is the sparsity pattern. A host-compiled

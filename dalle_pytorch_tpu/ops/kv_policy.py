@@ -11,7 +11,8 @@ users when it fell back. This module replaces it with a *policy*:
   so the update cost is a property of the CACHE, not of the batch size —
   the structural fix for the 4-D layout's whole-buffer dynamic-update-slice
   rewrites that made serving throughput non-monotone in batch (batch 32
-  measured 6,050 tok/s vs batch 8's 6,832 on v5e, BENCH_r05). Also the only
+  at 6,050 tok/s vs batch 8's 6,832 on v5e: pre-ledger note, not in
+  PERF_LEDGER.jsonl, as every figure in this docstring). Also the only
   format with ragged per-sequence decode offsets (continuous batching).
 - ``"flat"``  — (b, L, h*d): the measured batch-8 winner (+38% tok/s over
   4-D there, v5e 2026-07), and a measured LOSER at batches 1/4/16/32 on the
@@ -21,16 +22,15 @@ users when it fell back. This module replaces it with a *policy*:
   layout whose DUS tax grows with batch (trace-measured 43% of the batch-8
   decode program before the flat fix).
 
-Default policy (the measured numbers above are the provenance): 4-D at
+Default policy (the pre-ledger notes above are the provenance; no cell of
+BENCHMARK.json serves yet, ROADMAP D3): 4-D at
 batch 1, flat at batch 8, paged everywhere else. Batch 1 and 8 keep their
 proven layouts; every other batch — where 4-D was only ever the lesser
 evil — gets the format whose update cost does not scale with the buffer.
-Re-measure with ``bench.py --sweep`` on compiler/chip changes.
 
 Every choice is emitted once per (format, batch) through the
 ``dalle_tpu.kv_policy`` logger and recorded in ``CHOICE_LOG`` so an
-unexpected layout fallback is observable (bench.py surfaces the format in
-its throughput records) instead of a silent perf cliff.
+unexpected layout fallback is observable instead of a silent perf cliff.
 
 Overrides, strongest first:
 - ``format_override(fmt)`` context manager (how an explicit
@@ -88,15 +88,15 @@ DEFAULT_PAGE_SIZE = 128
 # vs fused engines, preempt replay, spec decode) — quantization is a
 # deterministic per-row elementwise map, so the PR 9/10/11 parity
 # arguments carry over unchanged. Quantized-vs-f32 is a pinned
-# token-AGREEMENT threshold (below), asserted in tests and reported by
-# bench.py --serve; it is never a bitwise claim.
+# token-AGREEMENT threshold (below), asserted in tests; it is never a
+# bitwise claim.
 
 QUANTS = ("none", "int8")
 
 # pinned quantized-vs-f32 token-agreement floor (fraction of generated
 # positions whose sampled token matches the unquantized run, same seed):
-# asserted by tests/test_kv_quant.py and tools/serve_smoke.py, reported
-# by bench.py --serve. Position-wise agreement is chance-level after a
+# asserted by tests/test_kv_quant.py and tools/serve_smoke.py.
+# Position-wise agreement is chance-level after a
 # first divergence, so the floor is deliberately below the typically
 # observed ~1.0 on the tiny f32 CPU tier — it guards against the
 # quantizer breaking (agreement collapsing toward the random-token
@@ -119,8 +119,7 @@ class InvalidKVFormatError(ValueError):
         self.got = got
         self.valid = valid
 
-# every (format, batch, reason) decision made this process, in order — the
-# observable record bench.py attaches to its throughput entries
+# every (format, batch, reason) decision made this process, in order
 CHOICE_LOG: list = []
 _EMITTED: set = set()
 

@@ -11,7 +11,8 @@ layout is then a property of the cache, not of the batch size:
   page) are identical at every batch size, so XLA's layout choice cannot
   re-tip per batch the way the flat/(b, L, h*d) vs 4-D/(b, L, h, d) ranks
   did (the root cause of serving throughput being non-monotone in batch:
-  batch 32 measured 6,050 tok/s below batch 8's 6,832, BENCH_r05);
+  batch 32 at 6,050 tok/s below batch 8's 6,832: pre-ledger note, not in
+  PERF_LEDGER.jsonl);
 - the per-step append is a one-row scatter inside one page per sequence —
   never the whole-buffer dynamic-update-slice rewrite the 4-D layout
   compiled to (trace-measured 43% of the batch-8 decode program);
@@ -55,8 +56,8 @@ without building it: that kernel is already a measured negative result for
 this decode shape (~29 us/layer vs ~10 us for the XLA op chain it
 replaces, v5e; its module docstring), and paging adds an indirection per
 K/V block on top of the same skinny-MXU serialization. Revisit only if a
-TPU sweep (bench.py --sweep) shows the take-gather path bound on gather
-overhead rather than on page bytes.
+serving cell's trace shows the take-gather path bound on gather overhead
+rather than on page bytes.
 
 All functions are pure array ops (no flax state); ops/attention.py owns
 the cache variables and calls these.

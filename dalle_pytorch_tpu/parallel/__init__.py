@@ -1,4 +1,5 @@
 from .context import activate_mesh, active_mesh
+from .loop import Dispatch, TrainLoop
 from .mesh import AXIS_NAMES, MeshRuntime, init_distributed, make_runtime
 from .pipeline import gpipe, stack_layer_params
 from .sharding import (
@@ -13,7 +14,9 @@ from .step import TrainState, create_train_state, make_eval_step, make_train_ste
 __all__ = [
     "AXIS_NAMES",
     "DEFAULT_RULES",
+    "Dispatch",
     "MeshRuntime",
+    "TrainLoop",
     "TrainState",
     "activate_mesh",
     "active_mesh",

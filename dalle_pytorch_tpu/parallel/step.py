@@ -112,9 +112,9 @@ def make_train_step(
     unguarded steps are bit-identical (pinned in tests/test_resilience.py).
     The returned loss doubles as the rejection signal: NaN whenever the
     step was rejected (even when only the grads were non-finite), finite
-    otherwise — the host keys its batch-retry and the
-    K-consecutive-rejections abort (train_dalle.py --nan_abort_after) off
-    exactly the device's decision.
+    otherwise — the host (loop.py) keys its batch-retry and the
+    K-consecutive-rejections abort (--nan_abort_after) off exactly the
+    device's decision.
 
     ``nan_inject_step`` is the fault hook (utils/faults.py nan_at_step):
     the loss is forced to NaN at that global step, compiled in as a trace

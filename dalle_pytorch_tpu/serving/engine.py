@@ -234,10 +234,9 @@ class EngineConfig:
     # f32 scale pools, quantized at append and dequantized at read
     # in-kernel (Pallas ragged path) / in the shared jnp formula
     # (paged_kv.dequant) — roughly HALVING the engine's largest HBM
-    # tenant: ~2x concurrent slots per chip at fixed budget, ~2x
-    # prefix-cache arena working set, and a faster streamed-page decode
-    # under the kv_sweep_weight_stream_hbm_roofline bound
-    # (bench.py:decode_roofline_tokens_per_sec; not measured on a chip).
+    # tenant (tests/test_kv_quant.py holds the bytes per slot to >= 1.8x).
+    # What that buys in slots, arena working set and decode speed is a
+    # pre-ledger expectation, not in PERF_LEDGER.jsonl: no cell serves yet.
     # Parity tiers: quantized-vs-quantized holds the standing BITWISE
     # contract (cold/warm hit, split/fused, preempt replay, spec
     # decode); quantized-vs-f32 is the pinned token-agreement threshold
@@ -1018,7 +1017,7 @@ class Engine:
         # jits (_copy_pages_jit): a publish copies at most the prompt's
         # pages, a COW/restore fewer — one padded shape covers all
         self._copy_pad = pages_for(self.T, self.page)
-        # dispatch accounting (bench.py --serve): model-jit calls and
+        # dispatch accounting: model-jit calls and
         # engine iterations that did device work — steady-state fused mode
         # is exactly 1 dispatch/iteration, the split path one per prefill
         # chunk plus one decode step

@@ -546,7 +546,7 @@ class Router:
                  max_steps: int = 10_000) -> None:
         """SIGTERM graceful drain (the serving analog of the trainer's
         emergency checkpoint; wired to ``PreemptionHandler.on_signal``
-        by bench.py --serve and the smoke tools): stop admissions
+        by the smoke tools): stop admissions
         fleet-wide, drive until in-flight work finishes, then flush
         durable state — the journal is SEALED (sidecar manifest) and
         the prefix cache snapshotted to ``snapshot_dir`` (from the
@@ -951,8 +951,7 @@ class Router:
             counters.inc("router.respawns")
             recovery = None if r.death_t is None else now - r.death_t
             if recovery is not None:
-                # kill -> healthy MTTR, per replica (the bench.py --serve
-                # recovery record reads this histogram)
+                # kill -> healthy MTTR, per replica
                 histograms.observe(
                     "serve.recovery_s", recovery, labels=r.labels
                 )

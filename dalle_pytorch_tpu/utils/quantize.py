@@ -112,7 +112,7 @@ def prepare_for_serving(dalle, params, int8: bool = False, batch_size: int = 1):
     """Standard serving transform: cast the model + f32 params to bf16
     (decode is HBM-bound on weight reads) and optionally quantize the Dense
     kernels to int8. The single home for the load sequence generate.py and
-    bench.py share."""
+    chip_smoke.py share."""
     dalle = dalle.clone(dtype=jnp.bfloat16)
     params = jax.tree_util.tree_map(
         lambda x: x.astype(jnp.bfloat16) if x.dtype == jnp.float32 else x,

@@ -27,7 +27,7 @@ while a new enum member only needs its expansion added here.
 
 Span-duration histograms (``<span>_s``, auto-observed by
 utils/telemetry.py) are derived — see ``SPAN_DURATION_HISTOGRAMS`` —
-and are valid histogram names wherever bench/tools read them.
+and are valid histogram names wherever tools read them.
 """
 
 from __future__ import annotations
@@ -235,8 +235,7 @@ GAUGES = frozenset({
     # KV storage-format footprint (quantized-KV capacity lever, §6.1):
     # bytes of K/V storage (content + scale pools) per slot row, and
     # total physical pages per pool (slots + prefix arena) — int8 pools
-    # roughly halve bytes_per_slot, which is the ~2x pages-at-fixed-HBM
-    # headline bench.py --serve asserts
+    # roughly halve bytes_per_slot (tests/test_kv_quant.py: >= 1.8x)
     "serve.kv_quant.bytes_per_slot",
     "serve.kv_quant.pages",
     # engine vitals: sliding-window reductions over existing metrics
@@ -270,15 +269,13 @@ HISTOGRAMS = frozenset({
     "serve.stage.request_to_image_s",
     "router.failover_latency_s",
     # TTFT split by prefix-cache hit class (serve.ttft_s still carries
-    # every request; bench's cached-vs-cold comparison reads these)
+    # every request; a cached-vs-cold comparison reads these)
     "serve.ttft_full_hit_s",
     "serve.ttft_partial_hit_s",
     "serve.ttft_cold_s",
-    # tokens committed per speculative verify step (1 .. spec_k+1); the
-    # bench's accepted-tokens-per-step distribution reads this
+    # tokens committed per speculative verify step (1 .. spec_k+1)
     "serve.spec_accepted_per_step",
-    # replica kill -> healthy-again (respawn) MTTR, per replica label —
-    # the bench recovery record's source
+    # replica kill -> healthy-again (respawn) MTTR, per replica label
     "serve.recovery_s",
     # backoff hints attached to load-typed rejections (queue_full /
     # no_replica): what the fleet told clients to wait — the traffic
@@ -339,7 +336,7 @@ KERNEL_NAMES = frozenset({
 })
 
 # span durations are auto-observed as "<span>_s" (utils/telemetry.py);
-# derived here so readers (bench latency splits) can validate against it
+# derived here so readers can validate against it
 SPAN_DURATION_HISTOGRAMS = frozenset(s + "_s" for s in SPANS)
 
 ALL_NAMES = (

@@ -18,7 +18,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 # programs); skipping XLA's optimization pipeline cuts the cold full run
 # ~35% without changing program semantics (measured: test_moe.py 85 -> 55 s).
 # Runtime of the tiny test shapes is negligible either way; the TPU
-# benchmarks (bench.py) never import this file and stay fully optimized.
+# benchmark (benchmarks/) never imports this file and stays fully optimized.
 # Exported via the environment so CLI-subprocess e2e tests and the
 # multiprocess workers inherit it; set to 0 to override.
 # The blanket disable means parity tests exercise the UNOPTIMIZED pipeline;

@@ -92,8 +92,8 @@ class TestCompileCache:
         assert setters == ["dalle_pytorch_tpu/compile_cache.py"], setters
 
     @pytest.mark.parametrize("script", [
-        "train_vae.py", "train_dalle.py", "train_clip.py", "generate.py",
-        "bench.py", "chip_smoke.py",
+        "train_vae.py", "train_dalle.py", "train_clip.py", "train_lm.py",
+        "generate.py", "chip_smoke.py",
     ])
     def test_entry_points_call_the_helper(self, script):
         assert "enable_compile_cache()" in (REPO / script).read_text()

@@ -391,6 +391,34 @@ class TestChunkFaults:
 # ------------------------------------------------------------- TTFT
 
 
+class TestInterference:
+    def test_late_prompt_prefill_is_spread_over_decoding_iterations(self, model):
+        """A prompt arriving during steady decode: monolithic prefill puts
+        the whole prompt into ONE iteration (the decode-gap a chunked engine
+        exists to shrink); chunked prefill spreads it over several, and the
+        steady request decodes in every one of them."""
+        def window(prefill_chunk):
+            counters.reset()
+            histograms.reset()
+            eng = make_engine(model, prefill_chunk=prefill_chunk)
+            assert eng.submit(req(0, max_new=4)) is None
+            while counters.get("serve.decode_steps") < 1:
+                eng.step()  # steady request admitted and decoding
+            assert eng.submit(req(1, max_new=2)) is None
+            decoded0, iterations = counters.get("serve.decode_steps"), 0
+            while histograms.get("serve.ttft_s").count < 2:
+                eng.step()  # ... until the late request's first token
+                iterations += 1
+            decoded = counters.get("serve.decode_steps") - decoded0
+            eng.run(max_steps=500)
+            check_accounting(eng)
+            return iterations, decoded
+
+        assert window(None) == (1, 1)
+        iterations, decoded = window(2)
+        assert iterations > 1 and decoded == iterations
+
+
 class TestTtft:
     def test_ttft_in_results_and_histogram(self, model):
         counters.reset()

@@ -21,8 +21,8 @@ REFERENCE = Path("/root/reference/train_dalle.py")
 # Reference flags deliberately replaced by a TPU-native analog (not a gap
 # — each row is a conscious substitution, documented at the cited site).
 SUBSTITUTED = {
-    # DeepSpeed flops-profiler dump -> XLA trace capture + HLO FLOPs table
-    # (train_dalle.py --profile_trace_dir/--profile_step, bench.py --breakdown)
+    # DeepSpeed flops-profiler dump -> XLA trace capture
+    # (train_dalle.py --profile_trace_dir/--profile_step)
     "--flops_profiler": ("--profile_trace_dir", "--profile_step"),
 }
 

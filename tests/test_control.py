@@ -281,6 +281,49 @@ class TestEngineControl:
         # ...through the pre-traced signatures only (no recompile)
         assert engine_mod._spec_iteration_jit._cache_size() == sig_count
 
+    def test_budget_tightens_under_interference_and_relaxes_back(
+        self, deep_model
+    ):
+        """The budget channel through a REAL engine on virtual time: the
+        per-iteration dt jumps 100x mid-trace (the deterministic stand-in
+        for interference). The TokenBudget holds while gaps sit under the
+        SLO threshold, tightens while they exceed it, relaxes back once the
+        vitals window flushes, keeps the SAME chunk width throughout (grant
+        geometry never re-traces), and every request still completes (the
+        head-of-line floor)."""
+        dalle, params = deep_model
+        clock = FakeClock(step_dt=0.02)
+        eng = Engine(dalle, params, EngineConfig(
+            max_batch=2, prefill_chunk=2, fused_iteration=True,
+            controller=True, vitals_window=4,
+            control=ControlConfig(interval=2, gap_high_s=0.5),
+        ), clock=clock)
+        budget_default, chunk = eng.budget.budget, eng.budget.chunk
+        for i in range(4):
+            eng.submit(Request(
+                request_id=f"r{i}", prompt=prompt(i),
+                max_new_tokens=16, seed=i,
+            ))
+        for _ in range(10):
+            assert eng.step()
+        assert eng.budget.budget == budget_default  # every gap under the SLO
+        clock.step_dt = 2.0  # interference: every gap breaches it
+        tightest = budget_default
+        for _ in range(10):
+            assert eng.step()
+            tightest = min(tightest, eng.budget.budget)
+        assert tightest < budget_default
+        clock.step_dt = 0.02  # interference clears
+        recovered = False
+        while eng.step():
+            recovered = recovered or eng.budget.budget == budget_default
+        assert recovered
+        assert eng.budget.chunk == chunk
+        assert len(eng.results) == 4 and all(
+            r.outcome is Outcome.COMPLETED for r in eng.results.values()
+        )
+        check_accounting(eng)
+
     def test_decision_sequence_replays_bit_deterministically(
         self, deep_model
     ):

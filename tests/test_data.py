@@ -269,64 +269,9 @@ class TestMetricsLogger:
         assert "histogram" in out and "unique=3" in out
 
 
-class TestHloBreakdown:
-    """bench.py --breakdown's parser (utils/hlo_breakdown.py): per-module
-    FLOPs from compiled HLO, the analog of the reference's DeepSpeed
-    flops-profiler table (ref train_dalle.py:473-480)."""
-
-    def test_dot_flops_from_compiled_hlo(self):
-        import jax
-        import jax.numpy as jnp
-
-        from dalle_pytorch_tpu.utils.hlo_breakdown import (
-            format_table,
-            parse_hlo_flops,
-        )
-
-        def f(x, w1, w2):
-            with jax.named_scope("layer_a"):
-                h = x @ w1
-            with jax.named_scope("layer_b"):
-                return h @ w2
-
-        x = jnp.zeros((8, 32))
-        w1, w2 = jnp.zeros((32, 64)), jnp.zeros((64, 16))
-        comp = jax.jit(f).lower(x, w1, w2).compile()
-        groups = parse_hlo_flops(comp.as_text())
-        flat = {k: v["fwd"] + v["bwd"] for k, v in groups.items()}
-        # 2*8*32*64 and 2*8*64*16 FLOPs, charged to their scopes
-        by_scope = {k.split("/")[-1]: v for k, v in flat.items()}
-        assert by_scope.get("layer_a") == 2 * 8 * 32 * 64
-        assert by_scope.get("layer_b") == 2 * 8 * 64 * 16
-        table = format_table(groups, step_time_s=0.001, peak_flops=1e12)
-        assert "layer_a" in table and "TOTAL" in table
-
-    def test_custom_call_and_backward_split(self):
-        from dalle_pytorch_tpu.utils.hlo_breakdown import parse_hlo_flops
-
-        hlo = """
-HloModule m
-ENTRY e {
-  %p0 = f32[4,8]{1,0} parameter(0)
-  %cc = f32[4,8]{1,0} custom-call(%p0), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/jvp(M)/attn/flash_fwd"}
-  %w = f32[8,2]{1,0} parameter(1)
-  %d = f32[4,2]{1,0} dot(%cc, %w), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="jit(f)/transpose(jvp(M))/head/dot_general"}
-}
-"""
-        def cc(line):
-            if "tpu_custom_call" not in line:
-                return None
-            return ("attn[pallas]", "fwd", 123.0)
-
-        groups = parse_hlo_flops(hlo, custom_call_flops=cc)
-        assert groups["attn[pallas]"]["fwd"] == 123.0
-        assert groups["head"]["bwd"] == 2 * 4 * 2 * 8
-
-
 def test_analyze_trace_tool(tmp_path):
     """tools/analyze_trace.py digests a Chrome-format profiler trace into
-    the per-category table (the measured-time complement of
-    bench.py --breakdown)."""
+    the per-category table."""
     import gzip
     import json
     import sys

@@ -24,10 +24,7 @@ and dequantized at READ time — pinned deterministically on CPU:
   >= 1.8x the pages of the unquantized format at a fixed budget, the
   ``serve.kv_quant.*`` gauges are registered and published, and the
   committed trace contract pins the quant serving entries to the same
-  signature budgets as their unquantized twins;
-- bench record shape: ``bench.bench_serve_quant`` on the tiny parity
-  model carries the capacity ratio, agreement fraction, and
-  zero-compile fields.
+  signature budgets as their unquantized twins.
 
 Page size 2 (env override), as in tests/test_serving.py, so the tiny
 model's T=5 prompt spans 3 pages with a partial terminal page and
@@ -552,10 +549,10 @@ class TestEngineQuantParity:
         )
 
 
-# --------------------------------------------------- contracts + bench
+# ------------------------------------------------------------ contracts
 
 
-class TestContractsAndBench:
+class TestContracts:
     def test_trace_contract_pins_quant_entries(self):
         """The committed trace contract carries the quantized serving
         entries at the SAME signature budgets as their unquantized twins
@@ -597,37 +594,3 @@ class TestContractsAndBench:
         assert dq["max_hbm_bytes"] < entries["serving.decode"][
             "max_hbm_bytes"
         ]
-
-    def test_bench_serve_quant_record(self, model):
-        import bench
-
-        rec = bench.bench_serve_quant(True, model=model, seed=0)
-        for k in ("kv_bytes_per_slot_unquant", "kv_bytes_per_slot_int8",
-                  "kv_pages_per_budget_ratio", "token_agreement_vs_unquant",
-                  "token_agreement_floor", "compiles_in_trace_int8",
-                  "jit_recompiles_in_trace_int8",
-                  "roofline_tokens_per_sec_batch8",
-                  "roofline_tokens_per_sec_batch8_kv_int8"):
-            assert k in rec, k
-        assert rec["metric"].startswith("serve_kv_quant")
-        assert rec["kv_pages_per_budget_ratio"] >= 1.8
-        assert (
-            rec["token_agreement_vs_unquant"]
-            >= rec["token_agreement_floor"]
-        )
-        assert rec["compiles_in_trace_int8"] in (0, -1)
-        assert all(
-            v in (0, -1)
-            for v in rec["jit_recompiles_in_trace_int8"].values()
-        )
-        # bytes halve (or better): the f32 parity-tier model quantizes
-        # 4-byte elements down to 1 + scale overhead
-        assert (
-            rec["kv_bytes_per_slot_int8"] * 2
-            <= rec["kv_bytes_per_slot_unquant"]
-        )
-        # the recomputed int8 stream bound sits ABOVE the bf16 bound
-        assert (
-            rec["roofline_tokens_per_sec_batch8_kv_int8"]
-            > rec["roofline_tokens_per_sec_batch8"]
-        )

@@ -3,7 +3,7 @@
 conftest.py runs the whole suite under JAX_DISABLE_MOST_OPTIMIZATIONS=1
 (a measured ~35% compile-time win for the compile-dominated suite), which
 means every other parity test exercises the UNOPTIMIZED XLA pipeline while
-bench.py/serving run fully optimized — a miscompile or numerical
+the benchmark and serving run fully optimized — a miscompile or numerical
 divergence introduced by XLA's optimization passes (exactly the bug class
 the parity suite exists to catch) would pass CI undetected (ADVICE.md
 round 5). This file is the counterweight: one decode-parity and one
@@ -79,7 +79,7 @@ def test_serving_decode_parity_with_optimizations_enabled(optimized_xla):
     """The serving path's pinned contract — chunked prefill bit-identical
     to monolithic — re-run with the optimization pipeline ENABLED: the
     continuous-batching engine's prefill/decode programs (the ones
-    bench.py and production serving actually compile) must sample the
+    production serving actually compiles) must sample the
     same tokens either way (ADVICE.md round 5: every other serving test
     runs unoptimized)."""
     from dalle_pytorch_tpu.serving import (

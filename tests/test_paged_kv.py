@@ -345,49 +345,6 @@ class TestRaggedOffsets:
                 np.testing.assert_array_equal(np.asarray(x), np.asarray(offs))
 
 
-# ------------------------------------------------- sweep bench (slow tier)
-
-
-def test_bench_decode_sweep_and_ragged_records():
-    """Drive bench.py's batch sweep + continuous-batching sections on CPU
-    (listed in tests/slow_tests.txt): every sweep record must carry the
-    named derived bound and its cache format, so a TPU run of the same
-    code emits the observability the layout policy stands on."""
-    import subprocess
-    import sys
-    import json
-    import os as _os
-
-    env = dict(_os.environ, JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [sys.executable, "bench.py", "--sweep", "--ragged"],
-        capture_output=True, text=True, timeout=1200, env=env,
-        cwd=_os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    records = [json.loads(l) for l in out.stdout.splitlines() if l.startswith("{")]
-    sweep = [r for r in records if r["metric"].startswith("decode_sweep")]
-    ragged = [r for r in records if "continuous_batching" in r["metric"]]
-    assert sweep and ragged
-    for r in sweep:
-        assert r["bound_name"] == "kv_sweep_weight_stream_hbm_roofline"
-        assert r["roofline_tokens_per_sec"] > 0
-        assert r["cache_format"] in ("paged", "flat", "4d")
-        assert "policy_default_format" in r
-    # the derived bound itself is monotone in batch (the in-source claim)
-    by_fmt = {}
-    for r in sweep:
-        by_fmt.setdefault(r["cache_format"], []).append(
-            (r["batch"], r["roofline_tokens_per_sec"])
-        )
-    for pts in by_fmt.values():
-        pts = sorted(pts)
-        assert all(b2 >= b1 for (_, b1), (_, b2) in zip(pts, pts[1:]))
-    assert ragged[0]["cache_format"] == "paged"
-    offs = ragged[0]["ragged_offsets"]
-    assert len(set(offs)) == len(offs) > 1  # genuinely ragged
-
-
 # ----------------------------------------------------------- the policy
 
 

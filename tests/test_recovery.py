@@ -47,6 +47,7 @@ from dalle_pytorch_tpu.serving import (
     request_from_record,
     request_to_record,
 )
+from dalle_pytorch_tpu.serving import engine as engine_mod
 from dalle_pytorch_tpu.utils.faults import FAULTS
 from dalle_pytorch_tpu.utils.metrics import counters
 from dalle_pytorch_tpu.utils.resilience import (
@@ -697,6 +698,44 @@ class TestRestartReplay:
         # idempotency: the finished request does not replay again
         router2._journal.seal()
         assert RequestJournal.unfinished(jpath) == []
+
+    def test_restarted_fleet_serves_through_the_programs_already_traced(
+        self, model, tmp_path
+    ):
+        """The post-restart serving window (snapshot restore, a warm hit, a
+        cold request) adds no signature to any serving jit: recovery time
+        holds no compile."""
+        snap = str(tmp_path / "prefix_snapshot")
+        jits = [
+            getattr(engine_mod, name) for name in (
+                "_prefill_chunk_jit", "_prefill_last_jit", "_decode_jit",
+                "_sample_cached_jit", "_copy_pages_jit",
+                "_copy_pages_across_jit",
+            )
+        ]
+
+        def serve(router, tag):
+            # a cold prompt, then the same prompt again (a warm full hit),
+            # then another cold one
+            for n, i in enumerate((0, 0, 1)):
+                assert router.submit(Request(
+                    request_id=f"{tag}{n}", prompt=prompt(i),
+                    max_new_tokens=4, seed=60 + n,
+                )) is None
+                router.run(max_steps=2000)
+            router.verify_invariants()
+
+        router = make_router(model, n=1, prefix_cache=True)
+        serve(router, "first")
+        router._replicas[0].engine.save_prefix_snapshot(snap)
+        before = [int(j._cache_size()) for j in jits]
+
+        router2 = make_router(model, n=1, prefix_cache=True)
+        eng2 = router2._replicas[0].engine
+        assert eng2.load_prefix_snapshot(snap)
+        serve(router2, "second")
+        assert eng2.prefix.stats.hits >= 2  # the first from the restored arena
+        assert [int(j._cache_size()) for j in jits] == before
 
     def test_shutdown_flushes_snapshot_and_leaves_queue_journaled(
         self, model, tmp_path

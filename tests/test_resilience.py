@@ -31,7 +31,12 @@ import optax
 import pytest
 from PIL import Image
 
-from dalle_pytorch_tpu.parallel import create_train_state, make_runtime, make_train_step
+from dalle_pytorch_tpu.parallel import (
+    TrainLoop,
+    create_train_state,
+    make_runtime,
+    make_train_step,
+)
 from dalle_pytorch_tpu.utils import (
     FAULTS,
     PreemptionHandler,
@@ -421,52 +426,35 @@ def _host(tree):
     return jax.tree_util.tree_map(np.asarray, tree)
 
 
-def _run_loop(state, step_fn, batches, *, start=0, ckpt_dir=None,
-              save_every=None, preempt=None, on_step=None, abort_after=5):
-    """Mirror train_dalle.py's loop semantics on the toy harness: verdict of
-    the previous step decides scheduler/retry BEFORE the next dispatch; a
-    NaN-skipped batch is re-fed so the applied-update sequence matches an
-    unfaulted run; periodic verified saves carry the next batch index; a
-    preemption flag triggers an emergency save and an early return.
+def _train(state, step_fn, batches, *, start=0, ckpt_dir=None,
+           save_every=None, preempt=None, on_step=None):
+    """Drive the program's own loop (parallel/loop.py) over the toy harness
+    the way train_dalle.py does: periodic verified saves and the emergency
+    save on a preemption flag both resolve the in-flight verdict first and
+    record the next batch index; ``start`` is a resume's first batch.
 
     -> (state, stopped_early)."""
-    prev_loss = None
-    nan_run = 0
-    retry_batch = None
-    last = None
-    i = start
-    while True:
-        if prev_loss is not None:
-            if math.isfinite(float(prev_loss)):
-                nan_run = 0
-            else:
-                nan_run += 1
-                assert nan_run < abort_after, "persistent NaN — abort"
-                retry_batch = last
-            prev_loss = None
-        if retry_batch is not None:
-            batch, retry_batch = retry_batch, None
-        else:
-            if i >= len(batches):
-                break
-            batch = batches[i]
-            i += 1
-        last = batch
-        state, loss = step_fn(state, batch, jax.random.key(0))
-        prev_loss = loss
-        if ckpt_dir and save_every and int(state.step) % save_every == 0:
+    loop = TrainLoop(
+        lambda s, batch, rng, lr: step_fn(s, batch, rng), state,
+        feed=lambda batch: batch, lr=0.0, nan_abort_after=5,
+        log=lambda line: None, global_step=int(state.step),
+        resume=(0, start - 1),
+    )
+    for _ in loop.epoch(0, batches):
+        step = int(loop.state.step)
+        if ckpt_dir and save_every and step % save_every == 0:
             save_sharded_checkpoint(
-                ckpt_dir, int(state.step), state, meta={"next": i}
+                ckpt_dir, step, loop.state, meta={"next": loop.resolve() + 1}
             )
         if on_step is not None:
-            on_step(int(state.step))
+            on_step(step)
         if preempt is not None and preempt.triggered:
             save_sharded_checkpoint(
-                ckpt_dir, int(state.step), state,
-                meta={"next": i, "emergency": True},
+                ckpt_dir, step, loop.state,
+                meta={"next": loop.resolve() + 1, "emergency": True},
             )
-            return state, True
-    return state, False
+            return loop.state, True
+    return loop.state, False
 
 
 # ------------------------------------------------------------- NaN guard
@@ -548,10 +536,10 @@ class TestNaNGuard:
         batches = _batches(4)
 
         clean_state, clean_fn = _toy_setup()
-        clean_state, _ = _run_loop(clean_state, clean_fn, batches)
+        clean_state, _ = _train(clean_state, clean_fn, batches)
 
         faulted_state, faulted_fn = _toy_setup(nan_inject_step=2)
-        faulted_state, _ = _run_loop(faulted_state, faulted_fn, batches)
+        faulted_state, _ = _train(faulted_state, faulted_fn, batches)
 
         assert int(faulted_state.skipped) == 1
         assert int(faulted_state.consec_skipped) == 0  # reset by recovery
@@ -568,11 +556,11 @@ class TestNaNGuard:
         before finishing (the epoch-boundary case in train_dalle.py)."""
         batches = _batches(3)
         clean_state, clean_fn = _toy_setup()
-        clean_state, _ = _run_loop(clean_state, clean_fn, batches)
+        clean_state, _ = _train(clean_state, clean_fn, batches)
 
         # input step 2 == the dispatch of the last batch
         f_state, f_fn = _toy_setup(nan_inject_step=2)
-        f_state, _ = _run_loop(f_state, f_fn, batches)
+        f_state, _ = _train(f_state, f_fn, batches)
         assert int(f_state.skipped) == 1
         assert int(f_state.step) == int(clean_state.step) + 1
         for a, b in zip(
@@ -696,12 +684,12 @@ class TestKillAndResume:
         root = str(tmp_path / "cp")
 
         clean_state, clean_fn = _toy_setup()
-        clean_state, _ = _run_loop(clean_state, clean_fn, batches)
+        clean_state, _ = _train(clean_state, clean_fn, batches)
 
         state, step_fn = _toy_setup()
         with PreemptionHandler() as preempt:
             kill = lambda step: step == 3 and os.kill(os.getpid(), signal.SIGTERM)
-            state, stopped = _run_loop(
+            state, stopped = _train(
                 state, step_fn, batches,
                 ckpt_dir=root, preempt=preempt, on_step=kill,
             )
@@ -711,7 +699,7 @@ class TestKillAndResume:
         state2, step_fn2 = _toy_setup()
         restored, meta, step = load_sharded_checkpoint(root, _host(state2))
         assert step == 3 and meta["emergency"]
-        resumed, _ = _run_loop(restored, step_fn2, batches, start=meta["next"])
+        resumed, _ = _train(restored, step_fn2, batches, start=meta["next"])
 
         assert int(resumed.step) == int(clean_state.step)
         for a, b in zip(
@@ -746,7 +734,7 @@ class TestKillAndResume:
 
         # -- reference: unfaulted run over the same data
         clean_state, clean_fn = _toy_setup()
-        clean_state, _ = _run_loop(clean_state, clean_fn, batches)
+        clean_state, _ = _train(clean_state, clean_fn, batches)
 
         # -- faulted run: NaN at step 2, SIGTERM at step 5, and the
         #    emergency save itself corrupted (post-commit bit rot)
@@ -757,7 +745,7 @@ class TestKillAndResume:
                     FAULTS.arm("ckpt_corrupt", 1)
                     os.kill(os.getpid(), signal.SIGTERM)
 
-            state, stopped = _run_loop(
+            state, stopped = _train(
                 state, step_fn, batches,
                 ckpt_dir=root, save_every=2, preempt=preempt, on_step=on_step,
             )
@@ -773,7 +761,7 @@ class TestKillAndResume:
         state2, step_fn2 = _toy_setup(nan_inject_step=2)  # env still armed
         restored, meta, step = load_sharded_checkpoint(root, _host(state2))
         assert step == 4 and not meta.get("emergency")
-        resumed, stopped = _run_loop(
+        resumed, stopped = _train(
             restored, step_fn2, batches, start=meta["next"]
         )
         assert not stopped

@@ -555,30 +555,3 @@ def test_serve_smoke_replicas_tool():
         )
         assert out.returncode == 0, (extra_env, out.stderr[-2000:])
         assert "replica crash drill bit-identically" in out.stderr
-
-
-@pytest.mark.slow
-def test_bench_serve_replicas_record():
-    """bench.py --serve --replicas 3 must emit the chaos-gate record (the
-    in-bench asserts — typed outcomes, bit-parity, one death — already
-    ran if the record prints)."""
-    import json
-    import os
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [sys.executable, "bench.py", "--serve", "--replicas", "3"],
-        capture_output=True, text=True, timeout=1200, env=env, cwd=repo,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    recs = [json.loads(l) for l in out.stdout.splitlines() if l.startswith("{")]
-    rep = [r for r in recs if r["metric"].startswith("serve_replicas")]
-    assert len(rep) == 1
-    r = rep[0]
-    assert r["n_replicas"] == 3
-    assert r["bit_identical_vs_clean"] is True
-    assert r["chaos_requests_failed_over"] >= 1
-    assert sum(r["chaos_outcomes"].values()) == r["n_requests"] + 3
-    assert list(r["chaos_replica_states"].values()).count("dead") == 1
-    assert r["failover_latency_p50_ms"] is not None

@@ -451,35 +451,6 @@ class TestSpecDispatchContract:
             accepted / drafted
         )
 
-    def test_bench_serve_spec_record_shape(self, model):
-        """bench.py's speculation on/off record (ISSUE 11 satellite):
-        the in-bench acceptance (>1 accepted token per verify step,
-        fewer verify steps than plain decode steps, zero in-trace
-        compiles, f32 bit-parity) ran if the record returns; pin its
-        field contract here on the tiny parity-tier model."""
-        import bench
-
-        rec = bench.bench_serve_spec(True, model=model, seed=0)
-        for k in ("accept_rate", "accepted_per_step", "drafted",
-                  "accepted", "verify_steps_spec", "decode_steps_plain",
-                  "tokens_per_sec_spec", "tokens_per_sec_plain",
-                  "tps_ratio_spec_over_plain", "compiles_in_trace",
-                  "jit_recompiles_in_trace", "spec_k", "arrival_seed",
-                  "max_batch"):
-            assert k in rec, k
-        assert rec["metric"].startswith("serve_spec_accepted_tokens")
-        # the exact full-depth drafter on the f32 tier: every draft
-        # accepted, so the mean accepted-per-step is bounded only by the
-        # remaining-budget cap and must clear 1
-        assert rec["accept_rate"] == 1.0
-        assert rec["accepted_per_step"]["mean"] > 1.0
-        assert rec["verify_steps_spec"] < rec["decode_steps_plain"]
-        assert rec["spec_tokens_bit_identical_to_plain"] is True
-        assert rec["compiles_in_trace"] in (0, -1)
-        assert all(
-            v in (0, -1) for v in rec["jit_recompiles_in_trace"].values()
-        ), rec["jit_recompiles_in_trace"]
-
     def test_trace_contract_pins_spec_and_page_copy(self):
         """The committed trace contract pins ``serving.iteration_spec``
         to EXACTLY the steady + final signature pair with the cache

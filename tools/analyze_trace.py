@@ -1,9 +1,8 @@
 #!/usr/bin/env python
 """Digest a jax.profiler trace into per-category / per-op device-time tables.
 
-The measured-time complement to ``bench.py --breakdown`` (which charges
-FLOPs from the compiled HLO): capture a trace with
-``train_dalle.py --profile_trace_dir DIR`` (or jax.profiler directly), then
+Capture a trace with ``train_dalle.py --profile_trace_dir DIR`` (or
+jax.profiler directly), then
 
     python tools/analyze_trace.py DIR [--module NAME] [--top N]
 

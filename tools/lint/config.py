@@ -135,10 +135,17 @@ def default_layer_rules() -> Tuple[LayerRule, ...]:
         LayerRule(
             name="library-below-entrypoints",
             files=("dalle_pytorch_tpu/*.py", "dalle_pytorch_tpu/*/*.py"),
-            forbid=("train_dalle", "train_vae", "train_clip",
-                    "generate", "bench"),
-            why="library code must not import the CLI entrypoints "
-                "(script-level side effects, circular bootstrap)",
+            forbid=("train_dalle", "train_vae", "train_clip", "train_lm",
+                    "generate", "benchmarks"),
+            why="library code must not import the CLI entrypoints or the "
+                "benchmark (script-level side effects, circular bootstrap)",
+        ),
+        LayerRule(
+            name="parallel-below-serving",
+            files=("dalle_pytorch_tpu/parallel/*.py",),
+            forbid=("dalle_pytorch_tpu.serving",),
+            why="the training runtime (mesh, step, loop) is what every "
+                "trainer shares; it knows nothing of the serving engine",
         ),
     )
 
@@ -152,8 +159,8 @@ def default_config(repo_root: str) -> LintConfig:
             "train_dalle.py",
             "train_vae.py",
             "train_clip.py",
+            "train_lm.py",
             "generate.py",
-            "bench.py",
         ),
         exclude=(
             "*/__pycache__/*",

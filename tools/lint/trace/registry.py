@@ -53,7 +53,7 @@ CANON_ENGINE = dict(max_batch=2, prefill_chunk=2)
 # (num_tokens == num_image_tokens, fmap == image_fmap_size, so the
 # engine's generated ids are valid decode input), and a CLIP sized to
 # the canonical text vocab/seq — the same tiny pair the serve-smoke
-# stage drill and the stage bench build
+# stage drill builds
 CANON_VAE = dict(
     image_size=4, num_layers=1, num_tokens=12, codebook_dim=16,
     hidden_dim=8,
@@ -665,11 +665,9 @@ def _stage_entries() -> List[EntryPoint]:
     every dispatch to its configured batch width (StageConfig.batch ==
     the canonical engine's max_batch), so each jit has EXACTLY one
     steady signature — a second signature is the shape-drift-recompile
-    bug class, and the in-bench zero-in-trace-compile assertion
-    (bench.py --serve, stage record) holds only because of it. VAE
-    params are the decode-scope tree (``init(..., method="decode")``):
-    the pipeline's contract is token ids -> pixels, so the encoder
-    never rides along. No donation: stage tensors are tiny relative to
+    bug class. VAE params are the decode-scope tree
+    (``init(..., method="decode")``): the pipeline's contract is token
+    ids -> pixels, so the encoder never rides along. No donation: stage tensors are tiny relative to
     the KV pools, and the image must survive the dispatch (it is the
     journal payload and the degraded-completion partial)."""
     import jax

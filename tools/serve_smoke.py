@@ -92,9 +92,7 @@ def lint_preflight(label: str = "serve smoke") -> int:
     request is admitted. Subprocesses on purpose: the AST stage must
     not inherit this process's jax initialization, and the contract
     stages re-import the package fresh so a broken import fails the
-    gate, not the drill. (tools/bench_trend.py is no longer a stage: the
-    BENCH_r*.json history it read will never grow again, and a gate over
-    frozen records guards nothing — ROADMAP S0 points it at the ledger.)"""
+    gate, not the drill."""
     import subprocess
 
     for stage, script, args in (
@@ -138,7 +136,7 @@ def build_tiny_stages(config=None):
     configs the trace-contract registry pins for ``serving.vae_decode``
     / ``serving.clip_rerank`` (tools/lint/trace/registry.py), so every
     gate that builds stages through this helper (this drill,
-    tools/chaos_soak.py, bench.py --serve, the unit tests) dispatches
+    tools/chaos_soak.py, the unit tests) dispatches
     the exact contracted signatures. VAE params are the decode-scope
     tree (``init(..., method="decode")``): the pipeline's contract is
     token ids -> pixels."""

@@ -22,9 +22,9 @@ release needs (docs/DESIGN.md §9):
 3. the ``/metrics`` exposition renders (every sample line parses as
    ``name{...} value``);
 4. the long-prompt-arrival-during-steady-decode interference scenario
-   (bench.py:bench_serve_interference, quick mode on the tiny model)
-   runs with the recorder on, its max-decode-gap metric is finite, and
-   the spans it adds still balance;
+   (``_interference_max_gap``, on the tiny model, monolithic and chunked
+   prefill) runs with the recorder on, its max-decode-gap is finite and
+   positive, and the spans it adds still balance;
 5. a 2-replica router pass (serving/router.py) runs traced: every
    request gets a balanced ``router.request`` span chain ending typed,
    the per-replica labeled series (``serve_submitted{replica="0"}``)
@@ -50,6 +50,7 @@ must still pass, with the retry visible in the trace.
 from __future__ import annotations
 
 import json
+import math
 import os
 import sys
 import tempfile
@@ -93,6 +94,63 @@ def _non_postmortem_unclosed(path, summary) -> list:
             continue
         out.append(rec)
     return out
+
+
+def _interference_max_gap(dalle, params, prefill_chunk) -> float:
+    """One request in steady decode, then a full-length prompt arrives
+    mid-stream. -> the max gap (s) between decode iterations over the
+    window from the late submit to the late request's first token: a
+    monolithic prefill is one gap holding the whole prefill, chunked
+    prefill bounds each gap by a chunk plus a decode step. Decode
+    iterations are read off the ``serve.decode_steps`` counter."""
+    import numpy as np
+
+    from dalle_pytorch_tpu.serving import (
+        Engine, EngineConfig, Outcome, Request, check_accounting,
+    )
+    from dalle_pytorch_tpu.utils.metrics import counters
+
+    engine = Engine(dalle, params, EngineConfig(
+        max_batch=2, prefill_chunk=prefill_chunk,
+    ))
+    n_text, n_image = dalle.text_seq_len, dalle.image_seq_len
+    # compile time is not interference: both slots warm outside the window
+    for i in range(2):
+        engine.submit(Request(
+            request_id=f"__warm{i}__", prompt=np.zeros(n_text, np.int32),
+            max_new_tokens=min(4, n_image), seed=0,
+        ))
+    engine.run()
+    prompts = np.random.RandomState(0).randint(
+        1, dalle.num_text_tokens, size=(2, n_text)
+    ).astype(np.int32)
+    engine.submit(Request(
+        request_id="steady", prompt=prompts[0],
+        max_new_tokens=min(6, n_image), seed=1,
+    ))
+    prev = counters.get("serve.decode_steps")
+    while counters.get("serve.decode_steps") - prev < 3:
+        engine.step()  # the steady request is admitted and decoding
+    t_sub = engine.clock.now()
+    engine.submit(Request(
+        request_id="late", prompt=prompts[1],
+        max_new_tokens=min(2, n_image), seed=2,
+    ))
+    ts = []
+    prev = counters.get("serve.decode_steps")
+    while engine.step():
+        cur = counters.get("serve.decode_steps")
+        if cur > prev:
+            ts.append(engine.clock.now())
+            prev = cur
+    check_accounting(engine)
+    for rid in ("steady", "late"):
+        assert engine.results[rid].outcome is Outcome.COMPLETED, (
+            rid, engine.results[rid]
+        )
+    window_end = t_sub + engine.results["late"].ttft_s
+    window = [t_sub] + [t for t in ts if t < window_end] + [window_end]
+    return float(np.max(np.diff(window)))
 
 
 def main(argv=None) -> int:
@@ -230,15 +288,14 @@ def main(argv=None) -> int:
         check(bool(name), f"unparseable exposition line: {line!r}")
 
     # -- 4. interference scenario with the recorder on --------------------
-    import bench
-
-    interference = bench.bench_serve_interference(
-        on_cpu=True, quick=True, model=serve_smoke.build_tiny_model(),
-    )
+    dalle, params = serve_smoke.build_tiny_model()
+    gaps = {
+        chunk: _interference_max_gap(dalle, params, chunk)
+        for chunk in (None, 2)
+    }
     check(
-        interference["value"] > 0
-        and interference["monolithic_max_gap_ms"] > 0,
-        f"interference gap metric not finite: {interference}",
+        all(math.isfinite(g) and g > 0 for g in gaps.values()),
+        f"interference gap metric not finite: {gaps}",
     )
     ipath = TELEMETRY.drain("interference")
     check(ipath is not None, "interference drain produced no flight file")
@@ -255,7 +312,6 @@ def main(argv=None) -> int:
         EngineConfig, Outcome, Request, Router, RouterConfig,
     )
 
-    dalle, params = serve_smoke.build_tiny_model()
     router = Router(
         dalle, params, RouterConfig(n_replicas=2),
         EngineConfig(max_batch=2, prefill_chunk=2),
@@ -349,9 +405,8 @@ def main(argv=None) -> int:
         "by_name": summary["by_name"],
         "prefill_chunk_spans": n_chunk_spans,
         "spec_verify_spans": n_spec_spans,
-        "interference_max_gap_ms": interference["value"],
-        "interference_monolithic_max_gap_ms":
-            interference["monolithic_max_gap_ms"],
+        "interference_max_gap_ms": round(gaps[2] * 1e3, 1),
+        "interference_monolithic_max_gap_ms": round(gaps[None] * 1e3, 1),
         "router_request_spans": router_spans,
         "control_decision_events": decision_events,
     }))

@@ -14,10 +14,9 @@ cross-validate each other:
   built from the SAME scheduler primitives the real engine uses
   (``Scheduler``/``PagePool``/``TokenBudget``/``pages_for``), replacing
   only the device work with a per-iteration cost distribution
-  set to the order of the last chip record (a round 1.0 ms/token; the
-  pre-PR-1 docs/BENCH_LATEST.jsonl has 0.901 ms/token bf16 batch-1
-  decode on v5e) — a modelled constant, not a measurement of the
-  engine. This is what reaches 100k+ requests in seconds.
+  set to a round 1.0 ms/token (a pre-ledger note has 0.901 ms/token
+  bf16 batch-1 decode on v5e) — a modelled constant, not a measurement
+  of the engine. This is what reaches 100k+ requests in seconds.
 * **fidelity lane** — the real tiny-model engine fleet on a
   ``FakeClock``, thousands of requests, asserting the modeled lane's
   predicted shed fraction / p99 TTFT / occupancy trajectory within the
@@ -102,9 +101,9 @@ _RETRIABLE = (RejectReason.QUEUE_FULL, RejectReason.NO_REPLICA)
 @dataclass(frozen=True)
 class IterationCostModel:
     """Virtual cost of one engine scheduling iteration in the modeled
-    lane. Defaults are round numbers of the order of the last chip
-    record: decode 1.0 ms/token bf16 (docs/BENCH_LATEST.jsonl, which
-    predates the engine, has 0.901 at batch 1 on v5e), prefill
+    lane. Defaults are round numbers: decode 1.0 ms/token bf16 (a
+    pre-ledger note that predates the engine has 0.901 at batch 1 on
+    v5e; PERF_LEDGER.jsonl has no serving line yet), prefill
     amortized well under decode
     (compute-bound batch processing of the whole chunk — the 0.9
     ms/token batch-1 decode figure in DESIGN §6 bounds it above), plus
@@ -1547,9 +1546,7 @@ def run_fidelity(n_requests: int = 600, seed: int = 0,
 
 
 def _mode_record(mode: str, seed: int) -> dict:
-    """BENCH-style record skeleton (tools/bench.py convention: one
-    self-describing JSON object per run, committed next to the code it
-    measures)."""
+    """Record skeleton: one self-describing JSON object per run."""
     return {
         "tool": "traffic_sim",
         "schema": 1,
@@ -1559,7 +1556,7 @@ def _mode_record(mode: str, seed: int) -> dict:
             "decode_ms_per_token": IterationCostModel.decode_ms_per_token,
             "prefill_ms_per_token": IterationCostModel.prefill_ms_per_token,
             "fixed_overhead_ms": IterationCostModel.fixed_overhead_ms,
-            "source": "modelled constant, order of docs/BENCH_LATEST.jsonl "
+            "source": "modelled constant, order of a pre-ledger note "
                       "(0.901 ms/token bf16 batch-1 decode, v5e, pre-engine)",
         },
     }

@@ -16,7 +16,6 @@ Differences from the reference, by design:
 """
 
 import argparse
-import math
 import sys
 import time
 from pathlib import Path
@@ -214,6 +213,7 @@ def main():
         vae_from_checkpoint,
     )
     from dalle_pytorch_tpu.parallel import (
+        TrainLoop,
         create_train_state,
         init_distributed,
         make_runtime,
@@ -524,26 +524,28 @@ def main():
         # gather is a collective — every process participates; only the
         # root writes the file
         with TELEMETRY.span("train.ckpt_save", kind="full", epoch=epoch):
-            host_params = runtime.to_host(state.params)
-            host_opt = runtime.to_host(state.opt_state)
+            host_params = runtime.to_host(loop.state.params)
+            host_opt = runtime.to_host(loop.state.opt_state)
             if not runtime.is_root_worker():
                 return
             save_dalle_checkpoint(
                 ckpt_path, dalle, host_params, vae, vae_params,
                 extra={"epoch": epoch, "scheduler_state": sched.state_dict()},
-                opt_state=host_opt, step=int(state.step),
+                opt_state=host_opt, step=int(loop.state.step),
             )
 
-    def save_sharded(step, epoch, it, emergency=False):
+    def save_sharded(epoch, it, emergency=False):
         # step-granular, verified (manifest + commit marker): the resume
         # probe above restores exactly this. Collective — every host writes
-        # its addressable shards.
+        # its addressable shards. int(state.step) = dispatched attempts:
+        # resume numbers its next step correctly
+        step = int(loop.state.step)
         with TELEMETRY.span(
             "train.ckpt_save", kind="sharded", step=step,
             emergency=emergency,
         ):
             save_sharded_checkpoint(
-                sharded_dir, step, state,
+                sharded_dir, step, loop.state,
                 meta={
                     "epoch": epoch, "iter": it,
                     "scheduler_state": sched.state_dict(),
@@ -552,166 +554,66 @@ def main():
                 keep_n=args.keep_n_checkpoints,
             )
 
-    # pre-flight save: fail early when misconfigured (train_dalle.py:561-563)
-    save(start_epoch - 1)
-
     throughput = Throughput(window=10)
-    prev_loss = None
-    step_span = None  # open train.step telemetry span (dispatch -> verdict)
     capture = StepCapture(
         args.profile_trace_dir if runtime.is_root_worker() else None,
         args.profile_step,
     )
-    # applied_steps keys the step rng by BATCH, not by dispatch attempt: a
-    # batch retried after a NaN skip reuses its key, so a recovered run's
-    # update sequence matches an unfaulted run's exactly
-    applied_steps = global_step - int(state.skipped)
-    nan_run = 0
-    last_fed = None  # (i, batch) of the most recent dispatch, for retry
-    retry_batch = None
 
-    def process_verdict():
-        # Read the most recent dispatched step's loss. This DOES wait for
-        # that step to finish — the price of the retry-on-skip contract
-        # (the next batch choice depends on this outcome); the loop
-        # overlaps what it can by prefetching the next batch before
-        # calling this. Called at the loop head AND before every
-        # checkpoint save, so saved scheduler state and consumed-batch
-        # metadata always reflect the in-flight step's outcome. The loss
-        # is NaN for ANY device-rejected step (parallel/step.py), grads
-        # included.
-        nonlocal prev_loss, nan_run, applied_steps, lr, retry_batch, step_span
-        if prev_loss is None:
-            return
-        loss_val = float(prev_loss)
-        # the train.step span runs dispatch -> verdict, so its duration is
-        # the REAL step latency (device included), not just host dispatch
-        TELEMETRY.end(step_span, loss=loss_val,
-                      finite=math.isfinite(loss_val))
-        step_span = None
-        if math.isfinite(loss_val):
-            nan_run = 0
-            applied_steps += 1
-            lr = sched.step(loss_val)
-        else:
-            # the device already rejected the update (parallel/step.py
-            # nan_guard); retry the batch — a transient NaN costs one
-            # step, a persistent one trips the consecutive-skip abort.
-            # The device-side counter is the source of truth: it includes
-            # skips from before a resume.
-            nan_run = int(state.consec_skipped)
-            counters.inc("train.nan_skips")
-            TELEMETRY.event(
-                "train.nan_skip", step=global_step - 1,
-                consec=nan_run,
-            )
+    def feed(batch):
+        image_tokens = vae_encode(batch["image"])
+        train_batch = {
+            "text": jnp.asarray(batch["text"]),
+            "image": image_tokens,
+        }
+        # a steady-state window of three steps; the loop's lexical
+        # TELEMETRY spans land in the same capture (utils/profiling.py)
+        if capture.at_step(loop.global_step, loop.state.params):
             logger.log_text(
-                f"step {global_step - 1}: non-finite loss — "
-                f"update skipped on device, retrying batch "
-                f"({nan_run}/{args.nan_abort_after})"
+                f"profiler trace for steps "
+                f"{args.profile_step}..{args.profile_step + 2} "
+                f"written to {args.profile_trace_dir}"
             )
-            if nan_run >= args.nan_abort_after:
-                # drain BEFORE the emergency save: the NaN-abort
-                # postmortem must reach disk even if the save hangs
-                TELEMETRY.event("train.nan_abort", step=global_step - 1,
-                                consec=nan_run)
-                TELEMETRY.drain("nan_abort")
-                # the rejected batch's update is NOT in state: record
-                # its predecessor so a later resume replays it
-                save_sharded(int(state.step), epoch,
-                             last_fed[0] - 1, emergency=True)
-                logger.finish()
-                raise SystemExit(
-                    f"{nan_run} consecutive non-finite steps — "
-                    "aborting (state saved for post-mortem at "
-                    f"{sharded_dir})"
-                )
-            retry_batch = last_fed
-        prev_loss = None
+        return train_batch
+
+    def on_nan_abort(it):
+        # the rejected batch's update is NOT in state: ``it`` is its
+        # predecessor, so a later resume replays it
+        save_sharded(epoch, it, emergency=True)
+        logger.finish()
+        return f"state saved for post-mortem at {sharded_dir}"
+
+    # the dispatch/verdict/retry policy (parallel/loop.py); sched.step sees
+    # the loss of every applied step
+    loop = TrainLoop(
+        step_fn, state, feed=feed, lr=lr, on_applied=sched.step,
+        nan_abort_after=args.nan_abort_after, log=logger.log_text,
+        on_abort=on_nan_abort, global_step=global_step,
+        resume=(resume_epoch, resume_iter),
+    )
+    del state  # donated by the first step: loop.state is the live one
+
+    # pre-flight save: fail early when misconfigured (train_dalle.py:561-563)
+    save(start_epoch - 1)
 
     def on_preempt_signal(signum):
         # flight recorder to disk INSIDE the signal handler: even if the
         # in-flight step or the emergency save below hangs, the run's last
         # seconds are already on disk (fail-open; utils/telemetry.py)
         TELEMETRY.event("train.preempt_signal", signum=signum,
-                        step=global_step)
+                        step=loop.global_step)
         TELEMETRY.drain("preempt_signal")
 
     with PreemptionHandler(on_signal=on_preempt_signal) as preempt:
         for epoch in range(start_epoch, args.epochs):
             if hasattr(loader, "epoch"):
                 loader.epoch = epoch  # keep shuffle order aligned on resume
-            retry_batch = None
-            nxt = None
-            exhausted = False
-            batches = enumerate(loader)
-            while True:
-                # prefetch the next candidate BEFORE blocking on the
-                # in-flight step's verdict, so host-side batch dequeue
-                # overlaps the device finishing the step. The verdict read
-                # itself is a genuine sync point: the retry-on-skip
-                # contract (bit-identical recovery) needs step N's outcome
-                # before choosing step N+1's input, so the dispatch
-                # pipeline is one deep by design — only batch prep
-                # overlaps. (Exhaustion doesn't end the epoch yet: the
-                # final dispatch's verdict may still demand a retry.)
-                if nxt is None and not exhausted:
-                    # host-side stall waiting on the data path — the
-                    # data-wait vs step split the percentile histograms
-                    # decompose (docs/DESIGN.md §9)
-                    with TELEMETRY.span("train.data_wait", epoch=epoch):
-                        while nxt is None and not exhausted:
-                            try:
-                                cand = next(batches)
-                            except StopIteration:
-                                exhausted = True
-                                break
-                            if epoch == resume_epoch and cand[0] <= resume_iter:
-                                continue  # consumed before the preemption
-                            nxt = cand
-
-                process_verdict()
-
-                if retry_batch is not None:
-                    i, batch = retry_batch
-                    retry_batch = None  # a prefetched nxt stays stashed
-                elif nxt is not None:
-                    i, batch = nxt
-                    nxt = None
-                else:
-                    break
-                last_fed = (i, batch)
-
-                # train.step spans dispatch (incl. the VAE encode feeding
-                # it) through the step's VERDICT — closed in
-                # process_verdict, so its histogram is true step latency
-                step_span = TELEMETRY.begin(
-                    "train.step", step=global_step, epoch=epoch,
-                )
-                image_tokens = vae_encode(batch["image"])
-                train_batch = {
-                    "text": jnp.asarray(batch["text"]),
-                    "image": image_tokens,
-                }
-                # a steady-state window of three steps; the loop's lexical
-                # TELEMETRY spans land in the same capture (utils/profiling.py)
-                if capture.at_step(global_step, state.params):
-                    logger.log_text(
-                        f"profiler trace for steps "
-                        f"{args.profile_step}..{args.profile_step + 2} "
-                        f"written to {args.profile_trace_dir}"
-                    )
-
-                state, loss = step_fn(
-                    state, train_batch, jax.random.key(applied_steps),
-                    jnp.asarray(lr),
-                )
-                prev_loss = loss
-
+            for i, train_batch, loss in loop.epoch(epoch, loader):
+                global_step = loop.global_step
                 if global_step % 10 == 0:
                     logger.log(
                         {"loss": float(loss), "epoch": epoch, "iter": i,
-                         "lr": lr, "nan_skips": counters.get("train.nan_skips")},
+                         "lr": loop.lr, "nan_skips": counters.get("train.nan_skips")},
                         step=global_step,
                     )
                 if global_step % 100 == 0:
@@ -725,22 +627,18 @@ def main():
                 if global_step > 0 and global_step % args.save_every_n_steps == 0:
                     # resolve the in-flight step first: the saved scheduler
                     # state must include its loss, and a device-rejected
-                    # batch (retry_batch set) is absent from the saved
-                    # state, so resume must replay it
-                    process_verdict()
+                    # batch is absent from the saved state, so resume must
+                    # replay it
+                    it = loop.resolve()
                     save(epoch)
                     if args.sharded_ckpt:
-                        # int(state.step) = dispatched attempts: resume
-                        # numbers its next step correctly (global_step here
-                        # is pre-increment)
-                        it = i - 1 if retry_batch is not None else i
-                        save_sharded(int(state.step), epoch, it)
+                        save_sharded(epoch, it)
 
                 if global_step > 0 and global_step % args.sample_every_n_steps == 0:
                     # sampling over sharded params is collective: all
                     # processes run it; only the root writes the image
                     images = generate_images(
-                        dalle, state.params, vae, {"params": vae_params},
+                        dalle, loop.state.params, vae, {"params": vae_params},
                         train_batch["text"][:1], jax.random.key(global_step),
                     )
                     if runtime.is_root_worker():
@@ -755,23 +653,18 @@ def main():
                         Image.fromarray(arr).save(out / f"sample_{global_step:07d}.png")
                         logger.log_images("samples", pix, step=global_step)
 
-                global_step += 1
-
                 if preempt.triggered:
-                    # SIGTERM/SIGINT (pod preemption): the in-flight step
-                    # finished above — write the emergency step-granular
-                    # checkpoint and exit cleanly; the next launch resumes
-                    # from it via the startup probe
-                    capture.close()
-                    # as with periodic saves: resolve the in-flight step's
-                    # verdict so scheduler state is complete and a
+                    # SIGTERM/SIGINT (pod preemption): write the emergency
+                    # step-granular checkpoint and exit cleanly; the next
+                    # launch resumes from it via the startup probe. As with
+                    # periodic saves, the in-flight step's verdict is
+                    # resolved first, so scheduler state is complete and a
                     # just-rejected batch is recorded as unconsumed (the
                     # relaunch must replay it)
-                    process_verdict()
-                    it = i - 1 if retry_batch is not None else i
-                    save_sharded(int(state.step), epoch, it, emergency=True)
+                    capture.close()
+                    save_sharded(epoch, loop.resolve(), emergency=True)
                     logger.log_text(
-                        f"emergency checkpoint at step {global_step} "
+                        f"emergency checkpoint at step {global_step + 1} "
                         f"(epoch {epoch}, iter {i}) written to {sharded_dir}; "
                         "exiting"
                     )
@@ -781,13 +674,13 @@ def main():
             save(epoch)
             if args.sharded_ckpt:
                 # epoch fully consumed: a resume starts at the NEXT epoch
-                save_sharded(int(state.step), epoch + 1, -1)
+                save_sharded(epoch + 1, -1)
             # per-epoch model artifact (reference train_dalle.py:637-649);
             # the logger is already root-gated via enabled=
             logger.log_artifact("trained-dalle", ckpt_path, metadata=vars(args))
             logger.log_text(f"epoch {epoch} complete")
 
-    capture.close(state.params)  # training ended inside the trace window
+    capture.close(loop.state.params)  # training ended inside the trace window
 
     logger.finish()
 

@@ -4,13 +4,13 @@
 Trains ``models/lm.py:CausalLM`` — a stack of Mamba-2 state-space and
 grouped-KV attention layers described by a ``config.json`` in its source's own
 keys (``--config``) — on a folder of ``.txt`` documents packed end to end,
-with the app surface of train_clip.py and the loop of train_dalle.py: compiled
-sharded train step over a dp x fsdp x tp mesh (``make_runtime`` →
-``create_train_state`` → ``make_train_step``), one dispatch in flight with the
-step's verdict read before the next (a device-rejected non-finite step is
-retried, ``--nan_abort_after`` consecutive ones abort), ``train.*`` telemetry
-spans, checkpoint/resume carrying all hparams and the Adam moments, pre-flight
-save.
+with the app surface of train_clip.py and train_dalle.py's loop
+(``parallel/loop.py``): compiled sharded train step over a dp x fsdp x tp mesh
+(``make_runtime`` → ``create_train_state`` → ``make_train_step``), one
+dispatch in flight with the step's verdict read before the next (a
+device-rejected non-finite step is retried, ``--nan_abort_after`` consecutive
+ones abort), ``train.*`` telemetry spans, checkpoint/resume carrying all
+hparams and the Adam moments, pre-flight save.
 
 ``build_model`` and ``build_step`` are the step's whole construction; the
 benchmark's driver (benchmarks/drivers/train_lm.py) calls the same two.
@@ -18,7 +18,6 @@ benchmark's driver (benchmarks/drivers/train_lm.py) calls the same two.
 
 import argparse
 import json
-import math
 
 import jax
 import jax.numpy as jnp
@@ -111,8 +110,8 @@ def main():
         restore_opt_state,
         save_lm_checkpoint,
     )
-    from dalle_pytorch_tpu.parallel import init_distributed, make_runtime, shard_pytree
-    from dalle_pytorch_tpu.utils import TELEMETRY, MetricsLogger, Throughput, counters
+    from dalle_pytorch_tpu.parallel import TrainLoop, init_distributed, make_runtime, shard_pytree
+    from dalle_pytorch_tpu.utils import TELEMETRY, MetricsLogger, Throughput
 
     init_distributed()
     runtime = make_runtime(fsdp=args.fsdp, tp=args.tp)
@@ -177,80 +176,37 @@ def main():
 
     def save(epoch):
         with TELEMETRY.span("train.ckpt_save", kind="full", epoch=epoch):
-            host_params = runtime.to_host(state.params)
-            host_opt = runtime.to_host(state.opt_state)
+            host_params = runtime.to_host(loop.state.params)
+            host_opt = runtime.to_host(loop.state.opt_state)
             if runtime.is_root_worker():
                 save_lm_checkpoint(
                     ckpt_path, lm, host_params, extra={"epoch": epoch}, opt_state=host_opt,
                 )
 
+    # one dispatch in flight, a device-rejected batch retried under its own
+    # rng key, the abort after --nan_abort_after in a row (parallel/loop.py)
+    loop = TrainLoop(
+        step_fn, state, feed=lambda batch: {"ids": jnp.asarray(batch["ids"])},
+        lr=args.learning_rate, nan_abort_after=args.nan_abort_after,
+        log=logger.log_text, on_abort=lambda _: logger.finish(),
+    )
+    del state  # donated by the first step: loop.state is the live one
+
     save(start_epoch - 1)  # pre-flight: fail fast on misconfiguration
 
     throughput = Throughput(window=10)
-    lr = jnp.asarray(args.learning_rate)
-    global_step = 0
-    # keys the step rng by BATCH, not by dispatch attempt (train_dalle.py)
-    applied_steps = 0
-    prev_loss = step_span = last_fed = retry_batch = None
-
-    def process_verdict():
-        """Read the in-flight step's loss (a sync point: the next batch
-        depends on it). NaN means the device rejected the update
-        (parallel/step.py nan_guard): the batch is retried."""
-        nonlocal prev_loss, step_span, applied_steps, retry_batch
-        if prev_loss is None:
-            return
-        loss_val = float(prev_loss)
-        TELEMETRY.end(step_span, loss=loss_val, finite=math.isfinite(loss_val))
-        prev_loss = step_span = None
-        if math.isfinite(loss_val):
-            applied_steps += 1
-            return
-        nan_run = int(state.consec_skipped)
-        counters.inc("train.nan_skips")
-        TELEMETRY.event("train.nan_skip", step=global_step - 1, consec=nan_run)
-        logger.log_text(
-            f"step {global_step - 1}: non-finite loss — update skipped on "
-            f"device, retrying batch ({nan_run}/{args.nan_abort_after})"
-        )
-        if nan_run >= args.nan_abort_after:
-            TELEMETRY.event("train.nan_abort", step=global_step - 1, consec=nan_run)
-            TELEMETRY.drain("nan_abort")
-            logger.finish()
-            raise SystemExit(f"{nan_run} consecutive non-finite steps — aborting")
-        retry_batch = last_fed
-
     for epoch in range(start_epoch, args.epochs):
-        batches, nxt, exhausted = iter(loader), None, False
-        while True:
-            # the next batch is fetched BEFORE blocking on the verdict; a
-            # retried batch goes first and the fetched one stays stashed
-            if nxt is None and not exhausted:
-                with TELEMETRY.span("train.data_wait", epoch=epoch):
-                    nxt = next(batches, None)
-                exhausted = nxt is None
-            process_verdict()
-            if retry_batch is not None:
-                batch, retry_batch = retry_batch, None
-            elif nxt is not None:
-                batch, nxt = nxt, None
-            else:
-                break
-            last_fed = batch
-            step_span = TELEMETRY.begin("train.step", step=global_step, epoch=epoch)
-            state, prev_loss = step_fn(
-                state, {"ids": jnp.asarray(batch["ids"])}, jax.random.key(applied_steps), lr,
-            )
+        for _, _, loss in loop.epoch(epoch, loader):
+            global_step = loop.global_step
             if global_step % 10 == 0:
-                logger.log({"loss": float(prev_loss), "epoch": epoch}, step=global_step)
-                logger.log_text(f"step {global_step}: loss={float(prev_loss):.4f} epoch={epoch}")
+                logger.log({"loss": float(loss), "epoch": epoch}, step=global_step)
+                logger.log_text(f"step {global_step}: loss={float(loss):.4f} epoch={epoch}")
             rate = throughput.update(args.batch_size * lm.seq_len)
             if rate is not None:
                 logger.log({"tokens_per_sec": rate}, step=global_step)
             if global_step % args.save_every_n_steps == args.save_every_n_steps - 1:
-                process_verdict()
+                loop.resolve()  # the save holds the in-flight step's outcome
                 save(epoch)
-            global_step += 1
         save(epoch)
         logger.log_text(f"epoch {epoch} complete")
 
