@@ -30,6 +30,18 @@ autodiff, the decay matrices recomputed in it (``jax.checkpoint``) a block of
 heads at a time so that they fit beside a full chip. Between chunks both forms
 call ``log_decay`` and ``carried_states`` of this module by name, in XLA.
 
+The convolution in front of the scan has two forms as well, chosen from the
+shape (``ssm_conv_kernel_eligible``; no switch) and recorded at the route
+site ``forward/ssm_conv``. Where every piece of ``x | B | C`` is whole lane
+tiles and the rows are whole blocks, the convolution, its bias and the
+``silu`` behind it are one Pallas kernel pair behind one ``jax.custom_vjp``:
+``ssm_conv_fwd`` reads the compute dtype once and writes the three pieces,
+``ssm_conv_bwd`` rebuilds the pre-activation from the saved input and
+writes the input's cotangent and the taps' and the bias's gradients in one
+pass; float32 exists only in registers. Every other shape keeps
+``causal_conv1d`` + ``silu`` in XLA (the oracle the kernels are tested
+against).
+
 One group of ``B``/``C`` shared by all heads (``mamba_n_groups`` 1) is the
 only layout written here.
 """
@@ -41,6 +53,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import flax.linen as nn
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -335,6 +348,27 @@ def _ssd_chunk_bwd_kernel(
         db_ref[0] = _mxu(total, Cm, (0, 0)).astype(db_ref.dtype)
 
 
+def _mosaic_call(kernel, grid, in_specs, out_specs, out_shape, scratch, operands, interpret, *, name):
+    """One ``pallas_call`` of this module: every grid axis parallel but the
+    innermost, along which a kernel sums (the scan: over the head blocks of
+    one chunk) or hands rows on (the convolution: from row block to row
+    block) in VMEM."""
+    return pl.pallas_call(
+        kernel,
+        name=name,
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * (len(grid) - 1) + ("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
+        interpret=interpret,
+    )(*operands)
+
+
 class _Blocks:
     """The grid (batch row, chunk, block of heads) and the block of every
     kind of operand in it."""
@@ -366,21 +400,10 @@ class _Blocks:
         return t.transpose(0, 1, 3, 2, 4).reshape(b, n, h)
 
     def call(self, kernel, in_specs, out_specs, out_shape, scratch, operands, interpret, *, name):
-        return pl.pallas_call(
-            functools.partial(kernel, p=self.dims[3]),
-            name=name,
-            grid=self.grid,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            out_shape=out_shape,
-            scratch_shapes=scratch,
-            compiler_params=pltpu.CompilerParams(
-                # the head blocks of one chunk share what is summed over heads
-                dimension_semantics=("parallel", "parallel", "arbitrary"),
-                vmem_limit_bytes=VMEM_LIMIT_BYTES,
-            ),
-            interpret=interpret,
-        )(*operands)
+        return _mosaic_call(
+            functools.partial(kernel, p=self.dims[3]), self.grid, in_specs, out_specs,
+            out_shape, scratch, operands, interpret, name=name,
+        )
 
 
 # Each call is a ``jax.jit`` of its own: a stack of nine mixers, each run
@@ -577,6 +600,260 @@ def ssd_scan(x, dt, A, B, C, D, chunk: int, dtype: Dtype = jnp.float32) -> jnp.n
     return y + x[:, :n].astype(jnp.float32) * D.astype(jnp.float32)[:, None]
 
 
+# ---- the convolution and its silu as Pallas kernels -----------------------
+#
+# One pass over HBM each way in the compute dtype; the float32 of the taps'
+# sum, of the silu and of its derivative exists only in vector registers. A
+# grid step takes a block of rows at the full width and writes the pieces the
+# mixer splits the channels into (``x | B | C``) as outputs of their own, so
+# that no split follows the forward kernel and no concatenation precedes the
+# backward one. Inside a step the block is computed a STRIP at a time, a few
+# registers an array (computed whole, every one of the ~20 operations an
+# element would pass the block through VMEM). Row blocks run in sequence:
+# what a block needs of its neighbour, the ``width - 1`` rows before it (after
+# it, for a cotangent), is handed over in VMEM scratch.
+
+CONV_ROWS = 256          # rows a grid step takes
+STRIP = (64, 256)        # rows, lanes computed at once
+EDGE = 16                # rows read before a strip: one bf16 tile
+AFTER = 8                # rows of cotangent kept from the strip after: one float32 tile
+
+
+def ssm_conv_kernel_eligible(n: int, sizes: tuple, width: int) -> bool:
+    """The shapes the convolution's kernels are written for: every piece of
+    the channels in whole lane tiles, rows in whole blocks, and no more taps
+    than a strip keeps rows of its neighbour for."""
+    return (
+        all(size > 0 and size % LANES == 0 for size in sizes)
+        and n > 0 and n % CONV_ROWS == 0 and 1 <= width <= AFTER
+    )
+
+
+def _for_each_strip(sizes, body):
+    """``body(piece, lanes, lo, at)`` for every column strip: whole lane
+    tiles inside ONE piece, ``lo`` and ``at`` the strip's first lane in its
+    piece and in all channels. Equal strips side by side run in a loop, not
+    unrolled: a kernel's text is loaded beside the weights once a call site
+    (27 a step), and unrolled it cost 3.8 MB of the chip's memory."""
+    lanes, base = STRIP[1], 0
+    for piece, size in enumerate(sizes):
+        whole, rest = divmod(size, lanes)
+
+        def one(j, carry, piece=piece, base=base):
+            lo = pl.multiple_of(j * lanes, LANES)
+            body(piece, lanes, lo, base + lo)
+            return carry
+
+        if whole > 1:
+            jax.lax.fori_loop(0, whole, one, 0)
+        elif whole:
+            body(piece, lanes, 0, base)
+        if rest:
+            body(piece, rest, whole * lanes, base + whole * lanes)
+        base += size
+
+
+def _taps_sum(ext, taps, bias):
+    """A strip's pre-activation ``sum_k taps[k] x_{t-K+1+k} + bias``, float32,
+    and the moved copies of ``x`` it is made of. ``ext``: the strip's rows
+    behind the ``EDGE`` rows before it, float32."""
+    width, rows = taps.shape[0], ext.shape[0] - EDGE
+    first = EDGE - width + 1
+    moved = [ext[first + k : first + k + rows] for k in range(width)]
+    pre = bias + sum(taps[k : k + 1] * moved[k] for k in range(width))
+    return pre, moved
+
+
+def _ssm_conv_fwd_kernel(x_ref, taps_ref, bias_ref, *refs, sizes):
+    *y_refs, before_ref = refs
+    rows, strip_rows = x_ref.shape[1], STRIP[0]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        before_ref[...] = jnp.zeros_like(before_ref)      # zeros before the sequence
+
+    def columns(piece, lanes, lo, at):
+        cols, y_ref = pl.ds(at, lanes), y_refs[piece]
+        taps, bias = taps_ref[0, :, cols], bias_ref[0, :, cols]
+
+        def strip(r0, ext):
+            pre, _ = _taps_sum(ext.astype(jnp.float32), taps, bias)
+            y = (pre * jax.nn.sigmoid(pre)).astype(y_ref.dtype)
+            y_ref[0, pl.ds(r0, strip_rows), pl.ds(lo, lanes)] = y
+
+        def later(i, carry):
+            r0 = pl.multiple_of(i * strip_rows, strip_rows)
+            strip(r0, x_ref[0, pl.ds(r0 - EDGE, EDGE + strip_rows), cols])
+            return carry
+
+        strip(0, jnp.concatenate([before_ref[:, cols], x_ref[0, :strip_rows, cols]], axis=0))
+        jax.lax.fori_loop(1, rows // strip_rows, later, 0)
+
+    _for_each_strip(sizes, columns)
+    before_ref[...] = x_ref[0, rows - EDGE :, :]
+
+
+def _fold(t):
+    """(rows, lanes) summed over its row TILES: (8, lanes), no reduction
+    across sublanes."""
+    return t.reshape(-1, 8, t.shape[-1]).sum(axis=0)
+
+
+def _ssm_conv_bwd_kernel(x_ref, before_ref, taps_ref, bias_ref, *refs, sizes):
+    """Row blocks, and the strips inside one, run from the LAST to the first:
+    the cotangent of a strip's input needs the pre-activation's cotangent of
+    the rows after it, carried from strip to strip in registers and from
+    block to block in ``after_ref``. The rows of ``x`` before the block are a
+    second small block of the same operand. The taps' and the bias's
+    gradients are summed over the row blocks in their output blocks."""
+    dy_refs, (dx_ref, dtaps_ref, dbias_ref, after_ref) = refs[: len(sizes)], refs[len(sizes) :]
+    step, last = pl.program_id(1), pl.num_programs(1) - 1
+    rows, strip_rows, width = x_ref.shape[1], STRIP[0], taps_ref.shape[1]
+
+    @pl.when(step == 0)
+    def _():
+        after_ref[...] = jnp.zeros_like(after_ref)        # nothing after the sequence
+        dtaps_ref[...] = jnp.zeros_like(dtaps_ref)
+        dbias_ref[...] = jnp.zeros_like(dbias_ref)
+
+    def columns(piece, lanes, lo, at):
+        cols, dy_ref = pl.ds(at, lanes), dy_refs[piece]
+        taps, bias = taps_ref[0, :, cols], bias_ref[0, :, cols]
+
+        def strip(r0, ext, carry):
+            after, sums = carry
+            pre, moved = _taps_sum(ext.astype(jnp.float32), taps, bias)
+            gate = jax.nn.sigmoid(pre)
+            dy = dy_ref[0, pl.ds(r0, strip_rows), pl.ds(lo, lanes)].astype(jnp.float32)
+            dpre = dy * gate * (1.0 + pre * (1.0 - gate))
+            dext = jnp.concatenate([dpre, after], axis=0)
+            dx = sum(
+                taps[k : k + 1] * dext[width - 1 - k : width - 1 - k + strip_rows]
+                for k in range(width)
+            )
+            dx_ref[0, pl.ds(r0, strip_rows), cols] = dx.astype(dx_ref.dtype)
+            sums = tuple(s + _fold(dpre * m) for s, m in zip(sums, moved)) + (
+                sums[-1] + _fold(dpre),
+            )
+            return dpre[:AFTER], sums
+
+        def later(i, carry):
+            r0 = pl.multiple_of((rows // strip_rows - 1 - i) * strip_rows, strip_rows)
+            return strip(r0, x_ref[0, pl.ds(r0 - EDGE, EDGE + strip_rows), cols], carry)
+
+        zeros = jnp.zeros((8, lanes), jnp.float32)
+        carry = jax.lax.fori_loop(
+            0, rows // strip_rows - 1, later, (after_ref[:, cols], (zeros,) * (width + 1))
+        )
+        before = before_ref[0, :, cols]
+        before = jnp.where(step == last, jnp.zeros_like(before), before)  # before the sequence
+        after, sums = strip(
+            0, jnp.concatenate([before, x_ref[0, :strip_rows, cols]], axis=0), carry
+        )
+        after_ref[:, cols] = after
+        for k in range(width):
+            dtaps_ref[0, k : k + 1, cols] += jnp.sum(sums[k], axis=0, keepdims=True)
+        dbias_ref[0, :, cols] += jnp.sum(sums[width], axis=0, keepdims=True)
+
+    _for_each_strip(sizes, columns)
+
+
+class _ConvBlocks:
+    """The grid (batch row, block of rows) and the blocks of the
+    convolution's operands in it, forward (rows ascending) and backward
+    (descending)."""
+
+    def __init__(self, x, taps, sizes):
+        b, n, c = x.shape
+        width = taps.shape[1]
+        assert c == sum(sizes) and ssm_conv_kernel_eligible(n, sizes, width), (x.shape, sizes)
+        self.grid = (b, n // CONV_ROWS)
+        last, per_block = self.grid[1] - 1, CONV_ROWS // EDGE
+        rows = lambda size: pl.BlockSpec((1, CONV_ROWS, size), lambda bi, ri: (bi, ri, 0))
+        back = lambda size: pl.BlockSpec((1, CONV_ROWS, size), lambda bi, ri: (bi, last - ri, 0))
+        self.rows, self.pieces = rows(c), [rows(size) for size in sizes]
+        self.rows_back, self.pieces_back = back(c), [back(size) for size in sizes]
+        # the tile of rows that ends where the block starts (for the first
+        # block its own first tile, which the kernel replaces with zeros)
+        self.before_back = pl.BlockSpec(
+            (1, EDGE, c), lambda bi, ri: (bi, jnp.maximum((last - ri) * per_block - 1, 0), 0)
+        )
+        self.taps = pl.BlockSpec((1, width, c), lambda bi, ri: (bi, 0, 0))
+        self.bias = pl.BlockSpec((1, 1, c), lambda bi, ri: (bi, 0, 0))
+
+
+_conv_kernel_call = functools.partial(jax.jit, static_argnames=("sizes", "interpret"))
+
+
+@_conv_kernel_call
+def _conv_call(x, taps, bias, *, sizes, interpret):
+    k = _ConvBlocks(x, taps, sizes)
+    b, n, c = x.shape
+    return tuple(_mosaic_call(
+        functools.partial(_ssm_conv_fwd_kernel, sizes=sizes), k.grid,
+        [k.rows, k.taps, k.bias], k.pieces,
+        [jax.ShapeDtypeStruct((b, n, size), x.dtype) for size in sizes],
+        [pltpu.VMEM((EDGE, c), x.dtype)], [x, taps, bias], interpret, name="ssm_conv_fwd",
+    ))
+
+
+@_conv_kernel_call
+def _conv_bwd_call(x, taps, bias, dys, *, sizes, interpret):
+    k = _ConvBlocks(x, taps, sizes)
+    return tuple(_mosaic_call(
+        functools.partial(_ssm_conv_bwd_kernel, sizes=sizes), k.grid,
+        [k.rows_back, k.before_back, k.taps, k.bias, *k.pieces_back],
+        [k.rows_back, k.taps, k.bias],
+        [jax.ShapeDtypeStruct(t.shape, t.dtype) for t in (x, taps, bias)],
+        [pltpu.VMEM((AFTER, x.shape[-1]), jnp.float32)],
+        [x, x, taps, bias, *dys], interpret, name="ssm_conv_bwd",
+    ))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def ssm_conv(x, taps, bias, sizes, interpret):
+    """``silu(causal_conv1d(x))`` in the dtype of ``x``: (b, n, c), split
+    along the channels into pieces of ``sizes``. ``taps``: (b, width, c)
+    float32 and ``bias``: (b, 1, c) float32, a copy a batch row (every operand
+    is split by rows under a mesh). The backward pass keeps only these
+    three."""
+    return _conv_call(x, taps, bias, sizes=sizes, interpret=interpret)
+
+
+def _conv_fwd_rule(x, taps, bias, sizes, interpret):
+    return ssm_conv(x, taps, bias, sizes, interpret), (x, taps, bias)
+
+
+def _conv_bwd_rule(sizes, interpret, res, dys):
+    return _conv_bwd_call(*res, dys, sizes=sizes, interpret=interpret)
+
+
+ssm_conv.defvjp(_conv_fwd_rule, _conv_bwd_rule)
+
+
+def conv_silu(x, kernel, bias, sizes: tuple, dtype: Dtype = jnp.float32) -> tuple:
+    """``silu(causal_conv1d(x, kernel, bias))`` in ``dtype``, accumulated and
+    activated in float32 and split along the channels into pieces of
+    ``sizes``: the kernel pair where the shape is eligible
+    (``ssm_conv_kernel_eligible``), XLA everywhere else; which of the two, at
+    the route site ``forward/ssm_conv``."""
+    from .attention import _per_device  # the one shard_map rule of every Mosaic call
+
+    b, n, c = x.shape
+    width, sizes = kernel.shape[0], tuple(sizes)
+    if not ssm_conv_kernel_eligible(n, sizes, width):
+        kv_policy.record_route("forward/ssm_conv", "xla")
+        y = jax.nn.silu(causal_conv1d(x, kernel, bias)).astype(dtype)
+        return tuple(jnp.split(y, np.cumsum(sizes[:-1]), axis=-1))
+    interpret = kv_policy.pallas_interpret()
+    kv_policy.record_route("forward/ssm_conv", "ssm_conv", interpret)
+    return _per_device(
+        lambda *operands: ssm_conv(*operands, sizes, interpret),
+        (x.astype(dtype), jnp.broadcast_to(kernel.astype(jnp.float32), (b, width, c)),
+         jnp.broadcast_to(bias.astype(jnp.float32), (b, 1, c))),
+    )
+
+
 def inverse_softplus(x):
     return x + jnp.log(-jnp.expm1(-x))
 
@@ -588,19 +865,20 @@ def _log_uniform(lo: float, hi: float):
 
 
 class CausalConv1D(nn.Module):
-    """``causal_conv1d`` with its (width, channels) kernel and its bias."""
+    """``conv_silu`` with its (width, channels) kernel and its bias."""
 
     width: int
+    dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, sizes):
         c = x.shape[-1]
         kernel = self.param(
             "kernel", nn.initializers.lecun_normal(), (self.width, c), self.param_dtype
         )
         bias = self.param("bias", nn.initializers.zeros, (c,), self.param_dtype)
-        return causal_conv1d(x, kernel, bias)
+        return conv_silu(x, kernel, bias, sizes, self.dtype)
 
 
 class MambaMixer(nn.Module):
@@ -645,9 +923,8 @@ class MambaMixer(nn.Module):
         D = self.param("D", nn.initializers.ones, (h,), self.param_dtype)
 
         with jax.named_scope("ssm.conv"):
-            conv = CausalConv1D(self.d_conv, self.param_dtype, name="conv")
-            xbc = jax.nn.silu(conv(xbc)).astype(self.dtype)
-        x, B, C = jnp.split(xbc, (inner, inner + s), axis=-1)
+            conv = CausalConv1D(self.d_conv, self.dtype, self.param_dtype, name="conv")
+            x, B, C = conv(xbc, (inner, s, s))
         with jax.named_scope("ssm.scan"):
             step = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias.astype(jnp.float32))
             y = ssd_scan(

@@ -333,6 +333,8 @@ KERNEL_NAMES = frozenset({
     "ssd_state_bwd",
     "ssd_chunk_fwd",        #   each chunk's output, decay matrices in VMEM
     "ssd_chunk_bwd",
+    "ssm_conv_fwd",         #   the causal convolution, its bias and its silu
+    "ssm_conv_bwd",
 })
 
 # span durations are auto-observed as "<span>_s" (utils/telemetry.py);

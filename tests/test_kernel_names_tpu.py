@@ -102,7 +102,23 @@ def _ssd(x, dt, cum, Bm, Cm, starts):
     )
 
 
+def _conv(xbc, taps, bias):
+    # the call of ``ssm.conv_silu``, compiled not interpreted, under ``remat``
+    # as the cell's blocks run it
+    pieces = jax.checkpoint(
+        lambda *operands: ssm.ssm_conv(*operands, (SSM_H * SSM_P, SSM_STATE, SSM_STATE), False)
+    )(xbc, taps, bias)
+    return jnp.concatenate(pieces, axis=-1)
+
+
+SSM_CONV = SSM_H * SSM_P + 2 * SSM_STATE
+
 ROUTES = {
+    "ssm_conv": (
+        _conv,
+        [(1, SSM_N, SSM_CONV), ((1, 4, SSM_CONV), F32), ((1, 1, SSM_CONV), F32)],
+        {"ssm_conv_fwd", "ssm_conv_bwd"},
+    ),
     "ssd_scan": (
         _ssd,
         [(1, SSM_N, SSM_H * SSM_P), ((1, SSM_N, SSM_H), F32), ((1, SSM_N, SSM_H), F32),

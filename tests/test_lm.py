@@ -205,8 +205,10 @@ def test_half_of_the_tokens_left_out_of_the_loss_fails(reference_three_steps):
 
 
 def test_the_cells_mixers_take_the_kernels_and_the_tiny_ones_the_einsums():
-    """The route of the state-space scan is read from the shape alone
-    (``ssd_kernels_eligible``) and recorded at ``forward/ssd``: the
+    """The routes of the state-space scan and of the convolution in front of
+    it are read from the shape alone (``ssd_kernels_eligible``,
+    ``ssm_conv_kernel_eligible``) and recorded at ``forward/ssd`` and
+    ``forward/ssm_conv``: the
     benchmark's configuration at its published widths is the shape the
     kernels are written for; this file's is not."""
     from dalle_pytorch_tpu.ops import kv_policy
@@ -214,13 +216,19 @@ def test_the_cells_mixers_take_the_kernels_and_the_tiny_ones_the_einsums():
     def routes(lm, n):
         kv_policy.ROUTE_LOG.clear()
         jax.eval_shape(lm.init, jax.random.key(0), jnp.zeros((1, n), jnp.int32))
-        return [r for r in kv_policy.ROUTE_LOG if r["site"] == "forward/ssd"]
+        return [r for r in kv_policy.ROUTE_LOG if r["site"] in ("forward/ssd", "forward/ssm_conv")]
 
     cell = json.loads((ROOT / "benchmarks/configs/granite-4.0-h-micro-d10.json").read_text())
     lm = CausalLM.from_config({**cell, "num_hidden_layers": 2}, seq_len=512, dtype=jnp.bfloat16)
-    assert routes(lm, 512) == [{"site": "forward/ssd", "impl": "ssd_chunk", "interpret": True}]
+    assert routes(lm, 512) == [
+        {"site": "forward/ssm_conv", "impl": "ssm_conv", "interpret": True},
+        {"site": "forward/ssd", "impl": "ssd_chunk", "interpret": True},
+    ]
     tiny, _, _ = model_and_params()
-    assert routes(tiny, N) == [{"site": "forward/ssd", "impl": "einsum", "interpret": None}]
+    assert routes(tiny, N) == [
+        {"site": "forward/ssm_conv", "impl": "xla", "interpret": None},
+        {"site": "forward/ssd", "impl": "einsum", "interpret": None},
+    ]
 
 
 # ------------------------------------------------------------------ sharding

@@ -149,13 +149,16 @@ def test_state_space_kernels_lower_for_tpu_under_a_mesh(compiled_branch, mesh):
         params = jax.eval_shape(mixer.init, jax.random.key(0), v)
         traced = jax.jit(jax.value_and_grad(loss)).trace(params, v)
         text = traced.lower(lowering_platforms=("tpu",)).as_text()
-    # state and chunk, forward and backward
-    assert text.count("tpu_custom_call") >= 4
+    # the scan's state and chunk and the convolution, forward and backward
+    assert text.count("tpu_custom_call") >= 6
 
 
-def test_state_space_kernels_under_a_mesh_match_single_device():
+@pytest.mark.parametrize("n,conv", [(200, "xla"), (256, "ssm_conv")])
+def test_state_space_kernels_under_a_mesh_match_single_device(n, conv):
+    """A ragged length (the scan pads it, the convolution keeps XLA's form)
+    and a whole block of rows (the convolution's kernels too)."""
     mixer, loss = _mixer_loss(jnp.float32)
-    v = jax.random.normal(jax.random.key(0), (4, 200, 128))
+    v = jax.random.normal(jax.random.key(0), (4, n, 128))
     params = mixer.init(jax.random.key(1), v)
     want, want_g = jax.value_and_grad(loss)(params, v)
     rt = make_runtime(devices=jax.devices()[:4], fsdp=2, tp=2)
@@ -163,6 +166,7 @@ def test_state_space_kernels_under_a_mesh_match_single_device():
     with rt.activate():
         got, got_g = jax.jit(jax.value_and_grad(loss))(params, v)
     assert {"site": "forward/ssd", "impl": "ssd_chunk", "interpret": True} in kv_policy.ROUTE_LOG
+    assert [r["impl"] for r in kv_policy.ROUTE_LOG if r["site"] == "forward/ssm_conv"] == [conv]
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for a, b in zip(jax.tree_util.tree_leaves(got_g), jax.tree_util.tree_leaves(want_g)):
         np.testing.assert_allclose(a, b, atol=2e-4 * float(jnp.max(jnp.abs(b))) + 1e-6, rtol=2e-4)

@@ -3,8 +3,10 @@ the same recurrence (step by step; the quadratic form), forward and
 gradients, at lengths that are and are not multiples of the chunk; the Pallas
 kernels of the scan, interpreted on the CPU at small eligible shapes, against
 the einsum form and the recurrence; the causal depthwise convolution and the
-gated norm against plain transcriptions; and planted faults that must FAIL
-those comparisons, through the einsum form and through the kernels."""
+gated norm against plain transcriptions; the convolution's kernel pair,
+interpreted, against that convolution and ``silu`` in XLA; and planted faults
+that must FAIL those comparisons, through the einsum form and through the
+kernels."""
 
 import jax
 import jax.numpy as jnp
@@ -248,6 +250,167 @@ def test_causal_depthwise_convolution():
     # causal: a later input moves no earlier output
     moved = ssm.causal_conv1d(x.at[:, 7].add(1.0), k, b)
     np.testing.assert_array_equal(moved[:, :7], ssm.causal_conv1d(x, k, b)[:, :7])
+
+
+# ---- the convolution's kernel pair, interpreted, against the XLA form
+
+
+def xla_form(x, k, b, sizes, dtype=jnp.float32):
+    """``causal_conv1d`` + ``silu`` + the split: what ``conv_silu`` computes
+    at every shape its predicate refuses, and the oracle of its kernels."""
+    y = jax.nn.silu(ssm.causal_conv1d(x, k, b)).astype(dtype)
+    return tuple(jnp.split(y, np.cumsum(sizes[:-1]), axis=-1))
+
+
+def conv_inputs(b, n, sizes, width, seed=0, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.key(seed), 4)
+    c = sum(sizes)
+    x = jax.random.normal(ks[0], (b, n, c)).astype(dtype)
+    k = jax.random.normal(ks[1], (width, c)) * 0.5
+    bias = jax.random.normal(ks[2], (c,))
+    cotangent = jax.random.normal(ks[3], (b, n, c))
+    return (x, k, bias), cotangent
+
+
+def conv_value_and_grads(f, args, cotangent, sizes, dtype=jnp.float32):
+    """The pieces side by side in float32, and the gradients of input, taps
+    and bias under ``cotangent``."""
+    def weighted(*a):
+        whole = jnp.concatenate(f(*a, sizes, dtype), axis=-1).astype(jnp.float32)
+        return jnp.sum(whole * cotangent), whole
+
+    (_, whole), grads = jax.value_and_grad(weighted, argnums=(0, 1, 2), has_aux=True)(*args)
+    return whole, grads
+
+
+def assert_follows_the_xla_form(args, cotangent, sizes):
+    y, grads = conv_value_and_grads(ssm.conv_silu, args, cotangent, sizes)
+    want, want_grads = conv_value_and_grads(xla_form, args, cotangent, sizes)
+    np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-5)
+    for name, a, b in zip(["x", "taps", "bias"], grads, want_grads):
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=2e-6 * scale, err_msg=name)
+
+
+R = ssm.CONV_ROWS
+CONV_SHAPES = {
+    # (batch, rows, pieces, taps): three row blocks, two equal column strips in
+    # a loop and two single ones, two batch rows
+    "rows3_strips4_b2": (2, 3 * R, (2 * ssm.STRIP[1], 128, 128), 4),
+    # the sequence starts AND ends inside the first block: nothing is handed over
+    "one_block": (1, R, (128,), 4),
+    # a piece of one whole strip and a narrower rest; as many taps as the
+    # strip after keeps rows for
+    "rest_strip_taps8": (1, 2 * R, (ssm.STRIP[1] + 128, 256), ssm.AFTER),
+    "taps2": (2, 2 * R, (128, 128), 2),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(CONV_SHAPES))
+def test_the_convolutions_kernels_follow_the_xla_form_in_float32(shape):
+    b, n, sizes, width = CONV_SHAPES[shape]
+    assert ssm.ssm_conv_kernel_eligible(n, sizes, width)
+    kv_policy.ROUTE_LOG.clear()
+    args, cotangent = conv_inputs(b, n, sizes, width)
+    assert_follows_the_xla_form(args, cotangent, sizes)
+    assert kv_policy.ROUTE_LOG == [
+        {"site": "forward/ssm_conv", "impl": "ssm_conv", "interpret": True}
+    ]
+    pieces = ssm.conv_silu(*args, sizes)
+    assert [p.shape for p in pieces] == [(b, n, size) for size in sizes]
+
+
+def test_the_convolutions_kernels_in_bfloat16_stay_in_the_xla_forms_band():
+    """bf16 in and out, float32 between: the output and each gradient within
+    twice the XLA form's own distance from float32 (or 1e-3: the form and the
+    kernels round the same float32 numbers, so most of them agree to the
+    bit)."""
+    b, n, sizes, width = CONV_SHAPES["rows3_strips4_b2"]
+    args, cotangent = conv_inputs(b, n, sizes, width, seed=1)
+    exact = conv_value_and_grads(xla_form, args, cotangent, sizes)
+    half = (args[0].astype(jnp.bfloat16),) + args[1:]
+    kernels = conv_value_and_grads(ssm.conv_silu, half, cotangent, sizes, jnp.bfloat16)
+    form = conv_value_and_grads(xla_form, half, cotangent, sizes, jnp.bfloat16)
+    assert kernels[1][0].dtype == jnp.bfloat16 and kernels[1][1].dtype == jnp.float32
+    off = lambda a, f: float(
+        jnp.linalg.norm(a.astype(jnp.float32) - f) / jnp.linalg.norm(f)
+    )
+    names = ["y", "x", "taps", "bias"]
+    for name, k, e, f in zip(names, (kernels[0], *kernels[1]), (form[0], *form[1]),
+                             (exact[0], *exact[1])):
+        assert 0 < off(e, f) < 0.01, (name, off(e, f))
+        assert off(k, f) < max(2 * off(e, f), 1e-3), (name, off(k, f), off(e, f))
+
+
+@pytest.mark.parametrize("way", ["forward", "backward"])
+def test_the_convolutions_kernels_are_causal_both_ways(way):
+    """A later input moves no earlier output; an earlier cotangent moves no
+    later input gradient. Moved at a block's last row, so that the rows
+    handed to the next block are the ones that carry it."""
+    b, n, sizes, width = CONV_SHAPES["rows3_strips4_b2"]
+    args, cotangent = conv_inputs(b, n, sizes, width, seed=2)
+    at = 2 * R - 1
+    if way == "forward":
+        y, _ = conv_value_and_grads(ssm.conv_silu, args, cotangent, sizes)
+        moved_args = (args[0].at[:, at].add(1.0),) + args[1:]
+        moved, _ = conv_value_and_grads(ssm.conv_silu, moved_args, cotangent, sizes)
+        np.testing.assert_array_equal(moved[:, :at], y[:, :at])
+        reach = np.abs(np.asarray(moved - y)).max(axis=(0, 2))
+        assert np.all(reach[at : at + width] > 0) and not reach[at + width :].any()
+    else:
+        _, (dx, _, _) = conv_value_and_grads(ssm.conv_silu, args, cotangent, sizes)
+        _, (moved, _, _) = conv_value_and_grads(
+            ssm.conv_silu, args, cotangent.at[:, at].add(1.0), sizes
+        )
+        np.testing.assert_array_equal(moved[:, at + 1 :], dx[:, at + 1 :])
+        reach = np.abs(np.asarray(moved - dx)).max(axis=(0, 2))
+        assert np.all(reach[at - width + 1 : at + 1] > 0) and not reach[: at - width + 1].any()
+
+
+def test_the_convolutions_form_is_read_from_the_shape():
+    inner, state = 64 * 64, 128
+    assert ssm.ssm_conv_kernel_eligible(8192, (inner, state, state), 4)     # the cell's mixer
+    assert not ssm.ssm_conv_kernel_eligible(8192, (inner, state, 64), 4)    # a piece off the lane tile
+    assert not ssm.ssm_conv_kernel_eligible(8192 - 5, (inner, state, state), 4)  # a ragged length
+    assert not ssm.ssm_conv_kernel_eligible(8192, (inner, state, state), ssm.AFTER + 1)
+    for n, sizes in [(R, (128, 64)), (R - 5, (128, 128)), (20, (5,))]:
+        kv_policy.ROUTE_LOG.clear()
+        args, cotangent = conv_inputs(1, n, sizes, 4)
+        got = conv_value_and_grads(ssm.conv_silu, args, cotangent, sizes)
+        want = conv_value_and_grads(xla_form, args, cotangent, sizes)
+        assert kv_policy.ROUTE_LOG == [{"site": "forward/ssm_conv", "impl": "xla", "interpret": None}]
+        for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+            np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("way", ["forward", "backward"])
+def test_leaving_out_the_rows_handed_between_blocks_fails(way, monkeypatch):
+    """The planted fault: every block starts as the first does, from zeros
+    (forward: the input's rows before it; backward: the cotangent's rows after
+    it). The comparison every shape above passes must not."""
+    name = "_ssm_conv_fwd_kernel" if way == "forward" else "_ssm_conv_bwd_kernel"
+    real = getattr(ssm, name)
+
+    def forgetful(*refs, **static):
+        refs[-1][...] = jnp.zeros_like(refs[-1])     # the scratch that carries the rows
+        real(*refs, **static)
+
+    # two blocks of a shape no other test uses: the calls are jitted by shape
+    sizes = (384,)
+    args, cotangent = conv_inputs(1, 2 * R, sizes, 4, seed=3)
+    assert_follows_the_xla_form(args, cotangent, sizes)
+    calls = (ssm._conv_call, ssm._conv_bwd_call)
+    try:
+        monkeypatch.setattr(ssm, name, forgetful)
+        for call in calls:
+            call.clear_cache()
+        with pytest.raises(AssertionError, match="Mismatched elements"):
+            assert_follows_the_xla_form(args, cotangent, sizes)
+    finally:
+        monkeypatch.undo()
+        for call in calls:
+            call.clear_cache()
+    assert_follows_the_xla_form(args, cotangent, sizes)
 
 
 def test_gated_rms_norm_is_over_all_inner_channels():
