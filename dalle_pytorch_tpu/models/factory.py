@@ -91,7 +91,7 @@ def _restore_dtypes(cfg: dict) -> dict:
     for k in ("dtype", "param_dtype"):
         if isinstance(cfg.get(k), str):
             cfg[k] = _DTYPES[cfg[k]]
-    for k in ("attn_types", "layer_types"):
+    for k in ("attn_types", "layer_types", "ff_types", "experts_held"):
         if isinstance(cfg.get(k), list):
             cfg[k] = tuple(cfg[k])
     if "normalization" in cfg and isinstance(cfg["normalization"], list):

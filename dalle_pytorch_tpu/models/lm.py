@@ -2,33 +2,48 @@
 
 ``CausalLM`` is what a text-only decoder needs around the trunk: a token
 embedding with its multiplier, the trunk with the block variants of
-``models/transformer.py`` (per-layer mixers from ``layer_types``, RMSNorm
-with a fixed residual multiplier, SwiGLU), a final RMSNorm, and a head TIED
-to the embedding with its logit scale. ``from_config`` reads the keys a
-published ``config.json`` of the ``granitemoehybrid`` family uses
-(``hidden_size``, ``layer_types``, ``mamba_*``, ``*_multiplier``, …; the first
-``num_hidden_layers`` entries of ``layer_types`` run), so a
-configuration file is the source's own keys and nothing is renamed.
+``models/transformer.py`` (per-layer mixers from ``layer_types``, per-layer
+feed-forwards from ``ff_types``, RMSNorm with a fixed residual multiplier,
+SwiGLU), a final RMSNorm, and a head with its logit scale, TIED to the
+embedding or a matrix of its own; with ``mtp_lambda`` a multi-token-prediction
+module and its second loss.
+
+``from_config`` reads a published ``config.json`` in its source's own keys,
+nothing renamed, by ``model_type``: ``granitemoehybrid`` (``hidden_size``,
+``layer_types``, ``mamba_*``, ``*_multiplier``, …; the first
+``num_hidden_layers`` entries of ``layer_types`` run) and ``joyai_llm_flash``
+(the DeepSeek-V3 family's keys: ``q_lora_rank``, ``kv_lora_rank``,
+``qk_*_head_dim``, ``n_routed_experts``, ``first_k_dense_replace``,
+``num_nextn_predict_layers``, …). What a key asks that is not written here is
+refused, not ignored.
 
 Training and whole-sequence evaluation only; serving a stack with
-recurrent-state layers is ROADMAP R13.
+recurrent-state layers is ROADMAP R13, one with latent attention R11, and a
+step that commits the MTP module's second token R12.
 """
 
 from __future__ import annotations
 
-from typing import Any, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import flax.linen as nn
+from flax import traverse_util
 
 from ..ops.layers import RMSNorm
+from ..ops.moe import balanced_bias
 from .transformer import Transformer
 
 Dtype = Any
 
 # rows of the sequence whose logits are live at once in the loss
 HEAD_BLOCK_ROWS = 2048
+
+
+def next_ids(ids):
+    """Token i + 1 at position i (the last position wraps)."""
+    return jnp.roll(ids, -1, axis=1)
 
 
 class CausalLM(nn.Module):
@@ -51,6 +66,22 @@ class CausalLM(nn.Module):
     ssm_state: int = 128
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    ff_types: Optional[Tuple[str, ...]] = None
+    mla_q_rank: int = 1536
+    mla_kv_rank: int = 512
+    mla_nope_dim: int = 128
+    mla_rope_dim: int = 64
+    mla_v_dim: int = 128
+    mla_rope_theta: float = 10000.0
+    experts_total: int = 0
+    experts_held: Optional[Tuple[int, int]] = None
+    experts_per_token: int = 0
+    experts_hidden: int = 0
+    experts_shared: int = 1
+    experts_scaling: float = 1.0
+    bias_update_speed: float = 0.0
+    tie_head: bool = True
+    mtp_lambda: Optional[float] = None
     remat: bool = False
     use_flash: bool = True
     dtype: Dtype = jnp.float32
@@ -60,32 +91,11 @@ class CausalLM(nn.Module):
     def from_config(cls, cfg: dict, seq_len: int, **overrides) -> "CausalLM":
         """``cfg``: the source's ``config.json`` keys. What this module cannot
         run is refused here, not ignored."""
-        unsupported = {
-            "num_local_experts": 0, "num_experts_per_tok": 0, "mamba_n_groups": 1,
-            "attention_bias": False, "mamba_proj_bias": False, "mamba_conv_bias": True,
-            "position_embedding_type": "nope", "normalization_function": "rmsnorm",
-            "hidden_act": "silu", "tie_word_embeddings": True,
-        }
-        for key, only in unsupported.items():
-            if cfg.get(key, only) != only:
-                raise ValueError(f"{key}={cfg[key]!r}: only {only!r} is written here")
-        d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-        if cfg["mamba_expand"] * d != cfg["mamba_n_heads"] * cfg["mamba_d_head"]:
-            raise ValueError("mamba_expand * hidden_size != mamba_n_heads * mamba_d_head")
-        fields = dict(
-            vocab_size=cfg["vocab_size"], dim=d, depth=cfg["num_hidden_layers"],
-            seq_len=seq_len, layer_types=tuple(cfg["layer_types"][: cfg["num_hidden_layers"]]),
-            heads=h, kv_heads=cfg["num_key_value_heads"], dim_head=d // h,
-            ff_hidden=cfg["shared_intermediate_size"],
-            attn_scale=cfg["attention_multiplier"],
-            embedding_multiplier=cfg["embedding_multiplier"],
-            residual_multiplier=cfg["residual_multiplier"],
-            logits_scaling=cfg["logits_scaling"], norm_eps=cfg["rms_norm_eps"],
-            ssm_heads=cfg["mamba_n_heads"], ssm_head_dim=cfg["mamba_d_head"],
-            ssm_state=cfg["mamba_d_state"], ssm_conv=cfg["mamba_d_conv"],
-            ssm_chunk=cfg["mamba_chunk_size"],
-        )
-        fields.update(overrides)
+        family = cfg.get("model_type", "granitemoehybrid")
+        if family not in _FAMILIES:
+            raise ValueError(f"model_type={family!r}: only {sorted(_FAMILIES)} are written here")
+        fields = _FAMILIES[family](cfg)
+        fields.update(seq_len=seq_len, **overrides)
         return cls(**fields)
 
     def setup(self):
@@ -100,32 +110,79 @@ class CausalLM(nn.Module):
             ff_hidden=self.ff_hidden, ssm_heads=self.ssm_heads,
             ssm_head_dim=self.ssm_head_dim, ssm_state=self.ssm_state,
             ssm_conv=self.ssm_conv, ssm_chunk=self.ssm_chunk,
+            ff_types=self.ff_types, **self._block_sizes(),
             dtype=self.dtype, param_dtype=self.param_dtype,
         )
         self.final_norm = RMSNorm(self.norm_eps, self.param_dtype)
+        if not self.tie_head:
+            # (vocab, dim), as the tied table is
+            self.lm_head = self.param(
+                "lm_head", nn.initializers.normal(self.dim**-0.5),
+                (self.vocab_size, self.dim), self.param_dtype,
+            )
+        if self.mtp_lambda is not None:
+            # ``nextn`` and not ``mtp``: a module's name is a component of every scope
+            # path beneath it, and ``mtp`` is the device scope of the module's OWN part
+            self.nextn = MultiTokenPrediction(
+                dim=self.dim, seq_len=self.seq_len, heads=self.heads, ff_hidden=self.ff_hidden,
+                norm_eps=self.norm_eps, residual_multiplier=self.residual_multiplier,
+                remat=self.remat, use_flash=self.use_flash, block_sizes=self._block_sizes(),
+                dtype=self.dtype, param_dtype=self.param_dtype,
+            )
+
+    def _block_sizes(self) -> Dict[str, Any]:
+        """The latent-attention and expert sizes the trunk and the MTP
+        module's one block share."""
+        names = (
+            "mla_q_rank", "mla_kv_rank", "mla_nope_dim", "mla_rope_dim", "mla_v_dim",
+            "mla_rope_theta", "experts_total", "experts_held", "experts_per_token",
+            "experts_hidden", "experts_shared", "experts_scaling",
+        )
+        return {name: getattr(self, name) for name in names}
 
     def __call__(self, ids: jnp.ndarray, return_loss: bool = False):
         """ids: (b, n) token ids. Returns the logits (b, n, vocab) in float32
         or, with ``return_loss``, the mean cross-entropy of every position's
-        next token (positions 0 … n-2 predict ids 1 … n-1)."""
+        next token (positions 0 … n-2 predict ids 1 … n-1), plus
+        ``mtp_lambda`` times the MTP module's (positions 0 … n-3 predict ids
+        2 … n-1) where there is one."""
         with jax.named_scope("embed"):
             table = self.tok_emb.embedding
             x = (jnp.take(table, ids, axis=0) * self.embedding_multiplier).astype(self.dtype)
         out = self.transformer(x)
         with jax.named_scope("head_loss"):
             normed = self.final_norm(out).astype(self.dtype)
-            head = jnp.asarray(table, self.dtype)
-            if not return_loss:
-                return self._logits(normed, head)
-            rows, labels = normed[:, :-1], ids[:, 1:]
-            total = jnp.zeros((), jnp.float32)
-            # a block of rows at a time, its logits recomputed in backward:
-            # (n, vocab) float32 is never whole
-            block_nll = jax.checkpoint(self._block_nll)
-            for lo in range(0, rows.shape[1], HEAD_BLOCK_ROWS):
-                sl = slice(lo, lo + HEAD_BLOCK_ROWS)
-                total = total + block_nll(rows[:, sl], head, labels[:, sl])
-            return total / labels.size
+            head = jnp.asarray(table if self.tie_head else self.lm_head, self.dtype)
+            result = (
+                self._nll(normed[:, :-1], head, ids[:, 1:]) if return_loss
+                else self._logits(normed, head)
+            )
+        if self.mtp_lambda is None or not (return_loss or self.is_initializing()):
+            return result
+        # outside ``head_loss``: the module's block has scopes of its own
+        deeper = self._mtp_rows(out, table, ids)
+        if not return_loss:
+            return result                  # ``init`` made the module's parameters too
+        with jax.named_scope("head_loss"):
+            return result + self.mtp_lambda * self._nll(deeper[:, :-2], head, ids[:, 2:])
+
+    def _mtp_rows(self, out, table, ids):
+        """Position i: the trunk's output there and the embedding of token
+        i + 1, for token i + 2; the last position wraps and is not scored."""
+        with jax.named_scope("mtp"):
+            emb = jnp.take(table, next_ids(ids), axis=0) * self.embedding_multiplier
+        return self.nextn(out, emb.astype(self.dtype))
+
+    def _nll(self, rows, head, labels):
+        """Mean cross-entropy of ``labels`` under ``rows``' logits, a block of
+        rows at a time, its logits recomputed in backward: (n, vocab) float32
+        is never whole."""
+        total = jnp.zeros((), jnp.float32)
+        block_nll = jax.checkpoint(self._block_nll)
+        for lo in range(0, rows.shape[1], HEAD_BLOCK_ROWS):
+            sl = slice(lo, lo + HEAD_BLOCK_ROWS)
+            total = total + block_nll(rows[:, sl], head, labels[:, sl])
+        return total / labels.size
 
     def _logits(self, normed, head):
         logits = jnp.einsum("bnd,vd->bnv", normed, head, preferred_element_type=jnp.float32)
@@ -136,3 +193,169 @@ class CausalLM(nn.Module):
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
         return jnp.sum(lse - picked)
+
+    def loss_and_loads(self, params, ids: jnp.ndarray):
+        """(the loss, what every expert layer sowed: the pairs it sent each of
+        ALL experts): a train step's ``loss_fn`` beside ``balance``."""
+        loss, sown = self.apply(
+            {"params": params}, ids, return_loss=True, mutable=["moe_stats"]
+        )
+        return loss, sown["moe_stats"]
+
+    def balance(self, params, loads):
+        """A train step's ``after_update`` (``parallel/step.py``): every
+        expert layer's (the MTP module's included) ``tokens_per_expert`` takes
+        the pairs the step sent each expert, and its selection bias moves by
+        ``bias_update_speed`` against them (``ops/moe.py:balanced_bias``)."""
+        flat = traverse_util.flatten_dict(params)
+        for path, (load,) in traverse_util.flatten_dict(loads).items():
+            layer = path[:-1]
+            bias = flat[layer + ("e_score_correction_bias",)]
+            flat[layer + ("e_score_correction_bias",)] = balanced_bias(
+                bias, load, self.bias_update_speed
+            )
+            flat[layer + ("tokens_per_expert",)] = load.astype(bias.dtype)
+        return traverse_util.unflatten_dict(flat)
+
+    def routing_stats(self, params) -> Dict[str, jnp.ndarray]:
+        """What the last step sent the expert layers, read from their
+        ``tokens_per_expert``: ``moe.pairs_here``, the (token, expert) pairs
+        routed to experts held here, summed over the layers, and
+        ``moe.load_max_over_mean``, the fullest expert's pairs over the mean
+        expert's, the worst layer's, over ALL experts. {} for a model without
+        expert layers."""
+        flat = traverse_util.flatten_dict(params)
+        loads = [flat[path] for path in sorted(flat) if path[-1] == "tokens_per_expert"]
+        if not loads:
+            return {}
+        loads = jnp.stack(loads).astype(jnp.float32)                # (layers, experts)
+        lo, hi = self.experts_held or (0, self.experts_total)
+        return {
+            "moe.pairs_here": jnp.sum(loads[:, lo:hi]).astype(jnp.int32),
+            "moe.load_max_over_mean": jnp.max(
+                jnp.max(loads, axis=1) / jnp.maximum(jnp.mean(loads, axis=1), 1.0)
+            ),
+        }
+
+
+class MultiTokenPrediction(nn.Module):
+    """One multi-token-prediction module (DeepSeek-V3 report, section 2.2):
+
+        h'_i = W_eh [RMSNorm(x_i) ; RMSNorm(E[t_{i+1}])]     (2 dim -> dim)
+
+    then one more block of the expert kind over ``h'`` and a final RMSNorm of
+    its own; the caller applies the model's own embedding and head. The
+    projection, the norms and the shifted embedding run under the device scope
+    ``mtp``; the block under ``attn.mla`` and ``moe`` like any other."""
+
+    dim: int
+    seq_len: int
+    heads: int
+    ff_hidden: int
+    norm_eps: float
+    residual_multiplier: float
+    block_sizes: Dict[str, Any]
+    remat: bool = False
+    use_flash: bool = True
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, hidden, shifted_emb):
+        with jax.named_scope("mtp"):
+            norm = lambda name, t: RMSNorm(self.norm_eps, self.param_dtype, name=name)(t)
+            both = jnp.concatenate(
+                (norm("hnorm", hidden), norm("enorm", shifted_emb)), axis=-1
+            ).astype(self.dtype)
+            x = nn.Dense(
+                self.dim, use_bias=False, dtype=self.dtype, param_dtype=self.param_dtype,
+                name="eh_proj",
+            )(both)
+        x = Transformer(
+            dim=self.dim, depth=1, seq_len=self.seq_len, causal=True, heads=self.heads,
+            rotary_emb=False, remat=self.remat, use_flash=self.use_flash,
+            layer_types=("mla",), ff_types=("experts",), norm="rmsnorm",
+            norm_eps=self.norm_eps, residual_multiplier=self.residual_multiplier,
+            ff_act="swiglu", ff_hidden=self.ff_hidden, **self.block_sizes,
+            dtype=self.dtype, param_dtype=self.param_dtype, name="block",
+        )(x)
+        with jax.named_scope("mtp"):
+            return norm("final_norm", x).astype(self.dtype)
+
+
+# ------------------------------------------------------------- the families
+
+
+def _refuse(cfg: dict, only: dict) -> None:
+    for key, value in only.items():
+        if cfg.get(key, value) != value:
+            raise ValueError(f"{key}={cfg[key]!r}: only {value!r} is written here")
+
+
+def _granite_fields(cfg: dict) -> dict:
+    _refuse(cfg, {
+        "num_local_experts": 0, "num_experts_per_tok": 0, "mamba_n_groups": 1,
+        "attention_bias": False, "mamba_proj_bias": False, "mamba_conv_bias": True,
+        "position_embedding_type": "nope", "normalization_function": "rmsnorm",
+        "hidden_act": "silu", "tie_word_embeddings": True,
+    })
+    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
+    if cfg["mamba_expand"] * d != cfg["mamba_n_heads"] * cfg["mamba_d_head"]:
+        raise ValueError("mamba_expand * hidden_size != mamba_n_heads * mamba_d_head")
+    return dict(
+        vocab_size=cfg["vocab_size"], dim=d, depth=cfg["num_hidden_layers"],
+        layer_types=tuple(cfg["layer_types"][: cfg["num_hidden_layers"]]),
+        heads=h, kv_heads=cfg["num_key_value_heads"], dim_head=d // h,
+        ff_hidden=cfg["shared_intermediate_size"],
+        attn_scale=cfg["attention_multiplier"],
+        embedding_multiplier=cfg["embedding_multiplier"],
+        residual_multiplier=cfg["residual_multiplier"],
+        logits_scaling=cfg["logits_scaling"], norm_eps=cfg["rms_norm_eps"],
+        ssm_heads=cfg["mamba_n_heads"], ssm_head_dim=cfg["mamba_d_head"],
+        ssm_state=cfg["mamba_d_state"], ssm_conv=cfg["mamba_d_conv"],
+        ssm_chunk=cfg["mamba_chunk_size"],
+    )
+
+
+def _joyai_fields(cfg: dict) -> dict:
+    """``joyai_llm_flash``: the DeepSeek-V3 family's keys, and three the
+    source does not have, each with a default: ``experts_held`` (``{"range":
+    [lo, hi], "of": total}``: this program is one chip's share of an
+    expert-parallel deployment, ``n_routed_experts`` then counts the experts
+    HELD, ``hi - lo``, and the router scores ``total``; default: all are held),
+    ``mtp_loss_weight`` (0.3) and ``bias_update_speed`` (0.001), the
+    DeepSeek-V3 report's values for most of its pre-training."""
+    _refuse(cfg, {
+        "rope_scaling": None, "n_group": 1, "topk_group": 1, "attention_bias": False,
+        "moe_layer_freq": 1, "scoring_func": "sigmoid", "topk_method": "noaux_tc",
+        "norm_topk_prob": True, "hidden_act": "silu", "tie_word_embeddings": False,
+        "rope_interleave": True, "num_nextn_predict_layers": 1,
+        "num_key_value_heads": cfg["num_attention_heads"],
+        "qk_head_dim": cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"],
+    })
+    depth, dense = cfg["num_hidden_layers"], cfg["first_k_dense_replace"]
+    held = cfg["n_routed_experts"]
+    share = cfg.get("experts_held", {"range": (0, held), "of": held})
+    (lo, hi), total = share["range"], share["of"]
+    if hi - lo != held or not 0 <= lo < hi <= total:
+        raise ValueError(f"experts_held={share} is not {held} of its experts")
+    return dict(
+        vocab_size=cfg["vocab_size"], dim=cfg["hidden_size"], depth=depth,
+        layer_types=("mla",) * depth,
+        ff_types=("dense",) * min(dense, depth) + ("experts",) * max(depth - dense, 0),
+        heads=cfg["num_attention_heads"], ff_hidden=cfg["intermediate_size"],
+        norm_eps=cfg["rms_norm_eps"],
+        mla_q_rank=cfg["q_lora_rank"], mla_kv_rank=cfg["kv_lora_rank"],
+        mla_nope_dim=cfg["qk_nope_head_dim"], mla_rope_dim=cfg["qk_rope_head_dim"],
+        mla_v_dim=cfg["v_head_dim"], mla_rope_theta=float(cfg["rope_theta"]),
+        experts_total=total, experts_held=(int(lo), int(hi)),
+        experts_per_token=cfg["num_experts_per_tok"],
+        experts_hidden=cfg["moe_intermediate_size"],
+        experts_shared=cfg["n_shared_experts"],
+        experts_scaling=float(cfg["routed_scaling_factor"]),
+        bias_update_speed=float(cfg.get("bias_update_speed", 0.001)),
+        tie_head=False, mtp_lambda=float(cfg.get("mtp_loss_weight", 0.3)),
+    )
+
+
+_FAMILIES = {"granitemoehybrid": _granite_fields, "joyai_llm_flash": _joyai_fields}

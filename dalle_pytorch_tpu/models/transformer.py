@@ -21,7 +21,7 @@ import flax.linen as nn
 
 import functools
 
-from ..ops.attention import GroupedKVAttention, PatternAttention
+from ..ops.attention import GroupedKVAttention, LatentAttention, PatternAttention
 from ..ops.flash_attention import StaticTable
 from ..ops.layers import (
     FeedForward,
@@ -32,7 +32,7 @@ from ..ops.layers import (
     PreShiftToken,
     SwiGLU,
 )
-from ..ops.moe import MoEFeedForward
+from ..ops.moe import MoEFeedForward, RoutedExperts
 from ..ops.reversible import reversible_forward_only, reversible_sequence
 from ..ops.rotary import angles, dalle_rotary_table, lang_freqs
 from ..ops.ssm import MambaMixer
@@ -42,7 +42,12 @@ Dtype = Any
 ATTENTION_TYPES = ("full", "axial_row", "axial_col", "conv_like", "sparse", "mlp")
 # ``layer_types``: mixers that take no pattern, mask or positional table.
 # layer type -> (layer kind, device scope)
-MIXER_TYPES = {"mamba": ("mamba", "ssm"), "attention": ("gqa", "attn.gqa")}
+MIXER_TYPES = {
+    "mamba": ("mamba", "ssm"), "attention": ("gqa", "attn.gqa"), "mla": ("mla", "attn.mla"),
+}
+# ``ff_types``: a layer_types stack's feed-forward kind, layer by layer.
+# kind -> device scope
+FF_TYPES = {"dense": "ff", "experts": "moe"}
 
 
 def cast_tuple(val, depth: int = 1) -> tuple:
@@ -77,7 +82,12 @@ class Transformer(nn.Module):
     above): ``layer_types`` gives each layer its mixer in place of
     ``attn_types`` — ``mamba`` (ops/ssm.py:MambaMixer, sized by ``ssm_*``) or
     ``attention`` (grouped-KV causal attention over ``kv_heads`` with the
-    softmax scale ``attn_scale``, no positional term); ``norm='rmsnorm'``
+    softmax scale ``attn_scale``, no positional term) or ``mla`` (latent
+    attention, ops/attention.py:LatentAttention, sized by ``mla_*``: its own
+    rotary key, nothing of ``rotary_emb``); ``ff_types`` gives each layer of
+    such a stack its feed-forward, ``dense`` (the SwiGLU of ``ff_hidden``) or
+    ``experts`` (ops/moe.py:RoutedExperts, sized by ``experts_*``, under the
+    device scope ``moe``); ``norm='rmsnorm'``
     with a fixed ``residual_multiplier`` replaces LayerNorm + learned
     LayerScale; ``ff_act='swiglu'`` with ``ff_hidden`` replaces the GEGLU
     feed-forward. Such a stack trains and evaluates whole sequences; it has
@@ -125,6 +135,19 @@ class Transformer(nn.Module):
     ssm_state: int = 128
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    mla_q_rank: int = 1536
+    mla_kv_rank: int = 512
+    mla_nope_dim: int = 128
+    mla_rope_dim: int = 64
+    mla_v_dim: int = 128
+    mla_rope_theta: float = 10000.0
+    ff_types: Optional[Tuple[str, ...]] = None
+    experts_total: int = 0
+    experts_held: Optional[Tuple[int, int]] = None
+    experts_per_token: int = 0
+    experts_hidden: int = 0
+    experts_shared: int = 1
+    experts_scaling: float = 1.0
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
 
@@ -171,7 +194,7 @@ class Transformer(nn.Module):
                 f"layer); got {self.moe_every}"
             )
         self._check_variants()
-        attn_blocks, ff_blocks, kinds, scopes = [], [], [], []
+        attn_blocks, ff_blocks, kinds, scopes, ff_scopes = [], [], [], [], []
         for ind in range(self.depth):
             attn_type = attn_types[ind % len(attn_types)]
             scope = f"attn.{attn_type}"
@@ -205,7 +228,17 @@ class Transformer(nn.Module):
                     dtype=self.dtype,
                     param_dtype=self.param_dtype,
                 )
-            if self.ff_act == "swiglu":
+            ff_scope = FF_TYPES[self.ff_types[ind] if self.ff_types else "dense"]
+            if ff_scope == "moe":
+                ff = RoutedExperts(
+                    dim=self.dim, hidden=self.experts_hidden,
+                    experts_total=self.experts_total,
+                    experts_held=tuple(self.experts_held or (0, self.experts_total)),
+                    per_token=self.experts_per_token, shared=self.experts_shared,
+                    scaling=self.experts_scaling, dtype=self.dtype,
+                    param_dtype=self.param_dtype,
+                )
+            elif self.ff_act == "swiglu":
                 ff = SwiGLU(
                     dim=self.dim,
                     hidden=self.ff_hidden or int(self.dim * self.ff_mult),
@@ -252,11 +285,13 @@ class Transformer(nn.Module):
             ff_blocks.append(self._half_block(ff, ind, f"ff_{ind}"))
             kinds.append(attn_type)
             scopes.append(scope)
+            ff_scopes.append(ff_scope)
 
         self.attn_blocks = attn_blocks
         self.ff_blocks = ff_blocks
         self.layer_kinds = tuple(kinds)
         self.layer_scopes = tuple(scopes)
+        self.ff_scopes = tuple(ff_scopes)
 
     def _mixer_name(self, ind: int) -> str:
         return f"attn_{ind}" if self.layer_types is None else f"mixer_{ind}"
@@ -272,7 +307,17 @@ class Transformer(nn.Module):
         if self.ff_act == "swiglu" and self.ff_experts > 0:
             raise ValueError("the expert feed-forward is GEGLU only")
         if self.layer_types is None:
+            if self.ff_types is not None:
+                raise ValueError("ff_types comes with layer_types")
             return
+        if self.ff_types is not None and (
+            len(self.ff_types) != self.depth or set(self.ff_types) - set(FF_TYPES)
+            or self.ff_act != "swiglu"
+        ):
+            raise ValueError(
+                f"ff_types needs {self.depth} entries of {sorted(FF_TYPES)} over "
+                f"ff_act='swiglu'; got {self.ff_types} over {self.ff_act!r}"
+            )
         bad = [t for t in self.layer_types if t not in MIXER_TYPES]
         if bad or len(self.layer_types) != self.depth:
             raise ValueError(
@@ -291,6 +336,14 @@ class Transformer(nn.Module):
                 dim=self.dim, n_heads=self.ssm_heads, d_head=self.ssm_head_dim,
                 d_state=self.ssm_state, d_conv=self.ssm_conv, chunk=self.ssm_chunk,
                 eps=self.norm_eps, dtype=self.dtype, param_dtype=self.param_dtype,
+            )
+        if kind == "mla":
+            return LatentAttention(
+                dim=self.dim, heads=self.heads, q_rank=self.mla_q_rank,
+                kv_rank=self.mla_kv_rank, nope_dim=self.mla_nope_dim,
+                rope_dim=self.mla_rope_dim, v_dim=self.mla_v_dim,
+                rope_theta=self.mla_rope_theta, eps=self.norm_eps,
+                use_flash=self.use_flash, dtype=self.dtype, param_dtype=self.param_dtype,
             )
         return GroupedKVAttention(
             dim=self.dim, heads=self.heads, kv_heads=self.kv_heads or self.heads,
@@ -400,7 +453,7 @@ class Transformer(nn.Module):
                 )
                 with jax.named_scope(self.layer_scopes[ind]):
                     x = x + self.attn_blocks[ind](x, **akw)
-                with jax.named_scope("ff"):
+                with jax.named_scope(self.ff_scopes[ind]):
                     x = x + self.ff_blocks[ind](x, **fkw)
             return x
 
@@ -425,11 +478,17 @@ class Transformer(nn.Module):
 
         if self.remat and not self.reversible:
             aux = jnp.zeros((), jnp.float32)
-            for (f, g), (pf, pg), (kwf, kwg) in zip(fns, params, kwargs):
+            for ind, ((f, g), (pf, pg), (kwf, kwg)) in enumerate(zip(fns, params, kwargs)):
                 d, a = jax.checkpoint(f)(pf, x, kwf)
                 x = x + d
                 dg, ag = jax.checkpoint(g)(pg, x, kwg)
                 x = x + dg
+                if isinstance(ag, tuple):
+                    # what an expert layer sowed (``moe_stats``: the pairs it
+                    # sent each expert), put where the layer itself would have
+                    ag, stats = ag
+                    if self.is_mutable_collection("moe_stats"):
+                        self.put_variable("moe_stats", f"ff_{ind}", stats)
                 aux = aux + a + ag
             if self.ff_experts > 0:
                 self.sow("moe_aux", "load_balance", aux)
@@ -602,9 +661,10 @@ class Transformer(nn.Module):
             attn_mod = self.attn_blocks[ind].clone(parent=None)
             ff_mod = self.ff_blocks[ind].clone(parent=None)
 
-            def make_fn(mod, is_attn, patterned=patterned, scope=self.layer_scopes[ind]):
+            def make_fn(mod, is_attn, patterned=patterned, scope=self.layer_scopes[ind],
+                        ff_scope=self.ff_scopes[ind]):
                 static_kwargs = dict(deterministic=deterministic)
-                scope = scope if is_attn else "ff"
+                scope = scope if is_attn else ff_scope
 
                 def fn(p, t, kw):
                     call_kwargs = dict(static_kwargs)
@@ -614,13 +674,15 @@ class Transformer(nn.Module):
                     rngs = {"dropout": kw["rng"]} if "rng" in kw else None
                     with jax.named_scope(scope):
                         y, mut = mod.apply(
-                            {"params": p}, t, rngs=rngs, mutable=["moe_aux"],
+                            {"params": p}, t, rngs=rngs, mutable=["moe_aux", "moe_stats"],
                             **call_kwargs,
                         )
                     aux = sum(
                         jax.tree_util.tree_leaves(mut.get("moe_aux", {})),
                         jnp.zeros((), jnp.float32),
                     )
+                    if "moe_stats" in mut:
+                        return y, (aux, mut["moe_stats"])
                     return y, aux
 
                 return fn

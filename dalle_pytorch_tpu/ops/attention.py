@@ -42,8 +42,8 @@ from .flash_attention import (
     fused_qkv_attention,
     fused_qkv_supported,
 )
-from .layers import stable_softmax
-from .rotary import apply_rotary_emb
+from .layers import RMSNorm, stable_softmax
+from .rotary import angles, apply_rotary_emb, lang_freqs
 
 
 _FLASH_MASK_CACHE: dict = {}
@@ -1452,4 +1452,91 @@ class GroupedKVAttention(nn.Module):
             causal = jnp.tril(jnp.ones((n, n), bool))
             out = dense_attend(q * self.sm_scale, k, v, causal)
         out = out.transpose(0, 2, 1, 3).reshape(b, n, h * d)
+        return dense(self.dim, "to_out")(out)
+
+
+@functools.lru_cache(maxsize=None)
+def _rope_angles(n: int, rot_dim: int, theta: float) -> np.ndarray:
+    """(n, rot_dim) float32 rotary angles of positions 0 … n-1: adjacent
+    channel pairs share a frequency (``rope_interleave``)."""
+    return angles(np.arange(n), lang_freqs(rot_dim, theta)).astype(np.float32)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (the DeepSeek-V2/V3 family's MLA) in its
+    EXPANDED form, for training and whole-sequence evaluation. No bias.
+
+        c_q = RMSNorm(W_qa u);  [q_nope_i | q_rope_i] = (W_qb c_q)_i
+        [c_kv | k_rope] = W_kva u;  [k_nope_i | v_i] = (W_kvb RMSNorm(c_kv))_i
+        s_i = (q_nope_i . k_nope_i + rot(q_rope_i) . rot(k_rope)) / sqrt(nope + rope)
+
+    Queries go through a rank-``q_rank`` bottleneck with a norm, keys and
+    values through a rank-``kv_rank`` latent with a norm; ONE rotary key of
+    width ``rope_dim`` is shared by all heads and rotated by position over
+    adjacent channel pairs (angles, cosines and sines in float32,
+    ops/rotary.py:cos_sin); query/key width ``nope_dim + rope_dim`` against
+    value width ``v_dim``.
+
+    Training route: the blocked flash kernels with a value width of their own
+    (ops/flash_attention.py: nothing is padded to the query width), recorded
+    at ``forward/mla``; where the length has no usable block it is one dense
+    masked softmax. The absorbed form (attention over the latent itself, no
+    per-head keys) is serving's and is not written: ROADMAP R11."""
+
+    dim: int
+    heads: int
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    rope_theta: float = 10000.0
+    eps: float = 1e-6
+    use_flash: bool = True
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, deterministic: bool = True) -> jnp.ndarray:
+        # ``deterministic``: the trunk's uniform half-block argument; no dropout here
+        b, n, _ = x.shape
+        h, dn, dr, dv = self.heads, self.nope_dim, self.rope_dim, self.v_dim
+        dense = lambda features, name: nn.Dense(
+            features, use_bias=False, name=name, dtype=self.dtype,
+            param_dtype=self.param_dtype,
+        )
+        norm = lambda name, t: RMSNorm(self.eps, self.param_dtype, name=name)(t).astype(self.dtype)
+        heads_first = lambda t: t.transpose(0, 2, 1, 3)
+
+        c_q = norm("q_norm", dense(self.q_rank, "to_q_a")(x))
+        q = heads_first(dense(h * (dn + dr), "to_q_b")(c_q).reshape(b, n, h, dn + dr))
+        kv_a = dense(self.kv_rank + dr, "to_kv_a")(x)
+        c_kv, k_rope = kv_a[..., : self.kv_rank], kv_a[..., self.kv_rank :]
+        kv = heads_first(
+            dense(h * (dn + dv), "to_kv_b")(norm("kv_norm", c_kv)).reshape(b, n, h, dn + dv)
+        )
+        table = jnp.asarray(_rope_angles(n, dr, float(self.rope_theta)))
+        q = jnp.concatenate((q[..., :dn], apply_rotary_emb(table, q[..., dn:])), axis=-1)
+        k_rope = apply_rotary_emb(table, k_rope[:, None])             # (b, 1, n, dr)
+        k = jnp.concatenate(
+            (kv[..., :dn], jnp.broadcast_to(k_rope, (b, h, n, dr))), axis=-1
+        )
+        v = kv[..., dn:]
+
+        scale = float((dn + dr) ** -0.5)
+        block = _flash_block(n) if self.use_flash else 0
+        if block:
+            interpret = kv_policy.pallas_interpret()
+            kv_policy.record_route("forward/mla", "blocked_flash", interpret)
+            out = _per_device(
+                lambda q, k, v: flash_attention(
+                    q, k, v, None, True, None, scale, block, block, interpret
+                ),
+                (q, k, v),
+            )
+        else:
+            kv_policy.record_route("forward/mla", "dense_masked")
+            causal = jnp.tril(jnp.ones((n, n), bool))
+            out = dense_attend(q * scale, k, v, causal)
+        out = heads_first(out).reshape(b, n, h * dv)
         return dense(self.dim, "to_out")(out)

@@ -47,6 +47,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .rotary import cos_sin
+
 NEG_INF = -1e30
 LANES = 128
 
@@ -335,7 +337,8 @@ def _prep(q, pattern_mask, block_q, block_k, causal):
 def _kernel_cost(
     visit: np.ndarray, bh: int, block_q: int, block_k: int, d: int,
     dots_per_block: int, per_step_rows: int, per_outer_rows: int,
-    dtype_bytes: int,
+    dtype_bytes: int, dv: Optional[int] = None, v_dots: int = 0,
+    v_step_rows: int = 0, v_outer_rows: int = 0,
 ) -> pl.CostEstimate:
     """Cost of one pass over the live blocks — fed to XLA so compiled-module
     cost analysis and the scheduler see the kernel's real FLOPs instead of
@@ -345,16 +348,20 @@ def _kernel_cost(
     (affine index maps — dead blocks skip compute, not traffic):
     ``per_step_rows`` rows of d move per inner step, ``per_outer_rows`` rows
     once per outer step (operands whose block index only depends on the
-    outer grid dimension, plus outputs)."""
+    outer grid dimension, plus outputs). Of those counts, ``v_dots`` dots
+    and ``v_step_rows`` / ``v_outer_rows`` rows have the VALUE width ``dv``
+    (v, o, do, dv) where it is not the query/key width ``d``; with one
+    width the estimate is the one-width formula's, number for number."""
     live = int((visit > 0).sum())
     n_outer, n_inner = visit.shape
-    per_dot = 2 * block_q * block_k * d
+    dv = d if dv is None else dv
+    width = lambda count, v_count: (count - v_count) * d + v_count * dv
     return pl.CostEstimate(
-        flops=bh * live * dots_per_block * per_dot,
+        flops=bh * live * 2 * block_q * block_k * width(dots_per_block, v_dots),
         transcendentals=bh * live * block_q * block_k,  # exp
         bytes_accessed=bh
-        * (n_outer * n_inner * per_step_rows + n_outer * per_outer_rows)
-        * d
+        * (n_outer * n_inner * width(per_step_rows, v_step_rows)
+           + n_outer * width(per_outer_rows, v_outer_rows))
         * dtype_bytes,
     )
 
@@ -414,9 +421,10 @@ def _bcast_key_mask(key_mask, b, h, n):
 
 def _flash_fwd(q, k, v, key_mask, causal, pattern_mask, sm_scale, block_q, block_k, interpret):
     b, h, n, d, nq, nk, mask_np, visit = _prep(q, pattern_mask, block_q, block_k, causal)
+    dv = v.shape[-1]    # the value width: v's and o's, where it is not q's and k's
     scale = d**-0.5 if sm_scale is None else sm_scale
     bh = b * h
-    qf, kf, vf = (t.reshape(bh, n, d) for t in (q, k, v))
+    qf, kf, vf = q.reshape(bh, n, d), k.reshape(bh, n, d), v.reshape(bh, n, dv)
 
     # index_maps under PrefetchScalarGridSpec receive the scalar-prefetch
     # ref as a trailing argument after the grid indices, but must stay affine
@@ -427,7 +435,7 @@ def _flash_fwd(q, k, v, key_mask, causal, pattern_mask, sm_scale, block_q, block
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda bhi, qb, kb, s: (bhi, qb, 0)),
         pl.BlockSpec((1, block_k, d), kv_im),
-        pl.BlockSpec((1, block_k, d), kv_im),
+        pl.BlockSpec((1, block_k, dv), kv_im),
     ]
     operands = [qf, kf, vf]
     if mask_np is not None:
@@ -456,25 +464,26 @@ def _flash_fwd(q, k, v, key_mask, causal, pattern_mask, sm_scale, block_q, block
         grid=(bh, nq, nk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bhi, qb, kb, s: (bhi, qb, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda bhi, qb, kb, s: (bhi, qb, 0)),
             pl.BlockSpec((1, 1, block_q), lambda bhi, qb, kb, s: (bhi, 0, qb)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, n, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, n, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, n), jnp.float32),
         ],
         scratch=[
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         scalar=jnp.asarray(_scalar_table(visit)),
         operands=operands,
         interpret=interpret,
         cost=_kernel_cost(visit, bh, block_q, block_k, d, 2,
-                          2 * block_k, 2 * block_q, q.dtype.itemsize),
+                          2 * block_k, 2 * block_q, q.dtype.itemsize,
+                          dv, 1, block_k, block_q),
     )
-    return o.reshape(b, h, n, d), lse.reshape(b, h, n)
+    return o.reshape(b, h, n, dv), lse.reshape(b, h, n)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
@@ -489,7 +498,9 @@ def flash_attention(
     interpret: bool = False,
 ):
     """Fused attention over (b, h, n, d); q is NOT pre-scaled (``sm_scale``
-    defaults to d**-0.5). ``pattern_mask``: static (n, n) bool array,
+    defaults to d**-0.5). ``v`` may have a width of its own, (b, h, n, dv):
+    the output and ``dv`` then have it too, and nothing is padded (latent
+    attention: queries and keys of 192, values of 128). ``pattern_mask``: static (n, n) bool array,
     True = may attend; hash by id, so build it once at model setup.
     ``key_mask``: runtime (b, n) bool array, True = key is attendable
     (the reference's pad mask, attention.py:71-74); rows with every key
@@ -506,10 +517,12 @@ def _fwd_rule(q, k, v, key_mask, causal, pattern_mask, sm_scale, block_q, block_
 def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, do):
     q, k, v, key_mask, o, lse = res
     b, h, n, d, nq, nk, mask_np, visit = _prep(q, pattern_mask, block_q, block_k, causal)
+    dv = v.shape[-1]
     scale = d**-0.5 if sm_scale is None else sm_scale
     bh = b * h
 
-    qf, kf, vf, dof, of = (t.reshape(bh, n, d) for t in (q, k, v, do, o))
+    qf, kf = q.reshape(bh, n, d), k.reshape(bh, n, d)
+    vf, dof, of = (t.reshape(bh, n, dv) for t in (v, do, o))
     lsef = lse.reshape(bh, 1, n)
     mask_op = [] if mask_np is None else [jnp.asarray(mask_np, jnp.int8)]
     km_op = [] if key_mask is None else [_bcast_key_mask(key_mask, b, h, n)]
@@ -524,7 +537,7 @@ def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, 
         fused_specs = [
             pl.BlockSpec((1, block_q, d), whole),
             pl.BlockSpec((1, block_k, d), whole),
-            pl.BlockSpec((1, block_k, d), whole),
+            pl.BlockSpec((1, block_k, dv), whole),
             *(
                 [pl.BlockSpec((block_q, block_k), lambda bhi, qb, kb, s: (0, 0))]
                 if mask_np is not None else []
@@ -533,8 +546,8 @@ def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, 
                 [pl.BlockSpec((1, 1, block_k), row)]
                 if key_mask is not None else []
             ),
-            pl.BlockSpec((1, block_q, d), whole),
-            pl.BlockSpec((1, block_q, d), whole),
+            pl.BlockSpec((1, block_q, dv), whole),
+            pl.BlockSpec((1, block_q, dv), whole),
             pl.BlockSpec((1, 1, block_q), row),
         ]
         fused_kernel = _with_optional_masks(
@@ -547,7 +560,7 @@ def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, 
             n_out=3,
             n_scratch=0,
         )
-        dq, dk, dv = _call(
+        dq, dk, dv_ = _call(
             fused_kernel,
             name="flash_bwd",
             grid=(bh, 1, 1),
@@ -555,19 +568,20 @@ def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, 
             out_specs=[
                 pl.BlockSpec((1, block_q, d), whole),
                 pl.BlockSpec((1, block_k, d), whole),
-                pl.BlockSpec((1, block_k, d), whole),
+                pl.BlockSpec((1, block_k, dv), whole),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((bh, n, d), q.dtype),
                 jax.ShapeDtypeStruct((bh, n, d), q.dtype),
-                jax.ShapeDtypeStruct((bh, n, d), q.dtype),
+                jax.ShapeDtypeStruct((bh, n, dv), q.dtype),
             ],
             scratch=[],
             scalar=jnp.asarray(_scalar_table(visit)),
             operands=[qf, kf, vf, *mask_op, *km_op, dof, of, lsef],
             interpret=interpret,
             cost=_kernel_cost(visit, bh, block_q, block_k, d, 5,
-                              0, 7 * block_q, q.dtype.itemsize),
+                              0, 7 * block_q, q.dtype.itemsize,
+                              dv, 2, 0, 4 * block_q),
         )
         dkm = (
             None if key_mask is None
@@ -576,7 +590,7 @@ def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, 
         return (
             dq.reshape(b, h, n, d),
             dk.reshape(b, h, n, d),
-            dv.reshape(b, h, n, d),
+            dv_.reshape(b, h, n, dv),
             dkm,
         )
 
@@ -587,7 +601,7 @@ def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, 
     dq_specs = [
         pl.BlockSpec((1, block_q, d), lambda bhi, qb, kb, s: (bhi, qb, 0)),
         pl.BlockSpec((1, block_k, d), kv_im),
-        pl.BlockSpec((1, block_k, d), kv_im),
+        pl.BlockSpec((1, block_k, dv), kv_im),
         *(
             [pl.BlockSpec((block_q, block_k), lambda bhi, qb, kb, s: (qb, kb))]
             if mask_np is not None else []
@@ -596,8 +610,8 @@ def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, 
             [pl.BlockSpec((1, 1, block_k), lambda bhi, qb, kb, s: (bhi, 0, kb))]
             if key_mask is not None else []
         ),
-        pl.BlockSpec((1, block_q, d), lambda bhi, qb, kb, s: (bhi, qb, 0)),
-        pl.BlockSpec((1, block_q, d), lambda bhi, qb, kb, s: (bhi, qb, 0)),
+        pl.BlockSpec((1, block_q, dv), lambda bhi, qb, kb, s: (bhi, qb, 0)),
+        pl.BlockSpec((1, block_q, dv), lambda bhi, qb, kb, s: (bhi, qb, 0)),
         pl.BlockSpec((1, 1, block_q), lambda bhi, qb, kb, s: (bhi, 0, qb)),
     ]
     dq_kernel = _with_optional_masks(
@@ -630,7 +644,8 @@ def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, 
         operands=[qf, kf, vf, *mask_op, *km_op, dof, of, lsef],
         interpret=interpret,
         cost=_kernel_cost(visit, bh, block_q, block_k, d, 3,
-                          2 * block_k, 4 * block_q, q.dtype.itemsize),
+                          2 * block_k, 4 * block_q, q.dtype.itemsize,
+                          dv, 1, block_k, 2 * block_q),
     )
 
     # ---- dk/dv over q blocks ----------------------------------------------
@@ -645,7 +660,7 @@ def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, 
     dkv_specs = [
         pl.BlockSpec((1, block_q, d), q_im),
         pl.BlockSpec((1, block_k, d), lambda bhi, kb, qb, s: (bhi, kb, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bhi, kb, qb, s: (bhi, kb, 0)),
+        pl.BlockSpec((1, block_k, dv), lambda bhi, kb, qb, s: (bhi, kb, 0)),
         *(
             [pl.BlockSpec((block_q, block_k), lambda bhi, kb, qb, s: (qb, kb))]
             if mask_np is not None else []
@@ -654,7 +669,7 @@ def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, 
             [pl.BlockSpec((1, 1, block_k), lambda bhi, kb, qb, s: (bhi, 0, kb))]
             if key_mask is not None else []
         ),
-        pl.BlockSpec((1, block_q, d), q_im),
+        pl.BlockSpec((1, block_q, dv), q_im),
         pl.BlockSpec((1, 1, block_q), row_im),
         pl.BlockSpec((1, 1, block_q), row_im),
     ]
@@ -667,34 +682,35 @@ def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, 
         n_out=2,
         n_scratch=2,
     )
-    dk, dv = _call(
+    dk, dv_ = _call(
         dkv_kernel,
         name="flash_dkv",
         grid=(bh, nk, nq),
         in_specs=dkv_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda bhi, kb, qb, s: (bhi, kb, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bhi, kb, qb, s: (bhi, kb, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda bhi, kb, qb, s: (bhi, kb, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, n, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, n, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, n, dv), q.dtype),
         ],
         scratch=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         scalar=jnp.asarray(_scalar_table(visit_t)),
         operands=[qf, kf, vf, *mask_op, *km_op, dof, lsef, deltaf],
         interpret=interpret,
         cost=_kernel_cost(visit_t, bh, block_q, block_k, d, 4,
-                          2 * block_q, 4 * block_k, q.dtype.itemsize),
+                          2 * block_q, 4 * block_k, q.dtype.itemsize,
+                          dv, 2, block_q, 2 * block_k),
     )
     dkm = None if key_mask is None else np.zeros(key_mask.shape, jax.dtypes.float0)
     return (
         dq.reshape(b, h, n, d),
         dk.reshape(b, h, n, d),
-        dv.reshape(b, h, n, d),
+        dv_.reshape(b, h, n, dv),
         dkm,
     )
 
@@ -742,10 +758,10 @@ jax.tree_util.register_pytree_node(
 
 def _rot_tables(rot, n, d, dtype):
     """cos/sin operands (n, d) in the compute dtype. The angle table is
-    zero-padded to the head dim (zero angle = identity rotation), and the
-    angles are cast to the compute dtype BEFORE cos/sin — exactly matching
-    apply_rotary_emb's `angle_table.astype(t.dtype)` (ops/rotary.py:82) so
-    the fused path is bit-compatible with the unfused one at f32.
+    zero-padded to the head dim (zero angle = identity rotation); cos/sin
+    are taken of the float32 angles and only the results cast, by the same
+    `ops/rotary.py:cos_sin` that `apply_rotary_emb` uses, so the fused path
+    is bit-compatible with the unfused one at f32.
 
     The table must be PAIR-CONSTANT (angle identical within each (2i, 2i+1)
     channel pair): the fused backward's inverse rotation computes
@@ -762,8 +778,7 @@ def _rot_tables(rot, n, d, dtype):
         "fused rotary requires a pair-constant angle table "
         "(table[:, 0::2] == table[:, 1::2]); see ops/rotary.py:angles"
     )
-    ang = jnp.asarray(table).astype(dtype)
-    return jnp.cos(ang), jnp.sin(ang)
+    return cos_sin(table, dtype)
 
 
 def _rot_block(t, cos, sin, P):

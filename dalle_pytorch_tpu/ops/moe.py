@@ -1,6 +1,10 @@
-"""Mixture-of-experts feed-forward with expert parallelism.
+"""Mixture-of-experts feed-forwards with expert parallelism: the Switch layer
+of ``DALLE``'s ``--moe_experts`` (``MoEFeedForward``, below) and the expert
+layer of the DeepSeek-V3 family's language models (``RoutedExperts``, at the
+end: sigmoid scores, top-k of all experts, no dropped pair, a shared expert,
+and a layer that is told which experts it holds).
 
-The reference has no MoE (SURVEY.md §2.2 lists EP as n/a); this is a
+``MoEFeedForward``: the reference has no MoE (SURVEY.md §2.2 lists EP as n/a); this is a
 beyond-parity scaling axis in the GShard/Switch lineage, built so GSPMD can
 shard it over the ``ep`` mesh axis with zero manual collectives:
 
@@ -24,12 +28,16 @@ shard it over the ``ep`` mesh axis with zero manual collectives:
 
 from __future__ import annotations
 
-from typing import Any
+import functools
+from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import flax.linen as nn
+
+from . import kv_policy
+from .layers import SwiGLU
 
 Dtype = Any
 
@@ -99,3 +107,211 @@ class MoEFeedForward(nn.Module):
         ys = jnp.einsum("ebch,ehd->ebcd", h, w_out.astype(self.dtype))
 
         return jnp.einsum("bnec,ebcd->bnd", combine, ys)
+
+
+# rows of the first chunk over the pairs a uniform router would send the held
+# experts: what a layer always computes, rounded up to whole CHUNK_MULTIPLEs
+HEADROOM = 1.25
+CHUNK_MULTIPLE = 256
+
+
+def route(scores, bias, per_token: int, scaling: float):
+    """(tokens, experts) sigmoid scores -> the ``per_token`` experts each token
+    chose, (tokens, per_token) indices, and their weights. The bias moves the
+    choice only; the weights are the scores themselves, normalised over all
+    the chosen (held here or not) and scaled."""
+    _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), per_token)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    return chosen, scaling * picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+
+
+def balanced_bias(bias, load, speed: float):
+    """The selection bias after a step that sent each expert ``load`` pairs
+    (``topk_method`` ``noaux_tc``, DeepSeek-V3 report section 2.1.2): down by
+    ``speed`` where an expert was sent more than the mean, up where fewer."""
+    load = load.astype(jnp.float32)
+    return bias + speed * jnp.sign(jnp.mean(load) - load).astype(bias.dtype)
+
+
+def _chunk(c, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, chunk: int, dtype):
+    """Chunk ``c`` of the pairs sorted by expert: (its tokens, its weighted
+    expert outputs in float32). Rows past the count have weight 0."""
+    with jax.named_scope("moe.dispatch"):
+        first = c * chunk
+        tok = jax.lax.dynamic_slice(sorted_tok, (first,), (chunk,))
+        w = jax.lax.dynamic_slice(sorted_w, (first,), (chunk,))
+        groups = jnp.clip(
+            jnp.minimum(starts + sizes, first + chunk) - jnp.maximum(starts, first), 0, chunk,
+        ).astype(jnp.int32)
+        # the rows past the count go to the last expert with weight 0: the
+        # TPU's grouped product leaves rows of NO group unwritten (NaNs from
+        # the chip's memory reached the sum through 0 * NaN)
+        groups = groups.at[-1].add(chunk - jnp.sum(groups))
+        rows = jnp.take(u, tok, axis=0).astype(dtype)
+    with jax.named_scope("moe.experts"):
+        h = jax.lax.ragged_dot(rows, w_in, groups, preferred_element_type=jnp.float32)
+        a, g = jnp.split(h.astype(dtype), 2, axis=-1)
+        out = jax.lax.ragged_dot(nn.silu(a) * g, w_out, groups, preferred_element_type=jnp.float32)
+    with jax.named_scope("moe.combine"):
+        return tok, out * w[:, None]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10))
+def _further_chunks(y, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, count, chunk, dtype):
+    """``y`` plus chunks 1, 2, … of the sorted pairs, as many as ``count``
+    needs: a loop whose trip count is read from the data, so a count that
+    passes the first chunk costs what it needs and no more. Such a loop has
+    no automatic transpose; the backward below walks the same chunks,
+    recomputing each, so nothing is kept for them."""
+    def body(c, y):
+        tok, out = _chunk(c, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, chunk, dtype)
+        return y.at[tok].add(out)
+
+    return jax.lax.fori_loop(1, -(-count // chunk), body, y)
+
+
+def _further_fwd(y, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, count, chunk, dtype):
+    out = _further_chunks(y, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, count, chunk, dtype)
+    return out, (u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, count)
+
+
+def _further_bwd(chunk, dtype, residuals, dy):
+    u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, count = residuals
+    floats = (u, w_in, w_out, sorted_w)
+
+    def body(c, sums):
+        def weighted(u, w_in, w_out, sorted_w):
+            return _chunk(c, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, chunk, dtype)[1]
+
+        tok = jax.lax.dynamic_slice(sorted_tok, (c * chunk,), (chunk,))
+        grads = jax.vjp(weighted, *floats)[1](jnp.take(dy, tok, axis=0))
+        return tuple(s + g.astype(jnp.float32) for s, g in zip(sums, grads))
+
+    zeros = tuple(jnp.zeros(x.shape, jnp.float32) for x in floats)
+    sums = jax.lax.fori_loop(1, -(-count // chunk), body, zeros)
+    whole = lambda x: np.zeros(x.shape, jax.dtypes.float0)
+    return (dy, *(s.astype(x.dtype) for s, x in zip(sums, floats)),
+            whole(sorted_tok), whole(starts), whole(sizes), whole(count))
+
+
+_further_chunks.defvjp(_further_fwd, _further_bwd)
+
+
+class RoutedExperts(nn.Module):
+    """The expert feed-forward of the DeepSeek-V3 family (``topk_method``
+    ``noaux_tc``, one group), told which experts it holds.
+
+        s = sigmoid(W_g u)                   float32, over ALL ``experts_total``
+        chosen = top-k of (s + b)            b: the selection bias, for choosing only
+        w_e = scaling * s_e / (sum_chosen s + 1e-20)   over all k chosen, held or not
+        y = sum_{e chosen and held} w_e SwiGLU_e(u) + SwiGLU_shared(u)
+
+    ``experts_held = (lo, hi)``: the layer routes over the total and computes
+    its own. What the absent experts would add is left out, and on one chip
+    nothing is exchanged: the partial ``y`` goes on (model-configs guide §4).
+    ``e_score_correction_bias`` and ``tokens_per_expert`` are leaves of the
+    tree so that a checkpoint carries them, and buffers in effect: they enter
+    nothing differentiable (top-k gives indices), their gradients are zero and
+    Adam leaves them bit for bit. What writes them is the model's
+    ``after_update`` (``models/lm.py:CausalLM.balance``, once a step from the
+    loads this layer sows): ``tokens_per_expert`` takes the pairs the step sent
+    each of ALL experts, and the bias moves against them (``balanced_bias``).
+
+    No pair is dropped and nothing is a capacity. The (token, expert) pairs
+    of the held experts are sorted by expert (index arrays of the worst-case
+    length ``tokens * per_token``: integers) and the experts are two grouped
+    matrix products over the sorted rows (``jax.lax.ragged_dot``). The rows
+    are gathered a chunk at a time, ``HEADROOM`` times the expected count:
+    the first chunk always (where the real count fits, the usual case,
+    it is all the work there is), then as many further chunks as the count
+    needs, in a loop whose trip count is read from the data
+    (``_further_chunks``: its own backward, each chunk recomputed), so that no
+    buffer has the worst-case size and a count that passes the first chunk
+    costs what it needs. Exact for every count from none to all. (Tried and
+    left: a ``lax.cond`` around a scan over ALL the other chunks cost 117 ms
+    a layer whenever a count passed the buffer, which a router that Adam has
+    moved does in some steps of some seeds: the step's time then depended on
+    the seed by 2 %; with a ``cond`` inside that scan's body the scan stacked
+    its loop-invariant residuals, 6 GB. The first chunk stands outside any
+    loop: operations inside one reach the device trace without their scope.)"""
+
+    dim: int
+    hidden: int
+    experts_total: int
+    experts_held: Tuple[int, int]
+    per_token: int
+    shared: int = 1
+    scaling: float = 1.0
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    def _chunk_rows(self, pairs: int) -> int:
+        lo, hi = self.experts_held
+        expected = HEADROOM * pairs * (hi - lo) / self.experts_total
+        return min(max(-(-int(expected) // CHUNK_MULTIPLE), 1) * CHUNK_MULTIPLE, pairs)
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, deterministic: bool = True) -> jnp.ndarray:
+        b, n, d = x.shape
+        tokens, k, total = b * n, self.per_token, self.experts_total
+        lo, hi = self.experts_held
+        held = hi - lo
+        assert 0 <= lo < hi <= total and k <= total, (self.experts_held, total, k)
+        u = x.reshape(tokens, d)
+
+        with jax.named_scope("moe.router"):
+            # bf16_3x: the activations are exact in bfloat16, the router's
+            # weights are not; one pass would round them before the sigmoid
+            logits = nn.Dense(
+                total, use_bias=False, dtype=jnp.float32, param_dtype=self.param_dtype,
+                precision=jax.lax.Precision.HIGH, name="gate",
+            )(u.astype(jnp.float32))
+            scores = jax.nn.sigmoid(logits)
+            bias = self.param(
+                "e_score_correction_bias", nn.initializers.zeros, (total,), self.param_dtype
+            )
+            chosen, weights = route(scores, bias, k, self.scaling)
+            # pairs sent to each of ALL experts: CausalLM.balance reads it
+            experts = jnp.arange(total, dtype=chosen.dtype)
+            self.sow("moe_stats", "load", jnp.sum(chosen.reshape(-1, 1) == experts, axis=0))
+            self.param("tokens_per_expert", nn.initializers.zeros, (total,), self.param_dtype)
+
+        with jax.named_scope("moe.dispatch"):
+            pairs = tokens * k
+            flat = chosen.reshape(pairs)
+            here = (flat >= lo) & (flat < hi)
+            key = jnp.where(here, flat - lo, held)          # the others last
+            order = jnp.argsort(key, stable=True)
+            sizes = jnp.sum(key[:, None] == jnp.arange(held, dtype=key.dtype), axis=0)
+            starts = jnp.cumsum(sizes) - sizes
+            count = jnp.sum(sizes)
+            chunk = self._chunk_rows(pairs)
+            n_chunks = -(-pairs // chunk)
+            pad = n_chunks * chunk - pairs
+            sorted_tok = jnp.pad(order // k, (0, pad))
+            sorted_w = jnp.pad(jnp.where(here, weights.reshape(pairs), 0.0)[order], (0, pad))
+
+        w_in = self.param(
+            "experts_in", nn.initializers.lecun_normal(), (held, d, 2 * self.hidden),
+            self.param_dtype,
+        ).astype(self.dtype)
+        w_out = self.param(
+            "experts_out", nn.initializers.lecun_normal(), (held, self.hidden, d),
+            self.param_dtype,
+        ).astype(self.dtype)
+        kv_policy.record_route("forward/moe_experts", "ragged_dot")
+
+        operands = (u, w_in, w_out, sorted_w, sorted_tok, starts, sizes)
+        tok, out = _chunk(0, *operands, chunk, self.dtype)
+        with jax.named_scope("moe.combine"):
+            y = jnp.zeros((tokens, d), jnp.float32).at[tok].add(out)
+        if n_chunks > 1:
+            y = _further_chunks(y, *operands, count, chunk, self.dtype)
+
+        with jax.named_scope("moe.shared"):
+            shared = SwiGLU(
+                dim=d, hidden=self.hidden * self.shared, dtype=self.dtype,
+                param_dtype=self.param_dtype, name="shared",
+            )(x) if self.shared else 0.0
+        with jax.named_scope("moe.combine"):
+            return y.astype(self.dtype).reshape(b, n, d) + shared

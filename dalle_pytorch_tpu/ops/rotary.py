@@ -71,6 +71,18 @@ def rotate_half(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.einsum("...i,ij->...j", x, P, precision=jax.lax.Precision.HIGHEST)
 
 
+def cos_sin(angle_table, dtype):
+    """(cos, sin) of an angle table in ``dtype``: taken of the FLOAT32 angles,
+    only the results cast. An angle rounded to bfloat16 first is off by up
+    to 16 rad at position 4,095 and frequency 1 (bfloat16 steps by 16-32
+    there), which no rotation survives; a cosine rounded afterwards is off by
+    2**-9. The one cast for the unfused path (``apply_rotary_emb``) and the
+    fused kernel's operands (``ops/flash_attention.py:_rot_tables``), so the
+    two agree bit for bit in float32."""
+    angle_table = jnp.asarray(angle_table, jnp.float32)
+    return jnp.cos(angle_table).astype(dtype), jnp.sin(angle_table).astype(dtype)
+
+
 def apply_rotary_emb(angle_table: jnp.ndarray, t: jnp.ndarray) -> jnp.ndarray:
     """Rotate the leading ``angle_table.shape[-1]`` channels of ``t``.
 
@@ -79,13 +91,13 @@ def apply_rotary_emb(angle_table: jnp.ndarray, t: jnp.ndarray) -> jnp.ndarray:
     3 * (dim_head // 3 // 2 * 2) of every head's channels).
     """
     rot_dim = angle_table.shape[-1]
-    angle_table = angle_table.astype(t.dtype)
+    cos, sin = cos_sin(angle_table, t.dtype)
     if rot_dim == t.shape[-1]:
         # full-width table (zero-padded angles rotate by identity): pure
         # elementwise — no slice/concat, so XLA emits no layout copies
-        return t * jnp.cos(angle_table) + rotate_half(t) * jnp.sin(angle_table)
+        return t * cos + rotate_half(t) * sin
     t_rot, t_pass = t[..., :rot_dim], t[..., rot_dim:]
-    t_rot = t_rot * jnp.cos(angle_table) + rotate_half(t_rot) * jnp.sin(angle_table)
+    t_rot = t_rot * cos + rotate_half(t_rot) * sin
     return jnp.concatenate((t_rot, t_pass), axis=-1)
 
 

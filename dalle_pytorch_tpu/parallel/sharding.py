@@ -43,14 +43,24 @@ DEFAULT_RULES: Tuple[Tuple[str, P], ...] = (
     # grouped-KV attention (ops/attention.py:GroupedKVAttention): q and the
     # fused k|v split their output features like to_qkv; to_out is above
     (r"to_(q|kv)/kernel$", P("fsdp", "tp")),
+    # latent attention (ops/attention.py:LatentAttention): the expansions
+    # split their heads (output features) like to_q; the compressions' small
+    # outputs (a latent, and the one rotary key every head shares) stay whole
+    # across tp, since a norm runs over each; to_out is above
+    (r"to_(q|kv)_b/kernel$", P("fsdp", "tp")),
+    (r"to_(q|kv)_a/kernel$", P("fsdp", None)),
     # state-space mixer (ops/ssm.py): the same pair, in splits its output
     # channels (z | x B C | dt), out its input channels
     (r"in_proj/kernel$", P("fsdp", "tp")),
     (r"out_proj/kernel$", P("tp", "fsdp")),
     # MoE experts: expert dim over ep, hidden over tp (ops/moe.py)
+    # (RoutedExperts holds the experts of its own range: the same two leaves
+    # at the same places; its router, its selection bias and its count of
+    # pairs stay whole)
     (r"experts_in$", P("ep", "fsdp", "tp")),
     (r"experts_out$", P("ep", "tp", "fsdp")),
     (r"gate/kernel$", P(None, None)),
+    (r"(e_score_correction_bias|tokens_per_expert)$", P(None)),
     (r"spatial_weight$", P(None, None)),
     # GEGLU FF / gMLP channel projections: up-projection splits hidden over
     # tp, down-projection splits input — matched by position inside any
@@ -65,6 +75,10 @@ DEFAULT_RULES: Tuple[Tuple[str, P], ...] = (
     (r"(text_emb|image_emb|tok_emb)/embedding(_q)?$", P("fsdp", "tp")),
     (r"(text_emb|image_emb)/scale$", P("fsdp")),
     (r"to_logits/kernel(_q)?$", P("fsdp", "tp")),
+    # an untied head (models/lm.py) lies as the embedding does, (vocab, dim);
+    # the MTP module's 2 dim -> dim projection splits its output features
+    (r"lm_head$", P("fsdp", "tp")),
+    (r"eh_proj/kernel$", P("fsdp", "tp")),
     # CLIP latent projections
     (r"to_(text|visual)_latent/kernel$", P("fsdp", "tp")),
     # VAE convs: shard output channels over tp when large

@@ -91,6 +91,7 @@ def make_train_step(
     data_shardings: Any = None,
     nan_guard: bool = True,
     nan_inject_step: Optional[int] = None,
+    after_update: Optional[Callable[[Any, Any], Any]] = None,
 ):
     """Compile ``(state, batch, rng[, lr]) -> (state, loss[, aux])``.
 
@@ -116,6 +117,13 @@ def make_train_step(
     K-consecutive-rejections abort (--nan_abort_after) off exactly the
     device's decision.
 
+    ``after_update(params, aux) -> params`` runs on the parameters the
+    optimizer has updated, with what ``loss_fn`` returned beside the loss
+    (``loss_fn`` then returns ``(loss, aux)`` whether or not ``has_aux`` hands
+    ``aux`` on to the caller): buffers among the leaves that a rule other than
+    the optimizer's writes once a step (an expert layer's selection bias,
+    models/lm.py:CausalLM.balance). A rejected step keeps the old ones.
+
     ``nan_inject_step`` is the fault hook (utils/faults.py nan_at_step):
     the loss is forced to NaN at that global step, compiled in as a trace
     constant — None (the default) adds nothing to the program.
@@ -140,9 +148,10 @@ def make_train_step(
         donate_argnums=(0,) if donate else (),
     )
     def train_step(state: TrainState, batch, rng, lr=None):
-        grad_fn = jax.value_and_grad(loss_fn, has_aux=has_aux)
+        with_aux = has_aux or after_update is not None
+        grad_fn = jax.value_and_grad(loss_fn, has_aux=with_aux)
         out, grads = grad_fn(state.params, batch, rng)
-        loss, aux = out if has_aux else (out, None)
+        loss, aux = out if with_aux else (out, None)
         if nan_inject_step is not None:
             loss = jnp.where(
                 state.step == nan_inject_step,
@@ -156,6 +165,8 @@ def make_train_step(
                 if dynamic_lr:
                     updates = jax.tree_util.tree_map(lambda u: -lr * u, updates)
                 params = optax.apply_updates(state.params, updates)
+                if after_update is not None:
+                    params = after_update(params, aux)
             skipped, consec = state.skipped, state.consec_skipped
             if nan_guard:
                 with jax.named_scope("update.nan_guard"):

@@ -111,6 +111,9 @@ EVENTS = frozenset({
 # ------------------------------------------------------------ counters
 
 COUNTERS = frozenset({
+    # (token, expert) pairs routed to experts held here, all expert layers
+    # of the last step (models/lm.py:CausalLM.routing_stats, train_lm.py every log step)
+    "moe.pairs_here",
     # serving engine lifecycle
     "serve.submitted",
     "serve.admitted",
@@ -224,6 +227,8 @@ COUNTERS = frozenset({
 # -------------------------------------------------------------- gauges
 
 GAUGES = frozenset({
+    # the fullest expert's pairs over the mean expert's, the worst layer's
+    "moe.load_max_over_mean",
     "serve.pool_occupancy",
     "serve.running",
     "serve.prefilling",
@@ -304,6 +309,21 @@ DEVICE_SCOPES = frozenset({
     "ssm",
     "ssm.conv",
     "ssm.scan",
+    # latent attention (ops/attention.py:LatentAttention), and the routed
+    # expert feed-forward with its parts (ops/moe.py:RoutedExperts): scores
+    # and choice; sort, group sizes and the gather of rows; the grouped
+    # products over the held experts; the weighted scatter back and the sum
+    # with the shared expert, which runs under its own scope
+    "attn.mla",
+    "moe",
+    "moe.router",
+    "moe.dispatch",
+    "moe.experts",
+    "moe.combine",
+    "moe.shared",
+    # the multi-token-prediction module's own projection, norms and shifted
+    # embedding (models/lm.py); its block runs under attn.mla and moe
+    "mtp",
     "ff",                   # one per feed-forward sublayer
     "embed",                # token + positional embeddings (models/dalle.py, lm.py)
     "head_loss",            # final norm, logits, the weighted cross-entropy

@@ -113,6 +113,18 @@ def _conv(xbc, taps, bias):
 
 SSM_CONV = SSM_H * SSM_P + 2 * SSM_STATE
 
+# train-joyaiflash-d6-ep16-s4k: 4 rows of 4096, 32 heads, queries and keys of
+# 128 + 64 against values of 128, blocks of 1024 (``_flash_block(4096)``)
+MLA_B, MLA_H, MLA_N, MLA_QK, MLA_V = 4, 32, 4096, 192, 128
+
+
+def _latent(q, k, v):
+    # the call of ``ops/attention.py:LatentAttention``: a value width of its own
+    out = flash_attention(q, k, v, None, True, None, MLA_QK**-0.5, 1024, 1024, False)
+    assert out.shape == (MLA_B, MLA_H, MLA_N, MLA_V)
+    return out
+
+
 ROUTES = {
     "ssm_conv": (
         _conv,
@@ -125,6 +137,10 @@ ROUTES = {
          (1, SSM_N, SSM_STATE), (1, SSM_N, SSM_STATE),
          ((1, SSM_N // SSM_CHUNK, SSM_STATE, SSM_H * SSM_P), F32)],
         {"ssd_state_fwd", "ssd_state_bwd", "ssd_chunk_fwd", "ssd_chunk_bwd"},
+    ),
+    "latent_flash": (
+        _latent, [(MLA_B, MLA_H, MLA_N, MLA_QK)] * 2 + [(MLA_B, MLA_H, MLA_N, MLA_V)],
+        {"flash_fwd", "flash_dq", "flash_dkv"},
     ),
     "packed_flash": (_packed, [(B, N, 3 * H * D)], {"flash_qkv_fwd", "flash_qkv_bwd"}),
     "blocked_flash": (_blocked, [(B, H, N, D)] * 3, {"flash_fwd", "flash_dq", "flash_dkv"}),

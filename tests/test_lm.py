@@ -337,3 +337,333 @@ def test_every_dalle_configurations_parameter_tree_is_what_it_was(name):
     )
     digest = hashlib.sha256("\n".join(lines).encode()).hexdigest()
     assert (len(lines), digest) == DALLE_TREES[name]
+
+
+# ------------------------------------------------- the second family: JoyAI
+# model_type joyai_llm_flash (the DeepSeek-V3 family's keys): latent
+# attention, routed experts of which a range is held, a shared expert, an
+# untied head and the multi-token-prediction module, through the same train
+# path and held to benchmarks/reference_moe.py (float32, one head at a time,
+# the experts as a dense loop; imports nothing of the program).
+
+from benchmarks import reference_moe, weights_moe  # noqa: E402
+from benchmarks.drivers import train_moe as moe_driver  # noqa: E402
+
+MOE_N = 40
+MOE_CFG = {
+    **costs.load_config("joyai-llm-flash-d6-ep16"),
+    **json.loads((ROOT / "benchmarks/rehearsal_moe.json").read_text())["config"],
+}
+
+
+def moe_model_and_params(remat=False, seed=5, **over):
+    cfg = {**MOE_CFG, **over}
+    lm = CausalLM.from_config(cfg, seq_len=MOE_N, remat=remat)
+    ids = jax.random.randint(jax.random.key(1), (2, MOE_N), 0, cfg["vocab_size"])
+    shapes = jax.eval_shape(lm.init, jax.random.key(0), ids)["params"]
+    return lm, weights_moe.make_params(shapes, seed, jnp.float32), ids, cfg
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_joyai_loss_and_every_leafs_gradient_match_the_reference(remat):
+    """Both losses (next token, and the MTP module's token after next), the
+    untied head and every leaf, the selection bias included (zero in both)."""
+    lm, params, ids, cfg = moe_model_and_params(remat=remat)
+    assert lm.ff_types == ("dense", "experts", "experts") and lm.experts_held == (2, 6)
+    assert not lm.tie_head and lm.mtp_lambda == 0.3 and "lm_head" in params and "nextn" in params
+    with jax.default_matmul_precision("highest"):
+        loss, grads = jax.value_and_grad(
+            lambda p: lm.apply({"params": p}, ids, return_loss=True))(params)
+        (want, want_loads), want_grads = jax.value_and_grad(
+            lambda p: reference_moe.loss(p, cfg, ids), has_aux=True)(params)
+        _, loads = lm.loss_and_loads(params, ids)
+        balanced = lm.balance(params, loads)
+    assert abs(float(loss) - float(want)) < 2e-6 * float(want)
+    gaps = leaf_gaps(grads, want_grads)
+    assert max(gaps.values()) < 2e-4, max(gaps.items(), key=lambda kv: kv[1])
+    # no pair dropped: every expert of every layer was sent what the reference
+    # sent it, and the selection bias moves against that as the reference's does
+    flat = traverse_util.flatten_dict(dict(params))
+    reference_moe.balance(flat, want_loads, cfg["bias_update_speed"])
+    want_balanced = traverse_util.unflatten_dict(flat)
+    for layer in (("transformer", "ff_1", "fn"), ("transformer", "ff_2", "fn"),
+                  ("nextn", "block", "ff_0", "fn")):
+        got, wanted = balanced, want_balanced
+        for name in layer:
+            got, wanted = got[name], wanted[name]
+        sent = np.asarray(got["tokens_per_expert"])
+        np.testing.assert_array_equal(sent, np.asarray(wanted["tokens_per_expert"]))
+        assert sent.sum() == ids.size * cfg["num_experts_per_tok"]
+        np.testing.assert_array_equal(
+            np.asarray(got["e_score_correction_bias"]), np.asarray(wanted["e_score_correction_bias"])
+        )
+    stats = lm.routing_stats(balanced)
+    lo, hi = lm.experts_held
+    assert int(stats["moe.pairs_here"]) == sum(int(v[lo:hi].sum()) for v in want_loads.values()) > 0
+    assert float(stats["moe.load_max_over_mean"]) >= 1.0
+    assert lm.routing_stats(params)["moe.pairs_here"] == 0          # no step has run
+    bias = grads["transformer"]["ff_1"]["fn"]["e_score_correction_bias"]
+    assert not np.any(np.asarray(bias))
+
+
+def test_the_mtp_modules_loss_is_the_references_second_loss():
+    """With the next-token loss taken out (the weight of the second loss is
+    what is left of a difference), the MTP module alone agrees."""
+    lm, params, ids, cfg = moe_model_and_params()
+    with jax.default_matmul_precision("highest"):
+        both = float(lm.apply({"params": params}, ids, return_loss=True))
+        more = float(lm.clone(mtp_lambda=1.3).apply({"params": params}, ids, return_loss=True))
+        rows = [reference_moe.row_losses(params, cfg, row) for row in ids]
+    second = sum(float(r[2]) for r in rows) / sum(r[3] for r in rows)
+    assert abs((more - both) - second) < 1e-5 * second
+    assert rows[0][1] == MOE_N - 1 and rows[0][3] == MOE_N - 2
+
+
+@pytest.mark.parametrize("key,value", [
+    ("rope_scaling", {"type": "yarn", "factor": 40}), ("n_group", 8), ("topk_group", 4),
+    ("attention_bias", True), ("moe_layer_freq", 2), ("scoring_func", "softmax"),
+    ("topk_method", "greedy"), ("norm_topk_prob", False), ("tie_word_embeddings", True),
+    ("rope_interleave", False), ("num_nextn_predict_layers", 2), ("num_key_value_heads", 2),
+    ("model_type", "deepseek_v2"),
+])
+def test_joyai_config_keys_this_model_cannot_run_are_refused(key, value):
+    with pytest.raises(ValueError, match=key):
+        CausalLM.from_config({**MOE_CFG, key: value}, seq_len=MOE_N)
+
+
+def test_from_config_builds_both_families_and_reads_the_share_it_is_given():
+    cell = costs.load_config("joyai-llm-flash-d6-ep16")
+    lm = CausalLM.from_config(cell, seq_len=4096)
+    assert (lm.depth, lm.experts_total, lm.experts_held, lm.experts_per_token) == (6, 256, (0, 16), 8)
+    assert lm.layer_types == ("mla",) * 6 and lm.ff_types == ("dense",) + ("experts",) * 5
+    assert (lm.mla_q_rank, lm.mla_kv_rank, lm.mla_nope_dim, lm.mla_rope_dim, lm.mla_v_dim) == (
+        1536, 512, 128, 64, 128)
+    with pytest.raises(ValueError, match="experts_held"):
+        CausalLM.from_config({**cell, "experts_held": {"range": [0, 32], "of": 256}}, seq_len=4096)
+    # the source's own file, with none of this program's three keys: every expert is held
+    source = {k: v for k, v in cell.items()
+              if k not in ("experts_held", "mtp_loss_weight", "bias_update_speed", "published",
+                           "assumed", "deployment", "reduced")}
+    whole = CausalLM.from_config({**source, "n_routed_experts": 256}, seq_len=4096)
+    assert (whole.experts_total, whole.experts_held) == (256, (0, 256))
+    assert (whole.mtp_lambda, whole.bias_update_speed) == (0.3, 0.001) == (
+        lm.mtp_lambda, lm.bias_update_speed)
+    granite = costs.load_config("granite-4.0-h-micro-d10")
+    assert CausalLM.from_config(granite, seq_len=64).ff_types is None
+    # the count the configuration file states
+    shapes = jax.eval_shape(lm.init, jax.random.key(0), jnp.zeros((1, 4096), jnp.int32))["params"]
+    assert sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(shapes)) == 787_534_848
+
+
+def moe_step_ctx(seed=11, control=None, **mix):
+    base = dict(rows=2, tokens=MOE_N, document_tokens={"min": 4, "max": MOE_N}, mesh={"dp": 1},
+                learning_rate=3e-4, clip_grad_norm=0.5, remat=True, check_steps=3)
+    return types.SimpleNamespace(cfg=MOE_CFG, mix={**base, **mix}, seed=seed, chips=1,
+                                 control=control, facts={})
+
+
+@pytest.fixture(scope="module")
+def moe_reference_three_steps():
+    ctx = moe_step_ctx()
+    _, shapes = driver._build(ctx)
+    fed = [driver.packed_batch(ctx.mix, ctx.cfg, ctx.seed, s) for s in range(3)]
+    with jax.default_matmul_precision("highest"):
+        return moe_driver.reference_steps(ctx, shapes, fed, "f32", keep_gradient=True)
+
+
+def first_gradient_distance(program, ref) -> float:
+    """The driver's ``grad_worst_leaf_distance``: the first gradient element
+    by element, the worst leaf."""
+    median = float(np.median(list(ref["grad"].values())))
+    return max(
+        float(np.linalg.norm(program["grad_leaves"][leaf] - g)) / max(ref["grad"][leaf], median)
+        for leaf, g in ref["grad_leaves"].items()
+    )
+
+
+def entries_apart(program, ref, speed=MOE_CFG["bias_update_speed"]) -> float:
+    """The driver's ``selection_bias_entries_apart``."""
+    ctx = types.SimpleNamespace(
+        cfg={"bias_update_speed": speed}, facts={"limits": {"selection_bias_entries_apart": 1.0}},
+        compare=lambda name, value, limit: ctx.facts.update({name: value}),
+    )
+    moe_driver._entries_apart(ctx, program["bias"], ref["bias"])
+    return ctx.facts["selection_bias_entries_apart"]
+
+
+def test_joyai_three_steps_of_make_train_step_match_the_references_three(moe_reference_three_steps):
+    job = moe_driver.Job(moe_step_ctx())
+    program = job.first_steps()
+    assert job.steps == 3 and int(job.state.step) == 3 and int(job.state.skipped) == 0
+    ref = moe_reference_three_steps
+    gaps = three_step_gaps(program, ref)
+    # the two leaves no optimizer writes are held by their own count
+    assert not any(leaf.endswith(moe_driver.BUFFERS) for leaf in program["change"])
+    assert entries_apart(program, ref) == 0.0
+    assert not over_a_limit(gaps), gaps
+    assert program["pairs"] == moe_reference_three_steps["pairs"]       # no pair dropped
+    assert first_gradient_distance(program, moe_reference_three_steps) < 1e-4
+
+
+@pytest.mark.parametrize("rejected", [False, True], ids=["applied", "rejected"])
+def test_the_step_writes_the_expert_layers_buffers_unless_it_is_rejected(rejected):
+    """``make_train_step(after_update=CausalLM.balance)``: an applied step
+    leaves what it sent every expert in ``tokens_per_expert`` and has moved
+    the selection bias by the speed against it; a step the ``nan_guard``
+    rejects keeps both as they were."""
+    import optax
+    from dalle_pytorch_tpu.parallel import create_train_state, make_runtime, make_train_step
+
+    lm, params, ids, cfg = moe_model_and_params(remat=True)
+    runtime = make_runtime(devices=jax.local_devices()[:1], dp=1)
+    optimizer = optax.chain(optax.clip_by_global_norm(0.5), optax.scale_by_adam())
+    state, shardings = create_train_state(params, optimizer, runtime)
+    step = make_train_step(
+        lambda p, batch, rng: lm.loss_and_loads(p, batch["ids"]), optimizer, runtime, shardings,
+        dynamic_lr=True, after_update=lm.balance, nan_inject_step=0 if rejected else None,
+        donate=False,
+    )
+    new, loss = step(state, {"ids": ids}, jax.random.key(0), jnp.asarray(3e-4))
+    before, after = (s.params["transformer"]["ff_1"]["fn"] for s in (state, new))
+    sent, moved = np.asarray(after["tokens_per_expert"]), np.asarray(
+        after["e_score_correction_bias"] - before["e_score_correction_bias"])
+    if rejected:
+        assert np.isnan(float(loss)) and int(new.skipped) == 1
+        assert not sent.any() and not moved.any()
+    else:
+        assert sent.sum() == ids.size * cfg["num_experts_per_tok"]
+        np.testing.assert_allclose(
+            moved, cfg["bias_update_speed"] * np.sign(sent.mean() - sent), atol=1e-9)
+        assert moved.any()
+
+
+def test_a_selection_bias_that_is_never_moved_fails_by_its_entries(moe_reference_three_steps):
+    """``bias_held``, the program as it stood before it ran the family's
+    rule: every trained leaf agrees and most entries of the bias lie a speed
+    or more from the reference's."""
+    with moe_driver._planted(moe_step_ctx(control="bias_held")):
+        program = moe_driver.Job(moe_step_ctx()).first_steps()
+    assert entries_apart(program, moe_reference_three_steps) > 0.5
+
+
+@pytest.mark.parametrize(
+    "fault", [f for f in moe_driver.PROGRAM_FAULTS if f not in ("bf16_rope", "bias_held")])
+def test_a_fault_planted_in_the_joyai_program_fails_the_three_steps(fault, moe_reference_three_steps):
+    """``bf16_rope`` is not among them: at 40 positions a bfloat16 angle is
+    exact to three digits; tests/test_mla.py holds it at position 4,095."""
+    from dalle_pytorch_tpu.models import lm as lm_module
+    from dalle_pytorch_tpu.ops import moe, rotary
+
+    real = (moe.route, rotary.cos_sin, lm_module.next_ids, lm_module.balanced_bias)
+    with moe_driver._planted(moe_step_ctx(control=fault)):
+        program = moe_driver.Job(moe_step_ctx()).first_steps()
+    gaps = three_step_gaps(program, moe_reference_three_steps)
+    assert over_a_limit(gaps), gaps
+    assert (moe.route, rotary.cos_sin, lm_module.next_ids, lm_module.balanced_bias) == real
+    # element by element the first gradient is far off, whatever its norms say
+    assert first_gradient_distance(program, moe_reference_three_steps) > 0.05
+
+
+def test_half_of_the_tokens_left_out_of_both_joyai_losses_fails(moe_reference_three_steps):
+    ctx = moe_step_ctx()
+    _, shapes = driver._build(ctx)
+    fed = [driver.packed_batch(ctx.mix, ctx.cfg, ctx.seed, s) for s in range(3)]
+    with jax.default_matmul_precision("highest"):
+        half = moe_driver.reference_steps(ctx, shapes, fed, "f32", positions=MOE_N // 2)
+    gaps = three_step_gaps(half, moe_reference_three_steps)
+    assert over_a_limit(gaps) and gaps["grad"] > 0.05, gaps
+
+
+def test_the_joyai_leaves_have_sharding_rules_on_a_cpu_mesh():
+    from jax.sharding import PartitionSpec as P
+    from dalle_pytorch_tpu.parallel import make_runtime, params_shardings, shard_pytree
+    from dalle_pytorch_tpu.parallel.sharding import params_spec_reports
+
+    _, params, _, _ = moe_model_and_params()
+    mesh = make_runtime(dp=1, fsdp=2, tp=2, devices=jax.devices()[:4]).mesh
+    specs = {r["path"]: (r["rule"], r["spec"]) for r in params_spec_reports(params, mesh, min_size=0)}
+    want = {
+        "transformer/mixer_0/fn/to_q_a/kernel": P("fsdp", None),
+        "transformer/mixer_0/fn/to_q_b/kernel": P("fsdp", "tp"),
+        "transformer/mixer_0/fn/to_kv_a/kernel": P("fsdp", None),
+        "transformer/mixer_0/fn/to_kv_b/kernel": P("fsdp", "tp"),
+        "transformer/mixer_0/fn/to_out/kernel": P("tp", "fsdp"),
+        "transformer/ff_0/fn/Dense_0/kernel": P("fsdp", "tp"),
+        "transformer/ff_1/fn/experts_in": P("ep", "fsdp", "tp"),
+        "transformer/ff_1/fn/experts_out": P("ep", "tp", "fsdp"),
+        "transformer/ff_1/fn/gate/kernel": P(None, None),
+        "transformer/ff_1/fn/e_score_correction_bias": P(None),
+        "transformer/ff_1/fn/shared/Dense_0/kernel": P("fsdp", "tp"),
+        "transformer/ff_1/fn/shared/Dense_1/kernel": P("tp", "fsdp"),
+        "nextn/block/ff_0/fn/experts_in": P("ep", "fsdp", "tp"),
+        "nextn/block/mixer_0/fn/to_q_b/kernel": P("fsdp", "tp"),
+        "nextn/eh_proj/kernel": P("fsdp", "tp"),
+        "lm_head": P("fsdp", "tp"),
+        "tok_emb/embedding": P("fsdp", "tp"),
+    }
+    for path, spec in want.items():
+        rule, got = specs[path]
+        assert rule is not None and got == spec, (path, rule, got)
+    # and the leaves lie where the rules say
+    runtime = make_runtime(dp=1, fsdp=2, tp=2, devices=jax.devices()[:4])
+    placed = shard_pytree(params, params_shardings(params, runtime.mesh))
+    head = placed["lm_head"]
+    assert head.sharding.spec == P("fsdp", "tp") and len(head.addressable_shards) == 4
+    assert head.addressable_shards[0].data.shape == (head.shape[0] // 2, head.shape[1] // 2)
+
+
+def test_the_joyai_cell_takes_the_blocked_flash_route_and_the_grouped_products():
+    from dalle_pytorch_tpu.ops import kv_policy
+
+    cell = costs.load_config("joyai-llm-flash-d6-ep16")
+    lm = CausalLM.from_config({**cell, "num_hidden_layers": 2}, seq_len=4096, dtype=jnp.bfloat16)
+    kv_policy.ROUTE_LOG.clear()
+    jax.eval_shape(lm.init, jax.random.key(0), jnp.zeros((1, 4096), jnp.int32))
+    routes = {(r["site"], r["impl"]) for r in kv_policy.ROUTE_LOG}
+    assert routes == {("forward/mla", "blocked_flash"), ("forward/moe_experts", "ragged_dot")}
+
+
+def test_train_lm_cli_trains_saves_and_resumes_the_joyai_family(tmp_path, monkeypatch):
+    """``train_lm.py --config <joyai file>`` goes through the same loop as the
+    granite configuration; with --telemetry it counts what the expert layers
+    held here are sent; the checkpoint carries the share and restores it."""
+    import sys
+    import train_lm
+    from dalle_pytorch_tpu.data import SimpleTokenizer
+    from dalle_pytorch_tpu.utils import TELEMETRY, MetricsLogger, counters, gauges
+    from dalle_pytorch_tpu.utils.checkpoint import load_checkpoint
+
+    docs = tmp_path / "docs"
+    docs.mkdir()
+    for i in range(16):
+        (docs / f"{i}.txt").write_text(" ".join(f"word{(i * 7 + j) % 13}" for j in range(40)))
+    vocab = SimpleTokenizer().vocab_size
+    cfg = {**MOE_CFG, "hidden_size": 32, "vocab_size": vocab, "num_hidden_layers": 2}
+    (tmp_path / "config.json").write_text(json.dumps(cfg))
+    losses = []
+    real_log = MetricsLogger.log
+    monkeypatch.setattr(MetricsLogger, "log", lambda self, logs, step=None: (
+        losses.append(logs["loss"]) if "loss" in logs else None, real_log(self, logs, step=step))[1])
+    out = tmp_path / "lm"
+    argv = ["--config", str(tmp_path / "config.json"), "--image_text_folder", str(docs),
+            "--text_seq_len", "32", "--batch_size", "8", "--epochs", "1", "--remat",
+            "--learning_rate", "3e-3", "--telemetry", "--telemetry_dir", str(tmp_path / "flight"),
+            "--lm_output_file_name", str(out)]
+    before = counters.get("moe.pairs_here")
+    try:
+        monkeypatch.setattr(sys, "argv", ["train_lm.py"] + argv)
+        train_lm.main()
+        assert losses and np.all(np.isfinite(losses))
+        # both losses at the seed: ln(vocab) and 0.3 of it
+        assert losses[0] > 1.3 * np.log(vocab) - 1
+        assert counters.get("moe.pairs_here") > before and gauges.get("moe.load_max_over_mean") >= 1.0
+        _, meta = load_checkpoint(f"{out}.ckpt")
+        assert meta["config"]["ff_types"] == ["dense", "experts"]
+        assert meta["config"]["experts_held"] == [2, 6] and meta["config"]["experts_total"] == 8
+        first = len(losses)
+        monkeypatch.setattr(sys, "argv", ["train_lm.py", "--lm_path", f"{out}.ckpt"] + argv[:-2]
+                            + ["--lm_output_file_name", str(out), "--epochs", "2"])
+        train_lm.main()
+        assert len(losses) > first and losses[first] < losses[0]
+    finally:
+        TELEMETRY.configure(enabled=False)

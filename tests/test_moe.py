@@ -258,3 +258,147 @@ class TestMoEMemoryModes:
         aux1 = sum(jax.tree_util.tree_leaves(mut["moe_aux"]))
         np.testing.assert_allclose(float(out), float(loss1), rtol=1e-5)
         np.testing.assert_allclose(float(aux0), float(aux1), rtol=1e-5)
+
+
+# ------------------------------------------------ the routed-expert layer
+# ops/moe.py:RoutedExperts (sigmoid scores, top-k of ALL experts, a selection
+# bias, a shared expert, told which experts it holds) against the plain
+# reference's DENSE loop (benchmarks/reference_moe.py: every token's weight or
+# zero times every held expert's SwiGLU of every token; imports nothing of the
+# program) for every count a layer can meet.
+
+from benchmarks import reference_moe  # noqa: E402
+from dalle_pytorch_tpu.ops import moe as moe_ops  # noqa: E402
+from dalle_pytorch_tpu.ops.moe import RoutedExperts, balanced_bias, route  # noqa: E402
+
+TOTAL, PER_TOKEN, DIM, WIDTH = 8, 2, 32, 16
+
+
+def routed_layer(held, **over):
+    return RoutedExperts(dim=DIM, hidden=WIDTH, experts_total=TOTAL, experts_held=held,
+                         per_token=PER_TOKEN, shared=1, scaling=2.5, **over)
+
+
+def whole_layer_params(seed=0, bias=None):
+    """The uncut layer's weights: all TOTAL experts."""
+    x = jax.random.normal(jax.random.key(seed), (2, 20, DIM))
+    p = routed_layer((0, TOTAL)).init(jax.random.key(seed + 1), x)["params"]
+    drawn = 0.02 * jax.random.normal(jax.random.key(seed + 2), (TOTAL,))
+    return x, {**p, "e_score_correction_bias": drawn if bias is None else jnp.asarray(bias)}
+
+
+def share_of(p, held):
+    lo, hi = held
+    return {**p, "experts_in": p["experts_in"][lo:hi], "experts_out": p["experts_out"][lo:hi]}
+
+
+def reference_layer(x, p, held):
+    """(output, pairs routed here) of the reference, a sequence at a time."""
+    cfg = dict(num_experts_per_tok=PER_TOKEN, routed_scaling_factor=2.5,
+               n_routed_experts=held[1] - held[0], experts_held={"range": held, "of": TOTAL})
+    with jax.default_matmul_precision("highest"):
+        rows = [reference_moe._experts(row, p, cfg, "f32") for row in x]
+    return jnp.stack([y for y, _ in rows]), sum(int(jnp.sum(n[held[0]:held[1]])) for _, n in rows)
+
+
+def far(bias_on, value):
+    bias = np.zeros(TOTAL, np.float32)
+    bias[list(bias_on)] = value
+    return bias
+
+
+CASES = {
+    "no_pair_routed_here": dict(held=(2, 4), bias=far((2, 3), -10.0), pairs=0),
+    "all_pairs_routed_here": dict(held=(0, TOTAL), bias=None, pairs=2 * 20 * PER_TOKEN),
+    "a_skewed_router": dict(held=(2, 4), bias=far((2,), 10.0), pairs=None),
+    "an_even_router": dict(held=(4, 8), bias=None, pairs=None),
+}
+
+
+@pytest.mark.parametrize("chunk_rows", [None, 8], ids=["one_chunk", "chunks_of_8"])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_routed_experts_match_the_references_dense_loop(case, chunk_rows, monkeypatch):
+    """Output, pair count and every gradient, for every count a layer can
+    meet; in chunks of 8 rows the count passes the bounded buffer and the
+    layer takes its loop over further chunks: exact either way, no pair
+    dropped."""
+    spec = CASES[case]
+    held = spec["held"]
+    x, whole = whole_layer_params(bias=spec["bias"])
+    p = share_of(whole, held)
+    if chunk_rows is not None:
+        monkeypatch.setattr(moe_ops, "HEADROOM", 0.0)
+        monkeypatch.setattr(moe_ops, "CHUNK_MULTIPLE", chunk_rows)
+    layer = routed_layer(held)
+    target = jax.random.normal(jax.random.key(5), x.shape)
+
+    def program(p, x):
+        y, sown = layer.apply({"params": p}, x, mutable=["moe_stats"])
+        return jnp.sum(y * target), (y, sown["moe_stats"]["load"][0])
+
+    with jax.default_matmul_precision("highest"):
+        (_, (y, load)), grads = jax.value_and_grad(program, argnums=(0, 1), has_aux=True)(p, x)
+        want, pairs = reference_layer(x, p, held)
+        want_grads = jax.grad(
+            lambda p, x: jnp.sum(reference_layer(x, p, held)[0] * target), argnums=(0, 1)
+        )(p, x)
+    assert int(jnp.sum(load)) == 2 * 20 * PER_TOKEN           # every pair goes somewhere
+    assert int(jnp.sum(load[held[0]:held[1]])) == pairs
+    if spec["pairs"] is not None:
+        assert pairs == spec["pairs"]
+    if case == "a_skewed_router":
+        assert int(load[2]) == 2 * 20                             # every token chose expert 2
+    assert float(jnp.max(jnp.abs(y - want))) < 2e-5
+    flat, want_flat = jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(want_grads)
+    for g, w in zip(flat, want_flat):
+        assert float(jnp.max(jnp.abs(g - w))) < 1e-4 * (1.0 + float(jnp.max(jnp.abs(w)))), g.shape
+    assert not np.any(np.asarray(grads[0]["e_score_correction_bias"]))   # chooses, never trained
+
+
+def test_the_shares_partial_results_add_up_to_the_uncut_layer():
+    """The share test of the model-configs guide, section 4: four chips hold
+    two experts each; their partial results, with the shared expert (which
+    every chip computes alike) counted once, are the uncut reference layer."""
+    x, whole = whole_layer_params(seed=3)
+    uncut, pairs = reference_layer(x, whole, (0, TOTAL))
+    assert pairs == 2 * 20 * PER_TOKEN
+    with jax.default_matmul_precision("highest"):
+        shared = jnp.stack([
+            reference_moe._swiglu(row, whole["shared"]["Dense_0"]["kernel"],
+                                  whole["shared"]["Dense_1"]["kernel"], "f32") for row in x
+        ])
+        shares = [(lo, lo + 2) for lo in range(0, TOTAL, 2)]
+        parts = [routed_layer(held).apply({"params": share_of(whole, held)}, x) for held in shares]
+    total = sum(part - shared for part in parts) + shared
+    assert float(jnp.max(jnp.abs(total - uncut))) < 5e-5
+    # and no share alone is the layer
+    assert float(jnp.max(jnp.abs(parts[0] - uncut))) > 1e-2
+
+
+def test_the_selection_bias_moves_against_the_load():
+    """``noaux_tc``: down by the speed where an expert was sent more than the
+    mean, up where fewer, unmoved at the mean; the reference's rule gives the
+    same."""
+    bias = jnp.asarray([0.5, -0.5, 0.0, 0.25])
+    load = jnp.asarray([30, 10, 20, 20])
+    np.testing.assert_allclose(
+        np.asarray(balanced_bias(bias, load, 0.01)), [0.49, -0.49, 0.0, 0.25], rtol=1e-6
+    )
+    flat = {("l", "e_score_correction_bias"): bias}
+    reference_moe.balance(flat, {"l": load}, 0.01)
+    np.testing.assert_allclose(
+        np.asarray(flat[("l", "e_score_correction_bias")]), [0.49, -0.49, 0.0, 0.25], rtol=1e-6
+    )
+    np.testing.assert_array_equal(np.asarray(flat[("l", "tokens_per_expert")]), np.asarray(load))
+
+
+def test_the_weights_are_normalised_over_all_the_chosen_and_the_bias_only_chooses():
+    scores = jax.nn.sigmoid(jax.random.normal(jax.random.key(0), (50, TOTAL)))
+    bias = jnp.asarray(far((5,), 10.0))
+    chosen, weights = route(scores, bias, PER_TOKEN, 2.5)
+    assert bool(jnp.all(jnp.any(chosen == 5, axis=-1)))           # the bias chose expert 5
+    np.testing.assert_allclose(np.asarray(jnp.sum(weights, -1)), 2.5, rtol=1e-6)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)         # the scores, not scores + bias
+    np.testing.assert_allclose(
+        np.asarray(weights), np.asarray(2.5 * picked / picked.sum(-1, keepdims=True)), rtol=1e-6
+    )

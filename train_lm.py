@@ -1,9 +1,11 @@
 #!/usr/bin/env python
 """Causal language-model training CLI, TPU-native.
 
-Trains ``models/lm.py:CausalLM`` — a stack of Mamba-2 state-space and
-grouped-KV attention layers described by a ``config.json`` in its source's own
-keys (``--config``) — on a folder of ``.txt`` documents packed end to end,
+Trains ``models/lm.py:CausalLM`` — a stack described by a ``config.json`` in
+its source's own keys (``--config``), by ``model_type``: Mamba-2 state-space
+and grouped-KV attention layers (``granitemoehybrid``), or latent attention
+with routed experts, a shared expert and a multi-token-prediction module
+(``joyai_llm_flash``) — on a folder of ``.txt`` documents packed end to end,
 with the app surface of train_clip.py and train_dalle.py's loop
 (``parallel/loop.py``): compiled sharded train step over a dp x fsdp x tp mesh
 (``make_runtime`` → ``create_train_state`` → ``make_train_step``), one
@@ -28,8 +30,9 @@ import optax
 def parse_args():
     parser = argparse.ArgumentParser(description="Train a causal language model on TPU")
     parser.add_argument("--config", type=str, required=True,
-                        help="the model's config.json (the source's own keys: "
-                             "hidden_size, layer_types, mamba_*, *_multiplier, ...)")
+                        help="the model's config.json (the source's own keys, by its "
+                             "model_type: hidden_size, layer_types, mamba_*, ... or "
+                             "q_lora_rank, n_routed_experts, first_k_dense_replace, ...)")
     parser.add_argument("--image_text_folder", type=str, required=True,
                         help="folder whose .txt files are the documents (images, if any, are ignored)")
     parser.add_argument("--lm_path", type=str, default=None,
@@ -84,10 +87,19 @@ def build_step(lm, params, runtime, clip_grad_norm: float):
     )
     state, shardings = create_train_state(params, optimizer, runtime)
 
+    # expert layers: the step also writes what they were sent and moves their
+    # selection bias against it (CausalLM.balance)
+    balances = "experts" in (lm.ff_types or ())
+
     def loss_fn(p, batch, rng):
+        if balances:
+            return lm.loss_and_loads(p, batch["ids"])
         return lm.apply({"params": p}, batch["ids"], return_loss=True)
 
-    step_fn = make_train_step(loss_fn, optimizer, runtime, shardings, dynamic_lr=True)
+    step_fn = make_train_step(
+        loss_fn, optimizer, runtime, shardings, dynamic_lr=True,
+        after_update=lm.balance if balances else None,
+    )
     return state, shardings, step_fn
 
 
@@ -111,7 +123,7 @@ def main():
         save_lm_checkpoint,
     )
     from dalle_pytorch_tpu.parallel import TrainLoop, init_distributed, make_runtime, shard_pytree
-    from dalle_pytorch_tpu.utils import TELEMETRY, MetricsLogger, Throughput
+    from dalle_pytorch_tpu.utils import TELEMETRY, MetricsLogger, Throughput, counters, gauges
 
     init_distributed()
     runtime = make_runtime(fsdp=args.fsdp, tp=args.tp)
@@ -195,12 +207,19 @@ def main():
     save(start_epoch - 1)  # pre-flight: fail fast on misconfiguration
 
     throughput = Throughput(window=10)
+    routing_stats = jax.jit(lm.routing_stats)
     for epoch in range(start_epoch, args.epochs):
         for _, _, loss in loop.epoch(epoch, loader):
             global_step = loop.global_step
             if global_step % 10 == 0:
                 logger.log({"loss": float(loss), "epoch": epoch}, step=global_step)
                 logger.log_text(f"step {global_step}: loss={float(loss):.4f} epoch={epoch}")
+                if args.telemetry:
+                    # what the step sent the expert layers held here ({} without them)
+                    stats = routing_stats(loop.state.params)
+                    if stats:
+                        counters.inc("moe.pairs_here", int(stats["moe.pairs_here"]))
+                        gauges.set("moe.load_max_over_mean", float(stats["moe.load_max_over_mean"]))
             rate = throughput.update(args.batch_size * lm.seq_len)
             if rate is not None:
                 logger.log({"tokens_per_sec": rate}, step=global_step)
