@@ -21,8 +21,9 @@ import flax.linen as nn
 
 import functools
 
+from ..ops import kv_policy
 from ..ops.attention import GroupedKVAttention, LatentAttention, PatternAttention
-from ..ops.flash_attention import StaticTable
+from ..ops.flash_attention import KERNEL_RESIDUAL_NAMES, StaticTable
 from ..ops.layers import (
     FeedForward,
     GMLPBlock,
@@ -64,6 +65,18 @@ def _interned_rotary(data: bytes, shape: tuple) -> StaticTable:
     return StaticTable(np.frombuffer(data, dtype=np.float32).reshape(shape))
 
 
+def _block_checkpoint(fn):
+    """The one ``jax.checkpoint`` the trunk puts around a block under
+    ``remat``: everything is rebuilt in backward but the attention kernels'
+    own residuals (``KERNEL_RESIDUAL_NAMES``: quadratic in the row length to
+    rebuild, linear to keep), so the rebuilt forward holds no flash kernel —
+    all of its results are in memory and XLA drops the call."""
+    kv_policy.record_route("remat/attn_residuals", "saved")
+    return jax.checkpoint(
+        fn, policy=jax.checkpoint_policies.save_only_these_names(*KERNEL_RESIDUAL_NAMES)
+    )
+
+
 class Transformer(nn.Module):
     """Depth-wise composition of attention + GEGLU feed-forward blocks.
 
@@ -74,8 +87,8 @@ class Transformer(nn.Module):
 
     Execution modes: sequential (default), ``reversible=True`` (O(1)
     activation memory via ops/reversible.py), or ``remat=True``
-    (jax.checkpoint per block — recompute in backward, standard pytree
-    activations).
+    (``_block_checkpoint`` per block — recompute in backward, standard pytree
+    activations; only the flash kernels' output and log-sum-exp are kept).
 
     Block variants (models/lm.py's causal language models; every DALL-E and
     CLIP configuration leaves them at their defaults, which are the block
@@ -479,9 +492,9 @@ class Transformer(nn.Module):
         if self.remat and not self.reversible:
             aux = jnp.zeros((), jnp.float32)
             for ind, ((f, g), (pf, pg), (kwf, kwg)) in enumerate(zip(fns, params, kwargs)):
-                d, a = jax.checkpoint(f)(pf, x, kwf)
+                d, a = _block_checkpoint(f)(pf, x, kwf)
                 x = x + d
-                dg, ag = jax.checkpoint(g)(pg, x, kwg)
+                dg, ag = _block_checkpoint(g)(pg, x, kwg)
                 x = x + dg
                 if isinstance(ag, tuple):
                     # what an expert layer sowed (``moe_stats``: the pairs it
@@ -612,7 +625,7 @@ class Transformer(nn.Module):
             # honor --remat inside the pipeline: recompute each layer's
             # activations in backward instead of storing them across the
             # n_micro + pp - 1 scan ticks
-            layer_fn = jax.checkpoint(layer_fn)
+            layer_fn = _block_checkpoint(layer_fn)
 
         p_specs = jax.tree_util.tree_map(lambda _: P(self.pp_axis), stacked)
         x_spec = P()  # batch stays auto-sharded over dp/fsdp by GSPMD

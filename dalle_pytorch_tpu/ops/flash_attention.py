@@ -44,6 +44,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -51,6 +52,20 @@ from .rotary import cos_sin
 
 NEG_INF = -1e30
 LANES = 128
+
+# the names a block's checkpoint keeps (models/transformer.py:_block_checkpoint,
+# docs/DESIGN.md section 9): a forward kernel here costs O(n^2) to run again
+# and its two results cost O(n) to hold
+KERNEL_RESIDUAL_NAMES = ("attn_kernel_out", "attn_kernel_lse")
+
+
+def _name_residuals(o, lse):
+    """A forward kernel's two results under ``KERNEL_RESIDUAL_NAMES``, as the
+    residuals hold them. BOTH are named: they come from one ``pallas_call``
+    and one unsaved output keeps the whole call alive in the recomputed
+    forward. Outside a checkpoint the name lowers to nothing."""
+    out_name, lse_name = KERNEL_RESIDUAL_NAMES
+    return checkpoint_name(o, out_name), checkpoint_name(lse, lse_name)
 
 
 class StaticMask:
@@ -510,7 +525,9 @@ def flash_attention(
 
 
 def _fwd_rule(q, k, v, key_mask, causal, pattern_mask, sm_scale, block_q, block_k, interpret):
-    o, lse = _flash_fwd(q, k, v, key_mask, causal, pattern_mask, sm_scale, block_q, block_k, interpret)
+    o, lse = _name_residuals(*_flash_fwd(
+        q, k, v, key_mask, causal, pattern_mask, sm_scale, block_q, block_k, interpret
+    ))
     return o, (q, k, v, key_mask, o, lse)
 
 
@@ -1059,9 +1076,9 @@ def fused_qkv_attention(
 
 
 def _fused_fwd_rule(qkv, key_mask, heads, dim_head, rot, causal, pattern_mask, sm_scale, interpret):
-    o, lse = _fused_qkv_fwd(
+    o, lse = _name_residuals(*_fused_qkv_fwd(
         qkv, key_mask, heads, dim_head, rot, causal, pattern_mask, sm_scale, interpret
-    )
+    ))
     return o, (qkv, key_mask, o, lse)
 
 

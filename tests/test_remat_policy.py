@@ -1,0 +1,200 @@
+"""``remat=True`` and the attention kernels (models/transformer.py:
+``_block_checkpoint``): a block's checkpoint rebuilds everything in backward
+but the flash kernels' output and log-sum-exp, which it keeps by name
+(ops/flash_attention.py:``KERNEL_RESIDUAL_NAMES``). Held here, on the CPU with
+interpreted kernels at tiny widths: the gradient's jaxpr holds each attention
+forward kernel once a layer where the bare ``jax.checkpoint`` held it twice;
+the gradients are the bare checkpoint's to the bit and ``remat=False``'s to
+rounding; the pipeline path and a mesh of several devices (the kernels then
+run inside ``ops/attention.py:_per_device``'s shard_map) keep the count; and
+the route ``remat/attn_residuals`` says the rule engaged."""
+
+import collections
+import json
+import pathlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from benchmarks import costs
+from dalle_pytorch_tpu.models import DALLE
+from dalle_pytorch_tpu.models import transformer
+from dalle_pytorch_tpu.models.lm import CausalLM
+from dalle_pytorch_tpu.ops import kv_policy
+from dalle_pytorch_tpu.ops.flash_attention import KERNEL_RESIDUAL_NAMES
+from dalle_pytorch_tpu.parallel import make_runtime
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+N = 128     # the smallest row the flash kernels take: one block
+DEPTH = 2
+ROUTE = {"site": "remat/attn_residuals", "impl": "saved", "interpret": None}
+
+GQA = dict(
+    model_type="granitemoehybrid",
+    hidden_size=64, num_attention_heads=8, num_key_value_heads=2, vocab_size=50,
+    shared_intermediate_size=96, num_hidden_layers=DEPTH, layer_types=["attention"] * DEPTH,
+    attention_multiplier=0.2, embedding_multiplier=12, residual_multiplier=0.22,
+    logits_scaling=8, rms_norm_eps=1e-5, mamba_n_heads=8, mamba_d_head=16,
+    mamba_d_state=8, mamba_d_conv=4, mamba_chunk_size=8, mamba_expand=2,
+)
+# the rehearsal's tiny joyai: a dense block, an expert block and the MTP
+# module's block, each with a latent-attention layer
+MLA = {
+    **costs.load_config("joyai-llm-flash-d6-ep16"),
+    **json.loads((ROOT / "benchmarks/rehearsal_moe.json").read_text())["config"],
+    "num_hidden_layers": DEPTH,
+}
+DALLE_FULL = dict(
+    dim=128, depth=DEPTH, num_text_tokens=64, text_seq_len=64, num_image_tokens=32,
+    image_fmap_size=8, heads=2, dim_head=64, attn_types=("full",),
+)
+# family -> the attention forward kernel its layers call on one device, and
+# how many attention layers the stack has
+FWD_KERNEL = {"gqa": "flash_fwd", "mla": "flash_fwd", "full": "flash_qkv_fwd"}
+LAYERS = {"gqa": DEPTH, "mla": DEPTH + 1, "full": DEPTH}
+
+
+def build(family, remat, **over):
+    """(loss of the parameters, parameters) of a tiny stack of ``family``."""
+    if family == "full":
+        model = DALLE(**{**DALLE_FULL, **over}, remat=remat)
+        text = jax.random.randint(jax.random.key(1), (4, 64), 1, 64)
+        image = jax.random.randint(jax.random.key(2), (4, 64), 0, 32)
+        params = model.init(jax.random.key(0), text[:1], image[:1])["params"]
+        return lambda p: model.apply({"params": p}, text, image, return_loss=True), params
+    model = CausalLM.from_config({"gqa": GQA, "mla": MLA}[family], seq_len=N, remat=remat)
+    ids = jax.random.randint(jax.random.key(1), (4, N), 0, 50)      # both vocabularies hold 50
+    params = model.init(jax.random.key(0), ids)["params"]
+    return lambda p: model.apply({"params": p}, ids, return_loss=True), params
+
+
+def kernel_calls(jaxpr, into=None) -> collections.Counter:
+    """How often each named ``pallas_call`` stands in ``jaxpr``, the jaxprs
+    of its checkpoints, shard_maps, scans and custom rules included."""
+    into = collections.Counter() if into is None else into
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            into[eqn.params["name"]] += 1
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            kernel_calls(inner, into)
+    return into
+
+
+def grad_kernel_calls(loss, params) -> collections.Counter:
+    return kernel_calls(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+
+
+def bare_checkpoint(monkeypatch):
+    """From here on the trunk builds the checkpoint it used before: nothing kept."""
+    monkeypatch.setattr(transformer, "_block_checkpoint", jax.checkpoint)
+
+
+def leaves(tree):
+    return jax.tree_util.tree_leaves(tree)
+
+
+def assert_equal_to_rounding(got, want):
+    for a, b in zip(leaves(got), leaves(want)):
+        scale = float(jnp.max(jnp.abs(b))) + 1e-12
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-5 * scale, a.shape
+
+
+@pytest.mark.parametrize("family", ["gqa", "mla", "full"])
+def test_the_gradient_holds_each_attention_forward_kernel_once_a_layer(family):
+    kv_policy.ROUTE_LOG.clear()
+    calls = grad_kernel_calls(*build(family, remat=True))
+    assert calls[FWD_KERNEL[family]] == LAYERS[family], calls
+    assert calls == grad_kernel_calls(*build(family, remat=False)), calls
+    assert ROUTE in kv_policy.ROUTE_LOG
+
+
+@pytest.mark.parametrize("family", ["gqa", "mla", "full"])
+def test_the_bare_checkpoint_held_it_twice(family, monkeypatch):
+    """What the count above is compared with: the test cannot pass because
+    the counter sees nothing inside a checkpoint."""
+    bare_checkpoint(monkeypatch)
+    kv_policy.ROUTE_LOG.clear()
+    calls = grad_kernel_calls(*build(family, remat=True))
+    assert calls[FWD_KERNEL[family]] == 2 * LAYERS[family], calls
+    assert ROUTE not in kv_policy.ROUTE_LOG
+
+
+@pytest.mark.parametrize("family", ["gqa", "mla", "full"])
+def test_gradients_are_the_bare_checkpoints_to_the_bit(family, monkeypatch):
+    """The kept arrays are the ones the rebuilt forward would have produced.
+    Operation by operation, not under one ``jit``: there XLA fuses the two
+    programs' interpreted kernels differently on the CPU and the last bit of
+    a sum moves with the fusion, not with the checkpoint."""
+    loss, params = build(family, remat=True)
+    kept = jax.value_and_grad(loss)(params)
+    bare_checkpoint(monkeypatch)
+    bare = jax.value_and_grad(loss)(params)
+    for a, b in zip(leaves(kept), leaves(bare)):
+        assert a.dtype == b.dtype and bool(jnp.all(a == b)), a.shape
+
+
+@pytest.mark.parametrize("family", ["gqa", "mla", "full"])
+def test_gradients_match_no_remat_to_rounding(family):
+    kept = jax.jit(jax.value_and_grad(build(family, remat=True)[0]))
+    plain_loss, params = build(family, remat=False)
+    assert_equal_to_rounding(kept(params), jax.jit(jax.value_and_grad(plain_loss))(params))
+
+
+def test_the_names_lower_to_nothing_outside_a_checkpoint():
+    """``remat=False`` (the DALL-E cell): the forward rule's two names are
+    identities, and the lowered program holds no trace of them."""
+    loss, params = build("full", remat=False)
+    names = [
+        eqn.params["name"] for eqn in jax.make_jaxpr(jax.grad(loss))(params).jaxpr.eqns
+        if eqn.primitive.name == "name"
+    ]
+    assert sorted(names) == sorted(KERNEL_RESIDUAL_NAMES * DEPTH)
+    text = jax.jit(jax.grad(loss)).lower(params).as_text()
+    assert not any(name in text for name in KERNEL_RESIDUAL_NAMES)
+
+
+# ------------------------------------------- several devices, and the pipeline
+
+
+@pytest.mark.parametrize("family,mesh,kernel", [
+    ("gqa", {"fsdp": 2, "tp": 2}, "flash_fwd"),
+    ("mla", {"fsdp": 2, "tp": 2}, "flash_fwd"),
+    ("full", {"fsdp": 2, "tp": 2}, "flash_fwd"),      # tp > 1: the per-head kernels
+    ("full", {}, "flash_qkv_fwd"),                    # dp = 4: the packed kernel
+], ids=["gqa_fsdp2_tp2", "mla_fsdp2_tp2", "full_fsdp2_tp2", "full_dp4"])
+def test_the_count_holds_through_the_per_device_shard_map(family, mesh, kernel, monkeypatch):
+    """The checkpoint's partial evaluation sees the names through the
+    shard_map the kernels run in on a mesh of several devices."""
+    rt = make_runtime(devices=jax.devices()[:4], **mesh)
+    loss, params = build(family, remat=True)
+    with rt.activate():
+        kept = grad_kernel_calls(loss, params)
+        bare_checkpoint(monkeypatch)
+        bare = grad_kernel_calls(loss, params)
+    assert kept[kernel] == LAYERS[family] and bare[kernel] == 2 * LAYERS[family], (kept, bare)
+
+
+def test_the_pipeline_path_takes_the_same_helper(monkeypatch):
+    """``_pp_forward`` stacks the layers and scans one ``layer_fn`` over the
+    schedule: the kernel stands once in the jaxpr for the forward pass and,
+    under the bare checkpoint, once more for the backward pass's rebuild."""
+    rt = make_runtime(devices=jax.devices()[:4], pp=2)
+    loss, params = build("full", remat=True, pp_axis="pp", pp_microbatches=2)
+    kv_policy.ROUTE_LOG.clear()
+    with rt.activate():
+        kept = grad_kernel_calls(loss, params)
+        assert ROUTE in kv_policy.ROUTE_LOG
+        bare_checkpoint(monkeypatch)
+        bare = grad_kernel_calls(loss, params)
+    assert kept["flash_qkv_fwd"] == 1 and bare["flash_qkv_fwd"] == 2, (kept, bare)
+    assert kept["flash_qkv_bwd"] == bare["flash_qkv_bwd"] == 1
+
+
+def test_gradients_on_a_mesh_match_one_device():
+    loss, params = build("gqa", remat=True)
+    want = jax.jit(jax.value_and_grad(loss))(params)
+    rt = make_runtime(devices=jax.devices()[:4], fsdp=2, tp=2)
+    with rt.activate():
+        got = jax.jit(jax.value_and_grad(loss))(params)
+    assert_equal_to_rounding(got, want)
