@@ -14,8 +14,13 @@ nothing renamed, by ``model_type``: ``granitemoehybrid`` (``hidden_size``,
 ``num_hidden_layers`` entries of ``layer_types`` run) and ``joyai_llm_flash``
 (the DeepSeek-V3 family's keys: ``q_lora_rank``, ``kv_lora_rank``,
 ``qk_*_head_dim``, ``n_routed_experts``, ``first_k_dense_replace``,
-``num_nextn_predict_layers``, …). What a key asks that is not written here is
-refused, not ignored.
+``num_nextn_predict_layers``, …) and ``qwen3_next`` (``full_attention_interval``,
+``linear_*``, ``partial_rotary_factor``, ``num_experts``,
+``shared_expert_intermediate_size``, …: linear-attention layers with a
+corrected, delta-rule state (ops/gdn.py) three to one with gated softmax
+attention, softmax-routed experts with a gated shared expert and the family's
+load-balance loss). What a key asks that is not written here is refused, not
+ignored.
 
 Training and whole-sequence evaluation only; serving a stack with
 recurrent-state layers is ROADMAP R13, one with latent attention R11, and a
@@ -79,7 +84,17 @@ class CausalLM(nn.Module):
     experts_hidden: int = 0
     experts_shared: int = 1
     experts_scaling: float = 1.0
+    experts_scoring: str = "sigmoid"
+    experts_gate_shared: bool = False
+    aux_loss_coef: float = 0.0
     bias_update_speed: float = 0.0
+    linattn_key_heads: int = 16
+    linattn_value_heads: int = 32
+    linattn_key_dim: int = 128
+    linattn_value_dim: int = 128
+    linattn_conv: int = 4
+    attn_rotary_dim: int = 0
+    attn_rope_theta: float = 10000.0
     tie_head: bool = True
     mtp_lambda: Optional[float] = None
     remat: bool = False
@@ -131,12 +146,15 @@ class CausalLM(nn.Module):
             )
 
     def _block_sizes(self) -> Dict[str, Any]:
-        """The latent-attention and expert sizes the trunk and the MTP
+        """The mixers' and the expert layer's sizes the trunk and the MTP
         module's one block share."""
         names = (
             "mla_q_rank", "mla_kv_rank", "mla_nope_dim", "mla_rope_dim", "mla_v_dim",
             "mla_rope_theta", "experts_total", "experts_held", "experts_per_token",
-            "experts_hidden", "experts_shared", "experts_scaling",
+            "experts_hidden", "experts_shared", "experts_scaling", "experts_scoring",
+            "experts_gate_shared", "linattn_key_heads", "linattn_value_heads",
+            "linattn_key_dim", "linattn_value_dim", "linattn_conv", "attn_rotary_dim",
+            "attn_rope_theta",
         )
         return {name: getattr(self, name) for name in names}
 
@@ -196,46 +214,80 @@ class CausalLM(nn.Module):
 
     def loss_and_loads(self, params, ids: jnp.ndarray):
         """(the loss, what every expert layer sowed: the pairs it sent each of
-        ALL experts): a train step's ``loss_fn`` beside ``balance``."""
+        ALL experts and, from a softmax router, each expert's mean
+        probability): a train step's ``loss_fn`` beside ``balance``. With
+        ``aux_loss_coef`` the loss is the cross-entropy plus that many times
+        the family's load-balance term (``aux_loss``); ``__call__`` alone
+        returns the cross-entropy."""
         loss, sown = self.apply(
             {"params": params}, ids, return_loss=True, mutable=["moe_stats"]
         )
+        if self.aux_loss_coef:
+            loads, probs = _sown(sown["moe_stats"])
+            loss = loss + self.aux_loss_coef * self.aux_loss(
+                [loads[layer] for layer in sorted(probs)], [probs[layer] for layer in sorted(probs)]
+            )
         return loss, sown["moe_stats"]
 
-    def balance(self, params, loads):
+    def aux_loss(self, loads, probs):
+        """``E sum_e f_e P_e`` over the expert layers TOGETHER (the family's
+        code concatenates their tokens): ``f_e`` the share of (layer, token)s
+        that chose expert ``e`` among their ``k`` (not divided by ``k``: a
+        uniform router reads ``k``), ``P_e`` the mean probability the router
+        gave it. ``loads``: a layer's pairs sent each expert, ``probs``: its
+        mean probability, each a sequence of (experts,) over the layers
+        (every layer sees every token). The gradient reaches the routers
+        through ``P`` only."""
+        load = jnp.sum(jnp.stack(loads), axis=0).astype(jnp.float32)
+        share = load * self.experts_per_token / jnp.maximum(jnp.sum(load), 1.0)   # 0 before a step
+        mean = jnp.mean(jnp.stack(probs).astype(jnp.float32), axis=0)
+        return self.experts_total * jnp.sum(share * mean)
+
+    def balance(self, params, sown):
         """A train step's ``after_update`` (``parallel/step.py``): every
         expert layer's (the MTP module's included) ``tokens_per_expert`` takes
-        the pairs the step sent each expert, and its selection bias moves by
-        ``bias_update_speed`` against them (``ops/moe.py:balanced_bias``)."""
+        the pairs the step sent each expert; where the layer has a selection
+        bias it moves by ``bias_update_speed`` against them
+        (``ops/moe.py:balanced_bias``), and where its router is a softmax
+        ``router_prob`` takes the mean probability the router gave each
+        expert. No leaf the optimizer trains is touched."""
         flat = traverse_util.flatten_dict(params)
-        for path, (load,) in traverse_util.flatten_dict(loads).items():
-            layer = path[:-1]
-            bias = flat[layer + ("e_score_correction_bias",)]
-            flat[layer + ("e_score_correction_bias",)] = balanced_bias(
-                bias, load, self.bias_update_speed
-            )
-            flat[layer + ("tokens_per_expert",)] = load.astype(bias.dtype)
+        loads, probs = _sown(sown)
+        for layer, load in loads.items():
+            if layer + ("e_score_correction_bias",) in flat:
+                flat[layer + ("e_score_correction_bias",)] = balanced_bias(
+                    flat[layer + ("e_score_correction_bias",)], load, self.bias_update_speed
+                )
+            buffer = flat[layer + ("tokens_per_expert",)]
+            flat[layer + ("tokens_per_expert",)] = load.astype(buffer.dtype)
+            if layer in probs:
+                flat[layer + ("router_prob",)] = probs[layer].astype(buffer.dtype)
         return traverse_util.unflatten_dict(flat)
 
     def routing_stats(self, params) -> Dict[str, jnp.ndarray]:
         """What the last step sent the expert layers, read from their
         ``tokens_per_expert``: ``moe.pairs_here``, the (token, expert) pairs
-        routed to experts held here, summed over the layers, and
+        routed to experts held here, summed over the layers,
         ``moe.load_max_over_mean``, the fullest expert's pairs over the mean
-        expert's, the worst layer's, over ALL experts. {} for a model without
-        expert layers."""
+        expert's, the worst layer's, over ALL experts, and where the routers
+        keep ``router_prob``, ``moe.aux_loss``, the last step's load-balance
+        term (``aux_loss``). {} for a model without expert layers."""
         flat = traverse_util.flatten_dict(params)
         loads = [flat[path] for path in sorted(flat) if path[-1] == "tokens_per_expert"]
         if not loads:
             return {}
         loads = jnp.stack(loads).astype(jnp.float32)                # (layers, experts)
         lo, hi = self.experts_held or (0, self.experts_total)
-        return {
+        stats = {
             "moe.pairs_here": jnp.sum(loads[:, lo:hi]).astype(jnp.int32),
             "moe.load_max_over_mean": jnp.max(
                 jnp.max(loads, axis=1) / jnp.maximum(jnp.mean(loads, axis=1), 1.0)
             ),
         }
+        probs = [flat[path] for path in sorted(flat) if path[-1] == "router_prob"]
+        if probs:
+            stats["moe.aux_loss"] = self.aux_loss(loads, probs)
+        return stats
 
 
 class MultiTokenPrediction(nn.Module):
@@ -283,6 +335,16 @@ class MultiTokenPrediction(nn.Module):
             return norm("final_norm", x).astype(self.dtype)
 
 
+def _sown(moe_stats) -> tuple:
+    """What the expert layers sowed, by layer path: ({layer: the pairs sent
+    each of ALL experts}, {layer: each expert's mean probability}; the
+    second only from softmax routers)."""
+    loads, probs = {}, {}
+    for path, (value,) in traverse_util.flatten_dict(moe_stats).items():
+        {"load": loads, "prob": probs}[path[-1]][path[:-1]] = value
+    return loads, probs
+
+
 # ------------------------------------------------------------- the families
 
 
@@ -290,6 +352,19 @@ def _refuse(cfg: dict, only: dict) -> None:
     for key, value in only.items():
         if cfg.get(key, value) != value:
             raise ValueError(f"{key}={cfg[key]!r}: only {value!r} is written here")
+
+
+def _share(cfg: dict, count_key: str) -> tuple:
+    """(lo, hi, total) of ``experts_held`` (``{"range": [lo, hi], "of":
+    total}``: this program is one chip's share of an expert-parallel
+    deployment, ``count_key`` then counts the experts HELD, ``hi - lo``, and
+    the router scores ``total``); all are held where the key is absent."""
+    held = cfg[count_key]
+    share = cfg.get("experts_held", {"range": (0, held), "of": held})
+    (lo, hi), total = share["range"], share["of"]
+    if hi - lo != held or not 0 <= lo < hi <= total:
+        raise ValueError(f"experts_held={share} is not {held} of its experts")
+    return int(lo), int(hi), total
 
 
 def _granite_fields(cfg: dict) -> dict:
@@ -334,11 +409,7 @@ def _joyai_fields(cfg: dict) -> dict:
         "qk_head_dim": cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"],
     })
     depth, dense = cfg["num_hidden_layers"], cfg["first_k_dense_replace"]
-    held = cfg["n_routed_experts"]
-    share = cfg.get("experts_held", {"range": (0, held), "of": held})
-    (lo, hi), total = share["range"], share["of"]
-    if hi - lo != held or not 0 <= lo < hi <= total:
-        raise ValueError(f"experts_held={share} is not {held} of its experts")
+    lo, hi, total = _share(cfg, "n_routed_experts")
     return dict(
         vocab_size=cfg["vocab_size"], dim=cfg["hidden_size"], depth=depth,
         layer_types=("mla",) * depth,
@@ -348,7 +419,7 @@ def _joyai_fields(cfg: dict) -> dict:
         mla_q_rank=cfg["q_lora_rank"], mla_kv_rank=cfg["kv_lora_rank"],
         mla_nope_dim=cfg["qk_nope_head_dim"], mla_rope_dim=cfg["qk_rope_head_dim"],
         mla_v_dim=cfg["v_head_dim"], mla_rope_theta=float(cfg["rope_theta"]),
-        experts_total=total, experts_held=(int(lo), int(hi)),
+        experts_total=total, experts_held=(lo, hi),
         experts_per_token=cfg["num_experts_per_tok"],
         experts_hidden=cfg["moe_intermediate_size"],
         experts_shared=cfg["n_shared_experts"],
@@ -358,4 +429,49 @@ def _joyai_fields(cfg: dict) -> dict:
     )
 
 
-_FAMILIES = {"granitemoehybrid": _granite_fields, "joyai_llm_flash": _joyai_fields}
+def _qwen3_next_fields(cfg: dict) -> dict:
+    """``qwen3_next``: the source's own keys, and two it does not have, each
+    with a default: ``experts_held`` (as ``_joyai_fields``: ``num_experts``
+    then counts the experts HELD and the router scores ``of``) and
+    ``router_aux_loss_coef`` (0.001, the family's published default). Layer
+    ``l`` is ``full_attention`` where ``(l + 1) % full_attention_interval ==
+    0`` and ``linear_attention`` elsewhere (or as ``layer_types`` says, where
+    the file has them); every feed-forward is the expert layer, so
+    ``intermediate_size`` is read by nothing."""
+    _refuse(cfg, {
+        "mlp_only_layers": [], "decoder_sparse_step": 1, "rope_scaling": None,
+        "use_sliding_window": False, "norm_topk_prob": True, "hidden_act": "silu",
+        "tie_word_embeddings": False, "attention_bias": False,
+    })
+    depth, every = cfg["num_hidden_layers"], cfg["full_attention_interval"]
+    kinds = cfg.get("layer_types") or [
+        "full_attention" if (l + 1) % every == 0 else "linear_attention" for l in range(depth)
+    ]
+    lo, hi, total = _share(cfg, "num_experts")
+    shared, width = cfg["shared_expert_intermediate_size"], cfg["moe_intermediate_size"]
+    if shared % width:
+        raise ValueError(f"shared_expert_intermediate_size={shared} is not whole experts of {width}")
+    rotary = int(cfg["head_dim"] * cfg["partial_rotary_factor"])
+    return dict(
+        vocab_size=cfg["vocab_size"], dim=cfg["hidden_size"], depth=depth,
+        layer_types=tuple(kinds[:depth]), ff_types=("experts",) * depth,
+        heads=cfg["num_attention_heads"], kv_heads=cfg["num_key_value_heads"],
+        dim_head=cfg["head_dim"], ff_hidden=cfg["intermediate_size"],
+        norm_eps=cfg["rms_norm_eps"], attn_rotary_dim=rotary,
+        attn_rope_theta=float(cfg["rope_theta"]),
+        linattn_key_heads=cfg["linear_num_key_heads"],
+        linattn_value_heads=cfg["linear_num_value_heads"],
+        linattn_key_dim=cfg["linear_key_head_dim"],
+        linattn_value_dim=cfg["linear_value_head_dim"],
+        linattn_conv=cfg["linear_conv_kernel_dim"],
+        experts_total=total, experts_held=(lo, hi),
+        experts_per_token=cfg["num_experts_per_tok"], experts_hidden=width,
+        experts_shared=shared // width, experts_scoring="softmax", experts_gate_shared=True,
+        aux_loss_coef=float(cfg.get("router_aux_loss_coef", 0.001)), tie_head=False,
+    )
+
+
+_FAMILIES = {
+    "granitemoehybrid": _granite_fields, "joyai_llm_flash": _joyai_fields,
+    "qwen3_next": _qwen3_next_fields,
+}

@@ -22,7 +22,7 @@ import flax.linen as nn
 import functools
 
 from ..ops import kv_policy
-from ..ops.attention import GroupedKVAttention, LatentAttention, PatternAttention
+from ..ops.attention import GatedAttention, GroupedKVAttention, LatentAttention, PatternAttention
 from ..ops.flash_attention import KERNEL_RESIDUAL_NAMES, StaticTable
 from ..ops.layers import (
     FeedForward,
@@ -36,6 +36,7 @@ from ..ops.layers import (
 from ..ops.moe import MoEFeedForward, RoutedExperts
 from ..ops.reversible import reversible_forward_only, reversible_sequence
 from ..ops.rotary import angles, dalle_rotary_table, lang_freqs
+from ..ops.gdn import GatedDeltaNet
 from ..ops.ssm import MambaMixer
 
 Dtype = Any
@@ -45,6 +46,7 @@ ATTENTION_TYPES = ("full", "axial_row", "axial_col", "conv_like", "sparse", "mlp
 # layer type -> (layer kind, device scope)
 MIXER_TYPES = {
     "mamba": ("mamba", "ssm"), "attention": ("gqa", "attn.gqa"), "mla": ("mla", "attn.mla"),
+    "linear_attention": ("gdn", "linattn"), "full_attention": ("gated", "attn.gated"),
 }
 # ``ff_types``: a layer_types stack's feed-forward kind, layer by layer.
 # kind -> device scope
@@ -97,7 +99,11 @@ class Transformer(nn.Module):
     ``attention`` (grouped-KV causal attention over ``kv_heads`` with the
     softmax scale ``attn_scale``, no positional term) or ``mla`` (latent
     attention, ops/attention.py:LatentAttention, sized by ``mla_*``: its own
-    rotary key, nothing of ``rotary_emb``); ``ff_types`` gives each layer of
+    rotary key, nothing of ``rotary_emb``) or ``linear_attention`` (the gated
+    delta rule, ops/gdn.py:GatedDeltaNet, sized by ``linattn_*``) or
+    ``full_attention`` (ops/attention.py:GatedAttention: grouped-KV attention
+    with per-head norms, rotary over ``attn_rotary_dim`` channels and an
+    output gate); ``ff_types`` gives each layer of
     such a stack its feed-forward, ``dense`` (the SwiGLU of ``ff_hidden``) or
     ``experts`` (ops/moe.py:RoutedExperts, sized by ``experts_*``, under the
     device scope ``moe``); ``norm='rmsnorm'``
@@ -161,6 +167,15 @@ class Transformer(nn.Module):
     experts_hidden: int = 0
     experts_shared: int = 1
     experts_scaling: float = 1.0
+    experts_scoring: str = "sigmoid"
+    experts_gate_shared: bool = False
+    linattn_key_heads: int = 16
+    linattn_value_heads: int = 32
+    linattn_key_dim: int = 128
+    linattn_value_dim: int = 128
+    linattn_conv: int = 4
+    attn_rotary_dim: int = 0
+    attn_rope_theta: float = 10000.0
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
 
@@ -248,7 +263,8 @@ class Transformer(nn.Module):
                     experts_total=self.experts_total,
                     experts_held=tuple(self.experts_held or (0, self.experts_total)),
                     per_token=self.experts_per_token, shared=self.experts_shared,
-                    scaling=self.experts_scaling, dtype=self.dtype,
+                    scaling=self.experts_scaling, scoring=self.experts_scoring,
+                    gate_shared=self.experts_gate_shared, dtype=self.dtype,
                     param_dtype=self.param_dtype,
                 )
             elif self.ff_act == "swiglu":
@@ -356,6 +372,20 @@ class Transformer(nn.Module):
                 kv_rank=self.mla_kv_rank, nope_dim=self.mla_nope_dim,
                 rope_dim=self.mla_rope_dim, v_dim=self.mla_v_dim,
                 rope_theta=self.mla_rope_theta, eps=self.norm_eps,
+                use_flash=self.use_flash, dtype=self.dtype, param_dtype=self.param_dtype,
+            )
+        if kind == "gdn":
+            return GatedDeltaNet(
+                dim=self.dim, key_heads=self.linattn_key_heads,
+                value_heads=self.linattn_value_heads, key_dim=self.linattn_key_dim,
+                value_dim=self.linattn_value_dim, conv=self.linattn_conv,
+                eps=self.norm_eps, dtype=self.dtype, param_dtype=self.param_dtype,
+            )
+        if kind == "gated":
+            return GatedAttention(
+                dim=self.dim, heads=self.heads, kv_heads=self.kv_heads or self.heads,
+                dim_head=self.dim_head, rotary_dim=self.attn_rotary_dim,
+                rope_theta=self.attn_rope_theta, eps=self.norm_eps,
                 use_flash=self.use_flash, dtype=self.dtype, param_dtype=self.param_dtype,
             )
         return GroupedKVAttention(

@@ -43,6 +43,7 @@ from .flash_attention import (
     fused_qkv_supported,
 )
 from .layers import RMSNorm, stable_softmax
+from . import rotary
 from .rotary import angles, apply_rotary_emb, lang_freqs
 
 
@@ -1399,6 +1400,27 @@ class PatternAttention(nn.Module):
     # flat-vs-4-D policy in _decode_caches (batch 8: +38% tokens/sec).
 
 
+def _causal_attend(q, k, v, scale: float, use_flash: bool, site: str):
+    """Causal softmax attention over (b, h, n, d) with as many key/value as
+    query heads: the blocked flash kernels where the length has a usable block
+    (``_flash_block``), one dense masked softmax elsewhere; which, at the
+    route site ``site``."""
+    n = q.shape[2]
+    block = _flash_block(n) if use_flash else 0
+    if block:
+        interpret = kv_policy.pallas_interpret()
+        kv_policy.record_route(site, "blocked_flash", interpret)
+        return _per_device(
+            lambda q, k, v: flash_attention(
+                q, k, v, None, True, None, scale, block, block, interpret
+            ),
+            (q, k, v),
+        )
+    kv_policy.record_route(site, "dense_masked")
+    causal = jnp.tril(jnp.ones((n, n), bool))
+    return dense_attend(q * scale, k, v, causal)
+
+
 class GroupedKVAttention(nn.Module):
     """Causal softmax attention with fewer key/value heads than query heads
     and no positional term: query head ``i`` attends key/value head
@@ -1436,23 +1458,87 @@ class GroupedKVAttention(nn.Module):
         k, v = (
             jnp.repeat(kv[:, :, i].transpose(0, 2, 1, 3), h // g, axis=1) for i in (0, 1)
         )
-        block = _flash_block(n) if self.use_flash else 0
-        if block:
-            interpret = kv_policy.pallas_interpret()
-            kv_policy.record_route("forward/gqa", "blocked_flash", interpret)
-            scale = float(self.sm_scale)
-            out = _per_device(
-                lambda q, k, v: flash_attention(
-                    q, k, v, None, True, None, scale, block, block, interpret
-                ),
-                (q, k, v),
-            )
-        else:
-            kv_policy.record_route("forward/gqa", "dense_masked")
-            causal = jnp.tril(jnp.ones((n, n), bool))
-            out = dense_attend(q * self.sm_scale, k, v, causal)
+        out = _causal_attend(q, k, v, float(self.sm_scale), self.use_flash, "forward/gqa")
         out = out.transpose(0, 2, 1, 3).reshape(b, n, h * d)
         return dense(self.dim, "to_out")(out)
+
+
+@functools.lru_cache(maxsize=None)
+def _half_split_angles(n: int, rot_dim: int, theta: float) -> np.ndarray:
+    """(n, rot_dim / 2) float32 rotary angles of positions 0 … n-1 for the
+    HALF-SPLIT pairing: channel ``c`` turns with channel ``c + rot_dim / 2``."""
+    return np.einsum(
+        "i,j->ij", np.arange(n, dtype=np.float64), lang_freqs(rot_dim, theta)
+    ).astype(np.float32)
+
+
+def rotate_half_split(t, rot_dim: int, theta: float):
+    """Rotary over the first ``rot_dim`` channels of (b, h, n, d), pairing
+    channel ``c`` with ``c + rot_dim / 2`` (the pairing of the ``qwen3_next``
+    family; ``ops/rotary.py`` pairs adjacent channels); the channels past
+    ``rot_dim`` pass untouched. Cosine and sine of the float32 angles
+    (``ops/rotary.py:cos_sin``)."""
+    half = rot_dim // 2
+    cos, sin = rotary.cos_sin(jnp.asarray(_half_split_angles(t.shape[2], rot_dim, float(theta))), t.dtype)
+    a, b = t[..., :half], t[..., half:rot_dim]
+    return jnp.concatenate((a * cos - b * sin, b * cos + a * sin, t[..., rot_dim:]), axis=-1)
+
+
+def output_gate(out, gate):
+    """``out * sigmoid(gate)``, element by element, in the dtype of ``out``."""
+    return out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
+
+
+class GatedAttention(nn.Module):
+    """The ``qwen3_next`` family's softmax attention: grouped key/value heads,
+    a per-head RMSNorm of queries and keys, rotary over the first
+    ``rotary_dim`` channels (half-split pairs) and an element-wise output gate
+    that comes out of the query projection. No bias.
+
+        [q_i | g_i] = (W_q u)_i;   k_j = (W_k u)_j,  v_j = (W_v u)_j
+        q_i <- rot(RMSNorm(q_i)),  k_j <- rot(RMSNorm(k_j))
+        o_i = softmax_causal(q_i . k_{i // group} / sqrt(d)) v_{i // group}
+        y = W_o [o . sigmoid(g)]
+
+    The norms' gains start at 1 (the source writes ``1 + w`` with ``w`` from
+    0: the same function and the same Adam trajectory). Training route as
+    ``GroupedKVAttention``: the key/value heads broadcast in front of the
+    blocked flash kernels, recorded at ``forward/gated_attn``; no decode mode
+    (ROADMAP R9)."""
+
+    dim: int
+    heads: int
+    kv_heads: int
+    dim_head: int
+    rotary_dim: int
+    rope_theta: float = 10000.0
+    eps: float = 1e-6
+    use_flash: bool = True
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, deterministic: bool = True) -> jnp.ndarray:
+        # ``deterministic``: the trunk's uniform half-block argument; no dropout here
+        b, n, _ = x.shape
+        h, g, d = self.heads, self.kv_heads, self.dim_head
+        assert h % g == 0, f"{h} query heads over {g} key/value heads"
+        dense = lambda features, name: nn.Dense(
+            features, use_bias=False, name=name, dtype=self.dtype,
+            param_dtype=self.param_dtype,
+        )
+        norm = lambda name, t: RMSNorm(self.eps, self.param_dtype, name=name)(t).astype(self.dtype)
+        heads_first = lambda t: t.transpose(0, 2, 1, 3)
+        qg = dense(h * 2 * d, "to_q")(x).reshape(b, n, h, 2 * d)
+        q, gate = qg[..., :d], qg[..., d:].reshape(b, n, h * d)
+        k = dense(g * d, "to_k")(x).reshape(b, n, g, d)
+        v = dense(g * d, "to_v")(x).reshape(b, n, g, d)
+        q = rotate_half_split(heads_first(norm("q_norm", q)), self.rotary_dim, self.rope_theta)
+        k = rotate_half_split(heads_first(norm("k_norm", k)), self.rotary_dim, self.rope_theta)
+        k, v = (jnp.repeat(t, h // g, axis=1) for t in (k, heads_first(v)))
+        out = _causal_attend(q, k, v, float(d**-0.5), self.use_flash, "forward/gated_attn")
+        out = heads_first(out).reshape(b, n, h * d)
+        return dense(self.dim, "to_out")(output_gate(out, gate))
 
 
 @functools.lru_cache(maxsize=None)
@@ -1523,20 +1609,6 @@ class LatentAttention(nn.Module):
         )
         v = kv[..., dn:]
 
-        scale = float((dn + dr) ** -0.5)
-        block = _flash_block(n) if self.use_flash else 0
-        if block:
-            interpret = kv_policy.pallas_interpret()
-            kv_policy.record_route("forward/mla", "blocked_flash", interpret)
-            out = _per_device(
-                lambda q, k, v: flash_attention(
-                    q, k, v, None, True, None, scale, block, block, interpret
-                ),
-                (q, k, v),
-            )
-        else:
-            kv_policy.record_route("forward/mla", "dense_masked")
-            causal = jnp.tril(jnp.ones((n, n), bool))
-            out = dense_attend(q * scale, k, v, causal)
+        out = _causal_attend(q, k, v, float((dn + dr) ** -0.5), self.use_flash, "forward/mla")
         out = heads_first(out).reshape(b, n, h * dv)
         return dense(self.dim, "to_out")(out)

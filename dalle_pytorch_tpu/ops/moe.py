@@ -125,6 +125,12 @@ def route(scores, bias, per_token: int, scaling: float):
     return chosen, scaling * picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
 
 
+def shared_gate(shared, logit):
+    """The shared expert's output times ``sigmoid`` of its gate's one logit a
+    token (the ``qwen3_next`` family), in the dtype of ``shared``."""
+    return shared * jax.nn.sigmoid(logit.astype(jnp.float32)).astype(shared.dtype)
+
+
 def balanced_bias(bias, load, speed: float):
     """The selection bias after a step that sent each expert ``load`` pairs
     (``topk_method`` ``noaux_tc``, DeepSeek-V3 report section 2.1.2): down by
@@ -217,6 +223,16 @@ class RoutedExperts(nn.Module):
     loads this layer sows): ``tokens_per_expert`` takes the pairs the step sent
     each of ALL experts, and the bias moves against them (``balanced_bias``).
 
+    ``scoring='softmax'`` is the ``qwen3_next`` family's router in the same
+    layer: ``s = softmax(W_g u)`` over all experts, the top-k of ``s`` itself,
+    the weights normalised over the chosen, NO selection bias (the leaf is not
+    made) and no scaling; beside the loads it sows each expert's mean
+    probability (``prob``), from which the model forms the family's
+    load-balance loss (``models/lm.py:CausalLM.loss_and_loads``), and
+    ``router_prob`` is the buffer that keeps the last step's mean.
+    ``gate_shared`` multiplies the shared expert by ``sigmoid(w_sg . u)``
+    (``shared_gate``), one logit a token.
+
     No pair is dropped and nothing is a capacity. The (token, expert) pairs
     of the held experts are sorted by expert (index arrays of the worst-case
     length ``tokens * per_token``: integers) and the experts are two grouped
@@ -242,6 +258,8 @@ class RoutedExperts(nn.Module):
     per_token: int
     shared: int = 1
     scaling: float = 1.0
+    scoring: str = "sigmoid"
+    gate_shared: bool = False
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
 
@@ -266,11 +284,18 @@ class RoutedExperts(nn.Module):
                 total, use_bias=False, dtype=jnp.float32, param_dtype=self.param_dtype,
                 precision=jax.lax.Precision.HIGH, name="gate",
             )(u.astype(jnp.float32))
-            scores = jax.nn.sigmoid(logits)
-            bias = self.param(
-                "e_score_correction_bias", nn.initializers.zeros, (total,), self.param_dtype
-            )
-            chosen, weights = route(scores, bias, k, self.scaling)
+            assert self.scoring in ("sigmoid", "softmax"), self.scoring
+            if self.scoring == "softmax":
+                scores = jax.nn.softmax(logits, axis=-1)
+                chosen, weights = route(scores, jnp.zeros((total,), scores.dtype), k, self.scaling)
+                self.sow("moe_stats", "prob", jnp.mean(scores, axis=0))
+                self.param("router_prob", nn.initializers.zeros, (total,), self.param_dtype)
+            else:
+                scores = jax.nn.sigmoid(logits)
+                bias = self.param(
+                    "e_score_correction_bias", nn.initializers.zeros, (total,), self.param_dtype
+                )
+                chosen, weights = route(scores, bias, k, self.scaling)
             # pairs sent to each of ALL experts: CausalLM.balance reads it
             experts = jnp.arange(total, dtype=chosen.dtype)
             self.sow("moe_stats", "load", jnp.sum(chosen.reshape(-1, 1) == experts, axis=0))
@@ -313,5 +338,10 @@ class RoutedExperts(nn.Module):
                 dim=d, hidden=self.hidden * self.shared, dtype=self.dtype,
                 param_dtype=self.param_dtype, name="shared",
             )(x) if self.shared else 0.0
+            if self.gate_shared:
+                shared = shared_gate(shared, nn.Dense(
+                    1, use_bias=False, dtype=self.dtype, param_dtype=self.param_dtype,
+                    name="shared_gate",
+                )(x))
         with jax.named_scope("moe.combine"):
             return y.astype(self.dtype).reshape(b, n, d) + shared

@@ -836,10 +836,14 @@ def conv_silu(x, kernel, bias, sizes: tuple, dtype: Dtype = jnp.float32) -> tupl
     activated in float32 and split along the channels into pieces of
     ``sizes``: the kernel pair where the shape is eligible
     (``ssm_conv_kernel_eligible``), XLA everywhere else; which of the two, at
-    the route site ``forward/ssm_conv``."""
+    the route site ``forward/ssm_conv``. ``bias`` None: a convolution without
+    one, the same kernels over zeros made at trace time (their cotangent is
+    dropped)."""
     from .attention import _per_device  # the one shard_map rule of every Mosaic call
 
     b, n, c = x.shape
+    if bias is None:
+        bias = jnp.zeros((c,), jnp.float32)
     width, sizes = kernel.shape[0], tuple(sizes)
     if not ssm_conv_kernel_eligible(n, sizes, width):
         kv_policy.record_route("forward/ssm_conv", "xla")
@@ -865,11 +869,13 @@ def _log_uniform(lo: float, hi: float):
 
 
 class CausalConv1D(nn.Module):
-    """``conv_silu`` with its (width, channels) kernel and its bias."""
+    """``conv_silu`` with its (width, channels) kernel and, unless
+    ``use_bias`` is off, its bias."""
 
     width: int
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self, x, sizes):
@@ -877,7 +883,10 @@ class CausalConv1D(nn.Module):
         kernel = self.param(
             "kernel", nn.initializers.lecun_normal(), (self.width, c), self.param_dtype
         )
-        bias = self.param("bias", nn.initializers.zeros, (c,), self.param_dtype)
+        bias = (
+            self.param("bias", nn.initializers.zeros, (c,), self.param_dtype)
+            if self.use_bias else None
+        )
         return conv_silu(x, kernel, bias, sizes, self.dtype)
 
 

@@ -42,7 +42,8 @@ DEFAULT_RULES: Tuple[Tuple[str, P], ...] = (
     (r"to_out/kernel(_q)?$", P("tp", "fsdp")),
     # grouped-KV attention (ops/attention.py:GroupedKVAttention): q and the
     # fused k|v split their output features like to_qkv; to_out is above
-    (r"to_(q|kv)/kernel$", P("fsdp", "tp")),
+    # (ops/attention.py:GatedAttention keeps k and v apart: the same layout)
+    (r"to_(q|k|v|kv)/kernel$", P("fsdp", "tp")),
     # latent attention (ops/attention.py:LatentAttention): the expansions
     # split their heads (output features) like to_q; the compressions' small
     # outputs (a latent, and the one rotary key every head shares) stay whole
@@ -53,6 +54,10 @@ DEFAULT_RULES: Tuple[Tuple[str, P], ...] = (
     # channels (z | x B C | dt), out its input channels
     (r"in_proj/kernel$", P("fsdp", "tp")),
     (r"out_proj/kernel$", P("tp", "fsdp")),
+    # gated delta rule (ops/gdn.py): q | k | v | z split their output channels
+    # like in_proj, out_proj is above; the 2 x heads columns of b | a stay whole
+    (r"in_proj_qkvz/kernel$", P("fsdp", "tp")),
+    (r"in_proj_ba/kernel$", P("fsdp", None)),
     # MoE experts: expert dim over ep, hidden over tp (ops/moe.py)
     # (RoutedExperts holds the experts of its own range: the same two leaves
     # at the same places; its router, its selection bias and its count of
@@ -60,7 +65,8 @@ DEFAULT_RULES: Tuple[Tuple[str, P], ...] = (
     (r"experts_in$", P("ep", "fsdp", "tp")),
     (r"experts_out$", P("ep", "tp", "fsdp")),
     (r"gate/kernel$", P(None, None)),
-    (r"(e_score_correction_bias|tokens_per_expert)$", P(None)),
+    (r"(e_score_correction_bias|tokens_per_expert|router_prob)$", P(None)),
+    (r"shared_gate/kernel$", P(None, None)),
     (r"spatial_weight$", P(None, None)),
     # GEGLU FF / gMLP channel projections: up-projection splits hidden over
     # tp, down-projection splits input — matched by position inside any

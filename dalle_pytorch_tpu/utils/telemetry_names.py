@@ -229,6 +229,9 @@ COUNTERS = frozenset({
 GAUGES = frozenset({
     # the fullest expert's pairs over the mean expert's, the worst layer's
     "moe.load_max_over_mean",
+    # the last step's load-balance term E sum_e f_e P_e of a softmax-routed
+    # model (models/lm.py:CausalLM.routing_stats; a uniform router reads k)
+    "moe.aux_loss",
     "serve.pool_occupancy",
     "serve.running",
     "serve.prefilling",
@@ -321,6 +324,17 @@ DEVICE_SCOPES = frozenset({
     "moe.experts",
     "moe.combine",
     "moe.shared",
+    # the gated delta rule's mixer (ops/gdn.py:GatedDeltaNet) with its parts:
+    # the projections in and out; the causal convolution and its silu; the
+    # gates, the L2 norms and the chunked rule; the gated per-head norm. And
+    # the gated softmax attention of the same family
+    # (ops/attention.py:GatedAttention), read with every other attn.*
+    "linattn",
+    "linattn.proj",
+    "linattn.conv",
+    "linattn.delta",
+    "linattn.norm",
+    "attn.gated",
     # the multi-token-prediction module's own projection, norms and shifted
     # embedding (models/lm.py); its block runs under attn.mla and moe
     "mtp",
@@ -355,6 +369,8 @@ KERNEL_NAMES = frozenset({
     "ssd_chunk_bwd",
     "ssm_conv_fwd",         #   the causal convolution, its bias and its silu
     "ssm_conv_bwd",
+    "gdn_chunk_fwd",        # ops/gdn.py: the gated delta rule, a chunk a grid step,
+    "gdn_chunk_bwd",        #   the state (backward: its cotangent) in VMEM scratch
 })
 
 # span durations are auto-observed as "<span>_s" (utils/telemetry.py);

@@ -125,7 +125,45 @@ def _latent(q, k, v):
     return out
 
 
+# train-qwen3next-d4-ep16-s8k: 2 rows of 8192, 16 key and 32 value heads of
+# 128, chunks of 64; the convolution over q | k | v WITHOUT a bias; gated
+# attention on the blocked flash kernels at head 256
+GDN_B, GDN_N, GDN_HK, GDN_HV, GDN_D, GDN_CHUNK = 2, 8192, 16, 32, 128, 64
+GDN_TABLE = ((GDN_B, GDN_HV, GDN_N // GDN_CHUNK, GDN_CHUNK), F32)
+GDN_CONV = (2 * GDN_HK + GDN_HV) * GDN_D
+
+
+def _delta(q, k, v, g, beta):
+    # the call of ``gdn.gated_delta_rule``, compiled not interpreted
+    from dalle_pytorch_tpu.ops import gdn
+
+    return gdn.delta_rule_chunks(q, k, v, -jnp.abs(g), jax.nn.sigmoid(beta), GDN_HK, False)
+
+
+def _conv_no_bias(qkv, taps):
+    sizes = (GDN_HK * GDN_D, GDN_HK * GDN_D, GDN_HV * GDN_D)
+    assert ssm.ssm_conv_kernel_eligible(GDN_N, sizes, 4)
+    pieces = ssm.ssm_conv(qkv, taps, jnp.zeros((GDN_B, 1, GDN_CONV), F32), sizes, False)
+    return jnp.concatenate(pieces, axis=-1)
+
+
+def _gated(q, k, v):
+    return flash_attention(q, k, v, None, True, None, 256**-0.5, 1024, 1024, False)
+
+
 ROUTES = {
+    "delta_rule": (
+        _delta,
+        [(GDN_B, GDN_N, GDN_HK * GDN_D)] * 2 + [(GDN_B, GDN_N, GDN_HV * GDN_D), GDN_TABLE, GDN_TABLE],
+        {"gdn_chunk_fwd", "gdn_chunk_bwd"},
+    ),
+    "ssm_conv_no_bias": (
+        _conv_no_bias, [(GDN_B, GDN_N, GDN_CONV), ((GDN_B, 4, GDN_CONV), F32)],
+        {"ssm_conv_fwd", "ssm_conv_bwd"},
+    ),
+    "gated_flash_head256": (
+        _gated, [(GDN_B, 16, GDN_N, 256)] * 3, {"flash_fwd", "flash_dq", "flash_dkv"},
+    ),
     "ssm_conv": (
         _conv,
         [(1, SSM_N, SSM_CONV), ((1, 4, SSM_CONV), F32), ((1, 1, SSM_CONV), F32)],

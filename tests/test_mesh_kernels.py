@@ -170,3 +170,48 @@ def test_state_space_kernels_under_a_mesh_match_single_device(n, conv):
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for a, b in zip(jax.tree_util.tree_leaves(got_g), jax.tree_util.tree_leaves(want_g)):
         np.testing.assert_allclose(a, b, atol=2e-4 * float(jnp.max(jnp.abs(b))) + 1e-6, rtol=2e-4)
+
+
+# ---- the gated delta rule's kernels (ops/gdn.py) go through the same wrapper
+
+
+def _delta_loss(dtype):
+    from dalle_pytorch_tpu.ops.gdn import GatedDeltaNet
+
+    # the smallest mixer the kernels take: keys and values of one lane tile
+    mixer = GatedDeltaNet(dim=128, key_heads=1, value_heads=2, key_dim=128, value_dim=128,
+                          chunk=16, dtype=dtype)
+    return mixer, lambda p, v: (mixer.apply(p, v).astype(jnp.float32) ** 2).sum()
+
+
+@pytest.mark.parametrize("mesh", [{}, {"fsdp": 2, "tp": 2}], ids=["dp4", "fsdp2_tp2"])
+def test_delta_rule_kernels_lower_for_tpu_under_a_mesh(compiled_branch, mesh):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dalle_pytorch_tpu.parallel.context import batch_axes
+
+    mixer, loss = _delta_loss(jnp.bfloat16)
+    rt = make_runtime(devices=jax.devices()[:4], **mesh)
+    rows = NamedSharding(rt.mesh, P(batch_axes(rt.mesh)))
+    v = jax.ShapeDtypeStruct((4, 256, 128), jnp.bfloat16, sharding=rows)
+    with rt.activate():
+        params = jax.eval_shape(mixer.init, jax.random.key(0), v)
+        traced = jax.jit(jax.value_and_grad(loss)).trace(params, v)
+        text = traced.lower(lowering_platforms=("tpu",)).as_text()
+    # the rule and the convolution without a bias, forward and backward
+    assert text.count("tpu_custom_call") >= 4
+
+
+def test_delta_rule_kernels_under_a_mesh_match_single_device():
+    mixer, loss = _delta_loss(jnp.float32)
+    v = jax.random.normal(jax.random.key(0), (4, 40, 128))
+    params = mixer.init(jax.random.key(1), v)
+    want, want_g = jax.value_and_grad(loss)(params, v)
+    rt = make_runtime(devices=jax.devices()[:4], fsdp=2, tp=2)
+    kv_policy.ROUTE_LOG.clear()
+    with rt.activate():
+        got, got_g = jax.jit(jax.value_and_grad(loss))(params, v)
+    assert {"site": "forward/delta_rule", "impl": "gdn_chunk", "interpret": True} in kv_policy.ROUTE_LOG
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(got_g), jax.tree_util.tree_leaves(want_g)):
+        np.testing.assert_allclose(a, b, atol=2e-4 * float(jnp.max(jnp.abs(b))) + 1e-6, rtol=2e-4)

@@ -258,18 +258,19 @@ def test_causal_depthwise_convolution():
 def xla_form(x, k, b, sizes, dtype=jnp.float32):
     """``causal_conv1d`` + ``silu`` + the split: what ``conv_silu`` computes
     at every shape its predicate refuses, and the oracle of its kernels."""
+    b = jnp.zeros((x.shape[-1],)) if b is None else b     # a convolution without a bias
     y = jax.nn.silu(ssm.causal_conv1d(x, k, b)).astype(dtype)
     return tuple(jnp.split(y, np.cumsum(sizes[:-1]), axis=-1))
 
 
-def conv_inputs(b, n, sizes, width, seed=0, dtype=jnp.float32):
+def conv_inputs(b, n, sizes, width, seed=0, dtype=jnp.float32, with_bias=True):
     ks = jax.random.split(jax.random.key(seed), 4)
     c = sum(sizes)
     x = jax.random.normal(ks[0], (b, n, c)).astype(dtype)
     k = jax.random.normal(ks[1], (width, c)) * 0.5
     bias = jax.random.normal(ks[2], (c,))
     cotangent = jax.random.normal(ks[3], (b, n, c))
-    return (x, k, bias), cotangent
+    return (x, k, bias if with_bias else None), cotangent
 
 
 def conv_value_and_grads(f, args, cotangent, sizes, dtype=jnp.float32):
@@ -279,7 +280,8 @@ def conv_value_and_grads(f, args, cotangent, sizes, dtype=jnp.float32):
         whole = jnp.concatenate(f(*a, sizes, dtype), axis=-1).astype(jnp.float32)
         return jnp.sum(whole * cotangent), whole
 
-    (_, whole), grads = jax.value_and_grad(weighted, argnums=(0, 1, 2), has_aux=True)(*args)
+    operands = tuple(i for i, a in enumerate(args) if a is not None)
+    (_, whole), grads = jax.value_and_grad(weighted, argnums=operands, has_aux=True)(*args)
     return whole, grads
 
 
@@ -303,15 +305,17 @@ CONV_SHAPES = {
     # strip after keeps rows for
     "rest_strip_taps8": (1, 2 * R, (ssm.STRIP[1] + 128, 256), ssm.AFTER),
     "taps2": (2, 2 * R, (128, 128), 2),
+    # the second user (ops/gdn.py): q | k | v, and NO bias (a fifth entry)
+    "q_k_v_no_bias": (1, R, (256, 256, 512), 4, False),
 }
 
 
 @pytest.mark.parametrize("shape", sorted(CONV_SHAPES))
 def test_the_convolutions_kernels_follow_the_xla_form_in_float32(shape):
-    b, n, sizes, width = CONV_SHAPES[shape]
+    b, n, sizes, width, *no_bias = CONV_SHAPES[shape]
     assert ssm.ssm_conv_kernel_eligible(n, sizes, width)
     kv_policy.ROUTE_LOG.clear()
-    args, cotangent = conv_inputs(b, n, sizes, width)
+    args, cotangent = conv_inputs(b, n, sizes, width, with_bias=not no_bias)
     assert_follows_the_xla_form(args, cotangent, sizes)
     assert kv_policy.ROUTE_LOG == [
         {"site": "forward/ssm_conv", "impl": "ssm_conv", "interpret": True}

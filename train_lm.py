@@ -5,7 +5,9 @@ Trains ``models/lm.py:CausalLM`` — a stack described by a ``config.json`` in
 its source's own keys (``--config``), by ``model_type``: Mamba-2 state-space
 and grouped-KV attention layers (``granitemoehybrid``), or latent attention
 with routed experts, a shared expert and a multi-token-prediction module
-(``joyai_llm_flash``) — on a folder of ``.txt`` documents packed end to end,
+(``joyai_llm_flash``), or gated-delta-rule linear attention beside gated
+softmax attention with softmax-routed experts and a gated shared expert
+(``qwen3_next``) — on a folder of ``.txt`` documents packed end to end,
 with the app surface of train_clip.py and train_dalle.py's loop
 (``parallel/loop.py``): compiled sharded train step over a dp x fsdp x tp mesh
 (``make_runtime`` → ``create_train_state`` → ``make_train_step``), one
@@ -32,7 +34,8 @@ def parse_args():
     parser.add_argument("--config", type=str, required=True,
                         help="the model's config.json (the source's own keys, by its "
                              "model_type: hidden_size, layer_types, mamba_*, ... or "
-                             "q_lora_rank, n_routed_experts, first_k_dense_replace, ...)")
+                             "q_lora_rank, n_routed_experts, first_k_dense_replace, ... or "
+                             "full_attention_interval, linear_*, num_experts, ...)")
     parser.add_argument("--image_text_folder", type=str, required=True,
                         help="folder whose .txt files are the documents (images, if any, are ignored)")
     parser.add_argument("--lm_path", type=str, default=None,
@@ -87,8 +90,8 @@ def build_step(lm, params, runtime, clip_grad_norm: float):
     )
     state, shardings = create_train_state(params, optimizer, runtime)
 
-    # expert layers: the step also writes what they were sent and moves their
-    # selection bias against it (CausalLM.balance)
+    # expert layers: the step also writes what they were sent and, where they
+    # have one, moves their selection bias against it (CausalLM.balance)
     balances = "experts" in (lm.ff_types or ())
 
     def loss_fn(p, batch, rng):
@@ -220,6 +223,8 @@ def main():
                     if stats:
                         counters.inc("moe.pairs_here", int(stats["moe.pairs_here"]))
                         gauges.set("moe.load_max_over_mean", float(stats["moe.load_max_over_mean"]))
+                        if "moe.aux_loss" in stats:
+                            gauges.set("moe.aux_loss", float(stats["moe.aux_loss"]))
             rate = throughput.update(args.batch_size * lm.seq_len)
             if rate is not None:
                 logger.log({"tokens_per_sec": rate}, step=global_step)
