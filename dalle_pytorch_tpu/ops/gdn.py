@@ -1,5 +1,5 @@
 """Gated delta rule linear attention (Gated DeltaNet): the mixer, its chunked
-form in XLA and as a Pallas kernel pair.
+form in XLA and as three Pallas kernels.
 
 The recurrence, per value head (keys ``d_k``, values ``d_v``; state ``S``:
 ``d_k x d_v``, zero before the sequence), with ``g_t <= 0`` and ``0 <= beta_t
@@ -22,20 +22,44 @@ the chunk, ``D_ij = exp(G_i - G_j)``):
 ``G``, its exponentials and ``T`` are float32 whatever the compute dtype (a
 rounded table of large arguments is what broke rotary: PERF.md section 7);
 the products run in the compute dtype with float32 accumulation. ``T`` is the
-inverse of a unit lower-triangular matrix: ``A`` is nilpotent, so
-``(I + A)^-1 = (I - A)(I + A^2)(I + A^4) ...`` exactly, ``log2 C - 1``
-squarings and as many products, which the MXU takes (float32 at ``HIGHEST``).
+inverse of a unit lower-triangular matrix, built from products the MXU takes
+(float32 at ``HIGHEST``), exactly: ``A`` is nilpotent, so the diagonal blocks
+of 8 are ``(I - A)(I + A^2)(I + A^4)``, and a block twice the size follows
+from two of half the size by ``T <- T - T L T`` with ``L`` the block between
+them (``_unit_lower_inverse``).
 
 Two forms of one algorithm, chosen from the shape
 (``delta_rule_kernels_eligible``; no switch) and recorded at the route site
-``forward/delta_rule``. Where keys and values are whole lane tiles it is
-``gdn_chunk_fwd`` and ``gdn_chunk_bwd`` behind one ``jax.custom_vjp``: a grid
-step is one (row, value head, chunk), the chunks of a head run in sequence
-with the state in VMEM scratch (backward: its cotangent, from the last chunk
-to the first), every ``(C, C)`` matrix is built, inverted and dropped in VMEM.
-The forward writes the state each chunk STARTS from (float32, ``d_k x d_v`` a
-chunk and head): the backward's only residual beside the operands. Every
-other shape keeps the XLA form (``lax.scan`` over the chunks, the inverse by
+``forward/delta_rule``. Where keys and values are whole lane tiles it is three
+kernels behind one ``jax.custom_vjp``, split where the state enters:
+
+- ``gdn_chunk_tables`` computes what a chunk computes WITHOUT the state: ``D``,
+  ``K K^T``, ``Q K^T``, ``T`` and ``P = tril(Q K^T . D)``. Its rows come a
+  TILE at a time, ``128 // C`` whole chunks (``chunks_a_tile``), so that a
+  product has the MXU's 128 rows, and no grid axis is sequential: a grid step
+  is one (row, key head, up to ``TILES_A_STEP`` tiles) and BOTH value heads
+  the key head serves (one fetch of ``q`` and ``k``, one ``K K^T``); a tile's
+  chunks are inverted as ONE block-diagonal ``(128, 128)`` matrix, and the
+  step's chains (tiles x value heads) stand side by side in one call of the
+  inverse. It writes ``T`` and ``P`` in the compute dtype as one ``(b, heads,
+  n, 2C)`` table: a position's row of ``T`` in the lanes ``[0, C)``, of ``P``
+  in ``[C, 2C)``.
+- ``gdn_chunk_fwd`` keeps the state and nothing else: a grid step is one (row,
+  the value heads of up to ``KEY_HEADS_A_STEP`` key heads, chunk), the chunks
+  of a head in sequence with the heads' states in VMEM scratch; a chunk is the
+  five products that read the state or follow from one that does (``Kg S_0``,
+  ``T (beta R)``, ``Qg S_0``, ``P U``, ``Kd^T U``), and the heads' chains are
+  independent. It writes the output and the state each chunk STARTS from
+  (float32, ``d_k x d_v`` a chunk and head).
+- ``gdn_chunk_bwd`` inverts nothing: the same grid from the last chunk to the
+  first with the states' cotangents in scratch, ``T`` and ``P`` read, only
+  ``D`` and ``K K^T`` rebuilt; a key head's ``dq`` and ``dk`` are summed over
+  its value heads in float32 before they are written.
+
+The forward rule's residuals beside the operands are that table and those
+states; under ``remat`` the rerun forward writes both. A tail that does not
+fill a tile is padded with chunks that neither decay nor write. Every other
+shape keeps the XLA form (``lax.scan`` over the chunks, the inverse by
 ``solve_triangular``; its backward is autodiff), which is also the oracle the
 kernels are tested against. The sequential recurrence itself is the
 benchmark's plain reference (``benchmarks/reference_gdn.py``).
@@ -57,7 +81,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import kv_policy
 from .layers import rms_norm
-from .ssm import LANES, CausalConv1D, _mosaic_call, _mxu
+from .ssm import LANES, VMEM_LIMIT_BYTES, CausalConv1D, _mosaic_call, _mxu
 
 Dtype = Any
 
@@ -95,22 +119,37 @@ def chunk_log_decay(g):
 # ---- the chunk's algebra, shared by both forms -----------------------------
 
 
-def _masks(c: int):
-    i = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    j = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    return i > j, i >= j, i == j
+def _grid(rows: int):
+    return (jax.lax.broadcasted_iota(jnp.int32, (rows, rows), axis) for axis in (0, 1))
+
+
+def _same_block(rows: int, block: int):
+    """Where (i, j) of a (rows, rows) tile lie in one diagonal block of
+    ``block`` (a power of two wherever a tile holds several)."""
+    if rows == block:
+        return True
+    i, j = (jnp.right_shift(x, block.bit_length() - 1) for x in _grid(rows))
+    return i == j
+
+
+def _masks(rows: int, chunk: int):
+    """(strictly below, on or below, on) the diagonal of a (rows, rows) tile
+    of ``rows // chunk`` chunks, below only inside a chunk's own block: a
+    tile of several chunks is block-diagonal."""
+    (i, j), same = _grid(rows), _same_block(rows, chunk)
+    return same & (i > j), same & (i >= j), i == j
 
 
 def _col(row):
     """(1, C) -> (C, 1) without a transpose: the diagonal of the row spread
     over the sublanes."""
-    eye = _masks(row.shape[1])[2]
-    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+    c = row.shape[1]
+    return jnp.sum(jnp.where(_masks(c, c)[2], row, 0.0), axis=1, keepdims=True)
 
 
 def _row(col):
-    eye = _masks(col.shape[0])[2]
-    return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
+    c = col.shape[0]
+    return jnp.sum(jnp.where(_masks(c, c)[2], col, 0.0), axis=0, keepdims=True)
 
 
 def _last(row):
@@ -122,65 +161,192 @@ def _last(row):
     return jnp.sum(jnp.where(last, row, 0.0), axis=1, keepdims=True)
 
 
-def _unit_lower_inverse(a):
-    """``(I + a)^-1`` for a strictly lower-triangular (C, C) float32 ``a``:
-    the finite product ``(I - a)(I + a^2)(I + a^4) ...``."""
-    c = a.shape[0]
+def _unit_lower_inverse(a, block=None):
+    """``(I + a)^-1`` for (.., R, R) float32 ``a`` that is strictly lower
+    triangular inside diagonal blocks of ``block`` (a power of two; default
+    R) and zero outside them; the result is block-diagonal alike. Leading axes
+    are independent chains whose products stand side by side in the program.
+
+    The diagonal blocks of 8 (a float32 sublane tile) first: ``a`` is
+    nilpotent, so ``(I + a)^-1 = (I - a)(I + a^2)(I + a^4)`` exactly. Then
+    block size ``s`` to ``2 s`` until ``block``: with ``L`` the lower-left
+    ``s x s`` block of every ``2 s`` block and ``T`` the inverse so far,
+    ``T <- T - T L T`` (``L T L = 0``). Both products of such a step have
+    non-zero rows only in the lower half of each ``2 s`` block, whole sublane
+    tiles: those rows alone pass through the MXU. Float32 at ``HIGHEST``."""
+    r, lead = a.shape[-1], tuple(range(a.ndim - 2))
+    block = block or r
+    assert block & (block - 1) == 0 and r % block == 0, (a.shape, block)
     dot = lambda x, y: jax.lax.dot_general(
-        x, y, (((1,), (0,)), ((), ())), precision=HIGHEST, preferred_element_type=jnp.float32
+        x, y, (((x.ndim - 1,), (y.ndim - 2,)), (lead, lead)),
+        precision=HIGHEST, preferred_element_type=jnp.float32,
     )
-    power = -a
-    out = jnp.where(_masks(c)[2], 1.0, 0.0) + power
-    for _ in range(max(c - 1, 1).bit_length() - 1):
+    base = min(block, 8)
+    power = -jnp.where(_same_block(r, base), a, 0.0)
+    out = jnp.where(_masks(r, r)[2], 1.0, 0.0) + power
+    for _ in range(max(base - 1, 1).bit_length() - 1):
         power = dot(power, power)
         out = out + dot(out, power)
+    s = base
+    while s < block:
+        lower = range(s, r, 2 * s)                      # where each 2s block's lower half starts
+        rows = lambda x: jnp.concatenate([x[..., lo : lo + s, :] for lo in lower], axis=-2)
+        zeros = jnp.zeros((*a.shape[:-2], s, r), jnp.float32)
+        spread = lambda y: jnp.concatenate(
+            [piece for n in range(len(lower)) for piece in (zeros, y[..., n * s : (n + 1) * s, :])], axis=-2
+        )
+        below = jnp.where(_same_block(r, 2 * s) & ~_same_block(r, s), a, 0.0)  # L
+        out = out - spread(dot(rows(out), spread(dot(rows(below), out))))
+        s *= 2
     return out
 
 
-def _chunk_forward(q, k, v, g_row, beta_row, s0, dtype):
-    """One chunk of one value head. q, k: (C, d_k), v: (C, d_v) in ``dtype``;
-    ``g_row``, ``beta_row``: (1, C) float32; ``s0``: (d_k, d_v) float32.
-    Everything the backward rebuilds, by name."""
-    c = q.shape[0]
-    strict, incl, _ = _masks(c)
-    g_col, beta_col = _col(g_row), _col(beta_row)
+def _tile_decay(g_row, chunk: int):
+    """``D`` of tiles of whole chunks: ``exp(G_i - G_j)`` on and below the
+    diagonal of each chunk's own block, 0 elsewhere. ``g_row``: (.., 1, R)."""
+    r = g_row.shape[-1]
+    g_col = jnp.sum(jnp.where(_masks(r, r)[2], g_row, 0.0), axis=-1, keepdims=True)
     # masked BEFORE the exponential: above the diagonal the difference is positive
-    decay = jnp.exp(jnp.where(incl, g_col - g_row, -jnp.inf))          # D, 1 on the diagonal
+    return jnp.exp(jnp.where(_masks(r, chunk)[1], g_col - g_row, -jnp.inf))
+
+
+def _fold(x, chunk: int):
+    """Block-diagonal (.., R, R) tiles as (.., R, C): each chunk's rows keep
+    their own block (every other block of a row is zero)."""
+    return sum(x[..., lo : lo + chunk] for lo in range(0, x.shape[-1], chunk))
+
+
+def _tile_tables(qk, kk, g_rows, beta_rows, chunk: int, dtype):
+    """What tiles of ``R // chunk`` whole chunks compute WITHOUT the state,
+    all of a grid step's (tile, value head) pairs at once along the leading
+    axis: ``qk``, ``kk``: their key head's ``Q K^T`` and ``K K^T``, (m, R, R)
+    float32; ``g_rows``, ``beta_rows``: (m, 1, R) float32. Returns (m, R, 2C)
+    in ``dtype``: a chunk's rows of ``T = (I + A)^-1`` in the lanes [0, C), of
+    ``P = tril(Q K^T . D)`` in [C, 2C). ONE call of the inverse: every pair's
+    chain side by side."""
+    r = g_rows.shape[-1]
+    strict, incl, eye = _masks(r, chunk)
+    decay = _tile_decay(g_rows, chunk)
+    beta_col = jnp.sum(jnp.where(eye, beta_rows, 0.0), axis=-1, keepdims=True)
+    t = _unit_lower_inverse(beta_col * jnp.where(strict, kk * decay, 0.0), chunk)
+    p = jnp.where(incl, qk * decay, 0.0)
+    return jnp.concatenate([_fold(t, chunk), _fold(p, chunk)], axis=-1).astype(dtype)
+
+
+# a chunk's algebra is a ``jax.jit`` each way: a grid step holds several heads,
+# and the kernel's body is then traced once a shape and not once a head
+_chunk_jit = functools.partial(jax.jit, static_argnames=("dtype",))
+
+
+def _chunk_state(q, k, v, g_row, beta_row, t, s0, dtype):
+    """What one chunk of one value head computes from the state it starts
+    with, by name (the backward rebuilds it). q, k: (C, d_k), v: (C, d_v),
+    ``t``: (C, C) in ``dtype``; ``g_row``, ``beta_row``: (1, C) float32;
+    ``s0``: (d_k, d_v) float32."""
+    g_col, beta_col = _col(g_row), _col(beta_row)
     since_start = jnp.exp(g_col)                                        # (C, 1)
     to_end = jnp.exp(_last(g_row) - g_col)                               # (C, 1)
     k32, q32 = k.astype(jnp.float32), q.astype(jnp.float32)
     kg32, qg32, kd32 = k32 * since_start, q32 * since_start, k32 * to_end
     s16 = s0.astype(dtype)
-    a_plain = jnp.where(strict, _mxu(k, k, (1, 1)) * decay, 0.0)        # without beta
-    t = _unit_lower_inverse(beta_col * a_plain)
     r_plain = v.astype(jnp.float32) - _mxu(kg32.astype(dtype), s16, (1, 0))
-    u = _mxu(t.astype(dtype), (beta_col * r_plain).astype(dtype), (1, 0))   # (C, d_v)
-    p = jnp.where(incl, _mxu(q, k, (1, 1)) * decay, 0.0)
+    u = _mxu(t, (beta_col * r_plain).astype(dtype), (1, 0))                 # (C, d_v)
     return dict(
-        strict=strict, incl=incl, g_col=g_col, beta_col=beta_col, decay=decay,
-        since_start=since_start, to_end=to_end, kg32=kg32, qg32=qg32, kd32=kd32, s16=s16,
-        a_plain=a_plain, t=t, r_plain=r_plain, u=u, p=p,
+        beta_col=beta_col, since_start=since_start, to_end=to_end,
+        kg32=kg32, qg32=qg32, kd32=kd32, s16=s16, r_plain=r_plain, u=u,
     )
 
 
-def _chunk_outputs(f, s0, g_row, dtype):
-    """(the chunk's output (C, d_v) float32, the state at its end)."""
+@_chunk_jit
+def _chunk_forward(q, k, v, g_row, beta_row, t, p, s0, dtype):
+    """(the chunk's output (C, d_v) float32, the state at its end); ``p``:
+    (C, C) in ``dtype``."""
+    f = _chunk_state(q, k, v, g_row, beta_row, t, s0, dtype)
     u16 = f["u"].astype(dtype)
-    o = _mxu(f["qg32"].astype(dtype), f["s16"], (1, 0)) + _mxu(f["p"].astype(dtype), u16, (1, 0))
+    o = _mxu(f["qg32"].astype(dtype), f["s16"], (1, 0)) + _mxu(p, u16, (1, 0))
     whole = jnp.broadcast_to(jnp.exp(_last(g_row)), (1, s0.shape[1]))
-    s_end = whole * s0 + _mxu(f["kd32"].astype(dtype), u16, (0, 0))
-    return o, s_end
+    return o, whole * s0 + _mxu(f["kd32"].astype(dtype), u16, (0, 0))
+
+
+@_chunk_jit
+def _chunk_backward(q, k, v, g_row, beta_row, t, p, kk, s0, do, ds, dtype):
+    """Cotangents of one chunk of one value head. ``t``, ``p``: the forward's
+    tables in ``dtype``; ``kk``: ``K K^T`` (C, C) float32, once a key head;
+    ``do``: (C, d_v); ``ds``: the cotangent of the state at the chunk's END.
+    With ``dR = T^T dU`` the inverse needs no cotangent of its own: ``dA =
+    -dR U^T``. Returns (dq, dk, dv, dg row, dbeta row, the cotangent of the
+    state the chunk STARTED from), float32."""
+    n = q.shape[0]
+    strict, incl, _ = _masks(n, n)
+    f = _chunk_state(q, k, v, g_row, beta_row, t, s0, dtype)
+    beta_col, since_start, to_end = f["beta_col"], f["since_start"], f["to_end"]
+    decay = _tile_decay(g_row, n)
+    a_plain = jnp.where(strict, kk * decay, 0.0)                                 # without beta
+    p32 = p.astype(jnp.float32)
+    s16, u16 = f["s16"], f["u"].astype(dtype)
+    do16, ds16 = do.astype(dtype), ds.astype(dtype)
+    kd16, kg16, qg16 = (f[name].astype(dtype) for name in ("kd32", "kg32", "qg32"))
+
+    # ---- back through O = (exp(G) Q) S_0 + P U and S_C = exp(G_C) S_0 + Kd^T U
+    du = _mxu(p, do16, (0, 0)) + _mxu(kd16, ds16, (1, 0))                        # (C, d_v)
+    dr = _mxu(t, du.astype(dtype), (0, 0))                                       # T^T dU
+    dr16, bdr = dr.astype(dtype), beta_col * dr
+    bdr16 = bdr.astype(dtype)
+    dbeta_col = jnp.sum(dr * f["r_plain"], axis=1, keepdims=True)
+    # R = beta (V - (exp(G) K) S_0)
+    dkg = -_mxu(bdr16, s16, (1, 1))                                              # (C, d_k)
+    dk = since_start * dkg
+    dg_col = jnp.sum(dkg * f["kg32"], axis=1, keepdims=True)
+    # T = (I + A)^-1, A = beta (K K^T . D) below the diagonal
+    da = jnp.where(strict, -_mxu(dr16, u16, (1, 1)), 0.0)
+    daa = da * a_plain
+    dbeta_col += jnp.sum(daa, axis=1, keepdims=True)
+    daa = daa * beta_col
+    dg_col += jnp.sum(daa, axis=1, keepdims=True)
+    dg_row = -jnp.sum(daa, axis=0, keepdims=True)
+    dm16 = (da * beta_col * decay).astype(dtype)
+    dk += _mxu(dm16, k, (1, 0)) + _mxu(dm16, k, (0, 0))
+    # P = Q K^T . D on and below the diagonal
+    dp = jnp.where(incl, _mxu(do16, u16, (1, 1)), 0.0)
+    dpp = dp * p32
+    dg_col += jnp.sum(dpp, axis=1, keepdims=True)
+    dg_row -= jnp.sum(dpp, axis=0, keepdims=True)
+    dqk16 = (dp * decay).astype(dtype)
+    dos = _mxu(do16, s16, (1, 1))                                                # dO S_0^T
+    dq = _mxu(dqk16, k, (1, 0)) + since_start * dos
+    dk += _mxu(dqk16, q, (0, 0))
+    dg_col += jnp.sum(dos * f["qg32"], axis=1, keepdims=True)
+    # Kd = exp(G_C - G) K
+    dkd = _mxu(u16, ds16, (1, 1))                                                # U dS^T
+    dk += to_end * dkd
+    left = jnp.sum(dkd * f["kd32"], axis=1, keepdims=True)                       # (C, 1)
+    dg_col -= left
+    whole = jnp.exp(_last(g_row))                                                # (1, 1)
+    total = lambda x: jnp.sum(jnp.sum(x, axis=1, keepdims=True), axis=0, keepdims=True)
+    at_end = total(left) + whole * total(ds * s0)
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1) == n - 1
+    dg = dg_row + _row(dg_col) + jnp.where(last, at_end, 0.0)
+    # ---- the cotangent of the state this chunk started from
+    ds0 = (
+        _mxu(qg16, do16, (0, 0)) + jnp.broadcast_to(whole, (1, ds.shape[1])) * ds
+        - _mxu(kg16, bdr16, (0, 0))
+    )
+    return dq, dk, bdr, dg, _row(dbeta_col), ds0
 
 
 # ---- the Pallas kernels -----------------------------------------------------
 #
 # q, k, v keep the projection's layout, (b, n, heads x width) with heads in
-# lanes: a grid step takes its head's 128-lane tile, its key head's for q and
-# k (a key head serves ``ratio`` value heads), and XLA never sees a (.., h, d)
-# array. The per-position scalars arrive as (b, heads, chunks, C) float32:
-# a head's whole table is one small block that stays in VMEM through its
-# chunks, and a chunk reads (backward: writes) its ROW; the column
-# orientation is made in the kernel (``_col``), so nothing is transposed.
+# lanes: a grid step takes the 128-lane tiles of its heads, its key heads' for
+# q and k (a key head serves ``ratio`` value heads), and XLA never sees a
+# (.., h, d) array. The per-position scalars arrive as (b, heads, chunks, C)
+# float32: the whole table of a step's heads is one small block that stays in
+# VMEM through their chunks, and a chunk reads (backward: writes) its ROW; the
+# state-free kernel, whose rows come a TILE at a time (``128 // C`` whole
+# chunks, one where a chunk is 128 positions or more: what fills the MXU's
+# rows where a chunk alone does not), reads the same memory as (b, heads,
+# tiles, R), a tile's row. The column orientation is made in the kernel
+# (``_col``), so nothing is transposed.
 
 
 def delta_rule_kernels_eligible(chunk: int, d_k: int, d_v: int) -> bool:
@@ -193,31 +359,76 @@ def delta_rule_kernels_eligible(chunk: int, d_k: int, d_v: int) -> bool:
     )
 
 
-def _gdn_chunk_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, s_ref, state):
+def chunks_a_tile(chunk: int) -> int:
+    """Whole chunks in the rows a grid step takes: what fills 128."""
+    return max(LANES // chunk, 1)
+
+
+def _divisor(n: int, most: int) -> int:
+    """The largest divisor of ``n`` that is at most ``most``."""
+    return max(d for d in range(1, min(n, most) + 1) if n % d == 0)
+
+
+def _gdn_chunk_tables_kernel(q_ref, k_ref, g_ref, beta_ref, tp_ref, *, chunk, tiles):
+    """The state-free half: one key head, ``tiles`` tiles of rows, every value
+    head the key head serves; no grid axis is sequential."""
+    rows = q_ref.shape[1] // tiles
+    first, heads = pl.program_id(2) * tiles, range(g_ref.shape[1])
+    at = [slice(i * rows, (i + 1) * rows) for i in range(tiles)]
+    pairs = [(i, h) for i in range(tiles) for h in heads]
+    of_key_head = lambda x_ref: [_mxu(x_ref[0, at[i]], k_ref[0, at[i]], (1, 1)) for i in range(tiles)]
+    qk, kk = of_key_head(q_ref), of_key_head(k_ref)                 # once a key head
+    row = lambda ref: jnp.stack([ref[0, h, pl.ds(first + i, 1), :] for i, h in pairs])
+    tables = _tile_tables(
+        jnp.stack([qk[i] for i, _ in pairs]), jnp.stack([kk[i] for i, _ in pairs]),
+        row(g_ref), row(beta_ref), chunk, q_ref.dtype,
+    )
+    for n, (i, h) in enumerate(pairs):
+        tp_ref[0, h, at[i]] = tables[n]
+
+
+def _head_operands(refs, h, ratio, d_k, d_v):
+    """(q, k, v, t, p) of the step's chunk for its value head ``h``, from
+    (q_ref, k_ref, v_ref, tp_ref)."""
+    q_ref, k_ref, v_ref, tp_ref = refs
+    key, chunk = slice((h // ratio) * d_k, (h // ratio + 1) * d_k), q_ref.shape[1]
+    return (
+        q_ref[0, :, key], k_ref[0, :, key], v_ref[0, :, h * d_v : (h + 1) * d_v],
+        tp_ref[0, h, :, :chunk], tp_ref[0, h, :, chunk:],
+    )
+
+
+def _gdn_chunk_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, tp_ref, o_ref, s_ref, state, *, ratio):
+    """The half that carries the state: one chunk of ``heads`` value heads a
+    grid step, the chunks of a head in sequence; the heads' chains are
+    independent and stand side by side. Only the five products that read the
+    state or follow from one that does."""
     c = pl.program_id(2)
 
     @pl.when(c == 0)
     def _():
         state[...] = jnp.zeros_like(state)             # nothing before the sequence
 
-    dtype = q_ref.dtype
-    g_row, beta_row = g_ref[0, 0, pl.ds(c, 1), :], beta_ref[0, 0, pl.ds(c, 1), :]
-    s0 = state[...]
-    s_ref[0, 0, 0] = s0
-    f = _chunk_forward(q_ref[0], k_ref[0], v_ref[0], g_row, beta_row, s0, dtype)
-    o, s_end = _chunk_outputs(f, s0, g_row, dtype)
-    o_ref[0] = o.astype(o_ref.dtype)
-    state[...] = s_end
+    heads, _, d_k, d_v = s_ref.shape[1:]
+    for h in range(heads):
+        q, k, v, t, p = _head_operands((q_ref, k_ref, v_ref, tp_ref), h, ratio, d_k, d_v)
+        s0 = state[h]
+        s_ref[0, h, 0] = s0
+        o, state[h] = _chunk_forward(
+            q, k, v, g_ref[0, h, pl.ds(c, 1), :], beta_ref[0, h, pl.ds(c, 1), :], t, p, s0, q_ref.dtype
+        )
+        o_ref[0, :, h * d_v : (h + 1) * d_v] = o.astype(o_ref.dtype)
 
 
 def _gdn_chunk_bwd_kernel(
-    q_ref, k_ref, v_ref, g_ref, beta_ref, s_ref, do_ref,
-    dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate,
+    q_ref, k_ref, v_ref, g_ref, beta_ref, tp_ref, s_ref, do_ref,
+    dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate, *, ratio,
 ):
-    """Cotangents of one (row, value head, chunk), the chunks from the LAST to
-    the first: ``dstate`` carries the cotangent of the state at the chunk's
-    end. With ``dR = T^T dU`` the inverse needs no cotangent of its own:
-    ``dA = -dR U^T``."""
+    """Cotangents of one chunk of ``heads`` value heads, the chunks from the
+    LAST to the first: ``dstate`` carries the cotangent of the state at a
+    chunk's end. Nothing is inverted: ``T`` and ``P`` are the forward's. A
+    key head's cotangent is summed over the value heads it serves here, in
+    float32."""
     step, chunks = pl.program_id(2), pl.num_programs(2)
     c = chunks - 1 - step
 
@@ -225,79 +436,23 @@ def _gdn_chunk_bwd_kernel(
     def _():
         dstate[...] = jnp.zeros_like(dstate)            # nothing after the sequence
 
-    dtype = q_ref.dtype
-    q, k = q_ref[0], k_ref[0]
-    g_row, beta_row = g_ref[0, 0, pl.ds(c, 1), :], beta_ref[0, 0, pl.ds(c, 1), :]
-    s0 = s_ref[0, 0, 0]
-    f = _chunk_forward(q, k, v_ref[0], g_row, beta_row, s0, dtype)
-    n = q.shape[0]
-    beta_col, decay, since_start, to_end = f["beta_col"], f["decay"], f["since_start"], f["to_end"]
-    s16, u16 = f["s16"], f["u"].astype(dtype)
-    do16 = do_ref[0].astype(dtype)
-    ds = dstate[...]
-    ds16 = ds.astype(dtype)
-    kd16, kg16, qg16 = (f[name].astype(dtype) for name in ("kd32", "kg32", "qg32"))
-
-    # ---- back through O = (exp(G) Q) S_0 + P U and S_C = exp(G_C) S_0 + Kd^T U
-    du = _mxu(f["p"].astype(dtype), do16, (0, 0)) + _mxu(kd16, ds16, (1, 0))     # (C, d_v)
-    dr = _mxu(f["t"].astype(dtype), du.astype(dtype), (0, 0))                    # T^T dU
-    dr16, bdr = dr.astype(dtype), beta_col * dr
-    bdr16 = bdr.astype(dtype)
-    dv_ref[0] = bdr.astype(dv_ref.dtype)
-    dbeta_col = jnp.sum(dr * f["r_plain"], axis=1, keepdims=True)
-    # R = beta (V - (exp(G) K) S_0)
-    dkg = -_mxu(bdr16, s16, (1, 1))                                              # (C, d_k)
-    dk = since_start * dkg
-    dg_col = jnp.sum(dkg * f["kg32"], axis=1, keepdims=True)
-    # T = (I + A)^-1, A = beta (K K^T . D) below the diagonal
-    da = jnp.where(f["strict"], -_mxu(dr16, u16, (1, 1)), 0.0)
-    daa = da * f["a_plain"]
-    dbeta_col += jnp.sum(daa, axis=1, keepdims=True)
-    daa = daa * beta_col
-    dg_col += jnp.sum(daa, axis=1, keepdims=True)
-    dg_row = -jnp.sum(daa, axis=0, keepdims=True)
-    dm16 = (da * beta_col * decay).astype(dtype)
-    dk += _mxu(dm16, k, (1, 0)) + _mxu(dm16, k, (0, 0))
-    # P = Q K^T . D on and below the diagonal
-    dp = jnp.where(f["incl"], _mxu(do16, u16, (1, 1)), 0.0)
-    dpp = dp * f["p"]
-    dg_col += jnp.sum(dpp, axis=1, keepdims=True)
-    dg_row -= jnp.sum(dpp, axis=0, keepdims=True)
-    dqk16 = (dp * decay).astype(dtype)
-    dos = _mxu(do16, s16, (1, 1))                                                # dO S_0^T
-    dq_ref[0] = (_mxu(dqk16, k, (1, 0)) + since_start * dos).astype(dq_ref.dtype)
-    dk += _mxu(dqk16, q, (0, 0))
-    dg_col += jnp.sum(dos * f["qg32"], axis=1, keepdims=True)
-    # Kd = exp(G_C - G) K
-    dkd = _mxu(u16, ds16, (1, 1))                                                # U dS^T
-    dk += to_end * dkd
-    left = jnp.sum(dkd * f["kd32"], axis=1, keepdims=True)                       # (C, 1)
-    dg_col -= left
-    whole = jnp.exp(_last(g_row))                                                # (1, 1)
-    total = lambda x: jnp.sum(jnp.sum(x, axis=1, keepdims=True), axis=0, keepdims=True)
-    at_end = total(left) + whole * total(ds * s0)
-    last = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1) == n - 1
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dg_ref[0, 0, pl.ds(c, 1), :] = dg_row + _row(dg_col) + jnp.where(last, at_end, 0.0)
-    dbeta_ref[0, 0, pl.ds(c, 1), :] = _row(dbeta_col)
-    # ---- the cotangent of the state this chunk started from
-    dstate[...] = (
-        _mxu(qg16, do16, (0, 0)) + jnp.broadcast_to(whole, (1, ds.shape[1])) * ds
-        - _mxu(kg16, bdr16, (0, 0))
-    )
-
-
-def _specs(chunks: int, chunk: int, d_k: int, d_v: int, ratio: int, back: bool = False):
-    """The block of every kind of operand in the grid (row, value head,
-    chunk); ``back``: the chunks from the last to the first."""
-    at = (lambda ci: chunks - 1 - ci) if back else (lambda ci: ci)
-    return dict(
-        key=pl.BlockSpec((1, chunk, d_k), lambda bi, hi, ci: (bi, at(ci), hi // ratio)),
-        own_key=pl.BlockSpec((1, chunk, d_k), lambda bi, hi, ci: (bi, at(ci), hi)),
-        value=pl.BlockSpec((1, chunk, d_v), lambda bi, hi, ci: (bi, at(ci), hi)),
-        table=pl.BlockSpec((1, 1, chunks, chunk), lambda bi, hi, ci: (bi, hi, 0, 0)),
-        state=pl.BlockSpec((1, 1, 1, d_k, d_v), lambda bi, hi, ci: (bi, hi, at(ci), 0, 0)),
-    )
+    heads, _, d_k, d_v = s_ref.shape[1:]
+    for key_head in range(heads // ratio):
+        key = slice(key_head * d_k, (key_head + 1) * d_k)
+        kk = _mxu(k_ref[0, :, key], k_ref[0, :, key], (1, 1))
+        dq, dk = 0.0, 0.0
+        for h in range(key_head * ratio, (key_head + 1) * ratio):
+            q, k, v, t, p = _head_operands((q_ref, k_ref, v_ref, tp_ref), h, ratio, d_k, d_v)
+            dq_h, dk_h, dv, dg, dbeta, dstate[h] = _chunk_backward(
+                q, k, v, g_ref[0, h, pl.ds(c, 1), :], beta_ref[0, h, pl.ds(c, 1), :], t, p, kk,
+                s_ref[0, h, 0], do_ref[0, :, h * d_v : (h + 1) * d_v], dstate[h], q_ref.dtype,
+            )
+            dq, dk = dq + dq_h, dk + dk_h
+            dv_ref[0, :, h * d_v : (h + 1) * d_v] = dv.astype(dv_ref.dtype)
+            dg_ref[0, h, pl.ds(c, 1), :] = dg
+            dbeta_ref[0, h, pl.ds(c, 1), :] = dbeta
+        dq_ref[0, :, key] = dq.astype(dq_ref.dtype)
+        dk_ref[0, :, key] = dk.astype(dk_ref.dtype)
 
 
 def _sizes(q, v, g, key_heads):
@@ -308,56 +463,104 @@ def _sizes(q, v, g, key_heads):
     return b, heads, chunks, chunk, d_k, d_v, heads // key_heads
 
 
+# how much independent work a grid step holds, from the shape: the state's
+# pass takes the value heads of up to KEY_HEADS_A_STEP key heads (their chains
+# interleave), the state-free kernel up to TILES_A_STEP tiles of one key head.
+# More of either is faster by little and is paid as Python tracing of a longer
+# body in every process's set-up (PERF.md section 6, PR 39)
+KEY_HEADS_A_STEP = 4
+TILES_A_STEP = 4
+
+
+def _specs(b, heads, chunks, chunk, d_k, d_v, ratio, back: bool = False):
+    """The grid (row, group of value heads, chunk) of the pass that carries
+    the state and the block of every kind of operand in it; ``back``: the
+    chunks from the last to the first."""
+    key_heads = _divisor(heads // ratio, KEY_HEADS_A_STEP)
+    step_heads = key_heads * ratio
+    at = (lambda ci: chunks - 1 - ci) if back else (lambda ci: ci)
+    return (b, heads // step_heads, chunks), dict(
+        key=pl.BlockSpec((1, chunk, key_heads * d_k), lambda bi, hi, ci: (bi, at(ci), hi)),
+        value=pl.BlockSpec((1, chunk, step_heads * d_v), lambda bi, hi, ci: (bi, at(ci), hi)),
+        table=pl.BlockSpec((1, step_heads, chunks, chunk), lambda bi, hi, ci: (bi, hi, 0, 0)),
+        tp=pl.BlockSpec((1, step_heads, chunk, 2 * chunk), lambda bi, hi, ci: (bi, hi, at(ci), 0)),
+        state=pl.BlockSpec((1, step_heads, 1, d_k, d_v), lambda bi, hi, ci: (bi, hi, at(ci), 0, 0)),
+        carried=pltpu.VMEM((step_heads, d_k, d_v), jnp.float32),
+    )
+
+
 # each call a ``jax.jit`` of its own, as ``ops/ssm.py``'s: three mixers, each
 # run forward, again under ``remat`` and backward, lower a kernel once a shape
 _kernel_call = functools.partial(jax.jit, static_argnames=("key_heads", "interpret"))
 
 
 @_kernel_call
-def _fwd_call(q, k, v, g, beta, *, key_heads, interpret):
-    b, heads, chunks, chunk, d_k, d_v, ratio = _sizes(q, v, g, key_heads)
-    s = _specs(chunks, chunk, d_k, d_v, ratio)
+def _tables_call(q, k, g, beta, *, key_heads, interpret):
+    """``T`` and ``P`` of every chunk and value head, (b, heads, n, 2C) in
+    the compute dtype: ``_tile_tables``'s layout."""
+    b, heads, chunks, chunk = (q.shape[0], *g.shape[1:])
+    d_k, ratio, group = q.shape[-1] // key_heads, heads // key_heads, chunks_a_tile(chunk)
+    assert q.shape[1] == chunks * chunk and chunks % group == 0, (q.shape, g.shape)
+    tiles, rows = chunks // group, group * chunk
+    per_step = _divisor(tiles, TILES_A_STEP)
+    key = pl.BlockSpec((1, per_step * rows, d_k), lambda bi, ki, ti: (bi, ti, ki))
+    table = pl.BlockSpec((1, ratio, tiles, rows), lambda bi, ki, ti: (bi, ki, 0, 0))
+    as_tiles = lambda t: t.reshape(b, heads, tiles, rows)
+    return pl.pallas_call(
+        functools.partial(_gdn_chunk_tables_kernel, chunk=chunk, tiles=per_step),
+        name="gdn_chunk_tables",
+        grid=(b, key_heads, tiles // per_step),
+        in_specs=[key, key, table, table],
+        out_specs=pl.BlockSpec((1, ratio, per_step * rows, 2 * chunk), lambda bi, ki, ti: (bi, ki, ti, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, heads, chunks * chunk, 2 * chunk), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3, vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
+        interpret=interpret,
+    )(q, k, as_tiles(g), as_tiles(beta))
+
+
+@_kernel_call
+def _fwd_call(q, k, v, g, beta, tp, *, key_heads, interpret):
+    b, heads, chunks, chunk, d_k, d_v, ratio = sizes = _sizes(q, v, g, key_heads)
+    grid, s = _specs(*sizes)
     return _mosaic_call(
-        _gdn_chunk_fwd_kernel, (b, heads, chunks),
-        [s["key"], s["key"], s["value"], s["table"], s["table"]], [s["value"], s["state"]],
+        functools.partial(_gdn_chunk_fwd_kernel, ratio=ratio), grid,
+        [s["key"], s["key"], s["value"], s["table"], s["table"], s["tp"]], [s["value"], s["state"]],
         [jax.ShapeDtypeStruct(v.shape, v.dtype),
          jax.ShapeDtypeStruct((b, heads, chunks, d_k, d_v), jnp.float32)],
-        [pltpu.VMEM((d_k, d_v), jnp.float32)], [q, k, v, g, beta], interpret, name="gdn_chunk_fwd",
+        [s["carried"]], [q, k, v, g, beta, tp], interpret, name="gdn_chunk_fwd",
     )
 
 
 @_kernel_call
-def _bwd_call(q, k, v, g, beta, states, do, *, key_heads, interpret):
-    b, heads, chunks, chunk, d_k, d_v, ratio = _sizes(q, v, g, key_heads)
-    s = _specs(chunks, chunk, d_k, d_v, ratio, back=True)
-    n = q.shape[1]
-    per_head = jax.ShapeDtypeStruct((b, n, heads * d_k), q.dtype)
+def _bwd_call(q, k, v, g, beta, tp, states, do, *, key_heads, interpret):
+    *_, ratio = sizes = _sizes(q, v, g, key_heads)
+    grid, s = _specs(*sizes, back=True)
     table = jax.ShapeDtypeStruct(g.shape, jnp.float32)
-    dq, dk, dv, dg, dbeta = _mosaic_call(
-        _gdn_chunk_bwd_kernel, (b, heads, chunks),
-        [s["key"], s["key"], s["value"], s["table"], s["table"], s["state"], s["value"]],
-        [s["own_key"], s["own_key"], s["value"], s["table"], s["table"]],
-        [per_head, per_head, jax.ShapeDtypeStruct(v.shape, v.dtype), table, table],
-        [pltpu.VMEM((d_k, d_v), jnp.float32)], [q, k, v, g, beta, states, do], interpret,
-        name="gdn_chunk_bwd",
+    return _mosaic_call(
+        functools.partial(_gdn_chunk_bwd_kernel, ratio=ratio), grid,
+        [s["key"], s["key"], s["value"], s["table"], s["table"], s["tp"], s["state"], s["value"]],
+        [s["key"], s["key"], s["value"], s["table"], s["table"]],
+        [jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct(k.shape, k.dtype),
+         jax.ShapeDtypeStruct(v.shape, v.dtype), table, table],
+        [s["carried"]], [q, k, v, g, beta, tp, states, do], interpret, name="gdn_chunk_bwd",
     )
-    # a key head's cotangent: the sum over the value heads it serves
-    over = lambda t: t.reshape(b, n, key_heads, ratio, d_k).astype(jnp.float32).sum(3).reshape(q.shape)
-    return over(dq).astype(q.dtype), over(dk).astype(k.dtype), dv, dg, dbeta
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def delta_rule_chunks(q, k, v, g, beta, key_heads, interpret):
-    """The rule over whole chunks. q, k: (b, n, key heads x d_k), normalised
-    and scaled; v: (b, n, heads x d_v); g: (b, heads, chunks, C) float32, the
-    cumulative log-decay INSIDE each chunk; beta: likewise. Returns (b, n,
-    heads x d_v) in the dtype of ``v``."""
-    return _fwd_call(q, k, v, g, beta, key_heads=key_heads, interpret=interpret)[0]
+    """The rule over whole tiles of chunks (``chunks_a_tile``). q, k: (b, n,
+    key heads x d_k), normalised and scaled; v: (b, n, heads x d_v); g: (b,
+    heads, chunks, C) float32, the cumulative log-decay INSIDE each chunk;
+    beta: likewise. Returns (b, n, heads x d_v) in the dtype of ``v``."""
+    return _chunks_fwd_rule(q, k, v, g, beta, key_heads, interpret)[0]
 
 
 def _chunks_fwd_rule(q, k, v, g, beta, key_heads, interpret):
-    o, states = _fwd_call(q, k, v, g, beta, key_heads=key_heads, interpret=interpret)
-    return o, (q, k, v, g, beta, states)
+    tp = _tables_call(q, k, g, beta, key_heads=key_heads, interpret=interpret)
+    o, states = _fwd_call(q, k, v, g, beta, tp, key_heads=key_heads, interpret=interpret)
+    return o, (q, k, v, g, beta, tp, states)
 
 
 def _chunks_bwd_rule(key_heads, interpret, res, do):
@@ -370,27 +573,41 @@ delta_rule_chunks.defvjp(_chunks_fwd_rule, _chunks_bwd_rule)
 # ---- the same algorithm in XLA ---------------------------------------------
 
 
-def _delta_rule_xla(q, k, v, g, beta, key_heads: int, dtype):
-    """``delta_rule_chunks`` in XLA: every chunk's matrices at once, the
-    inverse by ``solve_triangular``, the state handed from chunk to chunk in a
-    ``lax.scan``. The oracle of the kernels' tests, and the form of every
-    shape they are not written for."""
-    b, n, _ = q.shape
-    _, heads, chunks, c = g.shape
-    ratio = heads // key_heads
-    split = lambda t, h: t.reshape(b, chunks, c, h, -1).transpose(0, 3, 1, 2, 4)   # (b, h, chunks, C, d)
-    per_value_head = lambda t: jnp.repeat(split(t, key_heads), ratio, axis=1)
-    q, k, v = per_value_head(q.astype(dtype)), per_value_head(k.astype(dtype)), split(v.astype(dtype), heads)
-    dot = lambda spec, x, y: jnp.einsum(spec, x, y, preferred_element_type=jnp.float32)
-    strict, incl, eye = _masks(c)
-    g_col, beta_col = g[..., :, None], beta[..., :, None]
-    decay = jnp.exp(jnp.where(incl, g_col - g[..., None, :], -jnp.inf))
-    since_start, to_end = jnp.exp(g_col), jnp.exp(g[..., -1:, None] - g_col)
-    a = jnp.where(strict, beta_col * dot("bhnid,bhnjd->bhnij", k, k) * decay, 0.0)
+_dot = lambda spec, x, y: jnp.einsum(spec, x, y, preferred_element_type=jnp.float32)
+
+
+def _split_heads(t, g, heads: int, repeat: int = 1):
+    """(b, n, heads x d) as (b, heads x repeat, chunks, C, d), the chunks of ``g``."""
+    b, _, chunks, c = g.shape
+    return jnp.repeat(t.reshape(b, chunks, c, heads, -1).transpose(0, 3, 1, 2, 4), repeat, axis=1)
+
+
+def _tables_xla(q, k, g, beta, dtype):
+    """(``T``, ``P``) of every chunk, (b, heads, chunks, C, C) in ``dtype``,
+    the inverse by ``solve_triangular``. q, k: a value head's, (b, heads,
+    chunks, C, d_k)."""
+    strict, incl, eye = _masks(g.shape[-1], g.shape[-1])
+    decay = jnp.exp(jnp.where(incl, g[..., :, None] - g[..., None, :], -jnp.inf))
+    a = jnp.where(strict, beta[..., :, None] * _dot("bhnid,bhnjd->bhnij", k, k) * decay, 0.0)
     t = jax.scipy.linalg.solve_triangular(
         a + eye, jnp.broadcast_to(jnp.where(eye, 1.0, 0.0), a.shape), lower=True, unit_diagonal=True
-    ).astype(dtype)
-    p = jnp.where(incl, dot("bhnid,bhnjd->bhnij", q, k) * decay, 0.0).astype(dtype)
+    )
+    p = jnp.where(incl, _dot("bhnid,bhnjd->bhnij", q, k) * decay, 0.0)
+    return t.astype(dtype), p.astype(dtype)
+
+
+def _delta_rule_xla(q, k, v, g, beta, key_heads: int, dtype):
+    """``delta_rule_chunks`` in XLA: every chunk's matrices at once, the
+    state handed from chunk to chunk in a ``lax.scan``. The oracle of the
+    kernels' tests, and the form of every shape they are not written for."""
+    b, n, _ = q.shape
+    heads = g.shape[1]
+    ratio = heads // key_heads
+    q, k = (_split_heads(t.astype(dtype), g, key_heads, ratio) for t in (q, k))
+    v = _split_heads(v.astype(dtype), g, heads)
+    g_col, beta_col = g[..., :, None], beta[..., :, None]
+    since_start, to_end = jnp.exp(g_col), jnp.exp(g[..., -1:, None] - g_col)
+    t, p = _tables_xla(q, k, g, beta, dtype)
     f32 = lambda x: x.astype(jnp.float32)
     kg, qg, kd = ((f32(x) * w).astype(dtype) for x, w in ((k, since_start), (q, since_start), (k, to_end)))
     whole = jnp.exp(g[..., -1])                                         # (b, h, chunks)
@@ -398,10 +615,10 @@ def _delta_rule_xla(q, k, v, g, beta, key_heads: int, dtype):
     def chunk(s0, inp):
         kg, qg, kd, v, t, p, beta_col, whole = inp
         s16 = s0.astype(dtype)
-        r = beta_col * (f32(v) - dot("bhik,bhkv->bhiv", kg, s16))
-        u = dot("bhij,bhjv->bhiv", t, r.astype(dtype)).astype(dtype)
-        o = dot("bhik,bhkv->bhiv", qg, s16) + dot("bhij,bhjv->bhiv", p, u)
-        return whole[..., None, None] * s0 + dot("bhik,bhiv->bhkv", kd, u), o
+        r = beta_col * (f32(v) - _dot("bhik,bhkv->bhiv", kg, s16))
+        u = _dot("bhij,bhjv->bhiv", t, r.astype(dtype)).astype(dtype)
+        o = _dot("bhik,bhkv->bhiv", qg, s16) + _dot("bhij,bhjv->bhiv", p, u)
+        return whole[..., None, None] * s0 + _dot("bhik,bhiv->bhkv", kd, u), o
 
     chunks_first = lambda x: jnp.moveaxis(x, 2, 0)
     zeros = jnp.zeros((b, heads, k.shape[-1], v.shape[-1]), jnp.float32)
@@ -416,17 +633,19 @@ def gated_delta_rule(q, k, v, g, beta, key_heads: int, chunk: int, dtype: Dtype 
     (b, n, heads x d_v); g: (b, n, heads), the log-decay, <= 0; beta: (b, n,
     heads). Returns (b, n, heads x d_v) in ``dtype``. ``n`` need not be whole
     chunks: the tail is padded with positions that neither decay nor write
-    (``g = 0``, ``beta = 0``)."""
+    (``g = 0``, ``beta = 0``), for the kernels up to whole tiles of chunks
+    (``chunks_a_tile``)."""
     from .attention import _per_device  # the one shard_map rule of every Mosaic call
 
     b, n, heads = g.shape
     d_k, d_v = q.shape[-1] // key_heads, v.shape[-1] // heads
-    pad = -n % chunk
+    kernels = delta_rule_kernels_eligible(chunk, d_k, d_v)
+    pad = -n % (chunk * chunks_a_tile(chunk) if kernels else chunk)
     q, k, v, g, beta = (jnp.pad(t, ((0, 0), (0, pad), (0, 0))) for t in (q, k, v, g, beta))
     chunks = (n + pad) // chunk
     table = lambda t: t.astype(jnp.float32).reshape(b, chunks, chunk, heads).transpose(0, 3, 1, 2)
     g, beta = chunk_log_decay(table(g)), table(beta)
-    if delta_rule_kernels_eligible(chunk, d_k, d_v):
+    if kernels:
         interpret = kv_policy.pallas_interpret()
         kv_policy.record_route("forward/delta_rule", "gdn_chunk", interpret)
         o = _per_device(

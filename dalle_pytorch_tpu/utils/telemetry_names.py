@@ -369,8 +369,9 @@ KERNEL_NAMES = frozenset({
     "ssd_chunk_bwd",
     "ssm_conv_fwd",         #   the causal convolution, its bias and its silu
     "ssm_conv_bwd",
-    "gdn_chunk_fwd",        # ops/gdn.py: the gated delta rule, a chunk a grid step,
-    "gdn_chunk_bwd",        #   the state (backward: its cotangent) in VMEM scratch
+    "gdn_chunk_tables",     # ops/gdn.py: the gated delta rule; what a chunk computes without
+    "gdn_chunk_fwd",        #   the state (the inverse), no sequential axis; the pass that
+    "gdn_chunk_bwd",        #   carries the state (backward: its cotangent) in VMEM scratch
 })
 
 # span durations are auto-observed as "<span>_s" (utils/telemetry.py);

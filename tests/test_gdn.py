@@ -85,8 +85,13 @@ def test_decays_near_0_and_near_minus_20_and_beta_near_0_and_1(form, decay, beta
     assert_follows(rule(chunk), recurrence, args, cotangent)
 
 
-def test_the_kernels_match_the_xla_form_at_the_sources_chunk(monkeypatch):
-    args, cotangent = inputs(128, 128, seed=2, decay=0.05)
+# at the source's chunk a tile is two chunks and (HV = 2 = ratio) a grid step
+# of the state-free kernel two tiles: 128 positions leave the step's second
+# tile to the grid's edge, 192 end in a chunk that is padded to a tile, 256
+# fill a step
+@pytest.mark.parametrize("n", [128, 192, 256])
+def test_the_kernels_match_the_xla_form_at_the_sources_chunk(n, monkeypatch):
+    args, cotangent = inputs(n, 128, seed=2, decay=0.05)
     out, vjp = jax.vjp(rule(64), *args)
     grads = vjp(cotangent)
     monkeypatch.setattr(gdn, "delta_rule_kernels_eligible", lambda *a: False)
@@ -118,14 +123,53 @@ def test_a_planted_fault_fails(fault, monkeypatch):
         got = rule(16)(q, k, v, g, jnp.ones_like(beta))
     elif fault == "no_correction":
         # the rank-one ADDITION of a state-space layer: what the rule is not
-        monkeypatch.setattr(gdn, "_unit_lower_inverse", lambda a: jnp.eye(a.shape[0]) + 0 * a)
-        gdn._fwd_call.clear_cache()       # the kernel call is a jit of its own
+        monkeypatch.setattr(gdn, "_unit_lower_inverse", lambda a, block=None: jnp.eye(a.shape[-1]) + 0 * a)
+        gdn._tables_call.clear_cache()    # the state-free kernel's call is a jit of its own
         got = rule(16)(*args)
-        gdn._fwd_call.clear_cache()
+        gdn._tables_call.clear_cache()
     else:
         got = jnp.concatenate([rule(16)(*(t[:, lo : lo + 16] for t in args)) for lo in (0, 16, 32)], axis=1)
     gap = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
     assert gap > 0.02, gap         # the program itself reads 2e-5 here
+
+
+def kernel_operands(n, chunk, seed=5):
+    """``delta_rule_chunks``'s operands as ``gated_delta_rule`` hands them over."""
+    (q, k, v, g, beta), _ = inputs(n, 128, seed=seed)
+    table = lambda t: t.reshape(B, n // chunk, chunk, HV).transpose(0, 3, 1, 2)
+    return q, k, v, gdn.chunk_log_decay(table(g)), table(beta)
+
+
+def test_the_inverse_is_built_once_a_forward_call_and_never_in_the_backward(monkeypatch):
+    built = []
+    real = gdn._unit_lower_inverse
+    monkeypatch.setattr(gdn, "_unit_lower_inverse", lambda a, block=None: (built.append((a.shape, block)), real(a, block))[1])
+    calls = (gdn._tables_call, gdn._fwd_call, gdn._bwd_call)
+    for call in calls:
+        call.clear_cache()
+    operands = kernel_operands(256, 64)
+    traced = jax.make_jaxpr(
+        lambda *a: jax.vjp(lambda *b: gdn.delta_rule_chunks(*b, HK, True), *a)[1](a[2])
+    )(*operands)
+    for call in calls:
+        call.clear_cache()
+    # one call, in the state-free kernel: a grid step's two tiles of two chunks, both value heads
+    assert built == [((2 * HV, 128, 128), 64)], built
+    names = str(traced)
+    for kernel in ("gdn_chunk_tables", "gdn_chunk_fwd", "gdn_chunk_bwd"):
+        assert names.count(f"name={kernel}") == 1, kernel
+
+
+@pytest.mark.parametrize("n,chunk", [(256, 64), (128, 16)])
+def test_the_forwards_tables_are_the_xla_forms(n, chunk):
+    q, k, v, g, beta = kernel_operands(n, chunk)
+    tp = gdn._tables_call(q, k, g, beta, key_heads=HK, interpret=True)
+    assert tp.shape == (B, HV, n, 2 * chunk)
+    per_head = lambda t: gdn._split_heads(t, g, HK, HV // HK)
+    t, p = gdn._tables_xla(per_head(q), per_head(k), g, beta, jnp.float32)
+    tp = tp.reshape(B, HV, n // chunk, chunk, 2 * chunk)
+    np.testing.assert_allclose(tp[..., :chunk], t, atol=2e-6)
+    np.testing.assert_allclose(tp[..., chunk:], p, atol=2e-6)
 
 
 def test_the_inverse_is_the_inverse():
@@ -133,6 +177,17 @@ def test_the_inverse_is_the_inverse():
     np.testing.assert_allclose(
         gdn._unit_lower_inverse(a) @ (jnp.eye(64) + a), jnp.eye(64), atol=2e-4
     )
+
+
+@pytest.mark.parametrize("rows,block", [(128, 64), (128, 16), (128, 128), (16, 16), (8, 8)])
+def test_the_inverse_of_a_tile_of_blocks_is_the_blocks_inverses(rows, block):
+    a = jnp.tril(jax.random.normal(jax.random.key(1), (3, rows, rows)) * 0.3, -1)
+    at = np.arange(rows) // block
+    a = jnp.where(at[:, None] == at[None, :], a, 0.0)
+    want = np.linalg.inv(np.eye(rows) + np.asarray(a, np.float64))
+    got = gdn._unit_lower_inverse(a, block)
+    np.testing.assert_allclose(got, want, atol=2e-6 * np.max(np.abs(want)))
+    assert float(jnp.max(jnp.abs(jnp.where(at[:, None] == at[None, :], 0.0, got)))) == 0.0
 
 
 def test_eligibility_is_read_from_the_shape():

@@ -155,7 +155,7 @@ ROUTES = {
     "delta_rule": (
         _delta,
         [(GDN_B, GDN_N, GDN_HK * GDN_D)] * 2 + [(GDN_B, GDN_N, GDN_HV * GDN_D), GDN_TABLE, GDN_TABLE],
-        {"gdn_chunk_fwd", "gdn_chunk_bwd"},
+        {"gdn_chunk_tables", "gdn_chunk_fwd", "gdn_chunk_bwd"},
     ),
     "ssm_conv_no_bias": (
         _conv_no_bias, [(GDN_B, GDN_N, GDN_CONV), ((GDN_B, 4, GDN_CONV), F32)],
