@@ -431,12 +431,15 @@ class ChildReport:
 
     def __init__(self, path: str):
         import jax
-        import jax.monitoring as monitoring
+
+        from dalle_pytorch_tpu.compile_cache import enable_compile_cache
 
         if jax.default_backend() != "tpu":
             raise SystemExit(
                 f"chip_smoke child: backend is {jax.default_backend()!r}, not tpu"
             )
+        # installs the compile ledger, which counts this child's requests
+        enable_compile_cache()
         self.path = Path(path)
         self.ir_dir = self.path.with_suffix(".ir")
         jax.config.update("jax_dump_ir_to", str(self.ir_dir))
@@ -445,23 +448,7 @@ class ChildReport:
         # that requests hundreds of small ones, and a warm run should
         # show (almost) no backend compile at all
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        self.requests = 0
-        self.secs = 0.0
-        self.hits = 0
         self.lines: list[str] = []
-
-        def on_duration(name, secs, **_kw):
-            # fires once per compile REQUEST, persistent-cache hits included
-            if name == "/jax/core/compile/backend_compile_duration":
-                self.requests += 1
-                self.secs += secs
-
-        def on_event(name, **_kw):
-            if name == "/jax/compilation_cache/cache_hits":
-                self.hits += 1
-
-        monitoring.register_event_duration_secs_listener(on_duration)
-        monitoring.register_event_listener(on_event)
 
     def mosaic_modules(self) -> dict:
         """{jit module name: number of Mosaic custom calls in its lowered
@@ -480,13 +467,15 @@ class ChildReport:
         import jax
 
         from dalle_pytorch_tpu.ops import kv_policy
+        from dalle_pytorch_tpu.utils.profiling import COMPILE_LEDGER
 
+        compiles = COMPILE_LEDGER.summary()
         self.path.write_text(json.dumps({
             "backend": jax.default_backend(),
-            "compile_requests": self.requests,
-            "cache_hits": self.hits,
-            "backend_compiles": self.requests - self.hits,
-            "compile_secs": self.secs,
+            "compile_requests": compiles["requests"],
+            "cache_hits": compiles["cache_hits"],
+            "backend_compiles": compiles["cache_misses"],
+            "compile_secs": compiles["seconds"]["backend"],
             "routes": kv_policy.ROUTE_LOG,
             "mosaic_modules": self.mosaic_modules(),
             "lines": self.lines,
@@ -506,9 +495,6 @@ def child_cli(report: str, script: str, argv: list[str]) -> int:
 
 
 def child_checks(report: str, dalle_ckpt: str) -> int:
-    from dalle_pytorch_tpu.compile_cache import enable_compile_cache
-
-    enable_compile_cache()
     rep = ChildReport(report)
     check_engine(dalle_ckpt, rep.lines)
     check_kernels(rep.lines)

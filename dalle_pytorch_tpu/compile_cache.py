@@ -36,10 +36,15 @@ def cache_dir(default: Optional[Union[str, Path]] = None) -> str:
 
 def enable_compile_cache(default: Optional[Union[str, Path]] = None) -> str:
     """Apply the rule above and return the directory in force. Call before
-    the first compile; safe to call more than once."""
+    the first compile; safe to call more than once. Also installs the compile
+    ledger (``utils/profiling.py:COMPILE_LEDGER``), once: every entry point
+    calls this first, so no compile request precedes the ledger."""
     path = cache_dir(default)
     if not os.environ.get(ENV_VAR):
         import jax
 
         jax.config.update("jax_compilation_cache_dir", path)
+    from .utils.profiling import COMPILE_LEDGER
+
+    COMPILE_LEDGER.install()
     return path

@@ -16,6 +16,9 @@ lives here once, for every training CLI:
   (``state.consec_skipped``: it includes skips from before a resume); at
   ``nan_abort_after`` the flight recorder is drained, the caller's emergency
   hook runs, and the process exits.
+* the first finite verdict logs, once a loop, what set-up cost: seconds
+  traced, lowered, loaded or compiled, the cache's hits, the programs compiled
+  afresh and the costliest one (``utils/profiling.py:COMPILE_LEDGER``).
 * ``resolve()`` reads the in-flight verdict on demand, so what a caller saves
   (scheduler state, the index of the last consumed batch) includes it.
 
@@ -33,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from ..utils import TELEMETRY, counters
+from ..utils.profiling import COMPILE_LEDGER
 
 
 class Dispatch(NamedTuple):
@@ -90,6 +94,7 @@ class TrainLoop:
         self._span = None  # the open train.step span (dispatch -> verdict)
         self._last_fed = None  # (index, batch as fetched) of the last dispatch
         self._refeed = False  # its update was rejected: it goes again, first
+        self._set_up_logged = False  # what set-up cost, at the first finite verdict
 
     def epoch(self, epoch: int, batches: Iterable) -> Iterator[Dispatch]:
         """Dispatch every batch of one epoch, yielding after each dispatch;
@@ -146,6 +151,13 @@ class TrainLoop:
         self._span = None
         if finite:
             self.applied_steps += 1
+            if not self._set_up_logged:
+                # the operator's answer to "why did this job take two minutes
+                # to its first step" (docs/DESIGN.md §9)
+                self._set_up_logged = True
+                line = COMPILE_LEDGER.first_step_line()
+                if line is not None:
+                    self._log(line)
             if self._on_applied is not None:
                 self.lr = self._on_applied(loss)
             return

@@ -4,7 +4,7 @@ Every counter, gauge, histogram, span, and event name the package emits
 is declared here, per kind — one dot-separated namespace per subsystem
 (``serve.*`` engine, ``router.*`` front door, ``train.*`` trainer,
 ``data.*``/``webdata.*`` loaders, ``download.*`` fetcher,
-``telemetry.*`` the layer itself). The static checker
+``compile.*`` the compile ledger, ``telemetry.*`` the layer itself). The static checker
 (``tools/lint.py``, finding DTL041) flags any literal passed to
 ``counters.inc`` / ``gauges.set`` / ``histograms.observe`` /
 ``TELEMETRY.span|begin|event`` — and to ``jax.named_scope`` or
@@ -106,6 +106,10 @@ EVENTS = frozenset({
     "data.shard_open",
     "data.shard_quarantined",
     "data.shard_abort",
+    # the compile ledger (utils/profiling.py:COMPILE_LEDGER): one per program
+    # traced, lowered, and loaded or compiled (attrs kind, fun_name, seconds,
+    # cache_hit): WHICH program compiled, and when, in a postmortem
+    "compile.request",
 })
 
 # ------------------------------------------------------------ counters
@@ -219,6 +223,13 @@ COUNTERS = frozenset({
     "webdata.shard_aborts",
     "download.retries",
     "download.failures",
+    # the compile ledger: backend compile requests, those the persistent
+    # cache served, those compiled afresh (requests = hits + misses);
+    # chip_smoke.py's children report them, benchmarks/readers/setup_ledger.py
+    # reads the misses of set-up as setup.fresh_compiles
+    "compile.requests",
+    "compile.cache_hits",
+    "compile.cache_misses",
     # the telemetry layer's self-accounting
     "telemetry.dropped",
     "telemetry.sink_errors",
@@ -263,6 +274,9 @@ GAUGES = frozenset({
     "router.fleet_occupancy",
     "router.replicas_live",
     "router.replica_state_code",
+    # seconds from the compile ledger's installation (start-up) to the first
+    # finite verdict (parallel/loop.py): the operator's time-to-first-step
+    "train.first_step_s",
 })
 
 # ---------------------------------------------------------- histograms
@@ -289,6 +303,14 @@ HISTOGRAMS = frozenset({
     # no_replica): what the fleet told clients to wait — the traffic
     # sim's storm-amplification guard reads this distribution
     "router.retry_after_s",
+    # the compile ledger, one observation per program (sum = seconds,
+    # count = programs): its own Python trace, its conversion to MLIR, its
+    # backend request (a cache hit included), the cache's read inside a hit.
+    # The benchmark's setup.* metrics read the same events from the ledger
+    "compile.trace_s",
+    "compile.lower_s",
+    "compile.backend_s",
+    "compile.cache_load_s",
 })
 
 # ------------------------------------------- device scopes and kernels

@@ -14,13 +14,20 @@ docs/DESIGN.md §9):
   the lowered train step and serving jits, each ``attn.<kind>`` exactly for
   the layers of that kind;
 - the trainer's step-window capture opens and closes once, waiting for the
-  device on both edges.
+  device on both edges;
+- the compile ledger (ISSUE 40): one listener pair however often it is
+  installed; ``trace``, ``lower`` and ``backend`` of a named program with its
+  ``fun_name``; the persistent cache's hit, load and miss under the name of
+  the request they fired inside; a jit traced inside another's trace is kept
+  and summed once; nothing recorded by calls of a compiled function; the cap
+  drops the oldest and counts them; ``compile.request`` reaches a flight file.
 """
 
 import importlib.util
 import os
 import re
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +37,15 @@ import pytest
 from dalle_pytorch_tpu.models import DALLE, DiscreteVAE
 from dalle_pytorch_tpu.serving import Engine, EngineConfig, FakeClock, Request
 from dalle_pytorch_tpu.utils import profiling
-from dalle_pytorch_tpu.utils.telemetry import TELEMETRY, Telemetry
+from dalle_pytorch_tpu.compile_cache import enable_compile_cache
+from dalle_pytorch_tpu.utils.metrics import counters, gauges, histograms
+from dalle_pytorch_tpu.utils.profiling import (
+    COMPILE_LEDGER,
+    CompileLedger,
+    CompileRecord,
+    summarize,
+)
+from dalle_pytorch_tpu.utils.telemetry import TELEMETRY, Telemetry, validate_flight_file
 from dalle_pytorch_tpu.utils.telemetry_names import DEVICE_SCOPES, SPANS
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -305,3 +320,221 @@ def test_step_capture_opens_and_closes_once(monkeypatch):
     cut.at_step(1, "s1")
     cut.close()                    # preempted inside the window: no wait
     assert calls[-2:] == [("start", "/tmp/trace"), ("stop",)]
+
+
+# ------------------------------------------------------------ compile ledger
+
+TRACE = "/jax/core/compile/jaxpr_trace_duration"
+LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND = "/jax/core/compile/backend_compile_duration"
+
+
+def _since(mark, name=None):
+    """The process-wide ledger's records that ended after the time ``mark``
+    (a worker's ledger is at its cap, so not after an index), of the program
+    ``name`` if given."""
+    return [r for r in COMPILE_LEDGER.records() if r.end >= mark
+            and name in (None, profiling.program_of(r.fun_name))]
+
+
+def test_enable_compile_cache_installs_one_listener_pair():
+    import jax._src.monitoring as monitoring
+
+    enable_compile_cache()
+    pair = (len(monitoring.get_event_duration_listeners()), len(monitoring.get_event_listeners()))
+    installed_at = COMPILE_LEDGER.installed_at
+    enable_compile_cache()
+    COMPILE_LEDGER.install()
+    assert (len(monitoring.get_event_duration_listeners()),
+            len(monitoring.get_event_listeners())) == pair
+    assert COMPILE_LEDGER.installed_at == installed_at <= time.monotonic()
+    mine = [f for f in monitoring.get_event_duration_listeners()
+            if getattr(f, "__self__", None) is COMPILE_LEDGER]
+    assert len(mine) == 1
+
+
+def test_a_named_program_is_traced_lowered_and_requested_once_each():
+    mark = before = time.monotonic()
+
+    @jax.jit
+    def ledger_probe_named(x):
+        return jnp.tanh(x) @ x
+
+    ledger_probe_named(jnp.ones((8, 8))).block_until_ready()
+    after = time.monotonic()
+    got = _since(mark, "ledger_probe_named")
+    by_kind = {r.kind: r for r in got}
+    assert sorted(r.kind for r in got if r.seconds) == ["backend", "lower", "trace"]
+    assert by_kind["trace"].fun_name == "ledger_probe_named"
+    assert by_kind["lower"].fun_name == by_kind["backend"].fun_name == "jit(ledger_probe_named)"
+    for r in got:
+        assert before <= r.start <= r.end <= after and abs((r.end - r.start) - r.seconds) < 1e-9
+    assert by_kind["trace"].end <= by_kind["lower"].end <= by_kind["backend"].start + 1e-3
+    assert counters.get("compile.requests") >= 1
+    assert histograms.get("compile.trace_s").snapshot()["count"] >= 1
+
+    # a hundred calls of the compiled function are a hundred cache lookups in C++
+    x = jnp.ones((8, 8))
+    x.block_until_ready()
+    mark = time.monotonic()
+    for _ in range(100):
+        x = ledger_probe_named(x)
+    x.block_until_ready()
+    assert _since(mark) == []
+
+
+def test_the_persistent_caches_events_take_the_requests_name(tmp_path):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def probe():
+        @jax.jit
+        def ledger_probe_cached(x):
+            return jnp.cos(x) * 3.0 + x
+
+        return ledger_probe_cached
+
+    from dalle_pytorch_tpu.compile_cache import ENV_VAR
+
+    directory = ENV_VAR.lower()      # the option the variable places
+    keep = {k: getattr(jax.config, k) for k in (
+        directory, "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    try:
+        jax.config.update(directory, str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        compilation_cache.reset_cache()
+        mark = time.monotonic()
+        probe()(jnp.ones((4, 4))).block_until_ready()      # compiled, and written
+        first = _since(mark, "ledger_probe_cached")
+        mark = time.monotonic()
+        probe()(jnp.ones((4, 4))).block_until_ready()      # a new jit of the same program
+        second = _since(mark, "ledger_probe_cached")
+    finally:
+        for k, v in keep.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    assert [r.kind for r in first if not r.seconds] == ["cache_miss"]
+    assert not any(r.kind == "cache_load" for r in first)
+    kinds = [r.kind for r in second]
+    assert kinds.count("cache_hit") == 1 and kinds.count("cache_load") == 1
+    assert "cache_miss" not in kinds
+    backend = next(r for r in second if r.kind == "backend")
+    for r in second:
+        if r.kind in ("cache_hit", "cache_load"):
+            assert r.fun_name == "jit(ledger_probe_cached)"
+            assert backend.start - 1e-3 <= r.start <= r.end <= backend.end
+    assert counters.get("compile.cache_hits") == 1 and counters.get("compile.cache_misses") >= 1
+    assert counters.get("compile.requests") == (
+        counters.get("compile.cache_hits") + counters.get("compile.cache_misses"))
+    assert histograms.get("compile.cache_load_s").snapshot()["count"] == 1
+
+
+def test_a_jit_traced_inside_anothers_trace_is_kept_and_summed_once():
+
+    @jax.jit
+    def ledger_probe_inner(x):
+        return jnp.sin(x) * 2.0
+
+    @jax.jit
+    def ledger_probe_outer(x):
+        return ledger_probe_inner(x).sum()
+
+    mark = since = time.monotonic()
+    ledger_probe_outer(jnp.ones((4, 4))).block_until_ready()
+    inner = _since(mark, "ledger_probe_inner")
+    assert [r.kind for r in inner] == ["trace"]            # in the list, never lowered alone
+    found = COMPILE_LEDGER.summary(since, time.monotonic())
+    assert "ledger_probe_inner" not in found["programs"]
+    outer = found["programs"]["ledger_probe_outer"]
+    traced = next(r for r in _since(mark, "ledger_probe_outer") if r.kind == "trace")
+    assert outer["trace"] == traced.seconds and outer["lower"] > 0 and outer["backend"] > 0
+    assert traced.start <= inner[0].start and inner[0].end <= traced.end
+    assert found["costliest"][0][0] == "ledger_probe_outer"
+
+
+def _rec(start, end, kind, name):
+    return CompileRecord(float(start), float(end), kind, name, float(end - start))
+
+
+def test_summary_of_a_hand_written_list():
+    recs = [
+        _rec(0, 10, "trace", "step"),              # outermost
+        _rec(1, 3, "trace", "kernel"),             # nested in step's trace
+        _rec(3, 6, "trace", "kernel"),             # nested, adjacent to the one before
+        _rec(4, 5, "trace", "add"),                # nested twice over
+        _rec(10, 12, "lower", "jit(step)"),        # another kind: its own nesting
+        _rec(12, 20, "backend", "jit(step)"),
+        _rec(13, 13, "cache_hit", "jit(step)"),
+        _rec(13, 19, "cache_load", "jit(step)"),
+        _rec(20, 24, "trace", "init"),             # adjacent to nothing of its kind: counts
+        _rec(22, 28, "trace", "overlap"),          # overlaps init, inside nothing: counts in full
+        _rec(28, 29, "backend", "jit(init)"),
+        _rec(29, 29, "cache_miss", "jit(init)"),
+        _rec(30, 31, "trace", "step"),             # the same program again, later
+        _rec(30, 31, "trace", "twin"),             # the same interval: one of the two is outermost
+        _rec(100, 140, "backend", "jit(reference)"),   # after ``until``
+        _rec(101, 101, "cache_miss", "jit(reference)"),
+    ]
+    found = summarize(recs, 0.0, 50.0)
+    assert found["seconds"] == {"trace": 10 + 4 + 6 + 1, "lower": 2.0, "backend": 9.0, "cache_load": 6.0}
+    assert found["programs"]["step"] == {"trace": 11.0, "lower": 2.0, "backend": 8.0, "cache_load": 6.0}
+    assert "kernel" not in found["programs"] and "add" not in found["programs"]
+    assert "twin" not in found["programs"] and "reference" not in found["programs"]
+    assert (found["requests"], found["cache_hits"], found["cache_misses"]) == (2, 1, 1)
+    assert found["costliest"][:2] == [("step", 21.0), ("overlap", 6.0)]
+    assert found["records"] == 14
+    late = summarize(recs, 50.0)
+    assert late["seconds"]["backend"] == 40.0 and late["cache_misses"] == 1 and late["records"] == 2
+    # the order of the list does not matter
+    assert summarize(recs[::-1], 0.0, 50.0)["seconds"] == found["seconds"]
+
+
+def test_the_cap_drops_the_oldest_and_counts_them():
+    ledger = CompileLedger(cap=4)       # never installed: fed by hand
+    for i in range(3):
+        ledger._on_duration(TRACE, 0.5, fun_name=f"f{i}")
+        ledger._on_duration(LOWER, 0.25, fun_name=f"jit(f{i})")
+    kept = ledger.records()
+    assert len(kept) == 4 and ledger.dropped == 2
+    assert [r.fun_name for r in kept] == ["f1", "jit(f1)", "f2", "jit(f2)"]
+    ledger._on_duration(BACKEND, 1.0, fun_name="jit(f2)")      # two records at once
+    assert ledger.dropped == 4 and [r.kind for r in ledger.records()][-2:] == ["backend", "cache_miss"]
+    assert ledger.summary()["dropped"] == 4
+    ledger._on_duration("/jax/some/other_duration", 1.0)        # not a compile event
+    assert ledger.dropped == 4
+    assert ledger.installed_at is None and ledger.first_step_line() is None
+
+
+def test_a_compile_request_reaches_the_flight_file_by_name(tmp_path):
+    TELEMETRY.configure(enabled=True, flight_dir=str(tmp_path))
+
+    @jax.jit
+    def ledger_probe_flight(x):
+        return jnp.exp(x) - 1.0
+
+    ledger_probe_flight(jnp.ones((4,))).block_until_ready()
+    path = TELEMETRY.drain("test")
+    found = validate_flight_file(path)
+    assert found["unclosed"] == [] and found["by_name"]["compile.request"] >= 3
+    import json
+
+    mine = [json.loads(l) for l in open(path) if "ledger_probe_flight" in l]
+    assert [(r["name"], r["ph"], r["kind"], r["fun_name"]) for r in mine] == [
+        ("compile.request", "I", "trace", "ledger_probe_flight"),
+        ("compile.request", "I", "lower", "jit(ledger_probe_flight)"),
+        ("compile.request", "I", "backend", "jit(ledger_probe_flight)"),
+    ]
+    assert mine[2]["cache_hit"] in (True, False) and all(r["seconds"] > 0 for r in mine)
+    # only a program's own trace is published: the jnp functions traced inside it stay in the ledger
+    kinds = [json.loads(l).get("kind") for l in open(path) if '"compile.request"' in l]
+    assert kinds.count("trace") <= kinds.count("lower") == kinds.count("backend")
+
+
+def test_the_first_step_line_and_its_gauge():
+    line = COMPILE_LEDGER.first_step_line()
+    assert re.fullmatch(
+        r"first step verdict [0-9.]+ s after start-up: traced [0-9.]+ s, lowered [0-9.]+ s, "
+        r"loaded or compiled [0-9.]+ s \([0-9]+ requests: [0-9]+ cache hits taking [0-9.]+ s, "
+        r"[0-9]+ compiled afresh\)(; costliest program .+ [0-9.]+ s)?", line), line
+    assert gauges.get("train.first_step_s") > 0

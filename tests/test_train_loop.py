@@ -222,7 +222,9 @@ def test_abort_at_the_limit_drains_then_calls_the_hook_once(tmp_path, note):
     assert str(exit_.value) == (f"{want} ({note})" if note else want)
     # batch 2 was rejected three times; its predecessor is the last consumed
     assert s.tags() == [0, 1, 2, 2, 2] and hooked == [(1, True)]
-    assert logged == [
+    # the first finite verdict logs what set-up cost, once (the compile ledger)
+    assert logged[0].startswith("first step verdict ") and "compiled afresh" in logged[0]
+    assert logged[1:] == [
         f"step {k}: non-finite loss — update skipped on device, "
         f"retrying batch ({n}/3)"
         for k, n in ((2, 1), (3, 2), (4, 3))
