@@ -45,7 +45,9 @@ def build(force: bool = False) -> Optional[Path]:
         ):
             return so
         so.parent.mkdir(parents=True, exist_ok=True)
-        tmp = so.with_suffix(".so.tmp")
+        # a name of this process's own: test workers that find no .so all
+        # build at once, and one's os.replace took another's file away
+        tmp = so.with_suffix(f".{os.getpid()}.so.tmp")
         cmd = [
             os.environ.get("CXX", "g++"),
             "-O2", "-std=c++17", "-shared", "-fPIC",

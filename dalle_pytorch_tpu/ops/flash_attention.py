@@ -10,11 +10,13 @@ full (block_q x d x block_k) matmuls:
 - forward: grid (b·h, n/bq, n/bk); the innermost k dimension iterates
   sequentially with running (max, denom, unnormalized out) in VMEM scratch;
   emits per-row logsumexp for the backward;
-- backward: recompute-based (FlashAttention-2 decomposition, no stored
-  probabilities): one kernel accumulates dq over k blocks — and computes
-  delta = rowsum(do*o) in-kernel from blocks already in VMEM (no separate
-  elementwise pass over do/o in HBM) — another accumulates (dk, dv) over
-  q blocks, consuming the emitted delta;
+- backward: recompute-based (no stored probabilities), ONE kernel at every
+  grid (b·h, n/bk, n/bq): a live tile's scores, mask, exp and dp = do·v^T
+  are rebuilt once and give dq, dk and dv, five dots a tile; dk/dv sum
+  over the inner query blocks in VMEM scratch, dq over the outer key
+  blocks in a float32 VMEM row of the whole sequence (float32 partials
+  summed by XLA where that row does not fit: ``_bwd_rule``), and
+  delta = rowsum(do*o) comes from blocks already in VMEM, never from HBM;
 - masking: ``causal=True`` is analytic (above-diagonal blocks execute no
   dots); an optional static (n, n) pattern mask (ops/masks.py) is streamed
   blockwise for sparse/axial/conv layouts with all-empty blocks skipped the
@@ -198,108 +200,40 @@ def _fwd_kernel(
         lse_ref[0] = jax.lax.transpose(lse, (1, 0))
 
 
-def _bwd_dq_kernel(
+def _bwd_kernel(
     scalar_ref, q_ref, k_ref, v_ref, mask_ref, kmask_ref, do_ref, o_ref, lse_ref,
-    dq_ref, delta_ref, dq_scr, delta_scr,
-    *, sm_scale, block_q, block_k, nk,
+    dq_ref, dk_ref, dv_ref, *scratch,
+    sm_scale, block_q, block_k, nq, nk, dq_resident,
 ):
-    qb, kb = pl.program_id(1), pl.program_id(2)
+    """The backward at every grid: (b·h, key block, query block), each live
+    tile visited once. From ONE ``_masked_scores`` + ``_masked_exp`` the tile
+    gives ``dv += p^T do``, ``dp = do v^T``, ``ds = p (dp - delta) scale``,
+    ``dk += ds^T q`` and ``dq[query block] += ds k``: five dots.
+    delta = rowsum(do * o) is taken from the blocks already in VMEM and
+    never reaches HBM.
 
-    @pl.when(kb == 0)
-    def _():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
-        # delta = rowsum(do * o), computed here from the blocks already in
-        # VMEM instead of a separate elementwise pass over do/o in HBM; the
-        # dkv kernel consumes the emitted delta_ref
-        delta_scr[:, 0:1] = jnp.sum(
-            do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32),
-            axis=-1, keepdims=True,
-        )
-
-    visit = scalar_ref[0, qb * nk + kb]
-
-    @pl.when(visit > 0)
-    def _():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        s = _masked_scores(
-            q, k, sm_scale, mask_ref, kmask_ref, visit,
-            qb * block_q, kb * block_k, block_q, block_k,
-        )
-        p = _masked_exp(s, _row_vec(lse_ref))
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta_scr[:, 0:1]) * sm_scale
-        dq_scr[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(kb == nk - 1)
-    def _():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
-        delta_ref[0] = jax.lax.transpose(delta_scr[:, 0:1], (1, 0))
-
-
-def _bwd_fused_kernel(
-    scalar_ref, q_ref, k_ref, v_ref, mask_ref, kmask_ref, do_ref, o_ref, lse_ref,
-    dq_ref, dk_ref, dv_ref,
-    *, sm_scale, block_q, block_k,
-):
-    """Single-block backward (nq == nk == 1): the whole row fits one grid
-    step, so dq, dk and dv come out of ONE score recomputation — 5 block
-    dots (s, dp, dq, dv, dk) instead of the split kernels' 7 (the dq and
-    dkv passes each re-derive s). At the flagship seq-1280 whole-row block
-    this is the production backward; the split kernels remain for tiled
-    grids, where dq accumulates over the inner k dimension while dk/dv
-    need the transposed iteration order. delta = rowsum(do*o) is computed
-    in-register — never written to HBM at all."""
-    visit = scalar_ref[0, 0]
-
-    @pl.when(visit > 0)
-    def _():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        s = _masked_scores(
-            q, k, sm_scale, mask_ref, kmask_ref, visit, 0, 0, block_q, block_k,
-        )
-        p = _masked_exp(s, _row_vec(lse_ref))
-        dv_ref[0] = jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(dv_ref.dtype)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        delta = jnp.sum(
-            do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
-            axis=-1, keepdims=True,
-        )
-        ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
-        dq_ref[0] = jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        ).astype(dq_ref.dtype)
-        dk_ref[0] = jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        ).astype(dk_ref.dtype)
-
-    @pl.when(visit == 0)
-    def _():
-        dq_ref[0] = jnp.zeros_like(dq_ref[0])
-        dk_ref[0] = jnp.zeros_like(dk_ref[0])
-        dv_ref[0] = jnp.zeros_like(dv_ref[0])
-
-
-def _bwd_dkv_kernel(
-    scalar_ref, q_ref, k_ref, v_ref, mask_ref, kmask_ref, do_ref, lse_ref, delta_ref,
-    dk_ref, dv_ref, dk_scr, dv_scr,
-    *, sm_scale, block_q, block_k, nq,
-):
+    An output block that several tiles add to is summed in float32 scratch
+    and rounded once; one that a single tile completes is written as it is.
+    dk and dv sum over the inner query blocks (``scratch[:2]``, (block_k, d)
+    and (block_k, dv), where nq > 1). dq sums over the OUTER key blocks:
+    with ``dq_resident`` in ``scratch[-1]``, the whole (n, d) row of one b·h,
+    written to the resident ``dq_ref`` at the row's last tile; without it
+    ``dq_ref`` is this tile's own block (``_bwd_rule`` says which form)."""
     kb, qb = pl.program_id(1), pl.program_id(2)
+    dk_scr, dv_scr = scratch[:2] if nq > 1 else (None, None)
+    dq_row = scratch[-1] if dq_resident else None
+    rows = pl.ds(pl.multiple_of(qb * block_q, block_q), block_q)
 
-    @pl.when(qb == 0)
-    def _():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
+    if nq > 1:
+        @pl.when(qb == 0)
+        def _():
+            dk_scr[:] = jnp.zeros_like(dk_scr)
+            dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    if dq_resident:
+        @pl.when(kb == 0)
+        def _():
+            dq_row[rows, :] = jnp.zeros((block_q, dq_row.shape[1]), jnp.float32)
 
     visit = scalar_ref[0, kb * nq + qb]
 
@@ -311,22 +245,55 @@ def _bwd_dkv_kernel(
             qb * block_q, kb * block_k, block_q, block_k,
         )
         p = _masked_exp(s, _row_vec(lse_ref))
-        dv_scr[:] += jax.lax.dot_general(
+        dv = jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        ds = (p * (dp - _row_vec(delta_ref)) * sm_scale).astype(q.dtype)
-        dk_scr[:] += jax.lax.dot_general(
+        delta = jnp.sum(
+            do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
+            axis=-1, keepdims=True,
+        )
+        ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
+        dk = jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
+        dq = jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        if nq > 1:
+            dk_scr[:] += dk
+            dv_scr[:] += dv
+        else:
+            dk_ref[0] = dk.astype(dk_ref.dtype)
+            dv_ref[0] = dv.astype(dv_ref.dtype)
+        if dq_resident:
+            dq_row[rows, :] += dq
+        else:
+            dq_ref[0] = dq.astype(dq_ref.dtype)
 
-    @pl.when(qb == nq - 1)
-    def _():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+    if nq == 1 or not dq_resident:
+        # a dead tile still owes the blocks that it alone writes their zeros
+        @pl.when(visit == 0)
+        def _():
+            if nq == 1:
+                dk_ref[0] = jnp.zeros_like(dk_ref[0])
+                dv_ref[0] = jnp.zeros_like(dv_ref[0])
+            if not dq_resident:
+                dq_ref[0] = jnp.zeros_like(dq_ref[0])
+
+    if nq > 1:
+        @pl.when(qb == nq - 1)
+        def _():
+            dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+            dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+    if dq_resident:
+        @pl.when(jnp.logical_and(kb == nk - 1, qb == nq - 1))
+        def _():
+            dq_ref[0] = dq_row[:].astype(dq_ref.dtype)
 
 
 # ------------------------------------------------------------------ plumbing
@@ -358,12 +325,14 @@ def _kernel_cost(
     """Cost of one pass over the live blocks — fed to XLA so compiled-module
     cost analysis and the scheduler see the kernel's real FLOPs instead of
     zero for the opaque custom call. ``dots_per_block``: dot_generals the
-    body executes per live block (fwd 2: s, o-acc; dq 3: s, dp, dq;
-    dkv 4: s, dv, dp, dk). Streamed-operand DMA happens on EVERY grid step
+    body executes per live block (fwd 2: s, o-acc; bwd 5: s, dv, dp, dk,
+    dq). Streamed-operand DMA happens on EVERY grid step
     (affine index maps — dead blocks skip compute, not traffic):
     ``per_step_rows`` rows of d move per inner step, ``per_outer_rows`` rows
     once per outer step (operands whose block index only depends on the
-    outer grid dimension, plus outputs). Of those counts, ``v_dots`` dots
+    outer grid dimension, plus outputs; the backward's resident dq row
+    leaves once a b·h and is counted as its share a key block). Of those
+    counts, ``v_dots`` dots
     and ``v_step_rows`` / ``v_outer_rows`` rows have the VALUE width ``dv``
     (v, o, do, dv) where it is not the query/key width ``d``; with one
     width the estimate is the one-width formula's, number for number."""
@@ -381,7 +350,15 @@ def _kernel_cost(
     )
 
 
-def _call(kernel, grid, in_specs, out_specs, out_shape, scratch, scalar, operands, interpret, cost=None, *, name):
+# one budget for every kernel here that asks Mosaic for more than its default
+# scoped VMEM (v5e has 128 MiB physical): _call and _call_plain hand it over,
+# fused_qkv_supported derives the packed path's admissible n from it, and the
+# backward's resident dq row is held to DQ_ROW_VMEM_BYTES of it
+VMEM_LIMIT_BYTES = 100 * 1024 * 1024
+
+
+def _call(kernel, grid, in_specs, out_specs, out_shape, scratch, scalar, operands, interpret, cost=None,
+          *, name, semantics=("parallel", "parallel", "arbitrary"), vmem_limit_bytes=None):
     return pl.pallas_call(
         kernel,
         name=name,
@@ -393,10 +370,10 @@ def _call(kernel, grid, in_specs, out_specs, out_shape, scratch, scalar, operand
             scratch_shapes=scratch,
         ),
         out_shape=out_shape,
-        # batch*heads and outer blocks are independent; only the innermost
-        # (accumulating) dimension is order-dependent — lets Mosaic pipeline
+        # batch*heads and (forward) outer blocks are independent; a dimension
+        # something accumulates over is order-dependent — lets Mosaic pipeline
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+            dimension_semantics=semantics, vmem_limit_bytes=vmem_limit_bytes
         ),
         cost_estimate=cost,
         interpret=interpret,
@@ -531,153 +508,42 @@ def _fwd_rule(q, k, v, key_mask, causal, pattern_mask, sm_scale, block_q, block_
     return o, (q, k, v, key_mask, o, lse)
 
 
+# the backward keeps one b·h's whole dq row (n, d) in float32 VMEM scratch
+# while the row's tiles add to it; a longer row takes the partials form
+DQ_ROW_VMEM_BYTES = 16 * 1024 * 1024
+
+
 def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, do):
+    """One ``pallas_call`` (``_bwd_kernel``) at every grid. Where dq sums over
+    the key blocks is chosen from the SHAPE: a row of n·d·4 bytes within
+    ``DQ_ROW_VMEM_BYTES`` stays in VMEM and dq leaves the kernel once, in the
+    inputs' dtype; a longer row leaves as float32 partials (n/block_k, b·h,
+    n, d) that XLA sums (n/block_k times dq's bytes in HBM: the price of a
+    row that does not fit). With one key block there is nothing to sum."""
     q, k, v, key_mask, o, lse = res
     b, h, n, d, nq, nk, mask_np, visit = _prep(q, pattern_mask, block_q, block_k, causal)
     dv = v.shape[-1]
     scale = d**-0.5 if sm_scale is None else sm_scale
     bh = b * h
+    resident = nk > 1 and n * d * 4 <= DQ_ROW_VMEM_BYTES
 
     qf, kf = q.reshape(bh, n, d), k.reshape(bh, n, d)
     vf, dof, of = (t.reshape(bh, n, dv) for t in (v, do, o))
     lsef = lse.reshape(bh, 1, n)
     mask_op = [] if mask_np is None else [jnp.asarray(mask_np, jnp.int8)]
     km_op = [] if key_mask is None else [_bcast_key_mask(key_mask, b, h, n)]
-
-    # ---- single-block fast path: one fused kernel, 5 dots instead of 7 ----
-    if nq == 1 and nk == 1:
-        def whole(bhi, qb, kb, s):
-            return (bhi, 0, 0)
-
-        row = whole
-
-        fused_specs = [
-            pl.BlockSpec((1, block_q, d), whole),
-            pl.BlockSpec((1, block_k, d), whole),
-            pl.BlockSpec((1, block_k, dv), whole),
-            *(
-                [pl.BlockSpec((block_q, block_k), lambda bhi, qb, kb, s: (0, 0))]
-                if mask_np is not None else []
-            ),
-            *(
-                [pl.BlockSpec((1, 1, block_k), row)]
-                if key_mask is not None else []
-            ),
-            pl.BlockSpec((1, block_q, dv), whole),
-            pl.BlockSpec((1, block_q, dv), whole),
-            pl.BlockSpec((1, 1, block_q), row),
-        ]
-        fused_kernel = _with_optional_masks(
-            functools.partial(
-                _bwd_fused_kernel, sm_scale=scale,
-                block_q=block_q, block_k=block_k,
-            ),
-            mask_np is not None,
-            key_mask is not None,
-            n_out=3,
-            n_scratch=0,
-        )
-        dq, dk, dv_ = _call(
-            fused_kernel,
-            name="flash_bwd",
-            grid=(bh, 1, 1),
-            in_specs=fused_specs,
-            out_specs=[
-                pl.BlockSpec((1, block_q, d), whole),
-                pl.BlockSpec((1, block_k, d), whole),
-                pl.BlockSpec((1, block_k, dv), whole),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((bh, n, d), q.dtype),
-                jax.ShapeDtypeStruct((bh, n, d), q.dtype),
-                jax.ShapeDtypeStruct((bh, n, dv), q.dtype),
-            ],
-            scratch=[],
-            scalar=jnp.asarray(_scalar_table(visit)),
-            operands=[qf, kf, vf, *mask_op, *km_op, dof, of, lsef],
-            interpret=interpret,
-            cost=_kernel_cost(visit, bh, block_q, block_k, d, 5,
-                              0, 7 * block_q, q.dtype.itemsize,
-                              dv, 2, 0, 4 * block_q),
-        )
-        dkm = (
-            None if key_mask is None
-            else np.zeros(key_mask.shape, jax.dtypes.float0)
-        )
-        return (
-            dq.reshape(b, h, n, d),
-            dk.reshape(b, h, n, d),
-            dv_.reshape(b, h, n, dv),
-            dkm,
-        )
-
-    # ---- dq over k blocks (also emits delta = rowsum(do*o) for dkv) -------
-    def kv_im(bhi, qb, kb, s):
-        return (bhi, kb, 0)
-
-    dq_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bhi, qb, kb, s: (bhi, qb, 0)),
-        pl.BlockSpec((1, block_k, d), kv_im),
-        pl.BlockSpec((1, block_k, dv), kv_im),
-        *(
-            [pl.BlockSpec((block_q, block_k), lambda bhi, qb, kb, s: (qb, kb))]
-            if mask_np is not None else []
-        ),
-        *(
-            [pl.BlockSpec((1, 1, block_k), lambda bhi, qb, kb, s: (bhi, 0, kb))]
-            if key_mask is not None else []
-        ),
-        pl.BlockSpec((1, block_q, dv), lambda bhi, qb, kb, s: (bhi, qb, 0)),
-        pl.BlockSpec((1, block_q, dv), lambda bhi, qb, kb, s: (bhi, qb, 0)),
-        pl.BlockSpec((1, 1, block_q), lambda bhi, qb, kb, s: (bhi, 0, qb)),
-    ]
-    dq_kernel = _with_optional_masks(
-        functools.partial(
-            _bwd_dq_kernel, sm_scale=scale, block_q=block_q, block_k=block_k, nk=nk
-        ),
-        mask_np is not None,
-        key_mask is not None,
-        n_out=2,
-        n_scratch=2,
-    )
-    dq, deltaf = _call(
-        dq_kernel,
-        name="flash_dq",
-        grid=(bh, nq, nk),
-        in_specs=dq_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bhi, qb, kb, s: (bhi, qb, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda bhi, qb, kb, s: (bhi, 0, qb)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, n, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, 1, n), jnp.float32),
-        ],
-        scratch=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-        ],
-        scalar=jnp.asarray(_scalar_table(visit)),
-        operands=[qf, kf, vf, *mask_op, *km_op, dof, of, lsef],
-        interpret=interpret,
-        cost=_kernel_cost(visit, bh, block_q, block_k, d, 3,
-                          2 * block_k, 4 * block_q, q.dtype.itemsize,
-                          dv, 1, block_k, 2 * block_q),
-    )
-
-    # ---- dk/dv over q blocks ----------------------------------------------
     visit_t = np.ascontiguousarray(visit.T)
 
     def q_im(bhi, kb, qb, s):
         return (bhi, qb, 0)
 
-    def row_im(bhi, kb, qb, s):
-        return (bhi, 0, qb)
+    def kv_im(bhi, kb, qb, s):
+        return (bhi, kb, 0)
 
-    dkv_specs = [
+    in_specs = [
         pl.BlockSpec((1, block_q, d), q_im),
-        pl.BlockSpec((1, block_k, d), lambda bhi, kb, qb, s: (bhi, kb, 0)),
-        pl.BlockSpec((1, block_k, dv), lambda bhi, kb, qb, s: (bhi, kb, 0)),
+        pl.BlockSpec((1, block_k, d), kv_im),
+        pl.BlockSpec((1, block_k, dv), kv_im),
         *(
             [pl.BlockSpec((block_q, block_k), lambda bhi, kb, qb, s: (qb, kb))]
             if mask_np is not None else []
@@ -687,42 +553,62 @@ def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, 
             if key_mask is not None else []
         ),
         pl.BlockSpec((1, block_q, dv), q_im),
-        pl.BlockSpec((1, 1, block_q), row_im),
-        pl.BlockSpec((1, 1, block_q), row_im),
+        pl.BlockSpec((1, block_q, dv), q_im),
+        pl.BlockSpec((1, 1, block_q), lambda bhi, kb, qb, s: (bhi, 0, qb)),
     ]
-    dkv_kernel = _with_optional_masks(
+    scratch = [
+        pltpu.VMEM((block_k, d), jnp.float32), pltpu.VMEM((block_k, dv), jnp.float32),
+    ] if nq > 1 else []
+    if resident:
+        dq_spec = pl.BlockSpec((1, n, d), lambda bhi, kb, qb, s: (bhi, 0, 0))
+        dq_shape = jax.ShapeDtypeStruct((bh, n, d), q.dtype)
+        scratch.append(pltpu.VMEM((n, d), jnp.float32))
+    else:
+        dq_spec = pl.BlockSpec((1, block_q, d), lambda bhi, kb, qb, s: (kb * bh + bhi, qb, 0))
+        dq_shape = jax.ShapeDtypeStruct((nk * bh, n, d), jnp.float32 if nk > 1 else q.dtype)
+    kernel = _with_optional_masks(
         functools.partial(
-            _bwd_dkv_kernel, sm_scale=scale, block_q=block_q, block_k=block_k, nq=nq
+            _bwd_kernel, sm_scale=scale, block_q=block_q, block_k=block_k,
+            nq=nq, nk=nk, dq_resident=resident,
         ),
         mask_np is not None,
         key_mask is not None,
-        n_out=2,
-        n_scratch=2,
+        n_out=3,
+        n_scratch=len(scratch),
     )
-    dk, dv_ = _call(
-        dkv_kernel,
-        name="flash_dkv",
+    itemsize = q.dtype.itemsize
+    # rows of d that dq moves: resident, block_k of the row's n a key block;
+    # otherwise a block of its own dtype every inner step
+    dq_outer = block_k if resident else 0
+    dq_step = 0 if resident else block_q * dq_shape.dtype.itemsize // itemsize
+    dq, dk, dv_ = _call(
+        kernel,
+        name="flash_bwd",
         grid=(bh, nk, nq),
-        in_specs=dkv_specs,
+        in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bhi, kb, qb, s: (bhi, kb, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda bhi, kb, qb, s: (bhi, kb, 0)),
+            dq_spec,
+            pl.BlockSpec((1, block_k, d), kv_im),
+            pl.BlockSpec((1, block_k, dv), kv_im),
         ],
         out_shape=[
+            dq_shape,
             jax.ShapeDtypeStruct((bh, n, d), q.dtype),
             jax.ShapeDtypeStruct((bh, n, dv), q.dtype),
         ],
-        scratch=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, dv), jnp.float32),
-        ],
+        scratch=scratch,
         scalar=jnp.asarray(_scalar_table(visit_t)),
-        operands=[qf, kf, vf, *mask_op, *km_op, dof, lsef, deltaf],
+        operands=[qf, kf, vf, *mask_op, *km_op, dof, of, lsef],
         interpret=interpret,
-        cost=_kernel_cost(visit_t, bh, block_q, block_k, d, 4,
-                          2 * block_q, 4 * block_k, q.dtype.itemsize,
-                          dv, 2, block_q, 2 * block_k),
+        cost=_kernel_cost(visit_t, bh, block_q, block_k, d, 5,
+                          3 * block_q + dq_step, 4 * block_k + dq_outer, itemsize,
+                          dv, 2, 2 * block_q, 2 * block_k),
+        # dk/dv accumulate over the query blocks, dq over the key blocks
+        semantics=("parallel", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=VMEM_LIMIT_BYTES,
     )
+    if not resident and nk > 1:
+        dq = dq.reshape(nk, bh, n, d).sum(axis=0).astype(q.dtype)
     dkm = None if key_mask is None else np.zeros(key_mask.shape, jax.dtypes.float0)
     return (
         dq.reshape(b, h, n, d),
@@ -897,12 +783,6 @@ def _fused_qkv_bwd_kernel(
     dv_ref[0] = dvs[0] if hpb == 1 else jnp.concatenate(dvs, axis=-1)
 
 
-# one budget, two consumers: _call_plain hands it to Mosaic, and
-# fused_qkv_supported derives the admissible n from it — keep in sync by
-# construction
-FUSED_VMEM_LIMIT_BYTES = 100 * 1024 * 1024
-
-
 def _call_plain(kernel, grid, in_specs, out_specs, out_shape, operands, interpret, cost, *, name):
     return pl.pallas_call(
         kernel,
@@ -916,7 +796,7 @@ def _call_plain(kernel, grid, in_specs, out_specs, out_shape, operands, interpre
             # the head-group backward holds several (n, n) f32 temporaries
             # at once (s, p, dp, ds); the default 16 MiB scoped-vmem budget
             # is exceeded at n=1280 x 2 heads — v5e has 128 MiB physical
-            vmem_limit_bytes=FUSED_VMEM_LIMIT_BYTES,
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         cost_estimate=cost,
         interpret=interpret,
@@ -935,7 +815,7 @@ def fused_qkv_supported(n, heads, dim_head):
     a fixed n <= 2048 cap used to pass this check yet fail to compile on
     real hardware."""
     hpb = max(1, 128 // dim_head)
-    vmem_budget = int(FUSED_VMEM_LIMIT_BYTES * 0.8)
+    vmem_budget = int(VMEM_LIMIT_BYTES * 0.8)
     bwd_temp_bytes = 4 * n * n * 4 * hpb
     return (
         n % 128 == 0

@@ -375,9 +375,7 @@ DEVICE_SCOPES = frozenset({
 # reducers strip trailing digits and dots)
 KERNEL_NAMES = frozenset({
     "flash_fwd",            # ops/flash_attention.py, blocked
-    "flash_bwd",            #   single-block fused backward
-    "flash_dq",
-    "flash_dkv",
+    "flash_bwd",            #   dq, dk and dv from one pass over the live tiles
     "flash_qkv_fwd",        #   packed whole-row (fused qkv) route
     "flash_qkv_bwd",
     "block_sparse_fwd",     # ops/block_sparse_attention.py pair grid

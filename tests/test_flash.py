@@ -8,6 +8,8 @@ Reference semantics being matched: dense causal attention
 variable-sparsity block attention (attention.py:338-351).
 """
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,6 +18,9 @@ import pytest
 from dalle_pytorch_tpu.ops import masks as masks_lib
 from dalle_pytorch_tpu.ops.attention import dense_attend
 from dalle_pytorch_tpu.ops.flash_attention import StaticMask, flash_attention
+
+# the module itself (``ops/__init__.py`` exports the function under its name)
+fa = importlib.import_module("dalle_pytorch_tpu.ops.flash_attention")
 
 
 def _qkv(key, b, h, n, d, dtype=jnp.float32):
@@ -125,6 +130,115 @@ def test_fully_masked_rows_zero_output_and_grads():
     g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
     np.testing.assert_allclose(dk, g_ref[1], atol=5e-4, rtol=5e-4)
     np.testing.assert_allclose(dv, g_ref[2], atol=5e-4, rtol=5e-4)
+
+
+# ------------------------------------------- the one backward kernel, tiled
+
+
+def _dead_tile_mask(n, block):
+    """Causal, and the LAST query block sees nothing of the FIRST key block
+    (a dead tile under the diagonal) and half of the next one's columns."""
+    mask = masks_lib.causal_mask(n).copy()
+    mask[n - block:, :block] = False
+    mask[n - block:, block:block + block // 2] = False
+    return mask
+
+
+def _tiled_case(case, n, block):
+    """(q, k, v, do, flash kwargs, the (b, 1|h, n, n) pairs that may attend,
+    tolerance) of one case of the tiled backward."""
+    d, dv, dtype, tol = 32, 32, jnp.float32, 2e-5
+    if case == "own_value_width":
+        d, dv = 24, 16   # latent attention's 192 / 128, scaled down
+    if case == "bf16":
+        dtype, tol = jnp.bfloat16, 0.06
+    keys = jax.random.split(jax.random.PRNGKey(n + block), 5)
+    q, k = (jax.random.normal(keys[i], (2, 2, n, d), dtype) for i in (0, 1))
+    v, do = (jax.random.normal(keys[i], (2, 2, n, dv), dtype) for i in (2, 3))
+    kwargs, pairs = dict(causal=True), masks_lib.causal_mask(n)
+    if case == "non_causal":
+        kwargs, pairs = dict(causal=False), np.ones((n, n), bool)
+    if case in ("dead_tiles", "masked_row"):
+        pairs = _dead_tile_mask(n, block)
+        if case == "masked_row":
+            pairs[n - 5, :] = False   # a row that sees nothing at all
+        kwargs = dict(causal=True, pattern_mask=StaticMask(pairs))
+    allowed = jnp.asarray(pairs)[None, None]
+    if case == "key_mask":
+        km = _rand_key_mask(keys[4], 2, n, fully_masked_batch=None).at[:, 0].set(False)
+        kwargs["key_mask"] = km
+        allowed = allowed & km[:, None, None, :]
+    return q, k, v, do, kwargs, allowed, tol
+
+
+TILED_CASES = ["causal", "non_causal", "dead_tiles", "key_mask", "masked_row",
+               "own_value_width", "bf16"]
+
+
+@pytest.mark.parametrize("dq_form", ["resident", "partials"])
+@pytest.mark.parametrize("grid", [1, 2, 4])
+@pytest.mark.parametrize("case", TILED_CASES)
+def test_tiled_backward_matches_the_dense_oracle(case, grid, dq_form, monkeypatch):
+    """dq, dk and dv of the one backward kernel on a grid x grid tiling
+    against the dense oracle's, with dq summed over the key blocks in its
+    resident VMEM row and (a row over the budget) as float32 partials; at
+    grid 1 every block is one tile's and nothing is summed (``dead_tiles``
+    is then one dead tile: all zeros)."""
+    if dq_form == "partials":
+        monkeypatch.setattr(fa, "DQ_ROW_VMEM_BYTES", 0)
+    block = 32
+    n = grid * block
+    q, k, v, do, kwargs, allowed, tol = _tiled_case(case, n, block)
+    scale = q.shape[-1] ** -0.5
+
+    def flash(q, k, v):
+        return flash_attention(
+            q, k, v, sm_scale=scale, block_q=block, block_k=block, interpret=True, **kwargs
+        )
+
+    def dense(q, k, v):
+        out = dense_attend(q * scale, k, v, allowed)
+        return jnp.where(jnp.any(allowed, axis=-1)[..., None], out, 0.0)
+
+    f32 = lambda t: t.astype(jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        out, vjp = jax.vjp(flash, q, k, v)
+        want, want_vjp = jax.vjp(dense, f32(q), f32(k), f32(v))
+        got, wanted = vjp(do), want_vjp(f32(do))
+    assert float(jnp.max(jnp.abs(f32(out) - want))) < tol
+    for g, w, like in zip(got, wanted, (q, k, v)):
+        assert g.shape == like.shape and g.dtype == like.dtype
+        assert float(jnp.max(jnp.abs(f32(g) - w))) < tol * max(1.0, float(jnp.max(jnp.abs(w))))
+    if case == "masked_row":
+        assert float(jnp.max(jnp.abs(got[0][:, :, n - 5]))) == 0.0
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+@pytest.mark.parametrize("n,block", [(128, 32), (64, 64)])
+def test_the_backward_is_one_kernel_of_five_dots(n, block):
+    """A tiled call's backward (and a single block's) holds exactly one
+    ``pallas_call``, ``flash_bwd``, whose body has five ``dot_general``s: the
+    tile's scores are rebuilt once for dq, dk and dv."""
+    q, k, v = _qkv(jax.random.PRNGKey(20), 1, 2, n, 32)
+
+    def backward(q, k, v, do):
+        return jax.vjp(lambda *qkv: _flash(*qkv, True, None, block), q, k, v)[1](do)
+
+    jaxpr = jax.make_jaxpr(backward)(q, k, v, q).jaxpr
+    calls = [e for e in _eqns(jaxpr) if e.primitive.name == "pallas_call"]
+    assert sorted(e.params["name"] for e in calls) == ["flash_bwd", "flash_fwd"]
+    (bwd,) = [e for e in calls if e.params["name"] == "flash_bwd"]
+    dots = [e for e in _eqns(bwd.params["jaxpr"]) if e.primitive.name == "dot_general"]
+    assert len(dots) == 5
 
 
 @pytest.mark.slow
@@ -450,9 +564,9 @@ def test_flagship_production_block_parity():
     want = _oracle(q, k, v, mask)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=2e-5)
 
-    # gradient PARITY at the single-block configuration (nk == 1, so the
-    # kb==0 and kb==nk-1 epilogues coincide) — finiteness alone would miss
-    # a wrong accumulation there
+    # gradient PARITY at the single-block configuration (nq == nk == 1: the
+    # backward kernel sums nothing and writes each block from its one tile)
+    # — finiteness alone would miss a wrong write there
     cot = jax.random.normal(jax.random.PRNGKey(7), out.shape)
 
     def flash_loss(q, k, v):

@@ -162,7 +162,7 @@ ROUTES = {
         {"ssm_conv_fwd", "ssm_conv_bwd"},
     ),
     "gated_flash_head256": (
-        _gated, [(GDN_B, 16, GDN_N, 256)] * 3, {"flash_fwd", "flash_dq", "flash_dkv"},
+        _gated, [(GDN_B, 16, GDN_N, 256)] * 3, {"flash_fwd", "flash_bwd"},
     ),
     "ssm_conv": (
         _conv,
@@ -178,10 +178,10 @@ ROUTES = {
     ),
     "latent_flash": (
         _latent, [(MLA_B, MLA_H, MLA_N, MLA_QK)] * 2 + [(MLA_B, MLA_H, MLA_N, MLA_V)],
-        {"flash_fwd", "flash_dq", "flash_dkv"},
+        {"flash_fwd", "flash_bwd"},
     ),
     "packed_flash": (_packed, [(B, N, 3 * H * D)], {"flash_qkv_fwd", "flash_qkv_bwd"}),
-    "blocked_flash": (_blocked, [(B, H, N, D)] * 3, {"flash_fwd", "flash_dq", "flash_dkv"}),
+    "blocked_flash": (_blocked, [(B, H, N, D)] * 3, {"flash_fwd", "flash_bwd"}),
     "pair_grid": (
         _pair_grid, [(B, H, N, D)] * 3,
         {"block_sparse_fwd", "block_sparse_dq", "block_sparse_dkv"},

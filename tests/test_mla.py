@@ -97,8 +97,8 @@ def test_a_layer_that_leaves_the_rotation_out_fails_the_reference(monkeypatch):
 
 @pytest.mark.parametrize("d,dv,n,block", [(192, 128, 256, 128), (24, 16, 256, 128), (24, 16, 128, 128)])
 def test_flash_with_its_own_value_width_matches_the_dense_route(d, dv, n, block):
-    """Forward and the three gradients; (24, 16, 128) is one block and takes
-    the single-block fused backward."""
+    """Forward and the three gradients; (24, 16, 128) is one block, where
+    the backward kernel writes every block from its one tile."""
     keys = jax.random.split(jax.random.key(0), 4)
     q, k = (jax.random.normal(keys[i], (1, 2, n, d)) for i in (0, 1))
     v = jax.random.normal(keys[2], (1, 2, n, dv))
