@@ -19,8 +19,11 @@ nothing renamed, by ``model_type``: ``granitemoehybrid`` (``hidden_size``,
 ``shared_expert_intermediate_size``, …: linear-attention layers with a
 corrected, delta-rule state (ops/gdn.py) three to one with gated softmax
 attention, softmax-routed experts with a gated shared expert and the family's
-load-balance loss). What a key asks that is not written here is refused, not
-ignored.
+load-balance loss) and ``smallthinker`` (``sliding_window_layout``,
+``rope_layout``, ``sliding_window_size``, ``moe_*``: sliding-window attention
+with rotary beside global attention with no positional term, ReGLU experts
+routed from the block's input, before its attention). What a key asks that
+is not written here is refused, not ignored.
 
 Training and whole-sequence evaluation only; serving a stack with
 recurrent-state layers is ROADMAP R13, one with latent attention R11, and a
@@ -95,6 +98,9 @@ class CausalLM(nn.Module):
     linattn_conv: int = 4
     attn_rotary_dim: int = 0
     attn_rope_theta: float = 10000.0
+    attn_window: int = 0
+    experts_activation: str = "swiglu"
+    experts_route_first: bool = False
     tie_head: bool = True
     mtp_lambda: Optional[float] = None
     remat: bool = False
@@ -154,7 +160,7 @@ class CausalLM(nn.Module):
             "experts_hidden", "experts_shared", "experts_scaling", "experts_scoring",
             "experts_gate_shared", "linattn_key_heads", "linattn_value_heads",
             "linattn_key_dim", "linattn_value_dim", "linattn_conv", "attn_rotary_dim",
-            "attn_rope_theta",
+            "attn_rope_theta", "attn_window", "experts_activation", "experts_route_first",
         )
         return {name: getattr(self, name) for name in names}
 
@@ -471,7 +477,48 @@ def _qwen3_next_fields(cfg: dict) -> dict:
     )
 
 
+def _smallthinker_fields(cfg: dict) -> dict:
+    """``smallthinker``: the source's own keys, and ``experts_held`` (as
+    ``_joyai_fields``: ``moe_num_primary_experts`` then counts the experts
+    HELD and the router scores ``of``). Layer ``l`` is a window layer
+    (``sliding_attention``: rotary over the whole head at ``rope_theta``, a
+    window of ``sliding_window_size`` keys) where both layouts hold 1 at
+    ``l``, a global one (``attention``: no positional term, every earlier
+    key) where both hold 0. Every feed-forward is the expert layer: softmax
+    over all experts, the top ``moe_num_active_primary_experts`` with their
+    weights normalised over the chosen, ReGLU experts, no shared expert, the
+    router reading the block's normed input before the attention."""
+    _refuse(cfg, {
+        "rope_scaling": None, "norm_topk_prob": True, "moe_primary_router_apply_softmax": True,
+        "tie_word_embeddings": False,
+    })
+    depth = cfg["num_hidden_layers"]
+    windows, ropes = cfg["sliding_window_layout"], cfg["rope_layout"]
+    for key, layout in (("sliding_window_layout", windows), ("rope_layout", ropes)):
+        if len(layout) != depth or set(layout) - {0, 1}:
+            raise ValueError(f"{key}={layout}: {depth} entries of 0 or 1 are written here")
+    if windows != ropes:
+        raise ValueError(
+            f"sliding_window_layout={windows} against rope_layout={ropes}: only window layers "
+            "with rotary and global layers without it are written here"
+        )
+    lo, hi, total = _share(cfg, "moe_num_primary_experts")
+    return dict(
+        vocab_size=cfg["vocab_size"], dim=cfg["hidden_size"], depth=depth,
+        layer_types=tuple("sliding_attention" if w else "attention" for w in windows),
+        ff_types=("experts",) * depth, heads=cfg["num_attention_heads"],
+        kv_heads=cfg["num_key_value_heads"], dim_head=cfg["head_dim"],
+        norm_eps=cfg["rms_norm_eps"], attn_rotary_dim=cfg["head_dim"],
+        attn_rope_theta=float(cfg["rope_theta"]), attn_window=cfg["sliding_window_size"],
+        experts_total=total, experts_held=(lo, hi),
+        experts_per_token=cfg["moe_num_active_primary_experts"],
+        experts_hidden=cfg["moe_ffn_hidden_size"], experts_shared=0,
+        experts_scoring="softmax", experts_activation="reglu", experts_route_first=True,
+        tie_head=False,
+    )
+
+
 _FAMILIES = {
     "granitemoehybrid": _granite_fields, "joyai_llm_flash": _joyai_fields,
-    "qwen3_next": _qwen3_next_fields,
+    "qwen3_next": _qwen3_next_fields, "smallthinker": _smallthinker_fields,
 }

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import flax.linen as nn
 
+import contextlib
 import functools
 
 from ..ops import kv_policy
@@ -31,9 +32,10 @@ from ..ops.layers import (
     PreNorm,
     PreRMSNorm,
     PreShiftToken,
+    RMSNorm,
     SwiGLU,
 )
-from ..ops.moe import MoEFeedForward, RoutedExperts
+from ..ops.moe import MoEFeedForward, RoutedExperts, router_logits
 from ..ops.reversible import reversible_forward_only, reversible_sequence
 from ..ops.rotary import angles, dalle_rotary_table, lang_freqs
 from ..ops.gdn import GatedDeltaNet
@@ -47,6 +49,7 @@ ATTENTION_TYPES = ("full", "axial_row", "axial_col", "conv_like", "sparse", "mlp
 MIXER_TYPES = {
     "mamba": ("mamba", "ssm"), "attention": ("gqa", "attn.gqa"), "mla": ("mla", "attn.mla"),
     "linear_attention": ("gdn", "linattn"), "full_attention": ("gated", "attn.gated"),
+    "sliding_attention": ("swa", "attn.swa"),
 }
 # ``ff_types``: a layer_types stack's feed-forward kind, layer by layer.
 # kind -> device scope
@@ -65,6 +68,38 @@ def _interned_rotary(data: bytes, shape: tuple) -> StaticTable:
     the fused attention kernel hashes tables by id — interning keeps the
     id stable across traces so nothing retraces or recompiles."""
     return StaticTable(np.frombuffer(data, dtype=np.float32).reshape(shape))
+
+
+class PreRoutedRMSNorm(nn.Module):
+    """The mixer half-block of a block whose expert layer is routed from the
+    block's INPUT (``experts_route_first``): ``u = RMSNorm(x)`` feeds the
+    router first and then the mixer,
+
+        p = softmax(W_r u)            float32, under ``moe`` / ``moe.router``
+        -> (multiplier * fn(u), p)    the norm and the mixer under ``mixer_scope``
+
+    and the trunk hands ``p`` to the block's expert layer
+    (ops/moe.py:RoutedExperts). It sets its own device scopes, so that the
+    router's operations count with the expert layer's and under no
+    ``attn.*``; the router reads the norm's float32 output."""
+
+    fn: nn.Module
+    experts_total: int
+    mixer_scope: str
+    eps: float = 1e-5
+    multiplier: float = 1.0
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, **kwargs):
+        with jax.named_scope(self.mixer_scope):
+            u = RMSNorm(self.eps, self.param_dtype, name="norm")(x)
+        with jax.named_scope("moe"), jax.named_scope("moe.router"):
+            rows = u.reshape(-1, u.shape[-1])
+            probs = jax.nn.softmax(router_logits(rows, self.experts_total, self.param_dtype), axis=-1)
+        with jax.named_scope(self.mixer_scope):
+            out = self.fn(u.astype(x.dtype), **kwargs)
+        return (out if self.multiplier == 1.0 else out * self.multiplier), probs
 
 
 def _block_checkpoint(fn):
@@ -103,10 +138,14 @@ class Transformer(nn.Module):
     delta rule, ops/gdn.py:GatedDeltaNet, sized by ``linattn_*``) or
     ``full_attention`` (ops/attention.py:GatedAttention: grouped-KV attention
     with per-head norms, rotary over ``attn_rotary_dim`` channels and an
-    output gate); ``ff_types`` gives each layer of
+    output gate) or ``sliding_attention`` (GroupedKVAttention with rotary
+    over ``attn_rotary_dim`` channels at ``attn_rope_theta`` and a sliding
+    window of ``attn_window`` keys, scope ``attn.swa``); ``ff_types`` gives each layer of
     such a stack its feed-forward, ``dense`` (the SwiGLU of ``ff_hidden``) or
     ``experts`` (ops/moe.py:RoutedExperts, sized by ``experts_*``, under the
-    device scope ``moe``); ``norm='rmsnorm'``
+    device scope ``moe``; ``experts_activation`` ``reglu`` for ReGLU experts;
+    ``experts_route_first`` routes it from the block's normed INPUT before
+    the mixer, ``PreRoutedRMSNorm``); ``norm='rmsnorm'``
     with a fixed ``residual_multiplier`` replaces LayerNorm + learned
     LayerScale; ``ff_act='swiglu'`` with ``ff_hidden`` replaces the GEGLU
     feed-forward. Such a stack trains and evaluates whole sequences; it has
@@ -176,6 +215,9 @@ class Transformer(nn.Module):
     linattn_conv: int = 4
     attn_rotary_dim: int = 0
     attn_rope_theta: float = 10000.0
+    attn_window: int = 0
+    experts_activation: str = "swiglu"
+    experts_route_first: bool = False
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
 
@@ -222,7 +264,7 @@ class Transformer(nn.Module):
                 f"layer); got {self.moe_every}"
             )
         self._check_variants()
-        attn_blocks, ff_blocks, kinds, scopes, ff_scopes = [], [], [], [], []
+        attn_blocks, ff_blocks, kinds, scopes, ff_scopes, routed = [], [], [], [], [], []
         for ind in range(self.depth):
             attn_type = attn_types[ind % len(attn_types)]
             scope = f"attn.{attn_type}"
@@ -264,8 +306,8 @@ class Transformer(nn.Module):
                     experts_held=tuple(self.experts_held or (0, self.experts_total)),
                     per_token=self.experts_per_token, shared=self.experts_shared,
                     scaling=self.experts_scaling, scoring=self.experts_scoring,
-                    gate_shared=self.experts_gate_shared, dtype=self.dtype,
-                    param_dtype=self.param_dtype,
+                    gate_shared=self.experts_gate_shared, activation=self.experts_activation,
+                    dtype=self.dtype, param_dtype=self.param_dtype,
                 )
             elif self.ff_act == "swiglu":
                 ff = SwiGLU(
@@ -310,17 +352,29 @@ class Transformer(nn.Module):
                     seq_len=self.seq_len, pad=self.shift_pad,
                 )
 
-            attn_blocks.append(self._half_block(attn, ind, self._mixer_name(ind)))
+            routed_first = self.experts_route_first and ff_scope == "moe"
+            if routed_first:
+                attn = PreRoutedRMSNorm(
+                    fn=attn, experts_total=self.experts_total, mixer_scope=scope, eps=self.norm_eps,
+                    multiplier=self.residual_multiplier, param_dtype=self.param_dtype,
+                    name=self._mixer_name(ind),
+                )
+            else:
+                attn = self._half_block(attn, ind, self._mixer_name(ind))
+            attn_blocks.append(attn)
             ff_blocks.append(self._half_block(ff, ind, f"ff_{ind}"))
             kinds.append(attn_type)
             scopes.append(scope)
             ff_scopes.append(ff_scope)
+            routed.append(routed_first)
 
         self.attn_blocks = attn_blocks
         self.ff_blocks = ff_blocks
         self.layer_kinds = tuple(kinds)
         self.layer_scopes = tuple(scopes)
         self.ff_scopes = tuple(ff_scopes)
+        # layers whose mixer half-block also returns the expert layer's routing
+        self.routed_first = tuple(routed)
 
     def _mixer_name(self, ind: int) -> str:
         return f"attn_{ind}" if self.layer_types is None else f"mixer_{ind}"
@@ -358,6 +412,8 @@ class Transformer(nn.Module):
                 "layer_types stacks run sequentially or under remat only: no "
                 "token shift, reversible, sequence- or pipeline-parallel path"
             )
+        if self.experts_route_first and (self.norm != "rmsnorm" or self.experts_scoring != "softmax"):
+            raise ValueError("routing from the block's input needs rmsnorm and a softmax router")
 
     def _mixer(self, kind: str) -> nn.Module:
         if kind == "mamba":
@@ -380,6 +436,15 @@ class Transformer(nn.Module):
                 value_heads=self.linattn_value_heads, key_dim=self.linattn_key_dim,
                 value_dim=self.linattn_value_dim, conv=self.linattn_conv,
                 eps=self.norm_eps, dtype=self.dtype, param_dtype=self.param_dtype,
+            )
+        if kind == "swa":
+            return GroupedKVAttention(
+                dim=self.dim, heads=self.heads, kv_heads=self.kv_heads or self.heads,
+                dim_head=self.dim_head,
+                sm_scale=self.dim_head**-0.5 if self.attn_scale is None else self.attn_scale,
+                rotary_dim=self.attn_rotary_dim, rope_theta=self.attn_rope_theta,
+                window=self.attn_window or None,
+                use_flash=self.use_flash, dtype=self.dtype, param_dtype=self.param_dtype,
             )
         if kind == "gated":
             return GatedAttention(
@@ -494,8 +559,14 @@ class Transformer(nn.Module):
                     ind, mask, rot, deterministic, decode, block_len,
                     block_start,
                 )
-                with jax.named_scope(self.layer_scopes[ind]):
-                    x = x + self.attn_blocks[ind](x, **akw)
+                if self.routed_first[ind]:
+                    d, probs = self.attn_blocks[ind](x, **akw)
+                    fkw = dict(fkw, probs=probs)
+                    with jax.named_scope(self.layer_scopes[ind]):
+                        x = x + d
+                else:
+                    with jax.named_scope(self.layer_scopes[ind]):
+                        x = x + self.attn_blocks[ind](x, **akw)
                 with jax.named_scope(self.ff_scopes[ind]):
                     x = x + self.ff_blocks[ind](x, **fkw)
             return x
@@ -523,6 +594,9 @@ class Transformer(nn.Module):
             aux = jnp.zeros((), jnp.float32)
             for ind, ((f, g), (pf, pg), (kwf, kwg)) in enumerate(zip(fns, params, kwargs)):
                 d, a = _block_checkpoint(f)(pf, x, kwf)
+                if self.routed_first[ind]:
+                    d, probs = d
+                    kwg = dict(kwg, probs=probs)
                 x = x + d
                 dg, ag = _block_checkpoint(g)(pg, x, kwg)
                 x = x + dg
@@ -705,17 +779,24 @@ class Transformer(nn.Module):
             ff_mod = self.ff_blocks[ind].clone(parent=None)
 
             def make_fn(mod, is_attn, patterned=patterned, scope=self.layer_scopes[ind],
-                        ff_scope=self.ff_scopes[ind]):
+                        ff_scope=self.ff_scopes[ind], routed_first=self.routed_first[ind]):
                 static_kwargs = dict(deterministic=deterministic)
                 scope = scope if is_attn else ff_scope
+                # a PreRoutedRMSNorm sets its own scopes
+                scoped = (
+                    contextlib.nullcontext if is_attn and routed_first
+                    else functools.partial(jax.named_scope, scope)
+                )
 
                 def fn(p, t, kw):
                     call_kwargs = dict(static_kwargs)
                     if is_attn and patterned:
                         call_kwargs["mask"] = kw.get("mask")
                         call_kwargs["rotary_pos_emb"] = kw.get("rot")
+                    if "probs" in kw:
+                        call_kwargs["probs"] = kw["probs"]
                     rngs = {"dropout": kw["rng"]} if "rng" in kw else None
-                    with jax.named_scope(scope):
+                    with scoped():
                         y, mut = mod.apply(
                             {"params": p}, t, rngs=rngs, mutable=["moe_aux", "moe_stats"],
                             **call_kwargs,

@@ -41,6 +41,8 @@ from .flash_attention import (
     flash_attention,
     fused_qkv_attention,
     fused_qkv_supported,
+    window_supported,
+    window_tiles,
 )
 from .layers import RMSNorm, stable_softmax
 from . import rotary
@@ -1400,39 +1402,54 @@ class PatternAttention(nn.Module):
     # flat-vs-4-D policy in _decode_caches (batch 8: +38% tokens/sec).
 
 
-def _causal_attend(q, k, v, scale: float, use_flash: bool, site: str):
+def _causal_attend(q, k, v, scale: float, use_flash: bool, site: str, window=None):
     """Causal softmax attention over (b, h, n, d) with as many key/value as
     query heads: the blocked flash kernels where the length has a usable block
     (``_flash_block``), one dense masked softmax elsewhere; which, at the
-    route site ``site``."""
-    n = q.shape[2]
+    route site ``site``. ``window``: a sliding window, key j visible to query
+    i only where ``i - j < window``; the flash kernels then visit the band's
+    tiles only, and the route records the window and the tiles its grid
+    visits beside the causal triangle's (``flash_attention.window_tiles``)."""
+    n, d = q.shape[2], q.shape[3]
     block = _flash_block(n) if use_flash else 0
+    if block and window is not None and not window_supported(n, d, block):
+        block = 0
     if block:
         interpret = kv_policy.pallas_interpret()
-        kv_policy.record_route(site, "blocked_flash", interpret)
+        detail = {} if window is None else dict(window=window, **window_tiles(n, block, window))
+        kv_policy.record_route(site, "blocked_flash", interpret, **detail)
         return _per_device(
             lambda q, k, v: flash_attention(
-                q, k, v, None, True, None, scale, block, block, interpret
+                q, k, v, None, True, None, scale, block, block, interpret, window
             ),
             (q, k, v),
         )
-    kv_policy.record_route(site, "dense_masked")
-    causal = jnp.tril(jnp.ones((n, n), bool))
-    return dense_attend(q * scale, k, v, causal)
+    kv_policy.record_route(site, "dense_masked", **({} if window is None else dict(window=window)))
+    allowed = jnp.tril(jnp.ones((n, n), bool))
+    if window is not None:
+        allowed = jnp.logical_and(allowed, jnp.triu(jnp.ones((n, n), bool), 1 - window))
+    return dense_attend(q * scale, k, v, allowed)
 
 
 class GroupedKVAttention(nn.Module):
-    """Causal softmax attention with fewer key/value heads than query heads
-    and no positional term: query head ``i`` attends key/value head
-    ``i // (heads // kv_heads)``. No bias. ``sm_scale`` is the model's own
-    softmax scale (not necessarily ``dim_head ** -0.5``).
+    """Causal softmax attention with fewer key/value heads than query heads:
+    query head ``i`` attends key/value head ``i // (heads // kv_heads)``. No
+    bias. ``sm_scale`` is the model's own softmax scale (not necessarily
+    ``dim_head ** -0.5``). By default no positional term and every earlier
+    key; ``rotary_dim`` turns the first that many channels of ``q`` and
+    ``k`` in half-split pairs (``rotate_half_split`` at ``rope_theta``), and
+    ``window`` limits query i to keys ``i - window < j <= i`` (a sliding
+    window of ``window`` keys, its own included): the ``smallthinker``
+    family's window layer, recorded at ``forward/swa`` where the layer
+    without one is recorded at ``forward/gqa``.
 
     Training route: the key/value heads are broadcast to the query heads in
     front of the blocked flash kernel (ops/flash_attention.py; the sum of the
-    gradient over a group is autodiff's); where the length has no usable
-    block (``_flash_block``) it is one dense masked softmax. No kernel here
-    groups K/V heads yet, and there is no decode mode: serving a stack with
-    such layers is ROADMAP R9's other half."""
+    gradient over a group is autodiff's; with a window the kernels' grid
+    spans the band's tiles only); where the length has no usable block
+    (``_flash_block``) it is one dense masked softmax. No kernel here groups
+    K/V heads yet, and there is no decode mode: serving a stack with such
+    layers is ROADMAP R9's other half."""
 
     dim: int
     heads: int
@@ -1440,6 +1457,9 @@ class GroupedKVAttention(nn.Module):
     dim_head: int
     sm_scale: float
     use_flash: bool = True
+    rotary_dim: int = 0
+    rope_theta: float = 10000.0
+    window: Optional[int] = None
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
 
@@ -1453,12 +1473,19 @@ class GroupedKVAttention(nn.Module):
             features, use_bias=False, name=name, dtype=self.dtype,
             param_dtype=self.param_dtype,
         )
+        turn = lambda t: rotate_half_split(t, self.rotary_dim, self.rope_theta)
         q = dense(h * d, "to_q")(x).reshape(b, n, h, d).transpose(0, 2, 1, 3)
+        if self.rotary_dim:
+            q = turn(q)
         kv = dense(2 * g * d, "to_kv")(x).reshape(b, n, 2, g, d)
-        k, v = (
-            jnp.repeat(kv[:, :, i].transpose(0, 2, 1, 3), h // g, axis=1) for i in (0, 1)
-        )
-        out = _causal_attend(q, k, v, float(self.sm_scale), self.use_flash, "forward/gqa")
+
+        def grouped(i):   # the key (0) or value (1) heads, one per query head
+            t = kv[:, :, i].transpose(0, 2, 1, 3)
+            return jnp.repeat(turn(t) if i == 0 and self.rotary_dim else t, h // g, axis=1)
+
+        k, v = grouped(0), grouped(1)
+        site = "forward/gqa" if self.window is None else "forward/swa"
+        out = _causal_attend(q, k, v, float(self.sm_scale), self.use_flash, site, self.window)
         out = out.transpose(0, 2, 1, 3).reshape(b, n, h * d)
         return dense(self.dim, "to_out")(out)
 

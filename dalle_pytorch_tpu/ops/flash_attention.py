@@ -18,7 +18,10 @@ full (block_q x d x block_k) matmuls:
   summed by XLA where that row does not fit: ``_bwd_rule``), and
   delta = rowsum(do*o) comes from blocks already in VMEM, never from HBM;
 - masking: ``causal=True`` is analytic (above-diagonal blocks execute no
-  dots); an optional static (n, n) pattern mask (ops/masks.py) is streamed
+  dots); with a static ``window`` W it is the analytic band
+  ``0 <= i - j < W`` (a sliding window: W keys, the query's own included),
+  and the grid's inner axis spans only the band's blocks (below); an
+  optional static (n, n) pattern mask (ops/masks.py) is streamed
   blockwise for sparse/axial/conv layouts with all-empty blocks skipped the
   same way; an optional runtime (b, n) key-padding mask (the reference's
   ``mask`` argument, attention.py:71-74) is a fourth streamed operand —
@@ -33,6 +36,14 @@ full (block_q x d x block_k) matmuls:
   the grid indices — an earlier revision routed them through the
   scalar-prefetch table to re-fetch the last live block, which defeats
   Mosaic's DMA pipelining and measured 23x slower at block 256 on v5e.
+  A window keeps them affine and moves the grid instead: the forward's
+  inner step ``j`` of query block ``qb`` is key block ``qb - span + 1 + j``
+  clamped at 0, the backward's of key block ``kb`` query block ``kb + j``
+  clamped at the last, ``span`` blocks a row (``_band_span``: 5 of 16 at
+  n 16,384, block 1,024, W 4,096). No tile wholly outside the band is
+  fetched; a clamped step repeats the block before it (no new DMA) and is
+  dead in the visit table. ``window=None``, or a window of n or more, is
+  the causal program, step for step.
 
 Parity is tested against the dense masked oracle (ops.attention.dense_attend)
 in interpret mode on CPU and compiled on TPU.
@@ -90,7 +101,7 @@ class StaticMask:
 
 def _block_visit_map(
     nq: int, nk: int, block_q: int, block_k: int,
-    causal: bool, pattern_mask: Optional[np.ndarray],
+    causal: bool, pattern_mask: Optional[np.ndarray], window: Optional[int] = None,
 ) -> np.ndarray:
     """Static per-(qb, kb) class: 0 = skip, 1 = needs masking, 2 = dense."""
     visit = np.full((nq, nk), 2, dtype=np.int32)
@@ -107,9 +118,59 @@ def _block_visit_map(
             for kb in range(nk):
                 if kb * block_k > (qb + 1) * block_q - 1:
                     visit[qb, kb] = 0  # fully above the diagonal
-                elif (kb + 1) * block_k - 1 > qb * block_q:
-                    visit[qb, kb] = 1  # diagonal-crossing
+                elif window is not None and qb * block_q - (kb + 1) * block_k + 1 >= window:
+                    visit[qb, kb] = 0  # wholly left of the band
+                elif (kb + 1) * block_k - 1 > qb * block_q or (
+                    window is not None and (qb + 1) * block_q - 1 - kb * block_k >= window
+                ):
+                    visit[qb, kb] = 1  # crossing the diagonal or the band's far edge
     return visit
+
+
+def _band_span(n_blocks: int, block: int, window: int) -> int:
+    """Blocks a row of the banded grid spans: the diagonal block and those
+    that hold keys up to ``window - 1`` positions back."""
+    return min(n_blocks, -(-(window - 1) // block) + 1)
+
+
+def _band_tables(visit: np.ndarray, span: int) -> tuple:
+    """The visit classes on the banded grids: forward (nq, span), entry
+    ``[qb, j]`` the class of key block ``qb - span + 1 + j``; backward
+    (nk, span), entry ``[kb, j]`` that of query block ``kb + j``. A step the
+    index maps clamp (a block index outside the grid) is dead."""
+    nq, nk = visit.shape
+    fwd = np.zeros((nq, span), np.int32)
+    bwd = np.zeros((nk, span), np.int32)
+    for qb in range(nq):
+        for j in range(span):
+            if qb - span + 1 + j >= 0:
+                fwd[qb, j] = visit[qb, qb - span + 1 + j]
+    for kb in range(nk):
+        for j in range(span):
+            if kb + j < nq:
+                bwd[kb, j] = visit[kb + j, kb]
+    live = int((visit > 0).sum())
+    assert int((fwd > 0).sum()) == live == int((bwd > 0).sum()), "a live tile lies off the band"
+    return fwd, bwd
+
+
+def window_tiles(n: int, block: int, window: Optional[int]) -> dict:
+    """What the forward grid of a causal row of ``n`` at ``block`` visits:
+    ``tiles_visited``, the distinct (query block, key block) tiles it fetches
+    (every tile of the full grid without a window; with one, those that touch
+    the band), beside ``causal_tiles``, the causal triangle's live tiles."""
+    nb = n // block
+    causal = _block_visit_map(nb, nb, block, block, True, None)
+    if window is None or window >= n:
+        return {"tiles_visited": nb * nb, "causal_tiles": int((causal > 0).sum())}
+    visit = _block_visit_map(nb, nb, block, block, True, None, window)
+    return {"tiles_visited": int((visit > 0).sum()), "causal_tiles": int((causal > 0).sum())}
+
+
+def window_supported(n: int, d: int, block: int) -> bool:
+    """A windowed backward keeps dq resident (``DQ_ROW_VMEM_BYTES``): the
+    partials form would leave the blocks off the band unwritten."""
+    return n // block == 1 or n * d * 4 <= DQ_ROW_VMEM_BYTES
 
 
 def _scalar_table(visit: np.ndarray) -> np.ndarray:
@@ -122,12 +183,14 @@ def _scalar_table(visit: np.ndarray) -> np.ndarray:
 # ------------------------------------------------------------------ kernels
 
 
-def _masked_scores(q, k, sm_scale, mask_ref, kmask_ref, visit, row0, col0, bq, bk):
+def _masked_scores(q, k, sm_scale, mask_ref, kmask_ref, visit, row0, col0, bq, bk,
+                   window=None):
     """(bq, bk) f32 scores with pattern/causal and runtime key masking
     applied. The QK^T dot runs in the inputs' dtype (bf16 on the MXU fast
     path) with f32 accumulation; the scale is applied on the f32 result.
     ``kmask_ref``: optional (1, 1, bk) int32 block of the runtime
-    key-padding mask, broadcast over query rows."""
+    key-padding mask, broadcast over query rows. ``window``: the band
+    ``0 <= rows - cols < window`` in the causal mask's place."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * sm_scale
@@ -140,7 +203,11 @@ def _masked_scores(q, k, sm_scale, mask_ref, kmask_ref, visit, row0, col0, bq, b
     else:
         rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + row0
         cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + col0
-        s = jnp.where(jnp.logical_or(visit == 2, rows >= cols), s, NEG_INF)
+        dense = visit == 2
+        keep = rows >= cols
+        if window is not None:
+            keep = jnp.logical_and(keep, rows - cols < window)
+        s = jnp.where(jnp.logical_or(dense, keep), s, NEG_INF)
     if kmask_ref is not None:
         s = jnp.where(kmask_ref[0] > 0, s, NEG_INF)  # (1, bk) over rows
     return s
@@ -162,23 +229,27 @@ def _masked_exp(s, x):
 def _fwd_kernel(
     scalar_ref, q_ref, k_ref, v_ref, mask_ref, kmask_ref, o_ref, lse_ref,
     m_scr, l_scr, acc_scr,
-    *, sm_scale, block_q, block_k, nk,
+    *, sm_scale, block_q, block_k, nk, window=None,
 ):
-    qb, kb = pl.program_id(1), pl.program_id(2)
+    """``nk``: the inner grid's length, all key blocks or the band's span;
+    with a ``window`` inner step ``j`` is key block ``qb - nk + 1 + j``
+    (clamped at 0: such a step is dead in the table)."""
+    qb, j = pl.program_id(1), pl.program_id(2)
+    kb = j if window is None else jnp.maximum(qb - nk + 1 + j, 0)
 
-    @pl.when(kb == 0)
+    @pl.when(j == 0)
     def _():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    visit = scalar_ref[0, qb * nk + kb]
+    visit = scalar_ref[0, qb * nk + j]
 
     @pl.when(visit > 0)
     def _():
         s = _masked_scores(
             q_ref[0], k_ref[0], sm_scale, mask_ref, kmask_ref, visit,
-            qb * block_q, kb * block_k, block_q, block_k,
+            qb * block_q, kb * block_k, block_q, block_k, window,
         )
         m_prev = m_scr[:, 0:1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -191,7 +262,7 @@ def _fwd_kernel(
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
         )
 
-    @pl.when(kb == nk - 1)
+    @pl.when(j == nk - 1)
     def _():
         l = l_scr[:, 0:1]
         l_safe = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> 0 output
@@ -203,7 +274,7 @@ def _fwd_kernel(
 def _bwd_kernel(
     scalar_ref, q_ref, k_ref, v_ref, mask_ref, kmask_ref, do_ref, o_ref, lse_ref,
     dq_ref, dk_ref, dv_ref, *scratch,
-    sm_scale, block_q, block_k, nq, nk, dq_resident,
+    sm_scale, block_q, block_k, nq, nk, dq_resident, window=None, last_qb=None,
 ):
     """The backward at every grid: (b·h, key block, query block), each live
     tile visited once. From ONE ``_masked_scores`` + ``_masked_exp`` the tile
@@ -218,31 +289,42 @@ def _bwd_kernel(
     and (block_k, dv), where nq > 1). dq sums over the OUTER key blocks:
     with ``dq_resident`` in ``scratch[-1]``, the whole (n, d) row of one b·h,
     written to the resident ``dq_ref`` at the row's last tile; without it
-    ``dq_ref`` is this tile's own block (``_bwd_rule`` says which form)."""
-    kb, qb = pl.program_id(1), pl.program_id(2)
+    ``dq_ref`` is this tile's own block (``_bwd_rule`` says which form).
+
+    ``nq``: the inner grid's length, all query blocks or the band's span;
+    with a ``window`` inner step ``j`` is query block ``kb + j`` (clamped at
+    ``last_qb``: such a step is dead in the table), and the resident dq row
+    is zeroed whole at the first step, since a key block visits only the
+    query blocks of its band."""
+    kb, j = pl.program_id(1), pl.program_id(2)
+    qb = j if window is None else jnp.minimum(kb + j, last_qb)
     dk_scr, dv_scr = scratch[:2] if nq > 1 else (None, None)
     dq_row = scratch[-1] if dq_resident else None
     rows = pl.ds(pl.multiple_of(qb * block_q, block_q), block_q)
 
     if nq > 1:
-        @pl.when(qb == 0)
+        @pl.when(j == 0)
         def _():
             dk_scr[:] = jnp.zeros_like(dk_scr)
             dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    if dq_resident:
+    if dq_resident and window is None:
         @pl.when(kb == 0)
         def _():
             dq_row[rows, :] = jnp.zeros((block_q, dq_row.shape[1]), jnp.float32)
+    elif dq_resident:
+        @pl.when(jnp.logical_and(kb == 0, j == 0))
+        def _():
+            dq_row[:] = jnp.zeros_like(dq_row)
 
-    visit = scalar_ref[0, kb * nq + qb]
+    visit = scalar_ref[0, kb * nq + j]
 
     @pl.when(visit > 0)
     def _():
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         s = _masked_scores(
             q, k, sm_scale, mask_ref, kmask_ref, visit,
-            qb * block_q, kb * block_k, block_q, block_k,
+            qb * block_q, kb * block_k, block_q, block_k, window,
         )
         p = _masked_exp(s, _row_vec(lse_ref))
         dv = jax.lax.dot_general(
@@ -285,13 +367,13 @@ def _bwd_kernel(
                 dq_ref[0] = jnp.zeros_like(dq_ref[0])
 
     if nq > 1:
-        @pl.when(qb == nq - 1)
+        @pl.when(j == nq - 1)
         def _():
             dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
             dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
     if dq_resident:
-        @pl.when(jnp.logical_and(kb == nk - 1, qb == nq - 1))
+        @pl.when(jnp.logical_and(kb == nk - 1, j == nq - 1))
         def _():
             dq_ref[0] = dq_row[:].astype(dq_ref.dtype)
 
@@ -299,7 +381,9 @@ def _bwd_kernel(
 # ------------------------------------------------------------------ plumbing
 
 
-def _prep(q, pattern_mask, block_q, block_k, causal):
+def _prep(q, pattern_mask, block_q, block_k, causal, window=None):
+    """-> (b, h, n, d, nq, nk, the pattern as numpy, the visit map, the
+    window or None where it covers the row: then the program is causal's)."""
     b, h, n, d = q.shape
     assert n % block_q == 0 and n % block_k == 0, (
         f"seq {n} must divide block sizes ({block_q}, {block_k})"
@@ -312,8 +396,18 @@ def _prep(q, pattern_mask, block_q, block_k, causal):
         )
         mask_np = pattern_mask.mask
         assert mask_np.shape == (n, n), (mask_np.shape, n)
-    visit = _block_visit_map(nq, nk, block_q, block_k, causal, mask_np)
-    return b, h, n, d, nq, nk, mask_np, visit
+    if window is not None and window >= n:
+        window = None
+    if window is not None:
+        assert causal and mask_np is None and block_q == block_k and window >= 1, (
+            "a window is a causal band over square blocks, with no pattern"
+        )
+        assert window_supported(n, d, block_k), (
+            f"a windowed backward keeps dq resident: {n} x {d} float32 is over "
+            f"{DQ_ROW_VMEM_BYTES} bytes"
+        )
+    visit = _block_visit_map(nq, nk, block_q, block_k, causal, mask_np, window)
+    return b, h, n, d, nq, nk, mask_np, visit, window
 
 
 def _kernel_cost(
@@ -411,18 +505,27 @@ def _bcast_key_mask(key_mask, b, h, n):
     ).reshape(b * h, 1, n)
 
 
-def _flash_fwd(q, k, v, key_mask, causal, pattern_mask, sm_scale, block_q, block_k, interpret):
-    b, h, n, d, nq, nk, mask_np, visit = _prep(q, pattern_mask, block_q, block_k, causal)
+def _flash_fwd(q, k, v, key_mask, causal, pattern_mask, sm_scale, block_q, block_k, interpret,
+               window=None):
+    b, h, n, d, nq, nk, mask_np, visit, window = _prep(
+        q, pattern_mask, block_q, block_k, causal, window)
     dv = v.shape[-1]    # the value width: v's and o's, where it is not q's and k's
     scale = d**-0.5 if sm_scale is None else sm_scale
     bh = b * h
     qf, kf, vf = q.reshape(bh, n, d), k.reshape(bh, n, d), v.reshape(bh, n, dv)
+    if window is not None:
+        span = _band_span(nk, block_k, window)
+        visit = _band_tables(visit, span)[0]
+        nk = span
 
     # index_maps under PrefetchScalarGridSpec receive the scalar-prefetch
     # ref as a trailing argument after the grid indices, but must stay affine
     # in the grid indices (module docstring)
+    def key_block(qb, kb):
+        return kb if window is None else jnp.maximum(qb - nk + 1 + kb, 0)
+
     def kv_im(bhi, qb, kb, s):
-        return (bhi, kb, 0)
+        return (bhi, key_block(qb, kb), 0)
 
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda bhi, qb, kb, s: (bhi, qb, 0)),
@@ -437,13 +540,14 @@ def _flash_fwd(q, k, v, key_mask, causal, pattern_mask, sm_scale, block_q, block
         operands.append(jnp.asarray(mask_np, jnp.int8))
     if key_mask is not None:
         in_specs.append(
-            pl.BlockSpec((1, 1, block_k), lambda bhi, qb, kb, s: (bhi, 0, kb))
+            pl.BlockSpec((1, 1, block_k), lambda bhi, qb, kb, s: (bhi, 0, key_block(qb, kb)))
         )
         operands.append(_bcast_key_mask(key_mask, b, h, n))
 
     kernel = _with_optional_masks(
         functools.partial(
-            _fwd_kernel, sm_scale=scale, block_q=block_q, block_k=block_k, nk=nk
+            _fwd_kernel, sm_scale=scale, block_q=block_q, block_k=block_k, nk=nk,
+            window=window,
         ),
         mask_np is not None,
         key_mask is not None,
@@ -478,7 +582,7 @@ def _flash_fwd(q, k, v, key_mask, causal, pattern_mask, sm_scale, block_q, block
     return o.reshape(b, h, n, dv), lse.reshape(b, h, n)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
 def flash_attention(
     q, k, v,
     key_mask=None,
@@ -488,6 +592,7 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
     interpret: bool = False,
+    window: Optional[int] = None,
 ):
     """Fused attention over (b, h, n, d); q is NOT pre-scaled (``sm_scale``
     defaults to d**-0.5). ``v`` may have a width of its own, (b, h, n, dv):
@@ -496,14 +601,18 @@ def flash_attention(
     True = may attend; hash by id, so build it once at model setup.
     ``key_mask``: runtime (b, n) bool array, True = key is attendable
     (the reference's pad mask, attention.py:71-74); rows with every key
-    masked return exactly 0."""
-    o, _ = _flash_fwd(q, k, v, key_mask, causal, pattern_mask, sm_scale, block_q, block_k, interpret)
+    masked return exactly 0. ``window``: static; with ``causal`` and square
+    blocks, query i sees key j only where ``0 <= i - j < window``, and the
+    grid visits the band's tiles only (module docstring)."""
+    o, _ = _flash_fwd(q, k, v, key_mask, causal, pattern_mask, sm_scale, block_q, block_k, interpret,
+                      window)
     return o
 
 
-def _fwd_rule(q, k, v, key_mask, causal, pattern_mask, sm_scale, block_q, block_k, interpret):
+def _fwd_rule(q, k, v, key_mask, causal, pattern_mask, sm_scale, block_q, block_k, interpret,
+              window):
     o, lse = _name_residuals(*_flash_fwd(
-        q, k, v, key_mask, causal, pattern_mask, sm_scale, block_q, block_k, interpret
+        q, k, v, key_mask, causal, pattern_mask, sm_scale, block_q, block_k, interpret, window
     ))
     return o, (q, k, v, key_mask, o, lse)
 
@@ -513,15 +622,17 @@ def _fwd_rule(q, k, v, key_mask, causal, pattern_mask, sm_scale, block_q, block_
 DQ_ROW_VMEM_BYTES = 16 * 1024 * 1024
 
 
-def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, do):
+def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, window, res, do):
     """One ``pallas_call`` (``_bwd_kernel``) at every grid. Where dq sums over
     the key blocks is chosen from the SHAPE: a row of n·d·4 bytes within
     ``DQ_ROW_VMEM_BYTES`` stays in VMEM and dq leaves the kernel once, in the
     inputs' dtype; a longer row leaves as float32 partials (n/block_k, b·h,
     n, d) that XLA sums (n/block_k times dq's bytes in HBM: the price of a
-    row that does not fit). With one key block there is nothing to sum."""
+    row that does not fit). With one key block there is nothing to sum. With
+    a window the inner axis spans the band's query blocks (``_band_tables``)."""
     q, k, v, key_mask, o, lse = res
-    b, h, n, d, nq, nk, mask_np, visit = _prep(q, pattern_mask, block_q, block_k, causal)
+    b, h, n, d, nq, nk, mask_np, visit, window = _prep(
+        q, pattern_mask, block_q, block_k, causal, window)
     dv = v.shape[-1]
     scale = d**-0.5 if sm_scale is None else sm_scale
     bh = b * h
@@ -533,9 +644,17 @@ def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, 
     mask_op = [] if mask_np is None else [jnp.asarray(mask_np, jnp.int8)]
     km_op = [] if key_mask is None else [_bcast_key_mask(key_mask, b, h, n)]
     visit_t = np.ascontiguousarray(visit.T)
+    band = {}
+    if window is not None:
+        band = dict(window=window, last_qb=nq - 1)
+        nq = _band_span(nq, block_q, window)
+        visit_t = _band_tables(visit, nq)[1]
+
+    def query_block(kb, qb):
+        return qb if window is None else jnp.minimum(kb + qb, band["last_qb"])
 
     def q_im(bhi, kb, qb, s):
-        return (bhi, qb, 0)
+        return (bhi, query_block(kb, qb), 0)
 
     def kv_im(bhi, kb, qb, s):
         return (bhi, kb, 0)
@@ -554,7 +673,7 @@ def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, 
         ),
         pl.BlockSpec((1, block_q, dv), q_im),
         pl.BlockSpec((1, block_q, dv), q_im),
-        pl.BlockSpec((1, 1, block_q), lambda bhi, kb, qb, s: (bhi, 0, qb)),
+        pl.BlockSpec((1, 1, block_q), lambda bhi, kb, qb, s: (bhi, 0, query_block(kb, qb))),
     ]
     scratch = [
         pltpu.VMEM((block_k, d), jnp.float32), pltpu.VMEM((block_k, dv), jnp.float32),
@@ -564,12 +683,13 @@ def _bwd_rule(causal, pattern_mask, sm_scale, block_q, block_k, interpret, res, 
         dq_shape = jax.ShapeDtypeStruct((bh, n, d), q.dtype)
         scratch.append(pltpu.VMEM((n, d), jnp.float32))
     else:
-        dq_spec = pl.BlockSpec((1, block_q, d), lambda bhi, kb, qb, s: (kb * bh + bhi, qb, 0))
+        dq_spec = pl.BlockSpec(
+            (1, block_q, d), lambda bhi, kb, qb, s: (kb * bh + bhi, query_block(kb, qb), 0))
         dq_shape = jax.ShapeDtypeStruct((nk * bh, n, d), jnp.float32 if nk > 1 else q.dtype)
     kernel = _with_optional_masks(
         functools.partial(
             _bwd_kernel, sm_scale=scale, block_q=block_q, block_k=block_k,
-            nq=nq, nk=nk, dq_resident=resident,
+            nq=nq, nk=nk, dq_resident=resident, **band,
         ),
         mask_np is not None,
         key_mask is not None,

@@ -184,16 +184,18 @@ def tpu_auto_env(name: str) -> bool:
 ROUTE_LOG: list = []
 
 
-def record_route(site: str, impl: str, interpret: Optional[bool] = None) -> None:
+def record_route(site: str, impl: str, interpret: Optional[bool] = None, **detail) -> None:
     """Note that attention call site ``site`` resolved to implementation
-    ``impl`` (``interpret``: the Pallas mode, None for jnp paths). Once
-    per distinct choice, also emitted on the ``dalle_tpu.kv_policy``
-    logger."""
-    entry = {"site": site, "impl": impl, "interpret": interpret}
+    ``impl`` (``interpret``: the Pallas mode, None for jnp paths; ``detail``:
+    what else the choice fixed, e.g. a sliding window and the tiles its grid
+    visits). Once per distinct choice, also emitted on the
+    ``dalle_tpu.kv_policy`` logger."""
+    entry = {"site": site, "impl": impl, "interpret": interpret, **detail}
     if entry in ROUTE_LOG:
         return
     ROUTE_LOG.append(entry)
-    logger.info("attention route: %s -> %s (interpret=%s)", site, impl, interpret)
+    logger.info("attention route: %s -> %s (interpret=%s%s)", site, impl, interpret,
+                "".join(f", {k}={v}" for k, v in detail.items()))
 
 
 def page_size() -> int:

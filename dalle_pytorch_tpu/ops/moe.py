@@ -139,7 +139,12 @@ def balanced_bias(bias, load, speed: float):
     return bias + speed * jnp.sign(jnp.mean(load) - load).astype(bias.dtype)
 
 
-def _chunk(c, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, chunk: int, dtype):
+# an expert's gated activation: ``W_out (act(a) * b)`` with ``[a, b] = W_in u``
+ACTIVATIONS = {"swiglu": nn.silu, "reglu": jax.nn.relu}
+
+
+def _chunk(c, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, chunk: int, dtype,
+           activation: str = "swiglu"):
     """Chunk ``c`` of the pairs sorted by expert: (its tokens, its weighted
     expert outputs in float32). Rows past the count have weight 0."""
     with jax.named_scope("moe.dispatch"):
@@ -157,37 +162,43 @@ def _chunk(c, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, chunk: int, d
     with jax.named_scope("moe.experts"):
         h = jax.lax.ragged_dot(rows, w_in, groups, preferred_element_type=jnp.float32)
         a, g = jnp.split(h.astype(dtype), 2, axis=-1)
-        out = jax.lax.ragged_dot(nn.silu(a) * g, w_out, groups, preferred_element_type=jnp.float32)
+        out = jax.lax.ragged_dot(ACTIVATIONS[activation](a) * g, w_out, groups,
+                                 preferred_element_type=jnp.float32)
     with jax.named_scope("moe.combine"):
         return tok, out * w[:, None]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10))
-def _further_chunks(y, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, count, chunk, dtype):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11))
+def _further_chunks(y, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, count, chunk, dtype,
+                    activation):
     """``y`` plus chunks 1, 2, … of the sorted pairs, as many as ``count``
     needs: a loop whose trip count is read from the data, so a count that
     passes the first chunk costs what it needs and no more. Such a loop has
     no automatic transpose; the backward below walks the same chunks,
     recomputing each, so nothing is kept for them."""
     def body(c, y):
-        tok, out = _chunk(c, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, chunk, dtype)
+        tok, out = _chunk(c, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, chunk, dtype,
+                          activation)
         return y.at[tok].add(out)
 
     return jax.lax.fori_loop(1, -(-count // chunk), body, y)
 
 
-def _further_fwd(y, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, count, chunk, dtype):
-    out = _further_chunks(y, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, count, chunk, dtype)
+def _further_fwd(y, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, count, chunk, dtype,
+                 activation):
+    out = _further_chunks(y, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, count, chunk, dtype,
+                          activation)
     return out, (u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, count)
 
 
-def _further_bwd(chunk, dtype, residuals, dy):
+def _further_bwd(chunk, dtype, activation, residuals, dy):
     u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, count = residuals
     floats = (u, w_in, w_out, sorted_w)
 
     def body(c, sums):
         def weighted(u, w_in, w_out, sorted_w):
-            return _chunk(c, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, chunk, dtype)[1]
+            return _chunk(c, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, chunk, dtype,
+                          activation)[1]
 
         tok = jax.lax.dynamic_slice(sorted_tok, (c * chunk,), (chunk,))
         grads = jax.vjp(weighted, *floats)[1](jnp.take(dy, tok, axis=0))
@@ -201,6 +212,17 @@ def _further_bwd(chunk, dtype, residuals, dy):
 
 
 _further_chunks.defvjp(_further_fwd, _further_bwd)
+
+
+def router_logits(u, total: int, param_dtype):
+    """(tokens, ``total``) float32 router logits of the rows ``u``, from the
+    Dense ``gate`` of the calling module. bf16_3x: activations that are exact
+    in bfloat16 stay so, the router's weights are not rounded; one pass would
+    round them before the sigmoid or the softmax."""
+    return nn.Dense(
+        total, use_bias=False, dtype=jnp.float32, param_dtype=param_dtype,
+        precision=jax.lax.Precision.HIGH, name="gate",
+    )(u.astype(jnp.float32))
 
 
 class RoutedExperts(nn.Module):
@@ -233,6 +255,14 @@ class RoutedExperts(nn.Module):
     ``gate_shared`` multiplies the shared expert by ``sigmoid(w_sg . u)``
     (``shared_gate``), one logit a token.
 
+    ``activation='reglu'`` makes every routed expert ``W_out (relu(a) * b)``
+    (the ``smallthinker`` family) where ``swiglu`` has ``silu(a)``. A softmax
+    layer can be HANDED its router's probabilities, ``probs`` (tokens, ALL
+    experts) in float32: the block that routes from its own input computes
+    them before its mixer (models/transformer.py:PreRoutedRMSNorm) and this
+    layer then has no ``gate`` of its own; the choice, the weights and what
+    it sows are the same.
+
     No pair is dropped and nothing is a capacity. The (token, expert) pairs
     of the held experts are sorted by expert (index arrays of the worst-case
     length ``tokens * per_token``: integers) and the experts are two grouped
@@ -260,6 +290,7 @@ class RoutedExperts(nn.Module):
     scaling: float = 1.0
     scoring: str = "sigmoid"
     gate_shared: bool = False
+    activation: str = "swiglu"
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
 
@@ -269,29 +300,28 @@ class RoutedExperts(nn.Module):
         return min(max(-(-int(expected) // CHUNK_MULTIPLE), 1) * CHUNK_MULTIPLE, pairs)
 
     @nn.compact
-    def __call__(self, x: jnp.ndarray, deterministic: bool = True) -> jnp.ndarray:
+    def __call__(self, x: jnp.ndarray, deterministic: bool = True, probs=None) -> jnp.ndarray:
         b, n, d = x.shape
         tokens, k, total = b * n, self.per_token, self.experts_total
         lo, hi = self.experts_held
         held = hi - lo
         assert 0 <= lo < hi <= total and k <= total, (self.experts_held, total, k)
+        assert self.activation in ACTIVATIONS and (self.activation == "swiglu" or not self.shared)
         u = x.reshape(tokens, d)
 
         with jax.named_scope("moe.router"):
-            # bf16_3x: the activations are exact in bfloat16, the router's
-            # weights are not; one pass would round them before the sigmoid
-            logits = nn.Dense(
-                total, use_bias=False, dtype=jnp.float32, param_dtype=self.param_dtype,
-                precision=jax.lax.Precision.HIGH, name="gate",
-            )(u.astype(jnp.float32))
             assert self.scoring in ("sigmoid", "softmax"), self.scoring
+            assert probs is None or self.scoring == "softmax", "only softmax probabilities are handed"
             if self.scoring == "softmax":
-                scores = jax.nn.softmax(logits, axis=-1)
+                scores = (
+                    jax.nn.softmax(router_logits(u, total, self.param_dtype), axis=-1)
+                    if probs is None else probs.reshape(tokens, total)
+                )
                 chosen, weights = route(scores, jnp.zeros((total,), scores.dtype), k, self.scaling)
                 self.sow("moe_stats", "prob", jnp.mean(scores, axis=0))
                 self.param("router_prob", nn.initializers.zeros, (total,), self.param_dtype)
             else:
-                scores = jax.nn.sigmoid(logits)
+                scores = jax.nn.sigmoid(router_logits(u, total, self.param_dtype))
                 bias = self.param(
                     "e_score_correction_bias", nn.initializers.zeros, (total,), self.param_dtype
                 )
@@ -327,11 +357,11 @@ class RoutedExperts(nn.Module):
         kv_policy.record_route("forward/moe_experts", "ragged_dot")
 
         operands = (u, w_in, w_out, sorted_w, sorted_tok, starts, sizes)
-        tok, out = _chunk(0, *operands, chunk, self.dtype)
+        tok, out = _chunk(0, *operands, chunk, self.dtype, self.activation)
         with jax.named_scope("moe.combine"):
             y = jnp.zeros((tokens, d), jnp.float32).at[tok].add(out)
         if n_chunks > 1:
-            y = _further_chunks(y, *operands, count, chunk, self.dtype)
+            y = _further_chunks(y, *operands, count, chunk, self.dtype, self.activation)
 
         with jax.named_scope("moe.shared"):
             shared = SwiGLU(

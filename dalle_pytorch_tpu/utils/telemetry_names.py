@@ -357,6 +357,13 @@ DEVICE_SCOPES = frozenset({
     "linattn.delta",
     "linattn.norm",
     "attn.gated",
+    # the sliding-window attention layer (ops/attention.py:GroupedKVAttention
+    # with a window and rotary), read with every other attn.*; its route site
+    # forward/swa records the window, tiles_visited (the band's tiles the
+    # flash grid fetches) and causal_tiles (the causal triangle's). The
+    # router of a block routed from its input runs before it, under moe and
+    # moe.router (models/transformer.py:PreRoutedRMSNorm)
+    "attn.swa",
     # the multi-token-prediction module's own projection, norms and shifted
     # embedding (models/lm.py); its block runs under attn.mla and moe
     "mtp",

@@ -241,6 +241,81 @@ def test_the_backward_is_one_kernel_of_five_dots(n, block):
     assert len(dots) == 5
 
 
+def _band(n, window):
+    """The dense oracle's pairs of a sliding window: key j visible to query
+    i iff 0 <= i - j < window."""
+    gap = np.arange(n)[:, None] - np.arange(n)[None, :]
+    return (gap >= 0) & (gap < window)
+
+
+@pytest.mark.parametrize("n,block,window", [
+    (256, 64, 40),     # under a block
+    (256, 64, 64),     # a block
+    (256, 64, 100),    # not a multiple of the block
+    (256, 32, 1),      # the query alone
+    (128, 128, 50),    # one block: the band inside it
+])
+def test_windowed_kernels_match_the_dense_band(n, block, window):
+    """Forward and dq, dk, dv of the banded grid against the dense masked
+    oracle over the band's pairs."""
+    q, k, v = _qkv(jax.random.PRNGKey(window), 2, 2, n, 32)
+    do = jax.random.normal(jax.random.PRNGKey(7), q.shape)
+    scale = q.shape[-1] ** -0.5
+    flash = lambda q, k, v: flash_attention(q, k, v, None, True, None, scale, block, block, True, window)
+    dense = lambda q, k, v: dense_attend(q * scale, k, v, jnp.asarray(_band(n, window))[None, None])
+    with jax.default_matmul_precision("highest"):
+        out, vjp = jax.vjp(flash, q, k, v)
+        want, want_vjp = jax.vjp(dense, q, k, v)
+        got, wanted = vjp(do), want_vjp(do)
+    np.testing.assert_allclose(out, want, atol=2e-5)
+    for g, w in zip(got, wanted):
+        np.testing.assert_allclose(g, w, atol=2e-5 * max(1.0, float(jnp.max(jnp.abs(w)))))
+
+
+@pytest.mark.parametrize("window", [256, 1000])
+def test_a_window_that_covers_the_row_is_the_causal_program(window):
+    """``window >= n`` traces the causal kernels, equation for equation, and
+    gives their result bit for bit."""
+    q, k, v = _qkv(jax.random.PRNGKey(3), 1, 2, 256, 32)
+
+    def grads(window):
+        f = lambda q, k, v: flash_attention(q, k, v, None, True, None, None, 64, 64, True, window)
+        return lambda q, k, v: jax.vjp(f, q, k, v)[1](q)
+
+    assert str(jax.make_jaxpr(grads(window))(q, k, v)) == str(jax.make_jaxpr(grads(None))(q, k, v))
+    for a, b in zip(grads(window)(q, k, v), grads(None)(q, k, v)):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("n,block,window", [
+    (16384, 1024, 4096), (256, 64, 40), (256, 64, 64), (256, 64, 100), (512, 64, 300), (256, 64, 1),
+])
+def test_the_banded_grid_visits_exactly_the_tiles_that_touch_the_band(n, block, window):
+    """Brute force over the element band: the tiles the forward and the
+    backward grid address (after the clamp) are exactly those holding a pair
+    of the band, each live tile is live in the tables, and ``window_tiles``
+    counts them beside the causal triangle's."""
+    nb = n // block
+    gap = np.arange(n)[:, None] - np.arange(n)[None, :]
+    band = (gap >= 0) & (gap < window)
+    pairs = band.reshape(nb, block, nb, block).any(axis=(1, 3))
+    span = fa._band_span(nb, block, window)
+    forward = {(qb, max(qb - span + 1 + j, 0)) for qb in range(nb) for j in range(span)}
+    backward = {(min(kb + j, nb - 1), kb) for kb in range(nb) for j in range(span)}
+    touched = set(zip(*np.nonzero(pairs)))
+    assert forward == touched and backward == touched
+    visit = fa._block_visit_map(nb, nb, block, block, True, None, window)
+    fwd, bwd = fa._band_tables(visit, span)
+    assert int((fwd > 0).sum()) == int((bwd > 0).sum()) == len(touched)
+    full = band.reshape(nb, block, nb, block).all(axis=(1, 3))
+    assert np.array_equal(visit == 2, full) and np.array_equal(visit > 0, pairs)
+    counts = fa.window_tiles(n, block, window)
+    assert counts["tiles_visited"] == len(touched)
+    assert counts["causal_tiles"] == nb * (nb + 1) // 2
+    if (n, block, window) == (16384, 1024, 4096):
+        assert (span, counts["tiles_visited"], counts["causal_tiles"]) == (5, 70, 136)
+
+
 @pytest.mark.slow
 def test_flagship_seq_1280_forward_parity():
     """The exact shape that crashed round 1: seq 1280 (= 256 text + 1024

@@ -151,6 +151,12 @@ def _gated(q, k, v):
     return flash_attention(q, k, v, None, True, None, 256**-0.5, 1024, 1024, False)
 
 
+# train-smallthinker-d4-ep4-s16k's window layers: 28 heads of 128 over 16,384
+# positions, a window of 4,096 keys, block 1,024 (a banded grid of 5 key blocks)
+def _windowed(q, k, v):
+    return flash_attention(q, k, v, None, True, None, 128**-0.5, 1024, 1024, False, 4096)
+
+
 ROUTES = {
     "delta_rule": (
         _delta,
@@ -164,6 +170,7 @@ ROUTES = {
     "gated_flash_head256": (
         _gated, [(GDN_B, 16, GDN_N, 256)] * 3, {"flash_fwd", "flash_bwd"},
     ),
+    "windowed_flash": (_windowed, [(1, 28, 16384, 128)] * 3, {"flash_fwd", "flash_bwd"}),
     "ssm_conv": (
         _conv,
         [(1, SSM_N, SSM_CONV), ((1, 4, SSM_CONV), F32), ((1, 1, SSM_CONV), F32)],

@@ -7,7 +7,8 @@ and grouped-KV attention layers (``granitemoehybrid``), or latent attention
 with routed experts, a shared expert and a multi-token-prediction module
 (``joyai_llm_flash``), or gated-delta-rule linear attention beside gated
 softmax attention with softmax-routed experts and a gated shared expert
-(``qwen3_next``) — on a folder of ``.txt`` documents packed end to end,
+(``qwen3_next``), or sliding-window attention beside global attention with
+ReGLU experts routed from the block's input (``smallthinker``) — on a folder of ``.txt`` documents packed end to end,
 with the app surface of train_clip.py and train_dalle.py's loop
 (``parallel/loop.py``): compiled sharded train step over a dp x fsdp x tp mesh
 (``make_runtime`` → ``create_train_state`` → ``make_train_step``), one
@@ -35,7 +36,8 @@ def parse_args():
                         help="the model's config.json (the source's own keys, by its "
                              "model_type: hidden_size, layer_types, mamba_*, ... or "
                              "q_lora_rank, n_routed_experts, first_k_dense_replace, ... or "
-                             "full_attention_interval, linear_*, num_experts, ...)")
+                             "full_attention_interval, linear_*, num_experts, ... or "
+                             "sliding_window_layout, rope_layout, moe_num_primary_experts, ...)")
     parser.add_argument("--image_text_folder", type=str, required=True,
                         help="folder whose .txt files are the documents (images, if any, are ignored)")
     parser.add_argument("--lm_path", type=str, default=None,
