@@ -35,7 +35,10 @@ from ..ops.layers import (
     RMSNorm,
     SwiGLU,
 )
-from ..ops.moe import MoEFeedForward, RoutedExperts, router_logits
+from ..ops.moe import (
+    MOE_RESIDUAL_NAMES, MoEFeedForward, RoutedExperts, checkpointed_block, router_logits,
+    router_scores,
+)
 from ..ops.reversible import reversible_forward_only, reversible_sequence
 from ..ops.rotary import angles, dalle_rotary_table, lang_freqs
 from ..ops.gdn import GatedDeltaNet
@@ -75,7 +78,8 @@ class PreRoutedRMSNorm(nn.Module):
     block's INPUT (``experts_route_first``): ``u = RMSNorm(x)`` feeds the
     router first and then the mixer,
 
-        p = softmax(W_r u)            float32, under ``moe`` / ``moe.router``
+        p = softmax(W_r u)            float32, under ``moe`` / ``moe.router``, named
+                                      ``moe_router`` (a block's checkpoint keeps it)
         -> (multiplier * fn(u), p)    the norm and the mixer under ``mixer_scope``
 
     and the trunk hands ``p`` to the block's expert layer
@@ -96,21 +100,31 @@ class PreRoutedRMSNorm(nn.Module):
             u = RMSNorm(self.eps, self.param_dtype, name="norm")(x)
         with jax.named_scope("moe"), jax.named_scope("moe.router"):
             rows = u.reshape(-1, u.shape[-1])
-            probs = jax.nn.softmax(router_logits(rows, self.experts_total, self.param_dtype), axis=-1)
+            probs = router_scores(router_logits(rows, self.experts_total, self.param_dtype), "softmax")
         with jax.named_scope(self.mixer_scope):
             out = self.fn(u.astype(x.dtype), **kwargs)
         return (out if self.multiplier == 1.0 else out * self.multiplier), probs
 
 
-def _block_checkpoint(fn):
+def _block_checkpoint(fn, block: str = ""):
     """The one ``jax.checkpoint`` the trunk puts around a block under
     ``remat``: everything is rebuilt in backward but the attention kernels'
     own residuals (``KERNEL_RESIDUAL_NAMES``: quadratic in the row length to
     rebuild, linear to keep), so the rebuilt forward holds no flash kernel —
-    all of its results are in memory and XLA drops the call."""
+    all of its results are in memory and XLA drops the call — and an expert
+    layer's routing, dispatched rows and first grouped product
+    (``MOE_RESIDUAL_NAMES``), so its rebuilt forward runs the second product
+    alone. A block without those layers holds none of the names. An expert
+    layer traced inside records route ``remat/moe_residuals`` for ``block``."""
     kv_policy.record_route("remat/attn_residuals", "saved")
+
+    def traced(*args):
+        with checkpointed_block(block):
+            return fn(*args)
+
     return jax.checkpoint(
-        fn, policy=jax.checkpoint_policies.save_only_these_names(*KERNEL_RESIDUAL_NAMES)
+        traced, policy=jax.checkpoint_policies.save_only_these_names(
+            *KERNEL_RESIDUAL_NAMES, *MOE_RESIDUAL_NAMES)
     )
 
 
@@ -125,7 +139,8 @@ class Transformer(nn.Module):
     Execution modes: sequential (default), ``reversible=True`` (O(1)
     activation memory via ops/reversible.py), or ``remat=True``
     (``_block_checkpoint`` per block — recompute in backward, standard pytree
-    activations; only the flash kernels' output and log-sum-exp are kept).
+    activations; only the flash kernels' output and log-sum-exp and an expert
+    layer's routing, rows and first grouped product are kept).
 
     Block variants (models/lm.py's causal language models; every DALL-E and
     CLIP configuration leaves them at their defaults, which are the block
@@ -598,7 +613,7 @@ class Transformer(nn.Module):
                     d, probs = d
                     kwg = dict(kwg, probs=probs)
                 x = x + d
-                dg, ag = _block_checkpoint(g)(pg, x, kwg)
+                dg, ag = _block_checkpoint(g, "/".join((*self.path, f"ff_{ind}")))(pg, x, kwg)
                 x = x + dg
                 if isinstance(ag, tuple):
                     # what an expert layer sowed (``moe_stats``: the pairs it

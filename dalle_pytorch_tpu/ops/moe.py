@@ -28,6 +28,8 @@ shard it over the ``ep`` mesh axis with zero manual collectives:
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 from typing import Any, Tuple
 
@@ -35,11 +37,36 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import flax.linen as nn
+from jax.ad_checkpoint import checkpoint_name
 
 from . import kv_policy
 from .layers import SwiGLU
 
 Dtype = Any
+
+# the names a block's checkpoint keeps of ``RoutedExperts`` (models/transformer.py:
+# _block_checkpoint, beside the flash kernels' KERNEL_RESIDUAL_NAMES): the router's
+# scores, choice and picked scores; the dispatch (the pairs' order, the group
+# sizes, the sorted tokens and weights); the first chunk's gathered rows; its
+# first grouped product. Each costs 2-10 times a dot's time per byte to rebuild: kept,
+# the rebuilt forward runs no router, no sort, no row gather and only the second
+# product. Outside a checkpoint a name lowers to nothing.
+MOE_RESIDUAL_NAMES = ("moe_router", "moe_dispatch", "moe_rows", "moe_h")
+ROUTER, DISPATCH, ROWS, PRODUCT = MOE_RESIDUAL_NAMES
+
+# the block whose checkpoint is being traced, while it is (``checkpointed_block``):
+# an expert layer in it records what the names keep (route ``remat/moe_residuals``)
+_CHECKPOINTED_BLOCK: contextvars.ContextVar = contextvars.ContextVar("block", default=None)
+
+
+@contextlib.contextmanager
+def checkpointed_block(block: str):
+    """Inside: a block's checkpoint, named ``block``, is tracing its function."""
+    token = _CHECKPOINTED_BLOCK.set(block)
+    try:
+        yield
+    finally:
+        _CHECKPOINTED_BLOCK.reset(token)
 
 
 class MoEFeedForward(nn.Module):
@@ -121,8 +148,32 @@ def route(scores, bias, per_token: int, scaling: float):
     choice only; the weights are the scores themselves, normalised over all
     the chosen (held here or not) and scaled."""
     _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), per_token)
-    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    chosen = checkpoint_name(chosen, ROUTER)
+    picked = checkpoint_name(jnp.take_along_axis(scores, chosen, axis=-1), ROUTER)
     return chosen, scaling * picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+
+
+def _named_scores(logits, scoring: str):
+    scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" else jax.nn.softmax(logits, axis=-1)
+    return checkpoint_name(scores, ROUTER)
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(1,))
+def router_scores(logits, scoring: str):
+    """``sigmoid`` or ``softmax`` (over the last axis) of float32 router logits,
+    named ``moe_router``. The derivative is jax's own, written over the NAMED
+    scores, so that a checkpoint which keeps them rebuilds neither the function
+    nor the dot in front of it (jax's rules read the function's unnamed output)."""
+    return _named_scores(logits, scoring)
+
+
+@router_scores.defjvp
+def _router_scores_jvp(scoring, primals, tangents):
+    (logits,), (dot,) = primals, tangents
+    s = _named_scores(logits, scoring)
+    if scoring == "sigmoid":
+        return s, dot * (s * (1 - s))
+    return s, s * (dot - (s * dot).sum(-1, keepdims=True))
 
 
 def shared_gate(shared, logit):
@@ -146,7 +197,10 @@ ACTIVATIONS = {"swiglu": nn.silu, "reglu": jax.nn.relu}
 def _chunk(c, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, chunk: int, dtype,
            activation: str = "swiglu"):
     """Chunk ``c`` of the pairs sorted by expert: (its tokens, its weighted
-    expert outputs in float32). Rows past the count have weight 0."""
+    expert outputs in float32). Rows past the count have weight 0. The
+    gathered rows and the first product's output are named: a block's
+    checkpoint keeps the first chunk's (the further chunks' loop has a
+    backward of its own, which keeps nothing)."""
     with jax.named_scope("moe.dispatch"):
         first = c * chunk
         tok = jax.lax.dynamic_slice(sorted_tok, (first,), (chunk,))
@@ -158,10 +212,10 @@ def _chunk(c, u, w_in, w_out, sorted_w, sorted_tok, starts, sizes, chunk: int, d
         # TPU's grouped product leaves rows of NO group unwritten (NaNs from
         # the chip's memory reached the sum through 0 * NaN)
         groups = groups.at[-1].add(chunk - jnp.sum(groups))
-        rows = jnp.take(u, tok, axis=0).astype(dtype)
+        rows = checkpoint_name(jnp.take(u, tok, axis=0).astype(dtype), ROWS)
     with jax.named_scope("moe.experts"):
         h = jax.lax.ragged_dot(rows, w_in, groups, preferred_element_type=jnp.float32)
-        a, g = jnp.split(h.astype(dtype), 2, axis=-1)
+        a, g = jnp.split(checkpoint_name(h.astype(dtype), PRODUCT), 2, axis=-1)
         out = jax.lax.ragged_dot(ACTIVATIONS[activation](a) * g, w_out, groups,
                                  preferred_element_type=jnp.float32)
     with jax.named_scope("moe.combine"):
@@ -299,6 +353,21 @@ class RoutedExperts(nn.Module):
         expected = HEADROOM * pairs * (hi - lo) / self.experts_total
         return min(max(-(-int(expected) // CHUNK_MULTIPLE), 1) * CHUNK_MULTIPLE, pairs)
 
+    def _kept_bytes(self, tokens: int, padded: int, chunk: int) -> dict:
+        """Bytes a block's checkpoint keeps of this layer, by name: the float32
+        scores (handed probabilities are kept by the mixer's checkpoint), the
+        int32 choice and float32 picked scores; the int32 order and group sizes,
+        the padded sorted tokens and weights; the first chunk's rows and first
+        product in ``dtype``."""
+        width, pairs = jnp.dtype(self.dtype).itemsize, tokens * self.per_token
+        held = self.experts_held[1] - self.experts_held[0]
+        return {
+            ROUTER: 4 * (tokens * self.experts_total + 2 * pairs),
+            DISPATCH: 4 * (pairs + held + 2 * padded),
+            ROWS: width * chunk * self.dim,
+            PRODUCT: width * chunk * 2 * self.hidden,
+        }
+
     @nn.compact
     def __call__(self, x: jnp.ndarray, deterministic: bool = True, probs=None) -> jnp.ndarray:
         b, n, d = x.shape
@@ -314,14 +383,14 @@ class RoutedExperts(nn.Module):
             assert probs is None or self.scoring == "softmax", "only softmax probabilities are handed"
             if self.scoring == "softmax":
                 scores = (
-                    jax.nn.softmax(router_logits(u, total, self.param_dtype), axis=-1)
+                    router_scores(router_logits(u, total, self.param_dtype), "softmax")
                     if probs is None else probs.reshape(tokens, total)
                 )
                 chosen, weights = route(scores, jnp.zeros((total,), scores.dtype), k, self.scaling)
                 self.sow("moe_stats", "prob", jnp.mean(scores, axis=0))
                 self.param("router_prob", nn.initializers.zeros, (total,), self.param_dtype)
             else:
-                scores = jax.nn.sigmoid(router_logits(u, total, self.param_dtype))
+                scores = router_scores(router_logits(u, total, self.param_dtype), "sigmoid")
                 bias = self.param(
                     "e_score_correction_bias", nn.initializers.zeros, (total,), self.param_dtype
                 )
@@ -336,15 +405,17 @@ class RoutedExperts(nn.Module):
             flat = chosen.reshape(pairs)
             here = (flat >= lo) & (flat < hi)
             key = jnp.where(here, flat - lo, held)          # the others last
-            order = jnp.argsort(key, stable=True)
+            order = checkpoint_name(jnp.argsort(key, stable=True), DISPATCH)
             sizes = jnp.sum(key[:, None] == jnp.arange(held, dtype=key.dtype), axis=0)
+            sizes = checkpoint_name(sizes, DISPATCH)
             starts = jnp.cumsum(sizes) - sizes
             count = jnp.sum(sizes)
             chunk = self._chunk_rows(pairs)
             n_chunks = -(-pairs // chunk)
             pad = n_chunks * chunk - pairs
-            sorted_tok = jnp.pad(order // k, (0, pad))
+            sorted_tok = checkpoint_name(jnp.pad(order // k, (0, pad)), DISPATCH)
             sorted_w = jnp.pad(jnp.where(here, weights.reshape(pairs), 0.0)[order], (0, pad))
+            sorted_w = checkpoint_name(sorted_w, DISPATCH)
 
         w_in = self.param(
             "experts_in", nn.initializers.lecun_normal(), (held, d, 2 * self.hidden),
@@ -355,6 +426,10 @@ class RoutedExperts(nn.Module):
             self.param_dtype,
         ).astype(self.dtype)
         kv_policy.record_route("forward/moe_experts", "ragged_dot")
+        block = _CHECKPOINTED_BLOCK.get()
+        if block is not None:
+            kept = self._kept_bytes(tokens, chunk * n_chunks, chunk)
+            kv_policy.record_route("remat/moe_residuals", "saved", block=block, bytes=kept)
 
         operands = (u, w_in, w_out, sorted_w, sorted_tok, starts, sizes)
         tok, out = _chunk(0, *operands, chunk, self.dtype, self.activation)

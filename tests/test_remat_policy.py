@@ -7,11 +7,20 @@ forward kernel once a layer where the bare ``jax.checkpoint`` held it twice;
 the gradients are the bare checkpoint's to the bit and ``remat=False``'s to
 rounding; the pipeline path and a mesh of several devices (the kernels then
 run inside ``ops/attention.py:_per_device``'s shard_map) keep the count; and
-the route ``remat/attn_residuals`` says the rule engaged."""
+the route ``remat/attn_residuals`` says the rule engaged.
+
+The same checkpoint keeps an expert layer's routing, dispatch, gathered rows
+and first grouped product (ops/moe.py:``MOE_RESIDUAL_NAMES``), in the three
+expert families: its rebuilt forward holds one grouped product where it held
+two, and no sort and no ``top_k``; the gradients are the bare checkpoint's to
+the bit, also where the count passes the first chunk; the route
+``remat/moe_residuals`` is recorded for each expert block and no other; a
+block without experts lowers as it did."""
 
 import collections
 import json
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +31,9 @@ from dalle_pytorch_tpu.models import DALLE
 from dalle_pytorch_tpu.models import transformer
 from dalle_pytorch_tpu.models.lm import CausalLM
 from dalle_pytorch_tpu.ops import kv_policy
+from dalle_pytorch_tpu.ops import moe as moe_ops
 from dalle_pytorch_tpu.ops.flash_attention import KERNEL_RESIDUAL_NAMES
+from dalle_pytorch_tpu.ops.moe import MOE_RESIDUAL_NAMES
 from dalle_pytorch_tpu.parallel import make_runtime
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -45,6 +56,21 @@ MLA = {
     **json.loads((ROOT / "benchmarks/rehearsal_moe.json").read_text())["config"],
     "num_hidden_layers": DEPTH,
 }
+
+def rehearsal(cell, name):
+    return {**costs.load_config(cell), **json.loads((ROOT / name).read_text())["config"]}
+
+
+# the three expert families at their rehearsals' sizes: sigmoid scores, SwiGLU
+# experts and a shared expert (joyai, the dense block, one expert block and the
+# MTP module's); softmax scores and a gated shared expert (qwen3next, four
+# expert blocks); probabilities handed from the block's mixer and ReGLU experts
+# (smallthinker, four expert blocks)
+EXPERTS = {
+    "sigmoid_swiglu_shared": MLA,
+    "softmax_gated_shared": rehearsal("qwen3-next-80b-a3b-d4-ep16", "benchmarks/rehearsal_gdn.json"),
+    "handed_probs_reglu": rehearsal("smallthinker-21b-a3b-d4-ep4", "benchmarks/rehearsal_swa.json"),
+}
 DALLE_FULL = dict(
     dim=128, depth=DEPTH, num_text_tokens=64, text_seq_len=64, num_image_tokens=32,
     image_fmap_size=8, heads=2, dim_head=64, attn_types=("full",),
@@ -63,8 +89,8 @@ def build(family, remat, **over):
         image = jax.random.randint(jax.random.key(2), (4, 64), 0, 32)
         params = model.init(jax.random.key(0), text[:1], image[:1])["params"]
         return lambda p: model.apply({"params": p}, text, image, return_loss=True), params
-    model = CausalLM.from_config({"gqa": GQA, "mla": MLA}[family], seq_len=N, remat=remat)
-    ids = jax.random.randint(jax.random.key(1), (4, N), 0, 50)      # both vocabularies hold 50
+    model = CausalLM.from_config({"gqa": GQA, "mla": MLA, **EXPERTS}[family], seq_len=N, remat=remat)
+    ids = jax.random.randint(jax.random.key(1), (4, N), 0, 50)      # every vocabulary holds 50
     params = model.init(jax.random.key(0), ids)["params"]
     return lambda p: model.apply({"params": p}, ids, return_loss=True), params
 
@@ -87,7 +113,14 @@ def grad_kernel_calls(loss, params) -> collections.Counter:
 
 def bare_checkpoint(monkeypatch):
     """From here on the trunk builds the checkpoint it used before: nothing kept."""
-    monkeypatch.setattr(transformer, "_block_checkpoint", jax.checkpoint)
+    monkeypatch.setattr(transformer, "_block_checkpoint", lambda fn, block="": jax.checkpoint(fn))
+
+
+def attention_names_only(monkeypatch):
+    """From here on the trunk's checkpoint keeps the flash kernels' names
+    alone, a policy a block, as it did before the expert layer's names."""
+    monkeypatch.setattr(transformer, "_block_checkpoint", lambda fn, block="": jax.checkpoint(
+        fn, policy=jax.checkpoint_policies.save_only_these_names(*KERNEL_RESIDUAL_NAMES)))
 
 
 def leaves(tree):
@@ -143,7 +176,8 @@ def test_gradients_match_no_remat_to_rounding(family):
 
 def test_the_names_lower_to_nothing_outside_a_checkpoint():
     """``remat=False`` (the DALL-E cell): the forward rule's two names are
-    identities, and the lowered program holds no trace of them."""
+    identities, and the lowered program holds no trace of them; nor of the
+    expert layer's four, in a model that names all six."""
     loss, params = build("full", remat=False)
     names = [
         eqn.params["name"] for eqn in jax.make_jaxpr(jax.grad(loss))(params).jaxpr.eqns
@@ -152,6 +186,130 @@ def test_the_names_lower_to_nothing_outside_a_checkpoint():
     assert sorted(names) == sorted(KERNEL_RESIDUAL_NAMES * DEPTH)
     text = jax.jit(jax.grad(loss)).lower(params).as_text()
     assert not any(name in text for name in KERNEL_RESIDUAL_NAMES)
+
+    loss, params = build("handed_probs_reglu", remat=False)
+    names = {
+        eqn.params["name"] for eqn in eqns(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+        if eqn.primitive.name == "name"
+    }
+    assert names == set(KERNEL_RESIDUAL_NAMES + MOE_RESIDUAL_NAMES)
+    text = jax.jit(jax.grad(loss)).lower(params).as_text()
+    assert not any(name in text for name in names)
+
+
+# ------------------------------------------------------ the expert layer's names
+
+
+def eqns(jaxpr, rebuilt=False, only_rebuilt=False):
+    """Every equation of ``jaxpr`` and of the jaxprs inside its equations; with
+    ``only_rebuilt``, only those inside a checkpoint's backward (``remat2``):
+    the rebuilt forward and its transposes."""
+    for eqn in jaxpr.eqns:
+        if rebuilt or not only_rebuilt:
+            yield eqn
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from eqns(inner, rebuilt or eqn.primitive.name == "remat2", only_rebuilt)
+
+
+def grad_primitives(loss, params, only_rebuilt=False) -> collections.Counter:
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(params).jaxpr
+    return collections.Counter(eqn.primitive.name for eqn in eqns(jaxpr, only_rebuilt=only_rebuilt))
+
+
+def expert_blocks(family) -> set:
+    """The blocks an expert family's stack checkpoints with an expert layer."""
+    model = CausalLM.from_config(EXPERTS[family], seq_len=N)
+    blocks = {f"transformer/ff_{i}" for i, kind in enumerate(model.ff_types) if kind == "experts"}
+    return blocks | ({"nextn/block/ff_0"} if model.mtp_lambda is not None else set())
+
+
+def one_chunk(monkeypatch):
+    """Every layer's first chunk holds all its pairs: no loop over further chunks."""
+    monkeypatch.setattr(moe_ops, "CHUNK_MULTIPLE", 1 << 20)
+
+
+def chunks_of_8(monkeypatch):
+    """Chunks of 8 rows: every layer's count passes the first chunk."""
+    monkeypatch.setattr(moe_ops, "HEADROOM", 0.0)
+    monkeypatch.setattr(moe_ops, "CHUNK_MULTIPLE", 8)
+
+
+@pytest.mark.parametrize("family", sorted(EXPERTS))
+def test_a_rebuilt_expert_layer_runs_one_grouped_product_where_it_ran_two(family, monkeypatch):
+    """Two forward products, four in the backward pass and, rebuilt, only the
+    second: 7 a layer, where the bare checkpoint rebuilt both (8) and no
+    checkpoint rebuilds none (6)."""
+    one_chunk(monkeypatch)
+    layers = len(expert_blocks(family))
+    products = lambda remat: grad_primitives(*build(family, remat=remat))["ragged_dot_general"]
+    assert products(True) == 7 * layers
+    assert products(False) == 6 * layers
+    bare_checkpoint(monkeypatch)
+    assert products(True) == 8 * layers
+
+
+@pytest.mark.parametrize("family", sorted(EXPERTS))
+def test_the_rebuilt_forward_holds_no_sort_and_no_top_k(family, monkeypatch):
+    """Nor the router's dot and function: the kept scores, choice and order
+    stand in for them. The checkpoint that kept the attention kernels' names
+    alone rebuilt a sort and a ``top_k`` a layer, so the count does see inside
+    the checkpoint."""
+    loss, params = build(family, remat=True)
+    kept = grad_primitives(loss, params, only_rebuilt=True)
+    assert kept["sort"] == kept["top_k"] == 0, kept
+    attention_names_only(monkeypatch)
+    before = grad_primitives(loss, params, only_rebuilt=True)
+    layers = len(expert_blocks(family))
+    assert before["sort"] == before["top_k"] == layers, before
+    # the router's dot a layer, and for softmax scores its `exp`
+    assert before["dot_general"] - kept["dot_general"] == layers
+    assert before["exp"] - kept["exp"] == (0 if family == "sigmoid_swiglu_shared" else layers)
+
+
+@pytest.mark.parametrize("chunks", [one_chunk, chunks_of_8], ids=["one_chunk", "chunks_of_8"])
+@pytest.mark.parametrize("family", sorted(EXPERTS))
+def test_expert_gradients_are_the_bare_checkpoints_to_the_bit(family, chunks, monkeypatch):
+    """What is kept is what the rebuilt forward would have produced, and the
+    loop over further chunks keeps its own backward. Operation by operation,
+    as above."""
+    chunks(monkeypatch)
+    loss, params = build(family, remat=True)
+    kept = jax.value_and_grad(loss)(params)
+    bare_checkpoint(monkeypatch)
+    bare = jax.value_and_grad(loss)(params)
+    for a, b in zip(leaves(kept), leaves(bare)):
+        assert a.dtype == b.dtype and bool(jnp.all(a == b)), a.shape
+
+
+@pytest.mark.parametrize("family", [*sorted(EXPERTS), "gqa", "full"])
+def test_the_expert_route_is_recorded_for_expert_blocks_and_no_other(family):
+    """One entry an expert block, with the bytes each name keeps of it; none
+    for a stack without experts, and none without ``remat``."""
+    want = expert_blocks(family) if family in EXPERTS else set()
+    routes = lambda: [r for r in kv_policy.ROUTE_LOG if r["site"] == "remat/moe_residuals"]
+    loss, params = build(family, remat=False)
+    kv_policy.ROUTE_LOG.clear()
+    jax.make_jaxpr(jax.grad(loss))(params)
+    assert routes() == []
+    loss, params = build(family, remat=True)
+    kv_policy.ROUTE_LOG.clear()
+    jax.make_jaxpr(jax.grad(loss))(params)
+    assert sorted(r["block"] for r in routes()) == sorted(want)
+    for r in routes():
+        assert r["impl"] == "saved" and tuple(r["bytes"]) == MOE_RESIDUAL_NAMES
+        assert all(n > 0 for n in r["bytes"].values())
+
+
+@pytest.mark.parametrize("family", ["gqa", "full"])
+def test_a_block_without_experts_lowers_as_it_did(family, monkeypatch):
+    """granite's and DALL-E's blocks: the gradient's jaxpr under the policy
+    with the expert layer's names is the one under the attention kernels'
+    names alone, equation for equation (the policy function's address aside)."""
+    loss, params = build(family, remat=True)
+    text = lambda: re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(jax.grad(loss))(params)))
+    now = text()
+    attention_names_only(monkeypatch)
+    assert now == text()
 
 
 # ------------------------------------------- several devices, and the pipeline
