@@ -22,8 +22,13 @@ attention, softmax-routed experts with a gated shared expert and the family's
 load-balance loss) and ``smallthinker`` (``sliding_window_layout``,
 ``rope_layout``, ``sliding_window_size``, ``moe_*``: sliding-window attention
 with rotary beside global attention with no positional term, ReGLU experts
-routed from the block's input, before its attention). What a key asks that
-is not written here is refused, not ignored.
+routed from the block's input, before its attention) and ``kimi_linear``
+(``linear_attn_config``, ``mla_use_nope``, ``num_experts``,
+``moe_router_activation_func``, …: the delta rule with a decay per key
+channel (ops/kda.py) three to one with latent attention that has no
+positional term and no query compression, sigmoid-routed experts with a
+selection bias and a shared expert). What a key asks that is not written
+here is refused, not ignored.
 
 Training and whole-sequence evaluation only; serving a stack with
 recurrent-state layers is ROADMAP R13, one with latent attention R11, and a
@@ -75,12 +80,13 @@ class CausalLM(nn.Module):
     ssm_conv: int = 4
     ssm_chunk: int = 256
     ff_types: Optional[Tuple[str, ...]] = None
-    mla_q_rank: int = 1536
+    mla_q_rank: Optional[int] = 1536
     mla_kv_rank: int = 512
     mla_nope_dim: int = 128
     mla_rope_dim: int = 64
     mla_v_dim: int = 128
     mla_rope_theta: float = 10000.0
+    mla_rotary: bool = True
     experts_total: int = 0
     experts_held: Optional[Tuple[int, int]] = None
     experts_per_token: int = 0
@@ -156,7 +162,7 @@ class CausalLM(nn.Module):
         module's one block share."""
         names = (
             "mla_q_rank", "mla_kv_rank", "mla_nope_dim", "mla_rope_dim", "mla_v_dim",
-            "mla_rope_theta", "experts_total", "experts_held", "experts_per_token",
+            "mla_rope_theta", "mla_rotary", "experts_total", "experts_held", "experts_per_token",
             "experts_hidden", "experts_shared", "experts_scaling", "experts_scoring",
             "experts_gate_shared", "linattn_key_heads", "linattn_value_heads",
             "linattn_key_dim", "linattn_value_dim", "linattn_conv", "attn_rotary_dim",
@@ -518,7 +524,58 @@ def _smallthinker_fields(cfg: dict) -> dict:
     )
 
 
+def _kimi_linear_fields(cfg: dict) -> dict:
+    """``kimi_linear``: the source's own keys, and two it does not have, each
+    with a default: ``experts_held`` (as ``_joyai_fields``: ``num_experts``
+    then counts the experts HELD and the router scores ``of``) and
+    ``bias_update_speed`` (0.001, as ``_joyai_fields``). ``linear_attn_config``
+    names the layers by 1-BASED number: layer ``l`` is ``kda`` where ``l + 1``
+    is among its ``kda_layers``, latent attention where among its
+    ``full_attn_layers``; each of the ``num_hidden_layers`` is in exactly one.
+    The first ``first_k_dense_replace`` feed-forwards are the dense SwiGLU of
+    ``intermediate_size``, every later one the expert layer: sigmoid scores
+    over all experts, the top ``num_experts_per_token`` of the scores plus the
+    selection bias, their weights normalised over the chosen and times
+    ``routed_scaling_factor``, ``num_shared_experts`` shared experts of
+    ``moe_intermediate_size``. The latent attention has no positional term
+    (``mla_use_nope``) and no query compression (``q_lora_rank`` null)."""
+    _refuse(cfg, {
+        "rope_scaling": None, "num_expert_group": 1, "topk_group": 1, "moe_layer_freq": 1,
+        "moe_router_activation_func": "sigmoid", "moe_renormalize": True, "hidden_act": "silu",
+        "tie_word_embeddings": False, "q_lora_rank": None, "mla_use_nope": True,
+        "num_nextn_predict_layers": 0, "num_key_value_heads": cfg["num_attention_heads"],
+    })
+    depth, linear = cfg["num_hidden_layers"], cfg["linear_attn_config"]
+    kda, full = set(linear["kda_layers"]), set(linear["full_attn_layers"])
+    if kda & full or kda | full != set(range(1, depth + 1)):
+        raise ValueError(
+            f"linear_attn_config kda_layers={sorted(kda)}, full_attn_layers={sorted(full)}: "
+            f"each of the layers 1..{depth} (1-based) in exactly one is written here"
+        )
+    dense = cfg["first_k_dense_replace"]
+    lo, hi, total = _share(cfg, "num_experts")
+    return dict(
+        vocab_size=cfg["vocab_size"], dim=cfg["hidden_size"], depth=depth,
+        layer_types=tuple("kda" if l + 1 in kda else "mla" for l in range(depth)),
+        ff_types=("dense",) * min(dense, depth) + ("experts",) * max(depth - dense, 0),
+        heads=cfg["num_attention_heads"], ff_hidden=cfg["intermediate_size"],
+        norm_eps=cfg["rms_norm_eps"],
+        mla_q_rank=None, mla_kv_rank=cfg["kv_lora_rank"],
+        mla_nope_dim=cfg["qk_nope_head_dim"], mla_rope_dim=cfg["qk_rope_head_dim"],
+        mla_v_dim=cfg["v_head_dim"], mla_rotary=False,
+        linattn_key_heads=linear["num_heads"], linattn_key_dim=linear["head_dim"],
+        linattn_conv=linear["short_conv_kernel_size"],
+        experts_total=total, experts_held=(lo, hi),
+        experts_per_token=cfg["num_experts_per_token"],
+        experts_hidden=cfg["moe_intermediate_size"],
+        experts_shared=cfg["num_shared_experts"],
+        experts_scaling=float(cfg["routed_scaling_factor"]),
+        bias_update_speed=float(cfg.get("bias_update_speed", 0.001)), tie_head=False,
+    )
+
+
 _FAMILIES = {
     "granitemoehybrid": _granite_fields, "joyai_llm_flash": _joyai_fields,
     "qwen3_next": _qwen3_next_fields, "smallthinker": _smallthinker_fields,
+    "kimi_linear": _kimi_linear_fields,
 }

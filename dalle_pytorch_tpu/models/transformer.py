@@ -42,6 +42,7 @@ from ..ops.moe import (
 from ..ops.reversible import reversible_forward_only, reversible_sequence
 from ..ops.rotary import angles, dalle_rotary_table, lang_freqs
 from ..ops.gdn import GatedDeltaNet
+from ..ops.kda import KimiDeltaAttention
 from ..ops.ssm import MambaMixer
 
 Dtype = Any
@@ -52,7 +53,7 @@ ATTENTION_TYPES = ("full", "axial_row", "axial_col", "conv_like", "sparse", "mlp
 MIXER_TYPES = {
     "mamba": ("mamba", "ssm"), "attention": ("gqa", "attn.gqa"), "mla": ("mla", "attn.mla"),
     "linear_attention": ("gdn", "linattn"), "full_attention": ("gated", "attn.gated"),
-    "sliding_attention": ("swa", "attn.swa"),
+    "sliding_attention": ("swa", "attn.swa"), "kda": ("kda", "linattn"),
 }
 # ``ff_types``: a layer_types stack's feed-forward kind, layer by layer.
 # kind -> device scope
@@ -150,7 +151,9 @@ class Transformer(nn.Module):
     softmax scale ``attn_scale``, no positional term) or ``mla`` (latent
     attention, ops/attention.py:LatentAttention, sized by ``mla_*``: its own
     rotary key, nothing of ``rotary_emb``) or ``linear_attention`` (the gated
-    delta rule, ops/gdn.py:GatedDeltaNet, sized by ``linattn_*``) or
+    delta rule, ops/gdn.py:GatedDeltaNet, sized by ``linattn_*``) or ``kda``
+    (the delta rule with a decay per key channel, ops/kda.py:KimiDeltaAttention,
+    sized by ``kda_*``, scope ``linattn``) or
     ``full_attention`` (ops/attention.py:GatedAttention: grouped-KV attention
     with per-head norms, rotary over ``attn_rotary_dim`` channels and an
     output gate) or ``sliding_attention`` (GroupedKVAttention with rotary
@@ -208,12 +211,13 @@ class Transformer(nn.Module):
     ssm_state: int = 128
     ssm_conv: int = 4
     ssm_chunk: int = 256
-    mla_q_rank: int = 1536
+    mla_q_rank: Optional[int] = 1536
     mla_kv_rank: int = 512
     mla_nope_dim: int = 128
     mla_rope_dim: int = 64
     mla_v_dim: int = 128
     mla_rope_theta: float = 10000.0
+    mla_rotary: bool = True
     ff_types: Optional[Tuple[str, ...]] = None
     experts_total: int = 0
     experts_held: Optional[Tuple[int, int]] = None
@@ -442,7 +446,7 @@ class Transformer(nn.Module):
                 dim=self.dim, heads=self.heads, q_rank=self.mla_q_rank,
                 kv_rank=self.mla_kv_rank, nope_dim=self.mla_nope_dim,
                 rope_dim=self.mla_rope_dim, v_dim=self.mla_v_dim,
-                rope_theta=self.mla_rope_theta, eps=self.norm_eps,
+                rope_theta=self.mla_rope_theta, rotary=self.mla_rotary, eps=self.norm_eps,
                 use_flash=self.use_flash, dtype=self.dtype, param_dtype=self.param_dtype,
             )
         if kind == "gdn":
@@ -451,6 +455,12 @@ class Transformer(nn.Module):
                 value_heads=self.linattn_value_heads, key_dim=self.linattn_key_dim,
                 value_dim=self.linattn_value_dim, conv=self.linattn_conv,
                 eps=self.norm_eps, dtype=self.dtype, param_dtype=self.param_dtype,
+            )
+        if kind == "kda":
+            return KimiDeltaAttention(
+                dim=self.dim, heads=self.linattn_key_heads, head_dim=self.linattn_key_dim,
+                conv=self.linattn_conv, eps=self.norm_eps, dtype=self.dtype,
+                param_dtype=self.param_dtype,
             )
         if kind == "swa":
             return GroupedKVAttention(

@@ -1588,7 +1588,10 @@ class LatentAttention(nn.Module):
     width ``rope_dim`` is shared by all heads and rotated by position over
     adjacent channel pairs (angles, cosines and sines in float32,
     ops/rotary.py:cos_sin); query/key width ``nope_dim + rope_dim`` against
-    value width ``v_dim``.
+    value width ``v_dim``. ``q_rank`` None: the query is not compressed,
+    ``[q_nope_i | q_rope_i] = (W_q u)_i`` (``to_q``); ``rotary`` off (NoPE):
+    the ``rope_dim`` channels of the queries and of the shared key are not
+    rotated and enter the score as they are.
 
     Training route: the blocked flash kernels with a value width of their own
     (ops/flash_attention.py: nothing is padded to the query width), recorded
@@ -1598,12 +1601,13 @@ class LatentAttention(nn.Module):
 
     dim: int
     heads: int
-    q_rank: int
+    q_rank: Optional[int]
     kv_rank: int
     nope_dim: int
     rope_dim: int
     v_dim: int
     rope_theta: float = 10000.0
+    rotary: bool = True
     eps: float = 1e-6
     use_flash: bool = True
     dtype: Dtype = jnp.float32
@@ -1621,16 +1625,23 @@ class LatentAttention(nn.Module):
         norm = lambda name, t: RMSNorm(self.eps, self.param_dtype, name=name)(t).astype(self.dtype)
         heads_first = lambda t: t.transpose(0, 2, 1, 3)
 
-        c_q = norm("q_norm", dense(self.q_rank, "to_q_a")(x))
-        q = heads_first(dense(h * (dn + dr), "to_q_b")(c_q).reshape(b, n, h, dn + dr))
+        if self.q_rank is None:
+            q = dense(h * (dn + dr), "to_q")(x)
+        else:
+            c_q = norm("q_norm", dense(self.q_rank, "to_q_a")(x))
+            q = dense(h * (dn + dr), "to_q_b")(c_q)
+        q = heads_first(q.reshape(b, n, h, dn + dr))
         kv_a = dense(self.kv_rank + dr, "to_kv_a")(x)
         c_kv, k_rope = kv_a[..., : self.kv_rank], kv_a[..., self.kv_rank :]
         kv = heads_first(
             dense(h * (dn + dv), "to_kv_b")(norm("kv_norm", c_kv)).reshape(b, n, h, dn + dv)
         )
-        table = jnp.asarray(_rope_angles(n, dr, float(self.rope_theta)))
-        q = jnp.concatenate((q[..., :dn], apply_rotary_emb(table, q[..., dn:])), axis=-1)
-        k_rope = apply_rotary_emb(table, k_rope[:, None])             # (b, 1, n, dr)
+        if self.rotary:
+            table = jnp.asarray(_rope_angles(n, dr, float(self.rope_theta)))
+            q = jnp.concatenate((q[..., :dn], apply_rotary_emb(table, q[..., dn:])), axis=-1)
+            k_rope = apply_rotary_emb(table, k_rope[:, None])         # (b, 1, n, dr)
+        else:
+            k_rope = k_rope[:, None]
         k = jnp.concatenate(
             (kv[..., :dn], jnp.broadcast_to(k_rope, (b, h, n, dr))), axis=-1
         )

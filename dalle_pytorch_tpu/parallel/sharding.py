@@ -58,6 +58,15 @@ DEFAULT_RULES: Tuple[Tuple[str, P], ...] = (
     # like in_proj, out_proj is above; the 2 x heads columns of b | a stay whole
     (r"in_proj_qkvz/kernel$", P("fsdp", "tp")),
     (r"in_proj_ba/kernel$", P("fsdp", None)),
+    # the delta rule with a per-channel decay (ops/kda.py): q | k | v split
+    # their output channels like in_proj_qkvz, the per-head write strength
+    # stays whole like b | a; the low-rank gates' first factors (hidden ->
+    # head width) stay whole across tp, their second split their output
+    # channels, the log-decay's bias is a fallback vector
+    (r"in_proj_qkv/kernel$", P("fsdp", "tp")),
+    (r"in_proj_b/kernel$", P("fsdp", None)),
+    (r"(f|g)_a/kernel$", P("fsdp", None)),
+    (r"(f|g)_b/kernel$", P(None, "tp")),
     # MoE experts: expert dim over ep, hidden over tp (ops/moe.py)
     # (RoutedExperts holds the experts of its own range: the same two leaves
     # at the same places; its router, its selection bias and its count of

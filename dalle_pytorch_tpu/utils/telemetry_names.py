@@ -357,6 +357,12 @@ DEVICE_SCOPES = frozenset({
     "linattn.delta",
     "linattn.norm",
     "attn.gated",
+    # the KDA mixer (ops/kda.py:KimiDeltaAttention) runs under linattn with
+    # linattn.proj, linattn.conv and linattn.norm as above, and two of its
+    # own: the low-rank gates with the per-channel log-decay, and the L2
+    # norms with the chunked rule of the per-channel decay
+    "linattn.gate",
+    "linattn.kda",
     # the sliding-window attention layer (ops/attention.py:GroupedKVAttention
     # with a window and rotary), read with every other attn.*; its route site
     # forward/swa records the window, tiles_visited (the band's tiles the
@@ -399,6 +405,9 @@ KERNEL_NAMES = frozenset({
     "gdn_chunk_tables",     # ops/gdn.py: the gated delta rule; what a chunk computes without
     "gdn_chunk_fwd",        #   the state (the inverse), no sequential axis; the pass that
     "gdn_chunk_bwd",        #   carries the state (backward: its cotangent) in VMEM scratch
+    "kda_chunk_tables",     # ops/kda.py: the delta rule with a per-channel decay; the same
+    "kda_chunk_fwd",        #   three parts, the decayed products by sub-chunks, the
+    "kda_chunk_bwd",        #   backward's cotangent of the log-decay per channel
 })
 
 # span durations are auto-observed as "<span>_s" (utils/telemetry.py);

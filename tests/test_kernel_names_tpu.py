@@ -157,7 +157,26 @@ def _windowed(q, k, v):
     return flash_attention(q, k, v, None, True, None, 128**-0.5, 1024, 1024, False, 4096)
 
 
+# train-kimilinear-d5-ep32-s16k: 1 row of 16,384, 32 heads of 128 (keys and
+# values), chunks of 64, the decay per key channel
+KDA_N, KDA_H, KDA_D, KDA_CHUNK = 16384, 32, 128, 64
+
+
+def _kda(q, k, v, g, beta):
+    # the call of ``kda.kimi_delta_rule``, compiled not interpreted
+    from dalle_pytorch_tpu.ops import kda
+
+    g = kda.chunk_log_decay(-jnp.abs(g), KDA_CHUNK)
+    return kda.kda_chunks(q, k, v, g, jax.nn.sigmoid(beta), False)
+
+
 ROUTES = {
+    "kda_delta_rule": (
+        _kda,
+        [(1, KDA_N, KDA_H * KDA_D)] * 3 + [((1, KDA_N, KDA_H * KDA_D), F32),
+                                            ((1, KDA_H, KDA_N // KDA_CHUNK, KDA_CHUNK), F32)],
+        {"kda_chunk_tables", "kda_chunk_fwd", "kda_chunk_bwd"},
+    ),
     "delta_rule": (
         _delta,
         [(GDN_B, GDN_N, GDN_HK * GDN_D)] * 2 + [(GDN_B, GDN_N, GDN_HV * GDN_D), GDN_TABLE, GDN_TABLE],

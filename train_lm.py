@@ -8,7 +8,9 @@ with routed experts, a shared expert and a multi-token-prediction module
 (``joyai_llm_flash``), or gated-delta-rule linear attention beside gated
 softmax attention with softmax-routed experts and a gated shared expert
 (``qwen3_next``), or sliding-window attention beside global attention with
-ReGLU experts routed from the block's input (``smallthinker``) — on a folder of ``.txt`` documents packed end to end,
+ReGLU experts routed from the block's input (``smallthinker``), or the delta
+rule with a per-channel decay beside latent attention with no positional term,
+with sigmoid-routed experts (``kimi_linear``) — on a folder of ``.txt`` documents packed end to end,
 with the app surface of train_clip.py and train_dalle.py's loop
 (``parallel/loop.py``): compiled sharded train step over a dp x fsdp x tp mesh
 (``make_runtime`` → ``create_train_state`` → ``make_train_step``), one
@@ -37,7 +39,8 @@ def parse_args():
                              "model_type: hidden_size, layer_types, mamba_*, ... or "
                              "q_lora_rank, n_routed_experts, first_k_dense_replace, ... or "
                              "full_attention_interval, linear_*, num_experts, ... or "
-                             "sliding_window_layout, rope_layout, moe_num_primary_experts, ...)")
+                             "sliding_window_layout, rope_layout, moe_num_primary_experts, ... or "
+                             "linear_attn_config, mla_use_nope, num_experts_per_token, ...)")
     parser.add_argument("--image_text_folder", type=str, required=True,
                         help="folder whose .txt files are the documents (images, if any, are ignored)")
     parser.add_argument("--lm_path", type=str, default=None,
