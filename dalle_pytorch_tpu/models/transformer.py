@@ -41,7 +41,7 @@ from ..ops.moe import (
 )
 from ..ops.reversible import reversible_forward_only, reversible_sequence
 from ..ops.rotary import angles, dalle_rotary_table, lang_freqs
-from ..ops.gdn import GatedDeltaNet
+from ..ops.gdn import DELTA_RESIDUAL_NAMES, GatedDeltaNet
 from ..ops.kda import KimiDeltaAttention
 from ..ops.ssm import MambaMixer
 
@@ -112,11 +112,14 @@ def _block_checkpoint(fn, block: str = ""):
     ``remat``: everything is rebuilt in backward but the attention kernels'
     own residuals (``KERNEL_RESIDUAL_NAMES``: quadratic in the row length to
     rebuild, linear to keep), so the rebuilt forward holds no flash kernel —
-    all of its results are in memory and XLA drops the call — and an expert
+    all of its results are in memory and XLA drops the call — an expert
     layer's routing, dispatched rows and first grouped product
     (``MOE_RESIDUAL_NAMES``), so its rebuilt forward runs the second product
-    alone. A block without those layers holds none of the names. An expert
-    layer traced inside records route ``remat/moe_residuals`` for ``block``."""
+    alone, and the delta rules' ``T | P`` table (``DELTA_RESIDUAL_NAMES``), so
+    a rebuilt gated-delta-rule or KDA layer runs no state-free kernel. A block
+    without those layers holds none of the names. An expert layer traced
+    inside records route ``remat/moe_residuals`` for ``block``, a delta rule's
+    kernels route ``remat/delta_tables``."""
     kv_policy.record_route("remat/attn_residuals", "saved")
 
     def traced(*args):
@@ -125,7 +128,7 @@ def _block_checkpoint(fn, block: str = ""):
 
     return jax.checkpoint(
         traced, policy=jax.checkpoint_policies.save_only_these_names(
-            *KERNEL_RESIDUAL_NAMES, *MOE_RESIDUAL_NAMES)
+            *KERNEL_RESIDUAL_NAMES, *MOE_RESIDUAL_NAMES, *DELTA_RESIDUAL_NAMES)
     )
 
 
@@ -140,8 +143,9 @@ class Transformer(nn.Module):
     Execution modes: sequential (default), ``reversible=True`` (O(1)
     activation memory via ops/reversible.py), or ``remat=True``
     (``_block_checkpoint`` per block — recompute in backward, standard pytree
-    activations; only the flash kernels' output and log-sum-exp and an expert
-    layer's routing, rows and first grouped product are kept).
+    activations; only the flash kernels' output and log-sum-exp, an expert
+    layer's routing, rows and first grouped product and the delta rules'
+    ``T | P`` table are kept).
 
     Block variants (models/lm.py's causal language models; every DALL-E and
     CLIP configuration leaves them at their defaults, which are the block
@@ -618,7 +622,7 @@ class Transformer(nn.Module):
         if self.remat and not self.reversible:
             aux = jnp.zeros((), jnp.float32)
             for ind, ((f, g), (pf, pg), (kwf, kwg)) in enumerate(zip(fns, params, kwargs)):
-                d, a = _block_checkpoint(f)(pf, x, kwf)
+                d, a = _block_checkpoint(f, "/".join((*self.path, self._mixer_name(ind))))(pf, x, kwf)
                 if self.routed_first[ind]:
                     d, probs = d
                     kwg = dict(kwg, probs=probs)

@@ -57,12 +57,14 @@ kernels behind one ``jax.custom_vjp``, split where the state enters:
   its value heads in float32 before they are written.
 
 The forward rule's residuals beside the operands are that table and those
-states; under ``remat`` the rerun forward writes both. A tail that does not
-fill a tile is padded with chunks that neither decay nor write. Every other
-shape keeps the XLA form (``lax.scan`` over the chunks, the inverse by
-``solve_triangular``; its backward is autodiff), which is also the oracle the
-kernels are tested against. The sequential recurrence itself is the
-benchmark's plain reference (``benchmarks/reference_gdn.py``).
+states. A block's checkpoint under ``remat`` keeps the table by name
+(``DELTA_RESIDUAL_NAMES``), so the rerun forward runs ``gdn_chunk_fwd`` alone
+and writes the states again. A tail that does not fill a tile is padded with
+chunks that neither decay nor write. Every other shape keeps the XLA form
+(``lax.scan`` over the chunks, the inverse by ``solve_triangular``; its
+backward is autodiff), which is also the oracle the kernels are tested
+against. The sequential recurrence itself is the benchmark's plain reference
+(``benchmarks/reference_gdn.py``).
 
 Training and whole-sequence evaluation only: a single-token step that carries
 ``S`` and the convolution's tail is serving's (ROADMAP R13).
@@ -76,16 +78,26 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import flax.linen as nn
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import kv_policy
 from .layers import rms_norm
+from .moe import _CHECKPOINTED_BLOCK
 from .ssm import LANES, VMEM_LIMIT_BYTES, CausalConv1D, _mosaic_call, _mxu
 
 Dtype = Any
 
 HIGHEST = jax.lax.Precision.HIGHEST
+
+# the name a block's checkpoint keeps of the delta rules' kernels
+# (models/transformer.py:_block_checkpoint, beside the flash kernels'
+# KERNEL_RESIDUAL_NAMES): the state-free kernel's ``T | P`` table, here and in
+# ops/kda.py. It costs O(n C d) to rebuild and O(n 2C) to hold, and it is the
+# kernel's one result: kept, the rebuilt forward runs no tables kernel.
+# Outside a checkpoint the name lowers to nothing.
+DELTA_RESIDUAL_NAMES = ("delta_tables",)
 
 
 # ---- what the mixer computes in front of the rule, each by name ------------
@@ -557,8 +569,24 @@ def delta_rule_chunks(q, k, v, g, beta, key_heads, interpret):
     return _chunks_fwd_rule(q, k, v, g, beta, key_heads, interpret)[0]
 
 
+def kept_tables(tp):
+    """The tables kernel's result under ``DELTA_RESIDUAL_NAMES``, as the
+    residuals hold it."""
+    return checkpoint_name(tp, DELTA_RESIDUAL_NAMES[0])
+
+
+def record_kept_tables(beta, dtype):
+    """Inside a block's checkpoint: route ``remat/delta_tables`` with the block
+    and the bytes of the table it keeps, ``(b, heads, chunks x C, 2C)`` in
+    ``dtype`` for ``beta`` of ``(b, heads, chunks, C)``."""
+    block = _CHECKPOINTED_BLOCK.get()
+    if block is not None:
+        kept = beta.size * 2 * beta.shape[-1] * jnp.dtype(dtype).itemsize
+        kv_policy.record_route("remat/delta_tables", "saved", block=block, bytes=kept)
+
+
 def _chunks_fwd_rule(q, k, v, g, beta, key_heads, interpret):
-    tp = _tables_call(q, k, g, beta, key_heads=key_heads, interpret=interpret)
+    tp = kept_tables(_tables_call(q, k, g, beta, key_heads=key_heads, interpret=interpret))
     o, states = _fwd_call(q, k, v, g, beta, tp, key_heads=key_heads, interpret=interpret)
     return o, (q, k, v, g, beta, tp, states)
 
@@ -648,6 +676,7 @@ def gated_delta_rule(q, k, v, g, beta, key_heads: int, chunk: int, dtype: Dtype 
     if kernels:
         interpret = kv_policy.pallas_interpret()
         kv_policy.record_route("forward/delta_rule", "gdn_chunk", interpret)
+        record_kept_tables(beta, dtype)
         o = _per_device(
             lambda *operands: delta_rule_chunks(*operands, key_heads, interpret),
             (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta),

@@ -57,6 +57,10 @@ three kernels behind one ``jax.custom_vjp``, split where the state enters:
   first with the state's cotangent in scratch, ``T`` and ``P`` read, the
   decayed products rebuilt for ``dq``, ``dk`` and ``dG``.
 
+A block's checkpoint under ``remat`` keeps the table by the name
+``ops/gdn.py`` gives the scalar rule's (``DELTA_RESIDUAL_NAMES``): the rebuilt
+forward runs ``kda_chunk_fwd`` alone.
+
 Every other shape keeps the XLA form (``lax.scan`` over the chunks, each
 chunk's decays as a whole (C, C, d) tensor, the inverse by
 ``solve_triangular``; its backward is autodiff), which is also the oracle the
@@ -79,7 +83,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import kv_policy
-from .gdn import _col, _masks, _row, _unit_lower_inverse, l2norm, write_strength
+from .gdn import (
+    _col, _masks, _row, _unit_lower_inverse, kept_tables, l2norm, record_kept_tables, write_strength,
+)
 from .layers import rms_norm
 from .ssm import LANES, VMEM_LIMIT_BYTES, CausalConv1D, _mosaic_call, _mxu
 
@@ -473,7 +479,7 @@ def kda_chunks(q, k, v, g, beta, interpret):
 
 
 def _chunks_fwd_rule(q, k, v, g, beta, interpret):
-    tp = _tables_call(q, k, g, beta, interpret=interpret)
+    tp = kept_tables(_tables_call(q, k, g, beta, interpret=interpret))
     o, states = _fwd_call(q, k, v, g, beta, tp, interpret=interpret)
     return o, (q, k, v, g, beta, tp, states)
 
@@ -547,6 +553,7 @@ def kimi_delta_rule(q, k, v, g, beta, heads: int, chunk: int, dtype: Dtype = F32
     if kda_kernels_eligible(chunk, d_k, d_v):
         interpret = kv_policy.pallas_interpret()
         kv_policy.record_route("forward/delta_rule", "kda_chunk", interpret)
+        record_kept_tables(beta, dtype)
         o = _per_device(
             lambda *operands: kda_chunks(*operands, interpret),
             (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta),

@@ -15,7 +15,15 @@ expert families: its rebuilt forward holds one grouped product where it held
 two, and no sort and no ``top_k``; the gradients are the bare checkpoint's to
 the bit, also where the count passes the first chunk; the route
 ``remat/moe_residuals`` is recorded for each expert block and no other; a
-block without experts lowers as it did."""
+block without experts lowers as it did.
+
+And it keeps the delta rules' ``T | P`` table (ops/gdn.py:
+``DELTA_RESIDUAL_NAMES``), in a KDA stack and a gated-delta-rule stack whose
+heads are whole lane tiles, so that the rule takes its kernels: the gradient
+holds the tables kernel once a delta-rule layer where the bare checkpoint held
+it twice; the gradients are the bare checkpoint's to the bit; the route
+``remat/delta_tables`` is recorded for each delta-rule block and no other; a
+block without the kernels lowers as it did."""
 
 import collections
 import json
@@ -71,6 +79,25 @@ EXPERTS = {
     "softmax_gated_shared": rehearsal("qwen3-next-80b-a3b-d4-ep16", "benchmarks/rehearsal_gdn.json"),
     "handed_probs_reglu": rehearsal("smallthinker-21b-a3b-d4-ep4", "benchmarks/rehearsal_swa.json"),
 }
+# the delta rules at heads of 128 keys and 128 values, the kernels' smallest:
+# KDA two to one with latent attention (2 heads) and two gated-delta-rule
+# layers (a key head serving 2 value heads), each otherwise its rehearsal
+DELTA = {
+    "kda": {
+        **rehearsal("kimi-linear-48b-a3b-d5-ep32", "benchmarks/rehearsal_kda.json"),
+        "num_hidden_layers": 3,
+        "linear_attn_config": {
+            "full_attn_layers": [3], "head_dim": 128, "kda_layers": [1, 2], "num_heads": 2,
+            "short_conv_kernel_size": 4,
+        },
+    },
+    "gdn": {
+        **EXPERTS["softmax_gated_shared"], "num_hidden_layers": 2,
+        "linear_num_key_heads": 1, "linear_num_value_heads": 2,
+        "linear_key_head_dim": 128, "linear_value_head_dim": 128,
+    },
+}
+TABLES = {"kda": "kda_chunk_tables", "gdn": "gdn_chunk_tables"}
 DALLE_FULL = dict(
     dim=128, depth=DEPTH, num_text_tokens=64, text_seq_len=64, num_image_tokens=32,
     image_fmap_size=8, heads=2, dim_head=64, attn_types=("full",),
@@ -78,7 +105,7 @@ DALLE_FULL = dict(
 # family -> the attention forward kernel its layers call on one device, and
 # how many attention layers the stack has
 FWD_KERNEL = {"gqa": "flash_fwd", "mla": "flash_fwd", "full": "flash_qkv_fwd"}
-LAYERS = {"gqa": DEPTH, "mla": DEPTH + 1, "full": DEPTH}
+LAYERS = {"gqa": DEPTH, "mla": DEPTH + 1, "full": DEPTH, "kda": 2}
 
 
 def build(family, remat, **over):
@@ -89,7 +116,7 @@ def build(family, remat, **over):
         image = jax.random.randint(jax.random.key(2), (4, 64), 0, 32)
         params = model.init(jax.random.key(0), text[:1], image[:1])["params"]
         return lambda p: model.apply({"params": p}, text, image, return_loss=True), params
-    model = CausalLM.from_config({"gqa": GQA, "mla": MLA, **EXPERTS}[family], seq_len=N, remat=remat)
+    model = CausalLM.from_config({"gqa": GQA, "mla": MLA, **EXPERTS, **DELTA}[family], seq_len=N, remat=remat)
     ids = jax.random.randint(jax.random.key(1), (4, N), 0, 50)      # every vocabulary holds 50
     params = model.init(jax.random.key(0), ids)["params"]
     return lambda p: model.apply({"params": p}, ids, return_loss=True), params
@@ -116,11 +143,15 @@ def bare_checkpoint(monkeypatch):
     monkeypatch.setattr(transformer, "_block_checkpoint", lambda fn, block="": jax.checkpoint(fn))
 
 
-def attention_names_only(monkeypatch):
-    """From here on the trunk's checkpoint keeps the flash kernels' names
-    alone, a policy a block, as it did before the expert layer's names."""
+def names_only(monkeypatch, *names):
+    """From here on the trunk's checkpoint keeps ``names`` alone, a policy a block."""
     monkeypatch.setattr(transformer, "_block_checkpoint", lambda fn, block="": jax.checkpoint(
-        fn, policy=jax.checkpoint_policies.save_only_these_names(*KERNEL_RESIDUAL_NAMES)))
+        fn, policy=jax.checkpoint_policies.save_only_these_names(*names)))
+
+
+def attention_names_only(monkeypatch):
+    """The flash kernels' names alone, as before the expert layer's names."""
+    names_only(monkeypatch, *KERNEL_RESIDUAL_NAMES)
 
 
 def leaves(tree):
@@ -153,7 +184,7 @@ def test_the_bare_checkpoint_held_it_twice(family, monkeypatch):
     assert ROUTE not in kv_policy.ROUTE_LOG
 
 
-@pytest.mark.parametrize("family", ["gqa", "mla", "full"])
+@pytest.mark.parametrize("family", ["gqa", "mla", "full", *sorted(DELTA)])
 def test_gradients_are_the_bare_checkpoints_to_the_bit(family, monkeypatch):
     """The kept arrays are the ones the rebuilt forward would have produced.
     Operation by operation, not under one ``jit``: there XLA fuses the two
@@ -312,6 +343,65 @@ def test_a_block_without_experts_lowers_as_it_did(family, monkeypatch):
     assert now == text()
 
 
+@pytest.mark.parametrize("family", ["gqa", "full", *sorted(EXPERTS)])
+def test_a_block_without_a_delta_rule_kernel_lowers_as_it_did(family, monkeypatch):
+    """granite's, DALL-E's, joyai's and smallthinker's blocks, and qwen3next's
+    at its rehearsal's heads of 16 (the rule's XLA form): the gradient's jaxpr
+    under the policy with the delta rules' name is the one without it."""
+    loss, params = build(family, remat=True)
+    text = lambda: re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(jax.grad(loss))(params)))
+    now = text()
+    names_only(monkeypatch, *KERNEL_RESIDUAL_NAMES, *MOE_RESIDUAL_NAMES)
+    assert now == text()
+
+
+# ------------------------------------------------------ the delta rules' table
+
+
+def delta_blocks(family) -> set:
+    """The mixer blocks a stack checkpoints with a delta rule's kernels."""
+    model = CausalLM.from_config(DELTA[family], seq_len=N)
+    return {
+        f"transformer/mixer_{i}" for i, kind in enumerate(model.layer_types)
+        if kind in ("kda", "linear_attention")
+    }
+
+
+@pytest.mark.parametrize("family", sorted(DELTA))
+def test_the_gradient_holds_the_tables_kernel_once_a_delta_rule_layer(family, monkeypatch):
+    """Kept, the table stands in for the state-free kernel in the rebuilt
+    forward: once a layer, where the bare checkpoint ran it twice. The kernel
+    that carries the state still runs again (its states are not kept), and
+    the backward kernel once."""
+    layers = len(delta_blocks(family))
+    loss, params = build(family, remat=True)
+    kept = grad_kernel_calls(loss, params)
+    tables, fwd, bwd = (TABLES[family].replace("tables", part) for part in ("tables", "fwd", "bwd"))
+    assert (kept[tables], kept[fwd], kept[bwd]) == (layers, 2 * layers, layers), kept
+    bare_checkpoint(monkeypatch)
+    bare = grad_kernel_calls(loss, params)
+    assert (bare[tables], bare[fwd], bare[bwd]) == (2 * layers, 2 * layers, layers), bare
+
+
+@pytest.mark.parametrize("family", [*sorted(DELTA), "softmax_gated_shared", "gqa", "full"])
+def test_the_delta_route_is_recorded_for_delta_rule_blocks_and_no_other(family):
+    """One entry a delta-rule block, with the table's bytes: 4 rows x 2 heads
+    x 128 positions x 128 lanes in float32; none for a stack whose rule takes
+    its XLA form, none for a stack without the rule, and none without ``remat``."""
+    want = delta_blocks(family) if family in DELTA else set()
+    routes = lambda: [r for r in kv_policy.ROUTE_LOG if r["site"] == "remat/delta_tables"]
+    loss, params = build(family, remat=False)
+    kv_policy.ROUTE_LOG.clear()
+    jax.make_jaxpr(jax.grad(loss))(params)
+    assert routes() == []
+    loss, params = build(family, remat=True)
+    kv_policy.ROUTE_LOG.clear()
+    jax.make_jaxpr(jax.grad(loss))(params)
+    assert sorted(r["block"] for r in routes()) == sorted(want)
+    for r in routes():
+        assert r["impl"] == "saved" and r["bytes"] == 4 * 2 * N * 128 * 4
+
+
 # ------------------------------------------- several devices, and the pipeline
 
 
@@ -320,7 +410,8 @@ def test_a_block_without_experts_lowers_as_it_did(family, monkeypatch):
     ("mla", {"fsdp": 2, "tp": 2}, "flash_fwd"),
     ("full", {"fsdp": 2, "tp": 2}, "flash_fwd"),      # tp > 1: the per-head kernels
     ("full", {}, "flash_qkv_fwd"),                    # dp = 4: the packed kernel
-], ids=["gqa_fsdp2_tp2", "mla_fsdp2_tp2", "full_fsdp2_tp2", "full_dp4"])
+    ("kda", {"fsdp": 2, "tp": 2}, "kda_chunk_tables"),  # a head a device
+], ids=["gqa_fsdp2_tp2", "mla_fsdp2_tp2", "full_fsdp2_tp2", "full_dp4", "kda_fsdp2_tp2"])
 def test_the_count_holds_through_the_per_device_shard_map(family, mesh, kernel, monkeypatch):
     """The checkpoint's partial evaluation sees the names through the
     shard_map the kernels run in on a mesh of several devices."""
